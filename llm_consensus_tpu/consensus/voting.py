@@ -30,7 +30,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from llm_consensus_tpu.parallel.compat import shard_map
 
 # ---------------------------------------------------------------------------
 # Canonicalization
@@ -191,7 +190,7 @@ def _vote_reducer(mesh: Mesh, n_classes: int, axis_name: str):
 
     spec = P(axis_name)
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             tally,
             mesh=mesh,
             in_specs=(spec, spec),

@@ -151,6 +151,14 @@ class InferenceEngine:
         draft: tuple[ModelConfig, dict] | None = None,
         tracer=None,
     ):
+        # Kernel choice is observed here, once (ops.kernels): compiled
+        # Pallas on a TPU with no multi-device mesh — these kernels have
+        # no shard_map lowering — and the jnp references anywhere else.
+        from llm_consensus_tpu.ops.kernels import resolve_kernels
+
+        cfg = resolve_kernels(cfg, mesh)
+        if draft is not None:
+            draft = (resolve_kernels(draft[0], mesh), draft[1])
         self.cfg = cfg
         self.params = params
         # Optional utils.tracing.Tracer: generate calls record

@@ -69,9 +69,12 @@ class ModelConfig:
     # inference ignores them.
     moe_aux_loss_weight: float = 0.01
     moe_z_loss_weight: float = 1e-3
-    # Use the fused Pallas kernels (ops/pallas) for attention + RMSNorm on
-    # the hot path; False = pure-XLA jnp reference ops.
-    use_pallas: bool = False
+    # Fused Pallas kernels (ops/pallas) for attention + RMSNorm on the
+    # hot path, or the jnp reference ops. None = not decided yet: the
+    # engine/batcher fills it from the platform and its mesh
+    # (ops.kernels.resolve_kernels); code that traces a config nobody
+    # resolved (training, plain forward) takes the references.
+    use_pallas: bool | None = None
     # Route full/prefill attention through ring attention
     # (parallel/ring.py) when a mesh with seq > 1 is passed to
     # forward/prefill — sequence-parallel long-context support.

@@ -41,14 +41,24 @@ from llm_consensus_tpu.ops.attention import (
     chunk_decode_attention,
     decode_attention,
 )
+from llm_consensus_tpu.ops.kernels import single_device
 from llm_consensus_tpu.ops.norms import rms_norm
 from llm_consensus_tpu.ops.quant import matmul as _qmm
 from llm_consensus_tpu.ops.quant import maybe_dequantize as _w
 from llm_consensus_tpu.ops.rope import apply_rope, rope_cos_sin
 
 
-def _rms(cfg: ModelConfig, x, w):
-    if cfg.use_pallas:
+def _local_kernels(cfg: ModelConfig, mesh) -> bool:
+    """Kernels that carry no ``shard_map`` of their own (norm, the dense
+    attention family) run only where the program is not partitioned:
+    GSPMD cannot see inside a ``pallas_call`` and would gather its
+    operands. Gated on the mesh the caller was GIVEN — a one-chip engine
+    on a four-chip host keeps its kernels."""
+    return bool(cfg.use_pallas) and single_device(mesh)
+
+
+def _rms(cfg: ModelConfig, x, w, mesh=None):
+    if _local_kernels(cfg, mesh):
         from llm_consensus_tpu.ops.pallas import fused_rms_norm
 
         return fused_rms_norm(x, w, cfg.rms_norm_eps)
@@ -73,7 +83,7 @@ def _attn_causal(cfg: ModelConfig, q, k, v, positions, mesh=None):
     # The fused kernel implements index-causal masking; packed/offset
     # layouts (explicit positions) and sliding windows use the jnp path.
     if (
-        cfg.use_pallas
+        _local_kernels(cfg, mesh)
         and positions is None
         and cfg.sliding_window == 0
         and q.shape[1] % _pallas_blk(q.shape[1]) == 0
@@ -148,10 +158,7 @@ def _attn_decode_quant_stacked(
     stacked layout reads the common prefix once for the whole batch
     (the stacked-decode fallback PR 3 documented is gone).
     """
-    use_kernel = (
-        cfg.use_pallas and jax.device_count() == 1 and cfg.sliding_window == 0
-    )
-    if use_kernel:
+    if cfg.use_pallas and cfg.sliding_window == 0:
         if shared_prefix_len is not None:
             from llm_consensus_tpu.ops.pallas import (
                 flash_decode_attention_shared_prefix_q8_stacked,
@@ -184,17 +191,15 @@ def _attn_decode_quant(
 ):
     """int8-cache decode attention: the Pallas kernel reads int8 straight
     from HBM (the whole point of the quantized cache) but pallas_call is
-    opaque to GSPMD, so it is strictly opt-in via ``cfg.use_pallas`` and
-    single-device; sharded meshes take the shardable jnp dequant path.
-    (ops.quant._use_kernel auto-detects instead — its off-switch is
-    ``ops.quant.set_kernel_enabled(False)``.)
+    opaque to GSPMD, so an engine on a multi-device mesh resolves
+    ``cfg.use_pallas`` off (``ops.kernels.resolve_kernels``) and takes
+    the shardable jnp dequant path.
 
     ``shared_prefix_len``: as :func:`_attn_decode` — the two-phase
     shared-prefix kernel reads the fan-out's common prefix KV once for
     the whole batch (kernel path only; the jnp dequant path has no
     bandwidth to save and stays ungrouped)."""
-    use_kernel = cfg.use_pallas and jax.device_count() == 1
-    if use_kernel and cfg.sliding_window == 0:
+    if cfg.use_pallas and cfg.sliding_window == 0:
         if shared_prefix_len is not None:
             from llm_consensus_tpu.ops.pallas import (
                 flash_decode_attention_shared_prefix_q8,
@@ -217,12 +222,24 @@ def _attn_decode_quant(
 # ---------------------------------------------------------------------------
 
 
-def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
+def init_params(
+    cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16, *, quant_bits: int = 0
+) -> dict:
     """Random-init parameters (truncated-normal-free simple scheme:
-    normal(0, 0.02), residual projections scaled by 1/sqrt(2*n_layers))."""
+    normal(0, 0.02), residual projections scaled by 1/sqrt(2*n_layers)).
+
+    ``quant_bits`` (8 or 4; see :func:`init_params_quantized`): the
+    weights ``ops.quant.quantize_params`` covers come out already
+    quantized, generated a layer at a time."""
+    from llm_consensus_tpu.ops.quant import quant_axis
+
     keys = iter(jax.random.split(key, 16))
 
-    def normal(k, shape, scale=0.02):
+    def normal(name, shape, scale=0.02):
+        k = next(keys)
+        axis = quant_axis(name, len(shape)) if quant_bits else None
+        if axis is not None:
+            return _init_quantized_leaf(k, shape, scale, axis, quant_bits, dtype)
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
     L, D, H, Hkv, F, V = (
@@ -239,10 +256,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     blocks: dict = {
         "attn_norm": jnp.ones((L, D), dtype),
         "mlp_norm": jnp.ones((L, D), dtype),
-        "wq": normal(next(keys), (L, D, H * Dh)),
-        "wk": normal(next(keys), (L, D, Hkv * Dh)),
-        "wv": normal(next(keys), (L, D, Hkv * Dh)),
-        "wo": normal(next(keys), (L, H * Dh, D), resid_scale),
+        "wq": normal("wq", (L, D, H * Dh)),
+        "wk": normal("wk", (L, D, Hkv * Dh)),
+        "wv": normal("wv", (L, D, Hkv * Dh)),
+        "wo": normal("wo", (L, H * Dh, D), resid_scale),
     }
     if cfg.qkv_bias:
         blocks["bq"] = jnp.zeros((L, H * Dh), dtype)
@@ -250,23 +267,50 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
         blocks["bv"] = jnp.zeros((L, Hkv * Dh), dtype)
     if cfg.is_moe:
         E = cfg.n_experts
-        blocks["router"] = normal(next(keys), (L, D, E))
-        blocks["w_gate"] = normal(next(keys), (L, E, D, F))
-        blocks["w_up"] = normal(next(keys), (L, E, D, F))
-        blocks["w_down"] = normal(next(keys), (L, E, F, D), resid_scale)
+        blocks["router"] = normal("router", (L, D, E))
+        blocks["w_gate"] = normal("w_gate", (L, E, D, F))
+        blocks["w_up"] = normal("w_up", (L, E, D, F))
+        blocks["w_down"] = normal("w_down", (L, E, F, D), resid_scale)
     else:
-        blocks["w_gate"] = normal(next(keys), (L, D, F))
-        blocks["w_up"] = normal(next(keys), (L, D, F))
-        blocks["w_down"] = normal(next(keys), (L, F, D), resid_scale)
+        blocks["w_gate"] = normal("w_gate", (L, D, F))
+        blocks["w_up"] = normal("w_up", (L, D, F))
+        blocks["w_down"] = normal("w_down", (L, F, D), resid_scale)
 
     params = {
-        "embed": normal(next(keys), (V, D)),
+        "embed": normal("embed", (V, D)),
         "blocks": blocks,
         "norm_f": jnp.ones((D,), dtype),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = normal(next(keys), (D, V))
+        params["lm_head"] = normal("lm_head", (D, V))
     return params
+
+
+@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+def _init_quantized_leaf(key, shape, scale, axis, bits, dtype):
+    """One quantized weight leaf whose float form never exists whole.
+
+    A stacked leaf ([L, ...], every quantized block weight) is drawn and
+    quantized one layer at a time under ``lax.map`` with a key per
+    layer, so the live float temporary is one layer's slice — a few
+    hundred MB at 7B widths against the 3.8 GB bf16 leaf. The values
+    are therefore NOT those of ``quantize_params(init_params(...))``,
+    which draws the stack in one call; both are N(0, scale) from the
+    same key."""
+    from llm_consensus_tpu.ops.quant import quantizer
+
+    qfn = quantizer(bits)
+
+    def draw(k, shp, ax):
+        w = (jax.random.normal(k, shp, jnp.float32) * scale).astype(dtype)
+        return qfn(w, ax)
+
+    if len(shape) == 2:  # lm_head: no layer axis
+        return draw(key, shape, axis)
+    return jax.lax.map(
+        lambda k: draw(k, shape[1:], axis - 1),
+        jax.random.split(key, shape[0]),
+    )
 
 
 def param_count(params) -> int:
@@ -357,32 +401,19 @@ def program_hbm_cost(
 
 
 def init_params_quantized(
-    cfg: ModelConfig,
-    key: jax.Array,
-    *,
-    bits: int = 8,
-    dtype=jnp.bfloat16,
-    device=None,
+    cfg: ModelConfig, key: jax.Array, *, bits: int = 8, dtype=jnp.bfloat16
 ) -> dict:
-    """Init on the host CPU, quantize there, then transfer to ``device``.
+    """Random weights that reach int8/int4 without the bf16 tree ever
+    existing — on the device or anywhere else.
 
-    Peak device HBM is the *quantized* footprint, never the bf16 one.
-    ``init_params`` + ``quantize_params`` on-device would hold both copies
-    at once (~24 GB for Llama-3-8B int8) and OOM a 16 GB v5e chip; this
-    path stages through host RAM so the chip only ever sees int8/int4
-    leaves (~8.6 GB for 8B int8).
+    ``init_params`` + ``quantize_params`` holds both copies at once
+    (~22 GB for a 7B preset at int8) and cannot start on a 16 GB chip.
+    Here every quantized leaf is generated layer by layer on the
+    default device (:func:`_init_quantized_leaf`), so the peak is the
+    quantized tree plus one layer's float slice. The one random-weight
+    start-up path of the CLI and ``bench.py`` alike.
     """
-    from llm_consensus_tpu.ops.quant import quantize_params
-
-    cpu = jax.devices("cpu")[0]
-    with jax.default_device(cpu):
-        params = init_params(cfg, key, dtype=dtype)
-        params = quantize_params(params, bits=bits)
-        # Materialize on CPU before transfer so the donor buffers free.
-        params = jax.tree_util.tree_map(lambda x: x.block_until_ready(), params)
-    if device is not None:
-        params = jax.device_put(params, device)
-    return params
+    return init_params(cfg, key, dtype, quant_bits=bits)
 
 
 # ---------------------------------------------------------------------------
@@ -557,7 +588,7 @@ def _block(
     decode attention reads that region once for the whole batch via the
     shared-prefix kernels (see :func:`_attn_decode`).
     """
-    h = _rms(cfg, x, p["attn_norm"])
+    h = _rms(cfg, x, p["attn_norm"], mesh)
     q, k, v = _project_qkv(cfg, p, h)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
@@ -724,7 +755,7 @@ def _block(
         raise ValueError(mode)
 
     x = x + _qmm(attn.reshape(*x.shape[:-1], -1), p["wo"])
-    h2 = _rms(cfg, x, p["mlp_norm"])
+    h2 = _rms(cfg, x, p["mlp_norm"], mesh)
     if collect_aux:
         y, aux = _mlp(cfg, p, h2, collect_aux=True)
         return x + y, new_kv, aux
@@ -989,8 +1020,10 @@ def _run_layers_unrolled(
     return x, KVCache(k=leaves[0], v=leaves[1], length=cache.length)
 
 
-def _unembed(cfg: ModelConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
-    x = _rms(cfg, x, params["norm_f"])
+def _unembed(
+    cfg: ModelConfig, params: dict, x: jnp.ndarray, mesh=None
+) -> jnp.ndarray:
+    x = _rms(cfg, x, params["norm_f"], mesh)
     if cfg.tie_embeddings:
         return jnp.einsum(
             "...d,dv->...v",
@@ -1041,9 +1074,9 @@ def forward(
     )
     if return_moe_aux:
         x, _, aux = out
-        return _unembed(cfg, params, x), aux
+        return _unembed(cfg, params, x, mesh), aux
     x, _ = out
-    return _unembed(cfg, params, x)
+    return _unembed(cfg, params, x, mesh)
 
 
 def prefill(
@@ -1077,38 +1110,8 @@ def prefill(
     b = tokens.shape[0]
     last = jnp.clip(lengths - 1, 0, tokens.shape[1] - 1)
     x_last = x[jnp.arange(b), last]  # [B, D]
-    logits = _unembed(cfg, params, x_last)
+    logits = _unembed(cfg, params, x_last, mesh)
     return logits, cache.with_length(lengths)
-
-
-def _replicated(mesh, x, head_axis=None):
-    """Constrain ``x`` so its leading (batch/concat) axis is
-    UNSHARDED on ``mesh`` (no-op off-mesh). The fused step
-    concatenates per-row arrays along the batch axis, and XLA's SPMD
-    partitioner MISCOMPILES a concatenation along a sharded dimension
-    on this jax (observed on 0.4.37 CPU: every element comes out
-    doubled — each shard's halo contribution is summed twice).
-    De-sharding the concat axis on both operands AND the result
-    sidesteps the broken lowering (propagation from downstream
-    consumers can re-shard a pinned-input concat, so the result is
-    pinned too). ``head_axis``: keep THAT axis sharded over ``model``
-    when it divides — the attention outputs feed the row-sharded
-    ``wo`` GEMM, and fully replicating them would forfeit the TP
-    sharding of the attention→wo contraction on a real chip mesh; the
-    position/token vectors pass no head_axis (a handful of scalars
-    per row — full replication is noise next to the GEMMs)."""
-    if mesh is None:
-        return x
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    parts = [None] * x.ndim
-    if head_axis is not None:
-        mp = int(mesh.shape.get("model", 1))
-        if mp > 1 and x.shape[head_axis] % mp == 0:
-            parts[head_axis] = "model"
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, PartitionSpec(*parts))
-    )
 
 
 def ragged_mesh_shardable(cfg: ModelConfig, mesh, max_slots: int,
@@ -1274,7 +1277,7 @@ def decode_step_paged(
 
     def body(carry, layer_in):
         p, k_pool, v_pool = layer_in  # pools [n_pages, page, Hkv, Dh]
-        h = _rms(cfg, carry, p["attn_norm"])
+        h = _rms(cfg, carry, p["attn_norm"], mesh)
         q, k, v = _project_qkv(cfg, p, h)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -1285,14 +1288,14 @@ def decode_step_paged(
             groups=groups, mesh=mesh,
         )[:, None]  # [B, H, D] -> [B, 1, H, D] (seq axis restored)
         y = carry + _qmm(attn.reshape(*carry.shape[:-1], -1), p["wo"])
-        h2 = _rms(cfg, y, p["mlp_norm"])
+        h2 = _rms(cfg, y, p["mlp_norm"], mesh)
         y = y + _mlp(cfg, p, h2)
         return y, (k_pool, v_pool)
 
     x, (new_k, new_v) = jax.lax.scan(
         body, x, (params["blocks"], cache.k, cache.v)
     )
-    logits = _unembed(cfg, params, x[:, 0])
+    logits = _unembed(cfg, params, x[:, 0], mesh)
     new_cache = PagedKVCache(
         k=new_k, v=new_v, page_table=cache.page_table, length=pos + adv
     )
@@ -1352,7 +1355,7 @@ def verify_step_paged(
 
     def body(carry, layer_in):
         p, k_pool, v_pool = layer_in  # pools [n_pages, page, Hkv, Dh]
-        h = _rms(cfg, carry, p["attn_norm"])
+        h = _rms(cfg, carry, p["attn_norm"], mesh)
         q, k, v = _project_qkv(cfg, p, h)  # [B, NQ, H, Dh]
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -1363,14 +1366,14 @@ def verify_step_paged(
             mesh=mesh,
         )  # [B, NQ, H, D]
         y = carry + _qmm(attn.reshape(*carry.shape[:-1], -1), p["wo"])
-        h2 = _rms(cfg, y, p["mlp_norm"])
+        h2 = _rms(cfg, y, p["mlp_norm"], mesh)
         y = y + _mlp(cfg, p, h2)
         return y, (k_pool, v_pool)
 
     x, (new_k, new_v) = jax.lax.scan(
         body, x, (params["blocks"], cache.k, cache.v)
     )
-    logits = _unembed(cfg, params, x)  # [B, NQ, V]
+    logits = _unembed(cfg, params, x, mesh)  # [B, NQ, V]
     new_cache = PagedKVCache(
         k=new_k, v=new_v, page_table=cache.page_table, length=cache.length
     )
@@ -1427,7 +1430,7 @@ def prefill_chunk_paged(
 
     def body(carry, layer_in):
         p, k_pool, v_pool = layer_in  # pools [n_pages, page, Hkv, Dh]
-        h = _rms(cfg, carry, p["attn_norm"])
+        h = _rms(cfg, carry, p["attn_norm"], mesh)
         q, k, v = _project_qkv(cfg, p, h)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -1458,7 +1461,7 @@ def prefill_chunk_paged(
             mesh=mesh,
         )[1][None]  # out_chunk [C, H, D] -> [1, C, H, D]
         y = carry + _qmm(attn.reshape(*carry.shape[:-1], -1), p["wo"])
-        h2 = _rms(cfg, y, p["mlp_norm"])
+        h2 = _rms(cfg, y, p["mlp_norm"], mesh)
         y = y + _mlp(cfg, p, h2)
         return y, (k_pool, v_pool)
 
@@ -1518,74 +1521,39 @@ def fused_step_paged(
     c = chunk_tokens.shape[1]
     pos = cache.length  # [B] decode write positions
     chunk_pos = chunk_start + jnp.arange(c)  # [C] absolute positions
-    # Concats along the [B + C] axis go through _replicated on BOTH
-    # the operands and the result: the decode-side operands arrive
-    # data-sharded and XLA's partitioner miscompiles a concatenation
-    # along a sharded dim (see _replicated) — and sharding propagation
-    # from downstream consumers can re-shard the concat node even when
-    # its inputs are pinned, so the output is pinned too.
-    # Scatter/gather indices keep their native sharding.
-    all_pos = _replicated(
-        mesh, jnp.concatenate([_replicated(mesh, pos), chunk_pos])
-    )
+    all_pos = jnp.concatenate([pos, chunk_pos])
     x = params["embed"][
-        _replicated(
-            mesh,
-            jnp.concatenate(
-                [_replicated(mesh, tokens[:, 0]), chunk_tokens[0]]
-            ),
-        )
+        jnp.concatenate([tokens[:, 0], chunk_tokens[0]])
     ][None]  # [1, B+C, D]
     cos, sin = rope_cos_sin(
         all_pos[None], cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
     )
     pg = cache.page_size
     pages_dec = cache.page_table[jnp.arange(b), pos // pg]  # [B]
-    offs_dec = pos % pg
-    pages_ch = chunk_table[chunk_pos // pg]
-    offs_ch = chunk_pos % pg
+    pages_all = jnp.concatenate([pages_dec, chunk_table[chunk_pos // pg]])
+    offs_all = jnp.concatenate([pos % pg, chunk_pos % pg])
     tables = cache.page_table
     mlp_split = cfg.is_moe and cfg_chunk is not cfg
 
     def body(carry, layer_in):
         p, k_pool, v_pool = layer_in  # pools [n_pages, page, Hkv, Dh]
-        h = _rms(cfg, carry, p["attn_norm"])
+        h = _rms(cfg, carry, p["attn_norm"], mesh)
         q, k, v = _project_qkv(cfg, p, h)  # [1, B+C, H, Dh]
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        # TWO scatters, decode rows then the chunk lane, over DISJOINT
-        # real pages (decode writes private pages, the chunk writes
-        # positions >= chunk_start of its own table). One concatenated
-        # scatter would mix a data-sharded index vector with the
-        # chunk's replicated one, which XLA's SPMD partitioner
-        # miscompiles on a mesh (observed on jax 0.4.37 CPU: spurious
-        # writes to a page nobody indexed); split, each scatter's
-        # indices carry ONE consistent sharding and both partition
-        # correctly. Single-device bytes are unchanged (disjoint
-        # targets; only the NULL page's garbage ordering can differ).
-        k0 = k[0].astype(k_pool.dtype)
-        v0 = v[0].astype(v_pool.dtype)
-        k_pool = k_pool.at[pages_dec, offs_dec].set(k0[:b])
-        v_pool = v_pool.at[pages_dec, offs_dec].set(v0[:b])
-        k_pool = k_pool.at[pages_ch, offs_ch].set(k0[b:])
-        v_pool = v_pool.at[pages_ch, offs_ch].set(v0[b:])
+        # One scatter over DISJOINT real pages: decode rows write their
+        # private pages, the chunk writes positions >= chunk_start of
+        # its own table.
+        k_pool = k_pool.at[pages_all, offs_all].set(k[0].astype(k_pool.dtype))
+        v_pool = v_pool.at[pages_all, offs_all].set(v[0].astype(v_pool.dtype))
         attn_dec, attn_ch = _attn_paged(
             cfg, q[0, :b], q[0, b:], k_pool, v_pool, tables, pos + 1,
             chunk_table=chunk_table, chunk_start=chunk_start, groups=groups,
             mesh=mesh,
         )
-        attn = _replicated(
-            mesh,
-            jnp.concatenate(
-                [
-                    _replicated(mesh, attn_dec, head_axis=1),
-                    _replicated(mesh, attn_ch, head_axis=1),
-                ]
-            ),
-            head_axis=1,
-        )[None]  # [1, B+C, H, Dh]
+        attn = jnp.concatenate([attn_dec, attn_ch])[None]  # [1, B+C, H, Dh]
         y = carry + _qmm(attn.reshape(1, b + c, -1), p["wo"])
-        h2 = _rms(cfg, y, p["mlp_norm"])
+        h2 = _rms(cfg, y, p["mlp_norm"], mesh)
         if mlp_split:
             y = y + jnp.concatenate(
                 [_mlp(cfg, p, h2[:, :b]), _mlp(cfg_chunk, p, h2[:, b:])],
@@ -1598,7 +1566,7 @@ def fused_step_paged(
     x, (new_k, new_v) = jax.lax.scan(
         body, x, (params["blocks"], cache.k, cache.v)
     )
-    logits = _unembed(cfg, params, x[0, :b])
+    logits = _unembed(cfg, params, x[0, :b], mesh)
     hidden_chunk = x[:, b:]  # [1, C, D]
     new_cache = PagedKVCache(
         k=new_k, v=new_v, page_table=cache.page_table, length=pos + 1
@@ -1606,10 +1574,12 @@ def fused_step_paged(
     return logits, hidden_chunk, new_cache
 
 
-def unembed_one(cfg: ModelConfig, params: dict, h: jnp.ndarray) -> jnp.ndarray:
+def unembed_one(
+    cfg: ModelConfig, params: dict, h: jnp.ndarray, mesh=None
+) -> jnp.ndarray:
     """Logits [V] fp32 for ONE hidden state [D] — the final-chunk
     unembed of the chunked-prefill path (a D x V matvec, not C x V)."""
-    return _unembed(cfg, params, h[None])[0]
+    return _unembed(cfg, params, h[None], mesh)[0]
 
 
 def decode_chunk(

@@ -98,9 +98,8 @@ def load():
         lib.rt_loader_destroy.argtypes = [ctypes.c_void_p]
         lib.rt_loader_n_tokens.restype = ctypes.c_int64
         lib.rt_loader_n_tokens.argtypes = [ctypes.c_void_p]
-        if hasattr(lib, "rt_loader_skip"):  # older built libs lack it
-            lib.rt_loader_skip.restype = ctypes.c_int
-            lib.rt_loader_skip.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+        lib.rt_loader_skip.restype = ctypes.c_int
+        lib.rt_loader_skip.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         _lib = lib
         return _lib
 
@@ -265,12 +264,8 @@ class NativeLoader:
         """Discard n batches in C (checkpoint-resume fast-forward)."""
         if n <= 0:
             return
-        if hasattr(self._lib, "rt_loader_skip"):
-            if self._lib.rt_loader_skip(self._h, n) != 0:
-                raise RuntimeError("loader stopped")
-        else:  # old lib: draw-and-discard (correct, slower)
-            for _ in range(n):
-                self.next()
+        if self._lib.rt_loader_skip(self._h, n) != 0:
+            raise RuntimeError("loader stopped")
 
     def close(self) -> None:
         if getattr(self, "_h", None):

@@ -37,11 +37,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from llm_consensus_tpu.ops.kernels import interpret_default
+
 _NEG_INF = -1e30
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -107,7 +105,7 @@ def flash_causal_attention(
     if s % blk_q:
         raise ValueError(f"seq len {s} not divisible by q block {blk_q}")
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     scale = d**-0.5
 
     # [B, S, Hkv, G, D] -> per-(b, kv) programs see [blk_q, G, D] q tiles.
@@ -301,7 +299,7 @@ def flash_decode_attention_q8(
     hkv, s = k_q.shape[1], k_q.shape[2]
     g = h // hkv
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     scale = d**-0.5
 
     if 2 * hkv * s * d <= _ROW_KERNEL_MAX_KV_BYTES:
@@ -405,7 +403,7 @@ def flash_decode_attention(
     hkv = k_cache.shape[2]
     g = h // hkv
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     scale = d**-0.5
 
     q4 = q.reshape(b, 1, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(
@@ -470,7 +468,7 @@ def flash_decode_attention_q8_stacked(
     hkv, s = k_q.shape[2], k_q.shape[3]
     g = h // hkv
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     if 2 * hkv * s * d > _ROW_KERNEL_MAX_KV_BYTES:
         idx = layer
         return flash_decode_attention_q8(
@@ -646,6 +644,32 @@ def _online_fold(m_ref, l_ref, acc_ref, idx, scores, v, v_row_scale=None):
     m_ref[idx] = m_new
 
 
+def _pool_head(ref, head: int):
+    """One kv head's [pg, D] slab, in f32, from a [1, pg, Hkv, D] pool
+    block.
+
+    The pool keeps kv heads on the second-minor axis, so a head's rows
+    are a stride-Hkv walk over the block viewed as [pg * Hkv, D] — the
+    addressing of jax's own TPU ragged paged attention kernel. Mosaic
+    strides 32-bit rows only: a bf16 pool is read as uint32 words (two
+    adjacent heads of one token per word) and the wanted half is
+    shifted into an f32's high bits, which is exactly bf16 -> f32. An
+    odd head count cannot pair up and takes the plain indexed load.
+    """
+    _, pg, hkv, d = ref.shape
+    packing = 4 // ref.dtype.itemsize
+    if hkv == 1:
+        return ref[0, :, 0, :].astype(jnp.float32)
+    if packing == 1:
+        return ref.at[0].reshape(pg * hkv, d)[head::hkv, :]
+    if packing != 2 or hkv % 2:
+        return ref[0, :, head, :].astype(jnp.float32)
+    words = ref.at[0].reshape(pg * hkv, d).bitcast(jnp.uint32)
+    w = words[head // 2 :: hkv // 2, :]
+    bits = w << 16 if head % 2 == 0 else w & jnp.uint32(0xFFFF0000)
+    return pltpu.bitcast(bits, jnp.float32)
+
+
 def _ragged_kernel(
     *refs,
     scale: float,
@@ -674,12 +698,19 @@ def _ragged_kernel(
 
     ``refs`` is parsed positionally by the same static layout the
     wrapper builds: scalar prefetch ([layer?], tbl, kvlen, sstart,
-    [rep, gend]), VMEM inputs ([gid, kvlen_v?], q_dec, [q_chunk?],
+    [rep, gend]), VMEM inputs ([gid_rows, wlo_rows?], q_dec, [q_chunk?],
     [q_all?], K(+scales), V(+scales)), outputs (decode partials,
-    [chunk partials?], [group partials?]), then scratch. Row scratch is
+    [chunk out?], [group partials?]), then scratch. Row scratch is
     re-initialized at every row's first page; the group accumulator
     persists across all group programs (they run last) and is written
     once at the very last program.
+
+    Shapes are chosen for Mosaic, not for brevity: every per-head
+    quantity keeps the kv head on a LEADING axis (scratch
+    [Hkv, rows, ·], outputs [·, Hkv, rows, ·]) so a head's slab is a
+    tile-aligned view and nothing is reshaped across the (sublane,
+    lane) dims in-kernel; masks are built 2-D from iotas; queries
+    arrive already in f32.
     """
     i = 0
     if stacked:
@@ -691,7 +722,7 @@ def _ragged_kernel(
         rep_ref, gend_ref = refs[i : i + 2]
         i += 2
         del rep_ref
-        gid_ref, kvv_ref = refs[i : i + 2]
+        gid_ref, wlo_ref = refs[i : i + 2]
         i += 2
     q_dec_ref = refs[i]
     i += 1
@@ -710,8 +741,8 @@ def _ragged_kernel(
     md_ref, ld_ref, od_ref = refs[i : i + 3]
     i += 3
     if nc:
-        mc_ref, lc_ref, oc_ref = refs[i : i + 3]
-        i += 3
+        oc_ref = refs[i]
+        i += 1
     if gm:
         mg_ref, lg_ref, og_ref = refs[i : i + 3]
         i += 3
@@ -725,26 +756,24 @@ def _ragged_kernel(
     R = b + nc
     total = R + gm
 
-    def _k_head(head):
-        """This page's K slab [pg, D] (+ [1, pg] dequant row or None)."""
+    def _kv_head(ref, s_ref, head):
+        """This page's K (or V) slab [pg, D] for one kv head, plus its
+        [1, pg] dequant row (None for the pool layout)."""
         if quant:
-            kq = kq_ref[0, 0, head] if stacked else kq_ref[0, head]
-            ks = (ks_ref[0, 0, head] if stacked else ks_ref[0, head])[None, :]
-            return kq, ks
-        return k_ref[0, :, head, :], None
-
-    def _v_head(head):
-        if quant:
-            vq = vq_ref[0, 0, head] if stacked else vq_ref[0, head]
-            vs = (vs_ref[0, 0, head] if stacked else vs_ref[0, head])[None, :]
-            return vq, vs
-        return v_ref[0, :, head, :], None
+            if stacked:
+                return ref[0, 0, head], s_ref[0, 0, head : head + 1, :]
+            return ref[0, head], s_ref[0, head : head + 1, :]
+        return _pool_head(ref, head), None
 
     def _fold(idx, q, head, mask, mr, lr, ar):
-        k, ks = _k_head(head)
-        v, vs = _v_head(head)
+        if quant:
+            k, ks = _kv_head(kq_ref, ks_ref, head)
+            v, vs = _kv_head(vq_ref, vs_ref, head)
+        else:
+            k, ks = _kv_head(k_ref, None, head)
+            v, vs = _kv_head(v_ref, None, head)
         scores = jax.lax.dot_general(
-            q.astype(jnp.float32),
+            q,
             k.astype(jnp.float32),
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -756,10 +785,22 @@ def _ragged_kernel(
     # in the grid), shared by decode and chunk programs.
     @pl.when(j == 0)
     def _init_row():
-        rows = m_s.shape[0]
-        m_s[...] = jnp.full((rows, 1), _NEG_INF, jnp.float32)
-        l_s[...] = jnp.zeros((rows, 1), jnp.float32)
-        acc_s[...] = jnp.zeros((rows, d), jnp.float32)
+        m_s[...] = jnp.full(m_s.shape, _NEG_INF, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    def _causal_mask(n, qbase, lo):
+        """[n * g, pg] ragged-causal mask for n queries at absolute
+        positions qbase + i (rows (query, g)-ordered): query i sees
+        slots <= its own position — chunk_decode_attention's rule;
+        n == 1 is the classic slot < valid decode mask."""
+        row = jax.lax.broadcasted_iota(jnp.int32, (n * g, pg), 0)
+        qpos = qbase + (row // g if g > 1 else row)
+        slot = j * pg + jax.lax.broadcasted_iota(jnp.int32, (n * g, pg), 1)
+        mask = (slot <= qpos) & (slot >= lo)
+        if window > 0:
+            mask &= slot > qpos - window
+        return mask
 
     @pl.when(s < b)
     def _decode_row():
@@ -777,24 +818,10 @@ def _ragged_kernel(
 
         @pl.when(live)
         def _fold_page():
-            slot = j * pg + jax.lax.broadcasted_iota(
-                jnp.int32, (nq, 1, pg), 2
-            )
-            qpos = qbase + jax.lax.broadcasted_iota(
-                jnp.int32, (nq, 1, pg), 0
-            )
-            # Ragged causal: query i sees slots <= its own position —
-            # chunk_decode_attention's rule; nq == 1 is the classic
-            # slot < valid decode mask.
-            mask3 = (slot <= qpos) & (slot >= lo)
-            if window > 0:
-                mask3 &= slot > qpos - window
-            mask = jnp.broadcast_to(mask3, (nq, g, pg)).reshape(
-                nq * g, pg
-            )
+            mask = _causal_mask(nq, qbase, lo)
             for head in range(hkv):  # static unroll over kv heads
                 _fold(
-                    slice(head * nq * g, (head + 1) * nq * g),
+                    (head, slice(0, nq * g)),
                     q_dec_ref[0, head],
                     head,
                     mask,
@@ -819,24 +846,11 @@ def _ragged_kernel(
 
             @pl.when(live)
             def _fold_page():
-                slot = j * pg + jax.lax.broadcasted_iota(
-                    jnp.int32, (cq, 1, pg), 2
-                )
-                qpos = qbase + jax.lax.broadcasted_iota(
-                    jnp.int32, (cq, 1, pg), 0
-                )
-                # Ragged causal: chunk query i (absolute position
-                # qbase + i) sees slots <= its own — the cache so far
-                # plus the chunk itself, chunk_decode_attention's rule.
-                mask3 = (slot <= qpos) & (slot >= lo)
-                if window > 0:
-                    mask3 &= slot > qpos - window
-                mask = jnp.broadcast_to(mask3, (cq, g, pg)).reshape(
-                    cq * g, pg
-                )
+                # The cache so far plus the chunk itself.
+                mask = _causal_mask(cq, qbase, lo)
                 for head in range(hkv):  # static unroll over kv heads
                     _fold(
-                        slice(head * cq * g, (head + 1) * cq * g),
+                        (head, slice(0, cq * g)),
                         q_chunk_ref[0, head],
                         head,
                         mask,
@@ -860,33 +874,21 @@ def _ragged_kernel(
 
             @pl.when(j * pg < ge)
             def _fold_page():
-                member = gid_ref[...] == gi  # [B, 1]
-                mrow = jnp.broadcast_to(
-                    member[:, None], (b, nq, g)
-                ).reshape(b * nq * g, 1)
                 slot = j * pg + jax.lax.broadcasted_iota(
-                    jnp.int32, (1, pg), 1
+                    jnp.int32, (b * nq * g, pg), 1
                 )
                 # Every decode query sits past the shared run's end
                 # (shared pages cover prompt prefixes only), so the
                 # causal limit never binds here — mask is membership +
                 # run extent, for all nq queries alike.
-                mask = mrow & (slot < ge)
+                mask = (gid_ref[...] == gi) & (slot < ge)
                 if window > 0:
-                    # Per-member, per-query window edge: members of one
+                    # Per-member, per-query window edge (the wrapper
+                    # precomputes it per stacked row): members of one
                     # group can sit at different fills, and the nq
                     # verify queries of one member at different
                     # positions.
-                    qoff = jax.lax.broadcasted_iota(
-                        jnp.int32, (b, nq, g), 1
-                    )
-                    kvv = jnp.broadcast_to(
-                        kvv_ref[...][:, :, None], (b, nq, g)
-                    )
-                    wlo = (kvv - nq + qoff + 1 - window).reshape(
-                        b * nq * g, 1
-                    )
-                    mask &= slot >= wlo
+                    mask &= slot >= wlo_ref[...]
                 for head in range(hkv):  # static unroll over kv heads
                     _fold(
                         head, q_all_ref[head], head, mask, m2_s, l2_s, acc2_s
@@ -894,30 +896,24 @@ def _ragged_kernel(
 
     # -- writes ---------------------------------------------------------
 
+    # Slices, never [...]: the row scratch is sized for the WIDER of
+    # the chunk lane (cq) and the decode/verify lane (nq) — each lane's
+    # rows are the leading n * g of every head.
+
     @pl.when((s < b) & (j == p_per - 1))
     def _write_dec():
-        m = m_s[0 : hkv * nq * g]
-        l = l_s[0 : hkv * nq * g]
-        md_ref[0] = m
+        l = l_s[:, 0 : nq * g]
+        md_ref[0] = m_s[:, 0 : nq * g]
         ld_ref[0] = l
-        od_ref[0] = (
-            acc_s[0 : hkv * nq * g] / jnp.maximum(l, 1e-30)
-        ).reshape(hkv, nq * g, d)
+        od_ref[0] = acc_s[:, 0 : nq * g] / jnp.maximum(l, 1e-30)
 
     if nc:
 
         @pl.when((s == b) & (j == p_per - 1))
         def _write_chunk():
-            # Slice, never [...]: the scratch is sized for the WIDER of
-            # the chunk lane (cq) and the verify lane (nq) — with nq >
-            # cq the chunk's rows are the leading hkv * cq * g.
-            m = m_s[0 : hkv * cq * g]
-            l = l_s[0 : hkv * cq * g]
-            mc_ref[0] = m
-            lc_ref[0] = l
-            oc_ref[0] = (
-                acc_s[0 : hkv * cq * g] / jnp.maximum(l, 1e-30)
-            ).reshape(hkv, cq * g, d)
+            oc_ref[0] = acc_s[:, 0 : cq * g] / jnp.maximum(
+                l_s[:, 0 : cq * g], 1e-30
+            )
 
     if gm:
 
@@ -987,7 +983,7 @@ def _ragged_attention(
     R = b + nc
     total = R + gm
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     scale = d**-0.5
 
     kvlen = kv_len.astype(jnp.int32)
@@ -1037,13 +1033,21 @@ def _ragged_attention(
     inputs = []
     in_specs = []
     if gm:
-        inputs.append(gid.astype(jnp.int32).reshape(b, 1))
-        in_specs.append(pl.BlockSpec((b, 1), lambda s, j, *pf: (0, 0)))
-        inputs.append(kvlen[:b].reshape(b, 1))
-        in_specs.append(pl.BlockSpec((b, 1), lambda s, j, *pf: (0, 0)))
-    # Per-row q block rows are (nq, g)-ordered — the order the decode
-    # fold's mask reshape and the write-out both assume.
-    q4 = q_dec.reshape(b, nq, hkv, g, d)
+        # Per STACKED group row (b, nq, g)-ordered, like q_all below:
+        # its group id and its sliding-window low edge — built here so
+        # the kernel compares [rows, 1] columns and reshapes nothing.
+        rows = b * nq * g
+        qoff = jnp.tile(jnp.repeat(jnp.arange(nq, dtype=jnp.int32), g), b)
+        wlo = jnp.repeat(kvlen[:b], nq * g) - nq + qoff + 1 - window
+        for col in (jnp.repeat(gid.astype(jnp.int32), nq * g), wlo):
+            inputs.append(col.reshape(rows, 1))
+            in_specs.append(
+                pl.BlockSpec((rows, 1), lambda s, j, *pf: (0, 0))
+            )
+    # Per-row q block rows are (nq, g)-ordered — the order the fold's
+    # mask and the write-out both assume. f32 here: the kernel computes
+    # in f32 and a sub-tile bf16 block buys nothing.
+    q4 = q_dec.astype(jnp.float32).reshape(b, nq, hkv, g, d)
     inputs.append(q4.transpose(0, 2, 1, 3, 4).reshape(b, hkv, nq * g, d))
     in_specs.append(
         pl.BlockSpec(
@@ -1053,7 +1057,8 @@ def _ragged_attention(
     )
     if nc:
         inputs.append(
-            q_chunk.reshape(cq, hkv, g, d)
+            q_chunk.astype(jnp.float32)
+            .reshape(cq, hkv, g, d)
             .transpose(1, 0, 2, 3)
             .reshape(1, hkv, cq * g, d)
         )
@@ -1089,45 +1094,37 @@ def _ragged_attention(
     # (index b / index nc) absorbing the write-backs of programs that
     # own a different class's output — an output block revisited after
     # its owner moved on would otherwise land stale buffer contents.
-    def _dec_out_map3(s, j, *pf):
-        return (jnp.where(s < b, s, b), 0, 0)
-
-    def _dec_out_map4(s, j, *pf):
+    def _dec_out_map(s, j, *pf):
         return (jnp.where(s < b, s, b), 0, 0, 0)
 
+    def _out(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
     out_shapes = [
-        jax.ShapeDtypeStruct((b + 1, hkv * nq * g, 1), jnp.float32),
-        jax.ShapeDtypeStruct((b + 1, hkv * nq * g, 1), jnp.float32),
-        jax.ShapeDtypeStruct((b + 1, hkv, nq * g, d), jnp.float32),
+        _out(b + 1, hkv, nq * g, 1),
+        _out(b + 1, hkv, nq * g, 1),
+        _out(b + 1, hkv, nq * g, d),
     ]
     out_specs = [
-        pl.BlockSpec((1, hkv * nq * g, 1), _dec_out_map3),
-        pl.BlockSpec((1, hkv * nq * g, 1), _dec_out_map3),
-        pl.BlockSpec((1, hkv, nq * g, d), _dec_out_map4),
+        pl.BlockSpec((1, hkv, nq * g, 1), _dec_out_map),
+        pl.BlockSpec((1, hkv, nq * g, 1), _dec_out_map),
+        pl.BlockSpec((1, hkv, nq * g, d), _dec_out_map),
     ]
     if nc:
-
-        def _chunk_out_map3(s, j, *pf):
-            return (jnp.where(s == b, 0, 1), 0, 0)
-
-        def _chunk_out_map4(s, j, *pf):
-            return (jnp.where(s == b, 0, 1), 0, 0, 0)
-
-        out_shapes += [
-            jax.ShapeDtypeStruct((2, hkv * cq * g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((2, hkv * cq * g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((2, hkv, cq * g, d), jnp.float32),
-        ]
-        out_specs += [
-            pl.BlockSpec((1, hkv * cq * g, 1), _chunk_out_map3),
-            pl.BlockSpec((1, hkv * cq * g, 1), _chunk_out_map3),
-            pl.BlockSpec((1, hkv, cq * g, d), _chunk_out_map4),
-        ]
+        # The chunk lane never meets a group partial, so only its
+        # normalized output leaves the kernel.
+        out_shapes.append(_out(2, hkv, cq * g, d))
+        out_specs.append(
+            pl.BlockSpec(
+                (1, hkv, cq * g, d),
+                lambda s, j, *pf: (jnp.where(s == b, 0, 1), 0, 0, 0),
+            )
+        )
     if gm:
         out_shapes += [
-            jax.ShapeDtypeStruct((hkv, b * nq * g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((hkv, b * nq * g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((hkv, b * nq * g, d), jnp.float32),
+            _out(hkv, b * nq * g, 1),
+            _out(hkv, b * nq * g, 1),
+            _out(hkv, b * nq * g, d),
         ]
         out_specs += [
             pl.BlockSpec((hkv, b * nq * g, 1), lambda s, j, *pf: (0, 0, 0)),
@@ -1137,9 +1134,9 @@ def _ragged_attention(
 
     qs = max(nq, cq if nc else 1)
     scratch = [
-        pltpu.VMEM((hkv * qs * g, 1), jnp.float32),
-        pltpu.VMEM((hkv * qs * g, 1), jnp.float32),
-        pltpu.VMEM((hkv * qs * g, d), jnp.float32),
+        pltpu.VMEM((hkv, qs * g, 1), jnp.float32),
+        pltpu.VMEM((hkv, qs * g, 1), jnp.float32),
+        pltpu.VMEM((hkv, qs * g, d), jnp.float32),
     ]
     if gm:
         scratch += [
@@ -1200,7 +1197,7 @@ def _ragged_attention(
         out_dec = out_dec[:, 0]
     if not nc:
         return out_dec
-    oc = outs[5][0]  # [Hkv, cq*G, D]
+    oc = outs[3][0]  # [Hkv, cq*G, D]
     out_chunk = (
         oc.reshape(hkv, cq, g, d)
         .transpose(1, 0, 2, 3)
@@ -1324,8 +1321,6 @@ def ragged_paged_attention_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    from llm_consensus_tpu.parallel.compat import shard_map
-
     has_chunk = q_chunk is not None
     has_groups = groups is not None
     q_spec = (
@@ -1415,8 +1410,19 @@ def ragged_paged_attention_sharded(
             interpret=interpret,
         )
 
-    return shard_map(
-        fn, mesh, in_specs=tuple(in_specs), out_specs=out_specs
+    # check_vma=False: with the check on, jax 0.9.0 wants ``vma=`` on
+    # the pallas_call's out_shapes — and then its Pallas interpreter
+    # (the CPU tests' path) fails the same check on itself, slicing
+    # scalar-prefetch operands that vary over ``data`` with grid indices
+    # that do not. Nothing here relies on replication tracking: every
+    # output is declared varying by ``out_specs`` and the one collective
+    # is the explicit psum above.
+    return jax.shard_map(
+        fn,
+        mesh=mesh,
+        in_specs=tuple(in_specs),
+        out_specs=out_specs,
+        check_vma=False,
     )(*args)
 
 

@@ -14,6 +14,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from llm_consensus_tpu.ops.kernels import interpret_default
+
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[:].astype(jnp.float32)  # [blk, D]
@@ -32,7 +34,7 @@ def fused_rms_norm(
 ) -> jnp.ndarray:
     """RMSNorm over the last axis. x: [..., D]; weight: [D]."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     orig_shape = x.shape
     d = orig_shape[-1]
     x2 = x.reshape(-1, d)

@@ -1,0 +1,203 @@
+"""Kernel-vs-reference comparisons, one body for two callers.
+
+The CPU tests call these at toy shapes with ``interpret=True``;
+``chip_smoke.py`` calls them at the smoke model's shapes with
+``interpret=False``, which is the only thing that shows a kernel lowers
+through Mosaic. Each function builds seeded inputs, runs the kernel and
+its ``jax.numpy`` reference (under ``default_matmul_precision
+("highest")`` — on a TPU an f32 matmul is otherwise bf16) and returns
+the largest absolute error; :func:`check` turns that into a pass/fail
+against the tolerance written beside it.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+#: Attention outputs are bf16 (8 significand bits) convex combinations of
+#: unit-normal V rows, |out| <~ 4: one rounding of either side is up to
+#: 2^-8 * 4 = 1.6e-2; the online softmax's reassociation is far below it.
+ATTENTION_TOL = 2e-2
+#: RMSNorm on f32 input is f32 end to end in kernel and reference.
+NORM_TOL = 2e-5
+#: On bf16 input both compute in f32 and round once: |out| <~ 5 with
+#: unit-normal rows and weights near 1, so one bf16 ulp is 2^-8 * 4.
+NORM_BF16_TOL = 1.6e-2
+#: int8 matmul with an f32 result: bf16 x int8 products are exact in
+#: f32 on both sides, so the two differ by summation order alone —
+#: K * eps_f32 * sum|terms| ~ 1e-4 at K = 14336 with O(1) outputs. A
+#: kernel that rounded x or the accumulator to bf16 would miss by 1e-2.
+QUANT_MATMUL_TOL = 1e-3
+
+
+def _finite(got) -> np.ndarray:
+    got = np.asarray(got, np.float32)
+    if not np.isfinite(got).all():
+        raise AssertionError("kernel output has non-finite values")
+    return got
+
+
+def max_err(got, want) -> float:
+    got = _finite(got)
+    want = np.asarray(want, np.float32)
+    if got.shape != want.shape:
+        raise AssertionError(f"shape {got.shape} != reference {want.shape}")
+    return float(np.max(np.abs(got - want))) if got.size else 0.0
+
+
+def check(name: str, err: float, tol: float) -> float:
+    if not err <= tol:
+        raise AssertionError(f"{name}: max |kernel - reference| {err:.3e} > {tol}")
+    return err
+
+
+def ragged_attention_error(
+    *,
+    seed: int,
+    pg: int,
+    hkv: int,
+    g: int,
+    d: int,
+    p_per: int,
+    n_pages: int,
+    valid_len: list[int],
+    nq: int = 1,
+    cq: int = 0,
+    chunk_start: int = 0,
+    group_rows: tuple[int, ...] = (),
+    shared_pages: int = 1,
+    window: int = 0,
+    null_tables: bool = False,
+    interpret: bool | None = None,
+) -> dict[str, float]:
+    """Ragged paged attention kernel vs
+    :func:`~llm_consensus_tpu.ops.attention.ragged_paged_attention_reference`.
+
+    ``valid_len``: tokens readable per decode row (mid-page fills are
+    the interesting ones). ``nq`` > 1: verify rows. ``cq`` > 0: one
+    prefill-chunk row of cq queries from ``chunk_start``. ``group_rows``:
+    these rows share their first ``shared_pages`` pages and ride the
+    kernel's group phase (the reference has no groups — grouped output
+    must equal ungrouped math). ``null_tables``: all-NULL decode tables
+    (an idle batcher's rows next to a live chunk); their output only has
+    to be finite. Returns {"decode": err[, "chunk": err]}.
+    """
+    from llm_consensus_tpu.ops.attention import (
+        ragged_paged_attention_reference,
+    )
+    from llm_consensus_tpu.ops.pallas.attention import ragged_paged_attention
+
+    rng = np.random.default_rng(seed)
+    b, h = len(valid_len), hkv * g
+    kp = jnp.asarray(rng.standard_normal((n_pages, pg, hkv, d)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((n_pages, pg, hkv, d)), jnp.bfloat16)
+    q_shape = (b, h, d) if nq == 1 else (b, nq, h, d)
+    q = jnp.asarray(rng.standard_normal(q_shape), jnp.bfloat16)
+    perm = rng.permutation(np.arange(1, n_pages))
+    tbl = np.asarray(perm[: b * p_per].reshape(b, p_per), np.int32)
+    if null_tables:
+        tbl[:] = 0
+    groups = None
+    if group_rows:
+        rep = group_rows[0]
+        for r in group_rows[1:]:
+            tbl[r, :shared_pages] = tbl[rep, :shared_pages]
+        shared = shared_pages * pg
+        member = np.isin(np.arange(b), group_rows)
+        groups = (
+            jnp.asarray(np.where(member, 0, -1), jnp.int32),  # group_id
+            jnp.asarray([rep], jnp.int32),  # group_rep
+            jnp.asarray([shared], jnp.int32),  # group_end (tokens)
+            jnp.asarray(np.where(member, shared, 0), jnp.int32),
+        )
+    vl = jnp.asarray(valid_len, jnp.int32)
+    kw: dict = {"window": window}
+    if cq:
+        kw.update(
+            q_chunk=jnp.asarray(
+                rng.standard_normal((cq, h, d)), jnp.bfloat16
+            ),
+            chunk_table=jnp.asarray(
+                perm[b * p_per : (b + 1) * p_per], jnp.int32
+            ),
+            chunk_start=jnp.int32(chunk_start),
+        )
+    got = jax.jit(
+        lambda: ragged_paged_attention(
+            q, kp, vp, jnp.asarray(tbl), vl, groups=groups,
+            interpret=interpret, **kw,
+        )
+    )()
+    with jax.default_matmul_precision("highest"):
+        ref = ragged_paged_attention_reference(
+            q, kp, vp, jnp.asarray(tbl), vl, **kw
+        )
+    if not cq:
+        return {"decode": max_err(got, ref)}
+    if null_tables:
+        _finite(got[0])  # all a dead row owes
+        return {"chunk": max_err(got[1], ref[1])}
+    return {"decode": max_err(got[0], ref[0]), "chunk": max_err(got[1], ref[1])}
+
+
+def rms_norm_error(
+    *, seed: int, shape: tuple[int, ...], dtype=jnp.float32, eps: float = 1e-5,
+    blk: int = 256, interpret: bool | None = None,
+) -> float:
+    """``fused_rms_norm`` vs :func:`~llm_consensus_tpu.ops.norms.rms_norm`."""
+    from llm_consensus_tpu.ops.norms import rms_norm
+    from llm_consensus_tpu.ops.pallas.norms import fused_rms_norm
+
+    kx, kw = jax.random.split(jax.random.PRNGKey(seed))
+    x = jax.random.normal(kx, shape, jnp.float32).astype(dtype)
+    w = (jax.random.normal(kw, shape[-1:]) * 0.1 + 1.0).astype(dtype)
+    got = jax.jit(
+        lambda: fused_rms_norm(x, w, eps, blk=blk, interpret=interpret)
+    )()
+    if got.dtype != x.dtype:
+        raise AssertionError(f"output dtype {got.dtype} != input {x.dtype}")
+    return max_err(got, rms_norm(x, w, eps))
+
+
+def quant_matmul_error(
+    *, seed: int, m: int, k: int, n: int, n_layers: int = 0,
+    interpret: bool | None = None,
+) -> float:
+    """The int8 weight matmul kernel vs dequantize + ``jnp`` dot.
+
+    ``n_layers`` > 0 runs the stacked variant (layer index by scalar
+    prefetch) against the same reference on that layer's slice.
+    """
+    from llm_consensus_tpu.ops.pallas.quant_matmul import (
+        quant_matmul_2d,
+        quant_matmul_stacked,
+    )
+
+    key = jax.random.PRNGKey(seed)
+    lead = (n_layers,) if n_layers else ()
+    w = jax.random.randint(key, (*lead, k, n), -127, 128, jnp.int8)
+    # Scales that keep outputs O(1) at K in the thousands.
+    s = jnp.abs(jax.random.normal(key, (*lead, 1, n), jnp.float32)) * 1e-4 + 5e-5
+    x = jax.random.normal(jax.random.fold_in(key, 1), (m, k), jnp.bfloat16)
+    if n_layers:
+        layer = n_layers - 1
+        got = jax.jit(
+            lambda: quant_matmul_stacked(
+                x, w, s, jnp.int32(layer), out_dtype=jnp.float32,
+                interpret=interpret,
+            )
+        )()
+        w, s = w[layer], s[layer]
+    else:
+        got = jax.jit(
+            lambda: quant_matmul_2d(
+                x, w, s, out_dtype=jnp.float32, interpret=interpret
+            )
+        )()
+    with jax.default_matmul_precision("highest"):
+        ref = jnp.dot(
+            x.astype(jnp.float32), w.astype(jnp.float32)
+        ) * s
+    return max_err(got, ref)

@@ -24,9 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+from llm_consensus_tpu.ops.kernels import interpret_default
 
 
 def _pick_block(n: int, target: int = 512, align: int = 128) -> int | None:
@@ -78,7 +76,7 @@ def quant_matmul_2d(
             f"N={n} (K={k}) has no 128-aligned block within the VMEM budget"
         )
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     out_dtype = out_dtype or x.dtype
 
     return pl.pallas_call(
@@ -97,11 +95,14 @@ def quant_matmul_2d(
     )(x.astype(jnp.bfloat16), w_q, scale.astype(jnp.float32))
 
 
-# VMEM budget heuristic (~16 MB/core): x + one int8 weight tile
-# (double-buffered by the grid pipeline) + out block must fit.
+# VMEM budget: no kernel here raises Mosaic's default scoped limit
+# (16 MiB on a v5e). Live at once: x and the int8 weight tile, each
+# double-buffered by the grid pipeline, plus the tile's bf16 copy (twice
+# its bytes) — 2 * x + 4 * tile. The caps below bound that at 6 + 8 =
+# 14 MiB, leaving the out block and the compiler's scratch their room.
 _MAX_M = 256
-_MAX_X_BYTES = 4 * 1024 * 1024
-_MAX_W_TILE_BYTES = 4 * 1024 * 1024  # int8 K x blk_n, x2 for double-buffer
+_MAX_X_BYTES = 3 * 1024 * 1024
+_MAX_W_TILE_BYTES = 2 * 1024 * 1024  # int8 K x blk_n
 
 
 def _blk_target(k: int) -> int:
@@ -177,7 +178,7 @@ def quant4_matmul_2d(
             f"N={n} (K={k}) has no 128-aligned block within the VMEM budget"
         )
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     out_dtype = out_dtype or x.dtype
 
     return pl.pallas_call(
@@ -268,7 +269,7 @@ def quant_matmul_stacked(
             f"N={n} (K={k}) has no 128-aligned block within the VMEM budget"
         )
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     out_dtype = out_dtype or x.dtype
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

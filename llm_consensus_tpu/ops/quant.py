@@ -16,10 +16,12 @@ bf16 masters).
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field, replace
 
 import jax
 import jax.numpy as jnp
+
+from llm_consensus_tpu.ops.kernels import on_tpu
 
 # Weight leaves that get quantized, with the axis index of the
 # *contraction* (input) dimension in the stacked [L, ...] layout from
@@ -45,6 +47,10 @@ class QuantizedTensor:
 
     q: jnp.ndarray  # int8, same shape as the original weight
     scale: jnp.ndarray  # float32, original shape with contraction dim = 1
+    # Placed on a multi-device mesh (parallel.partitioning.shard_params
+    # sets it): the matmul belongs to GSPMD, which cannot partition a
+    # pallas_call, so the fused kernel stays off for this weight.
+    gspmd: bool = field(default=False, metadata=dict(static=True))
 
     @property
     def shape(self):
@@ -85,6 +91,7 @@ class Quantized4Tensor:
 
     q: jnp.ndarray  # int8 carrying 2x int4; contraction dim halved
     scale: jnp.ndarray  # float32, logical shape with contraction dim = 1
+    gspmd: bool = field(default=False, metadata=dict(static=True))
 
     @property
     def shape(self):  # logical (unpacked) shape
@@ -142,10 +149,8 @@ def maybe_dequantize(leaf, dtype=jnp.bfloat16):
     return leaf
 
 
-# Kernel override: None = auto (kernel on single-chip TPU); True/False
-# forces. Settable by tests and by bench.py's no-Pallas/fallback modes —
-# without it a quant_matmul lowering regression would be unreachable by
-# any fallback (this is the only gate on the kernel).
+# Kernel override: None = observe (kernel on a TPU for weights GSPMD does
+# not partition); True/False forces. For tests and bench.py --no-pallas.
 _FORCE_KERNEL: bool | None = None
 
 
@@ -170,21 +175,17 @@ def set_kernel4_enabled(enabled: bool) -> None:
     _FORCE_KERNEL4 = enabled
 
 
-def _use_kernel4() -> bool:
-    return (
-        _FORCE_KERNEL4
-        and jax.default_backend() == "tpu"
-        and jax.device_count() == 1
-    )
+def _use_kernel4(leaf: Quantized4Tensor) -> bool:
+    return _FORCE_KERNEL4 and on_tpu() and not leaf.gspmd
 
 
-def _use_kernel() -> bool:
+def _use_kernel(leaf: QuantizedTensor) -> bool:
     if _FORCE_KERNEL is not None:
         return _FORCE_KERNEL
-    # Single-chip TPU only: pallas_call is opaque to GSPMD, so on a
-    # multi-device mesh the kernel would force TP/EP-sharded weights to
-    # be all-gathered — the XLA dequant fallback shards fine there.
-    return jax.default_backend() == "tpu" and jax.device_count() == 1
+    # pallas_call is opaque to GSPMD: on a weight sharded over a mesh
+    # the kernel would force an all-gather of it — the XLA dequant path
+    # shards fine there.
+    return on_tpu() and not leaf.gspmd
 
 
 def _try_kernel_matmul(x, leaf, out_dtype):
@@ -196,7 +197,7 @@ def _try_kernel_matmul(x, leaf, out_dtype):
     if leaf.q.ndim != 2:
         return None
     if isinstance(leaf, QuantizedTensor):
-        if not _use_kernel():
+        if not _use_kernel(leaf):
             return None
         from llm_consensus_tpu.ops.pallas.quant_matmul import (
             quant_matmul_2d as kernel,
@@ -207,7 +208,7 @@ def _try_kernel_matmul(x, leaf, out_dtype):
 
         k = leaf.q.shape[0]
     else:
-        if not _use_kernel4():
+        if not _use_kernel4(leaf):
             return None
         from llm_consensus_tpu.ops.pallas.quant_matmul import (
             quant4_matmul_2d as kernel,
@@ -246,7 +247,8 @@ class StackedQuant:
     layer: jnp.ndarray  # traced scalar int32
 
     def sliced(self) -> QuantizedTensor:
-        return QuantizedTensor(
+        return replace(
+            self.full,
             q=jax.lax.dynamic_index_in_dim(
                 self.full.q, self.layer, 0, keepdims=False
             ),
@@ -257,7 +259,7 @@ class StackedQuant:
 
 
 def _try_kernel_matmul_stacked(x, leaf: StackedQuant, out_dtype):
-    if not _use_kernel():
+    if not _use_kernel(leaf.full):
         return None
     from llm_consensus_tpu.ops.pallas.quant_matmul import (
         quant_matmul_stacked,
@@ -322,26 +324,39 @@ def quantize_params(
     amax/127) or 4 (packed int4, amax/7 — half the HBM bytes again at
     reduced precision).
     """
-    if bits not in (8, 4):
-        raise ValueError(f"bits must be 8 or 4, got {bits}")
-    qfn = quantize_tensor if bits == 8 else quantize_tensor4
+    qfn = quantizer(bits)
     qtypes = (QuantizedTensor, Quantized4Tensor)
     out = dict(params)
     blocks = dict(params["blocks"])
     for name, w in blocks.items():
-        axes = (
-            _QUANT_AXES_MOE
-            if (name in _QUANT_AXES_MOE and w.ndim == 4)
-            else _QUANT_AXES_DENSE
-        )
-        if name in axes and not isinstance(w, qtypes):
-            blocks[name] = qfn(w, axes[name])
+        axis = quant_axis(name, w.ndim)
+        if axis is not None and not isinstance(w, qtypes):
+            blocks[name] = qfn(w, axis)
     out["blocks"] = blocks
     if quantize_lm_head and "lm_head" in params and not isinstance(
         params["lm_head"], qtypes
     ):
-        out["lm_head"] = qfn(params["lm_head"], axis=0)
+        out["lm_head"] = qfn(params["lm_head"], quant_axis("lm_head", 2))
     return out
+
+
+def quantizer(bits: int):
+    """``quantize_tensor`` (8) or ``quantize_tensor4`` (4)."""
+    if bits not in (8, 4):
+        raise ValueError(f"bits must be 8 or 4, got {bits}")
+    return quantize_tensor if bits == 8 else quantize_tensor4
+
+
+def quant_axis(name: str, ndim: int) -> int | None:
+    """Contraction axis of the parameter leaf ``name`` (``init_params``
+    layout, rank ``ndim``) when weight-only quantization covers it, else
+    None — the one rule :func:`quantize_params` and the quantizing init
+    (``models.transformer.init_params_quantized``) share."""
+    if name == "lm_head":
+        return 0
+    if name in _QUANT_AXES_MOE and ndim == 4:
+        return _QUANT_AXES_MOE[name]
+    return _QUANT_AXES_DENSE.get(name)
 
 
 def quantized_bytes(params) -> int:

@@ -71,14 +71,11 @@ def initialize_distributed(config: DistributedConfig | None = None) -> bool:
     config and no env hints on a single machine this is a no-op returning
     False — safe to call unconditionally at program start.
     """
-    from llm_consensus_tpu.parallel.compat import distributed_is_initialized
-
     config = config or DistributedConfig.from_env()
     # NOTE: must not touch jax.devices()/process_count() before
     # jax.distributed.initialize() — any backend-initializing call makes
-    # the real initialize raise. The initialized check is safe (compat:
-    # jaxes without is_initialized() read the client global state).
-    if distributed_is_initialized():
+    # the real initialize raise. The initialized check is safe.
+    if jax.distributed.is_initialized():
         return jax.process_count() > 1
     explicit = config.coordinator_address or config.num_processes
     if not explicit and not _on_cloud_tpu():

@@ -106,10 +106,26 @@ def batch_pspec() -> P:
 
 
 def shard_params(params, mesh: Mesh):
-    """Place a param tree on the mesh per :func:`param_pspecs`."""
+    """Place a param tree on the mesh per :func:`param_pspecs`.
+
+    On a multi-device mesh quantized leaves are marked ``gspmd``: their
+    matmuls are the partitioner's, so the fused Pallas kernel (which it
+    cannot see into) stays off for them (``ops.quant``)."""
+    from dataclasses import replace
+
+    from llm_consensus_tpu.ops.quant import Quantized4Tensor, QuantizedTensor
+
     specs = param_pspecs(params)
-    return jax.tree_util.tree_map(
+    placed = jax.tree_util.tree_map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), params, specs
+    )
+    if mesh.size == 1:
+        return placed
+    qtypes = (QuantizedTensor, Quantized4Tensor)
+    return jax.tree_util.tree_map(
+        lambda x: replace(x, gspmd=True) if isinstance(x, qtypes) else x,
+        placed,
+        is_leaf=lambda x: isinstance(x, qtypes),
     )
 
 

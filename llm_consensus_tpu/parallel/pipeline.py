@@ -37,7 +37,6 @@ from jax.sharding import PartitionSpec as P
 from llm_consensus_tpu.models.configs import ModelConfig
 from llm_consensus_tpu.models.transformer import _block, _unembed
 from llm_consensus_tpu.ops.rope import rope_cos_sin
-from llm_consensus_tpu.parallel.compat import pcast_varying, shard_map
 from llm_consensus_tpu.parallel.partitioning import param_pspecs
 
 
@@ -88,18 +87,12 @@ def _pipeline_logits_local(
     n_stages: int,
     n_micro: int,
     remat: bool,
-    stage: jnp.ndarray,  # scalar int32: this shard's pipe index
+    stage: jnp.ndarray,  # scalar int32: jax.lax.axis_index("pipe")
     params: dict,
     tokens_mb: jnp.ndarray,  # [M, mb, S] local shard (mb = B/M/dp)
 ) -> jnp.ndarray:
     """Inside-shard_map pipeline: returns logits [M, mb, S, V] (valid on
-    the last stage; garbage elsewhere — callers must mask by stage).
-
-    ``stage`` rides in as a ``P("pipe")``-sharded input instead of
-    ``jax.lax.axis_index``: under partial-auto shard_map the axis_index
-    lowering emits a ``PartitionId`` op the SPMD partitioner refuses on
-    jaxes predating the ``axis_names`` API (and on XLA:CPU generally) —
-    a sharded iota carries the same information with no such op."""
+    the last stage; garbage elsewhere — callers must mask by stage)."""
     m, mb, s = tokens_mb.shape
 
     x_mb = params["embed"][tokens_mb]  # [M, mb, S, D] — embed per stage
@@ -128,8 +121,8 @@ def _pipeline_logits_local(
     # The carry becomes pipe-varying after the first ppermute; mark the
     # (replicated) zero initials as varying so the scan carry type is
     # stable under shard_map's VMA check.
-    state0 = pcast_varying(jnp.zeros_like(x_mb[0]), ("pipe",))
-    out0 = pcast_varying(jnp.zeros_like(x_mb), ("pipe",))
+    state0 = jax.lax.pcast(jnp.zeros_like(x_mb[0]), ("pipe",), to="varying")
+    out0 = jax.lax.pcast(jnp.zeros_like(x_mb), ("pipe",), to="varying")
     (_, out), _ = jax.lax.scan(
         tick, (state0, out0), jnp.arange(m + n_stages - 1)
     )
@@ -162,8 +155,8 @@ def make_pipeline_forward(
         _check_microbatching(b, m, mesh)
         tokens_mb = tokens.reshape(m, b // m, s)
 
-        def f(stage_ids, params, tokens_mb):
-            stage = stage_ids[0]
+        def f(params, tokens_mb):
+            stage = jax.lax.axis_index("pipe")
             logits = _pipeline_logits_local(
                 cfg, n_stages, m, remat, stage, params, tokens_mb
             )
@@ -172,17 +165,13 @@ def make_pipeline_forward(
             logits = jnp.where(stage == n_stages - 1, logits, 0.0)
             return jax.lax.psum(logits, "pipe")
 
-        logits_mb = shard_map(
+        logits_mb = jax.shard_map(
             f,
             mesh=mesh,
-            in_specs=(
-                P("pipe"),
-                _param_in_specs(params),
-                P(None, "data", None),
-            ),
+            in_specs=(_param_in_specs(params), P(None, "data", None)),
             out_specs=P(None, "data"),
             axis_names={"data", "pipe"},
-        )(jnp.arange(n_stages, dtype=jnp.int32), params, tokens_mb)
+        )(params, tokens_mb)
         return logits_mb.reshape(b, s, -1)
 
     return jax.jit(run)
@@ -221,8 +210,8 @@ def pipeline_causal_lm_loss(
     tokens_mb = tokens.reshape(m, b // m, s)
     mask_mb = loss_mask.reshape(m, b // m, s)
 
-    def f(stage_ids, params, tokens_mb, mask_mb):
-        stage = stage_ids[0]
+    def f(params, tokens_mb, mask_mb):
+        stage = jax.lax.axis_index("pipe")
         logits = _pipeline_logits_local(
             cfg, n_stages, m, remat, stage, params, tokens_mb
         )  # [M, mb, S, V]
@@ -237,18 +226,17 @@ def pipeline_causal_lm_loss(
         mask_sum = jax.lax.psum(mask_sum, ("data", "pipe"))
         return nll_sum / jnp.maximum(mask_sum, 1.0)
 
-    return shard_map(
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=(
-            P("pipe"),
             _param_in_specs(params),
             P(None, "data", None),
             P(None, "data", None),
         ),
         out_specs=P(),
         axis_names={"data", "pipe"},
-    )(jnp.arange(n_stages, dtype=jnp.int32), params, tokens_mb, mask_mb)
+    )(params, tokens_mb, mask_mb)
 
 
 def make_pipeline_train_step(cfg, tcfg, mesh: Mesh, n_microbatches: int):

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from llm_consensus_tpu.parallel.compat import pcast_varying, shard_map
 
 _NEG_INF = -1e30
 
@@ -59,7 +58,9 @@ def ring_attention(
     # The accumulators are per-shard state, varying over the ring axis —
     # mark them so the scan carry type matches its updated value.
     def _varying(x):
-        return pcast_varying(x, varying_axes or (axis_name,))
+        return jax.lax.pcast(
+            x, tuple(varying_axes or (axis_name,)), to="varying"
+        )
 
     m0 = _varying(jnp.full((b, hkv, g, s), _NEG_INF, jnp.float32))
     l0 = _varying(jnp.zeros((b, hkv, g, s), jnp.float32))
@@ -144,7 +145,7 @@ def ring_attention_sharded(
         head_ax = "model"
     spec = P(batch_ax, axis_name, head_ax, None)
     varying = tuple(a for a in (batch_ax, axis_name, head_ax) if a)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(
             ring_attention,
             axis_name=axis_name,

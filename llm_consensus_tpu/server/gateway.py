@@ -537,6 +537,13 @@ class Gateway:
             reason = "serving loop dead"
             if wedged:
                 reason = f"serving loop dead (replicas {wedged})"
+            # A worker that died of an exception says which one — on
+            # its own heartbeat, or on the replica entries'.
+            failed = [
+                h["failed"] for h in (hb, *replicas) if h.get("failed")
+            ]
+            if failed:
+                reason += ": " + "; ".join(failed)
             return False, {**doc, "reason": reason}
         if age is not None and age > self.config.ready_stall_s:
             reason = (

@@ -63,9 +63,11 @@ CHANGES, like spec flips), counted in
 ``gateway_autotune_value{knob}`` gauge + the batcher's ``stats()``
 ``autotune_*`` keys (lockstep tested). Pin any knob via
 :class:`ControlConfig` (``tune_* = False``) to freeze it at its
-configured value; with an unresolvable ``--hbm-gbps auto`` the
+configured value; without a peak (``--hbm-gbps 0``, the default) the
 MBU-driven decisions disable themselves (acceptance and overhead
-steering keep working) — :func:`resolve_hbm_gbps`.
+steering keep working). ``--hbm-gbps auto`` reads the peak from the
+table of published ones and refuses a device that is not in it —
+:func:`resolve_hbm_gbps`.
 
 ``bench.py --serve-adaptive`` gates adaptive mode >= every fixed
 (spec_k x R) grid point on a mixed burst with per-pair byte-identical
@@ -99,28 +101,24 @@ __all__ = [
 #: stats() mirror keys.
 KNOBS = ("spec_k", "rounds", "chunk", "depth", "restore_batch")
 
-#: Per-platform peak HBM bandwidth (GB/s, 1e9 bytes/s) for
-#: ``--hbm-gbps auto``: matched as a lowercase substring of
-#: ``jax.devices()[0].device_kind``. The CPU sentinel is deliberately
-#: tiny and non-zero — it keeps the MBU plumbing live on smoke runs
-#: without pretending a laptop core has TPU HBM (CPU "MBU" values are
-#: a plumbing check, the PR-10 caveat).
+#: Published peak HBM bandwidth per chip (GB/s, 1e9 bytes/s) for
+#: ``--hbm-gbps auto``, matched as a lowercase substring of
+#: ``jax.devices()[0].device_kind``. Sources: Google Cloud TPU
+#: documentation, the system-architecture pages "TPU v4", "TPU v5e"
+#: and "TPU v5p". A CPU has no entry: CPU callers pass a number.
 HBM_GBPS_TABLE: tuple[tuple[str, float], ...] = (
     ("v5p", 2765.0),
     ("v5 lite", 819.0),
     ("v5e", 819.0),
     ("v4", 1228.0),
-    ("cpu", 10.0),
 )
 
 
 def resolve_hbm_gbps(spec) -> float:
     """Resolve an ``--hbm-gbps`` value: a number passes through,
-    ``"auto"`` looks the running platform up in
-    :data:`HBM_GBPS_TABLE`. Unresolvable auto returns 0.0 with ONE
-    warning — MBU-driven steering disables itself at 0 (the
-    controller's acceptance/overhead loops keep working), exactly the
-    ``hbm_gbps == 0`` contract the gauge already has."""
+    ``"auto"`` looks the device kind up in :data:`HBM_GBPS_TABLE`. A
+    device that is not in the table is an error, not a default: a
+    roofline share against a guessed peak is worse than none."""
     if not isinstance(spec, str):
         return float(spec)
     s = spec.strip().lower()
@@ -128,21 +126,14 @@ def resolve_hbm_gbps(spec) -> float:
         return float(s)
     import jax
 
-    try:
-        dev = jax.devices()[0]
-        kind = f"{dev.platform} {dev.device_kind}".lower()
-    except Exception:  # noqa: BLE001 - no backend is "unresolvable"
-        kind = ""
+    kind = jax.devices()[0].device_kind
     for sub, gbps in HBM_GBPS_TABLE:
-        if sub in kind:
+        if sub in kind.lower():
             return gbps
-    log.warning(
-        "--hbm-gbps auto: no roofline entry for device kind %r — "
-        "MBU-driven adaptive decisions disabled (acceptance and "
-        "overhead steering still run); pass a numeric peak to enable",
-        kind or "<none>",
+    raise ValueError(
+        f"--hbm-gbps auto: no published peak for device kind {kind!r} in "
+        "serving.control.HBM_GBPS_TABLE; pass the number"
     )
-    return 0.0
 
 
 @dataclass
@@ -209,7 +200,7 @@ class ControlConfig:
     #: for the next stretch — after first CALIBRATING the unmeasured
     #: arm, and re-probing the losing arm every
     #: ``rounds_probe_stretches`` stretches so a shifted workload (a
-    #: tunnel's RTT appearing, contexts growing KV-bound) can flip
+    #: dispatch cost appearing, contexts growing KV-bound) can flip
     #: the choice back. A gap longer than ``rounds_stretch_gap_s``
     #: between fetches (idle batcher between bursts) discards the
     #: open stretch instead of counting the idle as regime time.
@@ -226,7 +217,7 @@ class ControlConfig:
     #: rates on a contended box jitter ±5-10%; without a margin a
     #: single misranked fold flips the regime and costs a whole
     #: stretch at the slower arm before the next fold corrects it.
-    #: Real regime gaps (tunnel RTT, tail-masking waste) are tens of
+    #: Real regime gaps (dispatch cost, tail-masking waste) are tens of
     #: percent, far past the band.
     rounds_flip_margin: float = 0.05
     #: Probe backoff: a probe that LOSES (the regime snaps back)

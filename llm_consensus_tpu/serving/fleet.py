@@ -441,6 +441,21 @@ class PrefixRouter:
         return dst, "load"
 
 
+def _one_device_mesh(i: int):
+    """A one-device mesh on this host's device ``i mod n`` (None on a
+    one-device host, where there is nothing to choose). A batcher given
+    it places its weights and pool there and, the mesh being of size 1,
+    keeps every single-device kernel."""
+    import jax
+
+    from llm_consensus_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    devices = jax.local_devices()
+    if len(devices) == 1:
+        return None
+    return make_mesh(MeshConfig(), devices=[devices[i % len(devices)]])
+
+
 class ReplicaSet:
     """K continuous-batcher replicas + the router + the shared store.
 
@@ -511,7 +526,18 @@ class ReplicaSet:
             raise ValueError(
                 f"meshes has {len(meshes)} entries for {k} replicas"
             )
-        replica_meshes = meshes if meshes is not None else [mesh] * k
+        # Placement: an explicit mesh (or per-replica meshes) wins;
+        # otherwise replica i takes local device i mod n — K replicas
+        # on a four-chip host are K chips' worth of serving, not K
+        # pools on device 0.
+        self._replica_mesh = (
+            (lambda i: mesh) if mesh is not None else _one_device_mesh
+        )
+        replica_meshes = (
+            meshes
+            if meshes is not None
+            else [self._replica_mesh(i) for i in range(k)]
+        )
         c = self.config
         # Roles/states are LISTS (PR 19): elastic spawn appends, and
         # the router aliases both in place — replica indices stay
@@ -586,7 +612,6 @@ class ReplicaSet:
         self._draft = draft
         self._draft_map = draft_map
         self._control_cfg = control
-        self._spawn_mesh = replica_meshes[-1]
         self._store_scope = scope
         # Shared-config audit (PR 18): role_config must hand every
         # decode/mixed replica the SAME live instance (prefill copies
@@ -860,7 +885,7 @@ class ReplicaSet:
                 self._params,
                 tokenizer=self.tokenizer,
                 config=role_config(self.config, "mixed"),
-                mesh=self._spawn_mesh,
+                mesh=self._replica_mesh(len(self.batchers)),
                 draft=self._draft,
                 draft_map=self._draft_map,
                 host_store=self.store,

@@ -1,9 +1,11 @@
 """Persistent XLA compilation cache.
 
-First TPU compilation of the decode program costs 20-40 s; a persistent
-cache makes repeat CLI/serving launches near-instant. Off by default in
-JAX; this turns it on with sane thresholds. (Reference counterpart: none
-— it compiles nothing, SURVEY.md §0.)
+A cold start compiles every serving program; a persistent cache makes the
+next start of the same code read them back. JAX keys an entry on the
+cache directory's path among other things, so the directory must not
+move: it is wherever ``JAX_COMPILATION_CACHE_DIR`` says — JAX reads that
+variable itself and this module then sets nothing — and otherwise a fixed
+path inside the checkout. Never ``$HOME``, a temp name, a pid or a time.
 """
 
 from __future__ import annotations
@@ -14,27 +16,21 @@ from pathlib import Path
 
 log = logging.getLogger(__name__)
 
-_DEFAULT = "~/.cache/llm_consensus_tpu/xla"
+_CHECKOUT_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def enable_compilation_cache(path: str | None = None) -> str | None:
-    """Enable the persistent compile cache at ``path`` (idempotent).
-
-    Honors ``LLM_CONSENSUS_CACHE_DIR``; returns the directory used, or
-    None if enabling failed (old jax, read-only fs) — callers proceed
-    either way.
-    """
+def enable_compilation_cache() -> str:
+    """Turn the persistent compile cache on (idempotent); returns the
+    directory in use. Every entry point that compiles for the device —
+    the CLI, ``bench.py``, ``chip_smoke.py``'s children — calls this one
+    function before its first compile."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        log.info("compile cache: %s (JAX_COMPILATION_CACHE_DIR)", placed)
+        return placed
     import jax
 
-    cache_dir = str(
-        Path(
-            path or os.environ.get("LLM_CONSENSUS_CACHE_DIR", _DEFAULT)
-        ).expanduser()
-    )
-    try:
-        Path(cache_dir).mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        return cache_dir
-    except Exception as e:  # noqa: BLE001 - cache is best-effort
-        log.warning("compilation cache disabled: %s", e)
-        return None
+    cache_dir = str(_CHECKOUT_CACHE)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    log.info("compile cache: %s", cache_dir)
+    return cache_dir

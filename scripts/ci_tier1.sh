@@ -13,10 +13,6 @@ cd "$(dirname "$0")/.." || exit 1
 # must be declared in server/metrics.py and documented in the README
 # observability table. Stdlib-only, < 1 s.
 python scripts/check_metrics.py || exit 1
-# Bench-history gate (PR 10): the chip-round trajectory's regression
-# verdict — CHIP UNREACHABLE rounds count as no-data, never as 0-tok/s
-# measurements. Stdlib-only, < 1 s.
-python scripts/bench_history.py --check || exit 1
 if [ "$1" = "--smoke" ]; then
   exec env JAX_PLATFORMS=cpu python -m pytest \
     tests/test_paged_cache.py tests/test_server.py \

@@ -308,29 +308,20 @@ def test_restore_pacing_debt():
     assert c2.restore_pacing_ok(10_000, 10_000)
 
 
-def test_hbm_gbps_auto_resolution(caplog):
-    """Numbers pass through; 'auto' resolves from the platform table
-    (the CPU sentinel on this box); an unknown device kind warns once
-    and returns 0.0 (MBU-driven steering disables itself)."""
-    import logging
-
+def test_hbm_gbps_auto_resolution(monkeypatch):
+    """Numbers pass through; 'auto' reads the device kind's published
+    peak from the table; a kind that is not in it — this box's CPU, for
+    one — is an error, never a default or a silent 0."""
     assert resolve_hbm_gbps(3.5) == 3.5
     assert resolve_hbm_gbps("819") == 819.0
-    auto = resolve_hbm_gbps("auto")
-    assert auto == 10.0  # the CPU-smoke sentinel (JAX_PLATFORMS=cpu)
-    # Unknown device kind: patch the table empty to simulate.
-    import llm_consensus_tpu.serving.control as control
+    with pytest.raises(ValueError, match="no published peak"):
+        resolve_hbm_gbps("auto")  # device kind "cpu"
 
-    with caplog.at_level(logging.WARNING):
-        old = control.HBM_GBPS_TABLE
-        control.HBM_GBPS_TABLE = ()
-        try:
-            assert control.resolve_hbm_gbps("auto") == 0.0
-        finally:
-            control.HBM_GBPS_TABLE = old
-    assert any(
-        "no roofline entry" in r.message for r in caplog.records
-    )
+    class _V5e:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda: [_V5e()])
+    assert resolve_hbm_gbps("auto") == 819.0
     c = AdaptiveController()
     c.bind(hbm_gbps=0.0)
     assert not c.mbu_driven

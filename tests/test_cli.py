@@ -53,6 +53,7 @@ def test_hf_checkpoint_backend(tmp_path, capsys):
         [
             "--backend",
             "local",
+            "--cpu",
             "--hf-checkpoint",
             str(tmp_path),
             "--quant",
@@ -108,7 +109,7 @@ def test_eval_bundled_dataset_with_local_backend(capsys):
 
     rc = main(
         [
-            "--backend", "local",
+            "--backend", "local", "--cpu",
             "--model", "test-tiny",
             "--eval-gsm8k", "bundled",
             "--eval-n", "2",
@@ -131,7 +132,7 @@ def test_eval_synthetic2_hard_task(capsys):
 
     rc = main(
         [
-            "--backend", "local",
+            "--backend", "local", "--cpu",
             "--model", "test-tiny",
             "--eval-gsm8k", "synthetic2",
             "--eval-n", "2",
@@ -151,7 +152,7 @@ def test_cli_mesh_flag_shards_engine(capsys):
 
     rc = main(
         [
-            "--backend", "local",
+            "--backend", "local", "--cpu",
             "--model", "test-tiny",
             "--mesh", "data=8",
             "--question", "What is 2+2?",
@@ -169,7 +170,7 @@ def test_debate_mode_one_shot(capsys):
 
     rc = main(
         [
-            "--backend", "local",
+            "--backend", "local", "--cpu",
             "--model", "test-tiny",
             "--question", "What is 2+2?",
             "--debate", "4",
@@ -186,14 +187,14 @@ def test_debate_requires_local_and_question(capsys):
     from llm_consensus_tpu.cli import main
 
     assert main(["--debate", "4", "--question", "q"]) == 2  # fake backend
-    assert main(["--backend", "local", "--model", "test-tiny", "--debate", "4"]) == 2
+    assert main(["--backend", "local", "--cpu", "--model", "test-tiny", "--debate", "4"]) == 2
 
 
 def test_debate_rejects_bad_n(capsys):
     from llm_consensus_tpu.cli import main
 
     rc = main([
-        "--backend", "local", "--model", "test-tiny",
+        "--backend", "local", "--cpu", "--model", "test-tiny",
         "--question", "q", "--debate", "-1",
     ])
     assert rc == 2
@@ -205,7 +206,7 @@ def test_cli_stream_prints_completion(capsys):
 
     rc = main(
         [
-            "--backend", "local",
+            "--backend", "local", "--cpu",
             "--model", "test-tiny",
             "--question", "hello there",
             "--stream",
@@ -221,7 +222,7 @@ def test_cli_stream_requires_local_backend():
     from llm_consensus_tpu.cli import main
 
     assert main(["--stream", "--question", "q"]) == 2
-    assert main(["--backend", "local", "--model", "test-tiny", "--stream"]) == 2
+    assert main(["--backend", "local", "--cpu", "--model", "test-tiny", "--stream"]) == 2
 
 
 def test_plan_capacity_command(capsys):

@@ -451,7 +451,9 @@ def test_roled_fleet_dead_store_never_wedges(params):
         prompts = [f"{_HEADER}m{i}?" for i in range(4)]
         results = _serve(fleet, prompts, max_new_tokens=4, temperature=0.0)
         assert len(results) == 4
-        assert all(r.text for r in results)
+        # num_tokens, not text: a random model's argmax lands in the
+        # padded vocab tail the byte tokenizer decodes to nothing.
+        assert all(r.num_tokens > 0 for r in results)
         assert _errors_total() > e0
         hb = fleet.heartbeat()
         assert hb["alive"] is True

@@ -21,9 +21,7 @@ Contract layers:
 - GATEWAY: ``/debug/flight`` (+ ``?format=chrome``), ``/debug/requests``
   (+ ``?id=`` by request OR trace id), response ``meta``, and the shed
   event on a 429.
-- CI: the ``bench.py --serve-flight-overhead`` dual tok/s gate and the
-  ``scripts/bench_history.py`` no-data rule (CHIP UNREACHABLE is never
-  a 0-tok/s measurement).
+- CI: the ``bench.py --serve-flight-overhead`` dual tok/s gate.
 """
 
 import json
@@ -679,105 +677,3 @@ def test_bench_serve_flight_overhead_cpu_ab_leg(tmp_path):
     # a sanity check that both legs measured something.
     assert payload["vs_baseline"] > 0
     assert list(out.parent.glob("*.tmp.*")) == []
-
-
-def _hist(args, cwd):
-    return subprocess.run(
-        [sys.executable, str(ROOT / "scripts" / "bench_history.py"), *args],
-        cwd=cwd,
-        capture_output=True,
-        text=True,
-        timeout=60,
-    )
-
-
-def test_bench_history_unreachable_rounds_are_no_data(tmp_path):
-    """The satellite's one hard rule: a CHIP UNREACHABLE round (rc != 0
-    / status chip-unreachable / legacy 0.0-value row) is NO-DATA —
-    never a 0-tok/s measurement that fires the regression gate."""
-
-    def write(n, doc):
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(doc))
-
-    ok = {
-        "rc": 0,
-        "parsed": {
-            "metric": "candidate-tokens/sec/chip (x)",
-            "value": 100.0,
-            "unit": "tokens/sec/chip",
-            "status": "ok",
-        },
-    }
-    # Legacy unreachable row (pre-PR-10: no status field, rc != 0,
-    # 0.0 value) AND the new explicit form.
-    legacy_dead = {
-        "rc": 2,
-        "parsed": {
-            "metric": "CHIP UNREACHABLE (probe timeout)",
-            "value": 0.0,
-            "unit": "tokens/sec/chip",
-        },
-    }
-    new_dead = {
-        "rc": 2,
-        "parsed": {
-            "metric": "CHIP UNREACHABLE (probe timeout)",
-            "value": 0.0,
-            "unit": "tokens/sec/chip",
-            "status": "chip-unreachable",
-        },
-    }
-    write(1, ok)
-    write(2, legacy_dead)
-    write(3, new_dead)
-    r = _hist(["--dir", str(tmp_path), "--check", "--json"], tmp_path)
-    assert r.returncode == 0, r.stderr
-    doc = json.loads(r.stdout)
-    assert [b["status"] for b in doc["bench"]] == [
-        "ok", "chip-unreachable", "chip-unreachable",
-    ]
-    # Latest round is an outage: verdict stale, gate passes, and the
-    # last MEASUREMENT (not 0.0) is what the trajectory reports.
-    assert doc["verdict"]["verdict"] == "stale"
-    assert doc["verdict"]["latest_value"] == 100.0
-
-    # A real regression on a measured round DOES fail the gate...
-    write(4, json.loads(json.dumps(ok).replace("100.0", "50.0")))
-    r = _hist(["--dir", str(tmp_path), "--check"], tmp_path)
-    assert r.returncode == 1
-    assert "regression" in r.stdout
-    # ...and a recovered round passes again.
-    write(5, json.loads(json.dumps(ok).replace("100.0", "97.0")))
-    r = _hist(["--dir", str(tmp_path), "--check"], tmp_path)
-    assert r.returncode == 0, r.stdout
-
-    # No measured rounds at all: no-data, never an error.
-    for p in tmp_path.glob("BENCH_r*.json"):
-        p.unlink()
-    write(1, legacy_dead)
-    r = _hist(["--dir", str(tmp_path), "--check"], tmp_path)
-    assert r.returncode == 0
-    assert "no-data" in r.stdout
-
-    # A malformed value is an artifact-format problem — still
-    # no-data, never a gate-crashing traceback.
-    write(2, {"rc": 0, "parsed": {"metric": "m", "value": "n/a"}})
-    r = _hist(["--dir", str(tmp_path), "--check", "--json"], tmp_path)
-    assert r.returncode == 0, r.stderr
-    doc = json.loads(r.stdout)
-    assert doc["bench"][-1]["status"] == "no-data"
-
-
-def test_bench_history_real_repo_artifacts():
-    """The committed r01..r05 artifacts parse: r03 is the only
-    measured bench round (23.8k), r04/r05 are unreachable no-data —
-    and the CI gate passes on the repo as it stands."""
-    r = _hist(["--check", "--json"], ROOT)
-    assert r.returncode == 0, r.stderr
-    doc = json.loads(r.stdout)
-    by_round = {b["round"]: b for b in doc["bench"]}
-    assert by_round[3]["status"] == "ok"
-    assert by_round[3]["value"] == pytest.approx(23800.22)
-    assert by_round[4]["status"] == "chip-unreachable"
-    assert by_round[5]["status"] == "chip-unreachable"
-    assert doc["verdict"]["verdict"] in ("stale", "ok")

@@ -65,11 +65,7 @@ def test_initialize_raises_on_explicit_config_failure(monkeypatch):
         initialize_distributed,
     )
 
-    from llm_consensus_tpu.parallel import compat
-
-    monkeypatch.setattr(
-        compat, "distributed_is_initialized", lambda: False
-    )
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: False)
 
     def boom(**kw):
         raise ConnectionError("coordinator unreachable")

@@ -12,7 +12,6 @@ import pytest
 
 from llm_consensus_tpu.models.configs import get_config
 from llm_consensus_tpu.models.transformer import forward, init_params
-from llm_consensus_tpu.parallel.compat import SUPPORTS_PARTIAL_AUTO
 from llm_consensus_tpu.parallel.mesh import MeshConfig, make_mesh
 from llm_consensus_tpu.parallel.pipeline import (
     make_pipeline_forward,
@@ -28,15 +27,6 @@ from llm_consensus_tpu.training.train import (
 
 CFG = get_config("test-tiny").with_(n_layers=4)
 TCFG = TrainConfig(warmup_steps=1, total_steps=10, remat=True)
-
-
-def _require_partial_auto(meshcfg: MeshConfig) -> None:
-    """Skip when the mesh mixes manual (data/pipe) with auto (model)
-    axes on a jax whose shard_map cannot express partial-auto — the
-    old API's ``auto=`` lowering aborts XLA's partitioner outright
-    (see ``parallel.compat.SUPPORTS_PARTIAL_AUTO``)."""
-    if meshcfg.model > 1 and not SUPPORTS_PARTIAL_AUTO:
-        pytest.skip("partial-auto shard_map unsupported on this jax")
 
 
 def _batch(b=8, s=16, seed=1):
@@ -61,7 +51,6 @@ def _params():
 )
 def test_pipeline_forward_matches_reference(cpu_devices, meshcfg, micro):
     """Pipelined logits == plain forward logits for dp/pp/tp combos."""
-    _require_partial_auto(meshcfg)
     mesh = make_mesh(meshcfg, cpu_devices[: meshcfg.size])
     params = _params()
     tokens, _ = _batch()
@@ -75,7 +64,6 @@ def test_pipeline_forward_matches_reference(cpu_devices, meshcfg, micro):
 
 def test_pipeline_train_step_matches_unsharded(cpu_devices):
     """One GPipe train step == one unsharded train step (same init/batch)."""
-    _require_partial_auto(MeshConfig(data=2, pipe=2, model=2))
     mesh = make_mesh(MeshConfig(data=2, pipe=2, model=2), cpu_devices)
     tokens, mask = _batch()
 

@@ -295,31 +295,17 @@ def test_int4_params_shard_on_mesh():
     assert sharded["blocks"]["wq"].q.sharding.spec == P(None, None, "model")
 
 
-def test_stacked_kernel_matches_per_layer_slice():
-    """quant_matmul_stacked(x, stack, l) == quant_matmul_2d(x, stack[l])."""
-    import numpy as np
+@pytest.mark.parametrize("n_layers", [0, 3])
+def test_quant_matmul_kernels_match_dequant_dot(n_layers):
+    """The int8 matmul kernel — plain and stacked (layer index by
+    scalar prefetch, read on the last layer) — against dequantize + dot:
+    the comparison chip_smoke.py runs compiled at K = 4096 / 14336."""
+    from llm_consensus_tpu.ops.pallas import parity
 
-    from llm_consensus_tpu.ops.pallas.quant_matmul import (
-        quant_matmul_2d,
-        quant_matmul_stacked,
+    err = parity.quant_matmul_error(
+        seed=0, m=8, k=128, n=256, n_layers=n_layers, interpret=True
     )
-
-    key = jax.random.PRNGKey(0)
-    n_layers, m, k, n = 3, 8, 128, 256
-    w = jax.random.randint(key, (n_layers, k, n), -127, 127, jnp.int8)
-    s = jnp.abs(jax.random.normal(key, (n_layers, 1, n), jnp.float32)) * 0.02
-    x = jax.random.normal(jax.random.fold_in(key, 1), (m, k), jnp.bfloat16)
-    for layer in range(n_layers):
-        want = quant_matmul_2d(x, w[layer], s[layer], interpret=True)
-        got = quant_matmul_stacked(
-            x, w, s, jnp.asarray(layer), interpret=True
-        )
-        np.testing.assert_allclose(
-            np.asarray(got, np.float32),
-            np.asarray(want, np.float32),
-            rtol=1e-2,
-            atol=1e-2,
-        )
+    parity.check("quant_matmul", err, parity.QUANT_MATMUL_TOL)
 
 
 def test_matmul_stacked_quant_view_matches_sliced():

@@ -26,11 +26,10 @@ from llm_consensus_tpu.models.configs import get_config
 from llm_consensus_tpu.models.transformer import init_params
 from llm_consensus_tpu.ops.attention import (
     decode_attention_shared_prefix_quant,
-    ragged_paged_attention_reference,
 )
+from llm_consensus_tpu.ops.pallas import parity
 from llm_consensus_tpu.ops.pallas.attention import (
     flash_decode_attention_shared_prefix_q8_stacked,
-    ragged_paged_attention,
 )
 from llm_consensus_tpu.serving.continuous import (
     ContinuousBatcher,
@@ -57,14 +56,12 @@ def params():
 
 
 # ---------------------------------------------------------------------------
-# Kernel vs XLA reference (CPU interpret)
+# Kernel vs XLA reference (CPU interpret). The comparison body lives in
+# ops/pallas/parity.py: chip_smoke.py runs the same one compiled, at the
+# smoke model's shapes.
 # ---------------------------------------------------------------------------
 
-
-def _pool(rng, n_pages=40, pg=8, hkv=2, d=32):
-    k = jnp.asarray(rng.standard_normal((n_pages, pg, hkv, d)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((n_pages, pg, hkv, d)), jnp.bfloat16)
-    return k, v
+_TOY = dict(pg=8, hkv=2, d=32, p_per=6, n_pages=40, interpret=True)
 
 
 def _check(got, want, rtol=2e-2, atol=2e-2):
@@ -74,45 +71,30 @@ def _check(got, want, rtol=2e-2, atol=2e-2):
     )
 
 
+def _within_tol(errs: dict):
+    for lane, err in errs.items():
+        parity.check(lane, err, parity.ATTENTION_TOL)
+
+
 @pytest.mark.parametrize("window", [0, 9])
 def test_ragged_mixed_rows_match_reference(window):
-    """Decode rows at mid-block lengths + one chunk row, one program."""
-    rng = np.random.default_rng(0)
-    pg, hkv, d, g, b, p_per, cq = 8, 2, 32, 3, 4, 6, 16
-    h = hkv * g
-    kp, vp = _pool(rng, pg=pg, hkv=hkv, d=d)
-    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.bfloat16)
-    qc = jnp.asarray(rng.standard_normal((cq, h, d)), jnp.bfloat16)
-    perm = rng.permutation(np.arange(1, 40))
-    tbl = jnp.asarray(perm[: b * p_per].reshape(b, p_per), jnp.int32)
-    ctbl = jnp.asarray(perm[b * p_per : b * p_per + p_per], jnp.int32)
-    vl = jnp.asarray([13, 1, 40, 23], jnp.int32)  # mid-block fills
-    cstart = jnp.int32(11)  # chunk starts mid-block too
-    got_d, got_c = ragged_paged_attention(
-        q, kp, vp, tbl, vl, q_chunk=qc, chunk_table=ctbl,
-        chunk_start=cstart, window=window, interpret=True,
+    """Decode rows at mid-block lengths + one chunk row (starting
+    mid-block too), one program."""
+    errs = parity.ragged_attention_error(
+        **_TOY, seed=0, g=3, valid_len=[13, 1, 40, 23], cq=16,
+        chunk_start=11, window=window,
     )
-    ref_d, ref_c = ragged_paged_attention_reference(
-        q, kp, vp, tbl, vl, q_chunk=qc, chunk_table=ctbl,
-        chunk_start=cstart, window=window,
-    )
-    _check(got_d, ref_d)
-    _check(got_c, ref_c)
+    assert set(errs) == {"decode", "chunk"}
+    _within_tol(errs)
 
 
 def test_ragged_mqa_single_kv_head():
-    rng = np.random.default_rng(1)
-    pg, hkv, d, g, b, p_per = 8, 1, 32, 4, 3, 4
-    kp, vp = _pool(rng, pg=pg, hkv=hkv, d=d)
-    q = jnp.asarray(rng.standard_normal((b, hkv * g, d)), jnp.bfloat16)
-    tbl = jnp.asarray(
-        rng.permutation(np.arange(1, 40))[: b * p_per].reshape(b, p_per),
-        jnp.int32,
+    _within_tol(
+        parity.ragged_attention_error(
+            **{**_TOY, "hkv": 1, "p_per": 4}, seed=1, g=4,
+            valid_len=[7, 30, 12],
+        )
     )
-    vl = jnp.asarray([7, 30, 12], jnp.int32)
-    got = ragged_paged_attention(q, kp, vp, tbl, vl, interpret=True)
-    ref = ragged_paged_attention_reference(q, kp, vp, tbl, vl)
-    _check(got, ref)
 
 
 @pytest.mark.parametrize("window", [0, 9])
@@ -120,88 +102,35 @@ def test_ragged_grouped_rows_with_chunk(window):
     """Groups + ungrouped rows + a chunk lane in the same program —
     grouping is a bandwidth optimization, output must equal the
     ungrouped reference (including under a sliding window, the config
-    that used to fall back)."""
-    rng = np.random.default_rng(2)
-    pg, hkv, d, g, b, p_per, cq = 8, 2, 32, 3, 4, 6, 16
-    h = hkv * g
-    kp, vp = _pool(rng, pg=pg, hkv=hkv, d=d)
-    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.bfloat16)
-    qc = jnp.asarray(rng.standard_normal((cq, h, d)), jnp.bfloat16)
-    perm = rng.permutation(np.arange(1, 40))
-    tbl = np.asarray(perm[: b * p_per].reshape(b, p_per), np.int32)
-    # Rows 0, 2, 3 share their first page (same tokens by construction).
-    tbl[2, 0] = tbl[0, 0]
-    tbl[3, 0] = tbl[0, 0]
-    tbl = jnp.asarray(tbl)
-    ctbl = jnp.asarray(perm[b * p_per : b * p_per + p_per], jnp.int32)
-    vl = jnp.asarray([13, 9, 40, 23], jnp.int32)
-    groups = (
-        jnp.asarray([0, -1, 0, 0], jnp.int32),  # group_id
-        jnp.asarray([0], jnp.int32),  # rep
-        jnp.asarray([pg], jnp.int32),  # group_end (tokens)
-        jnp.asarray([pg, 0, pg, pg], jnp.int32),  # shared_start
+    that used to fall back). Rows 0, 2, 3 share their first page."""
+    _within_tol(
+        parity.ragged_attention_error(
+            **_TOY, seed=2, g=3, valid_len=[13, 9, 40, 23], cq=16,
+            chunk_start=11, group_rows=(0, 2, 3), window=window,
+        )
     )
-    got_d, got_c = ragged_paged_attention(
-        q, kp, vp, tbl, vl, q_chunk=qc, chunk_table=ctbl,
-        chunk_start=jnp.int32(11), groups=groups, window=window,
-        interpret=True,
-    )
-    ref_d, ref_c = ragged_paged_attention_reference(
-        q, kp, vp, tbl, vl, q_chunk=qc, chunk_table=ctbl,
-        chunk_start=jnp.int32(11), window=window,
-    )
-    _check(got_d, ref_d)
-    _check(got_c, ref_c)
 
 
 def test_ragged_degenerate_single_member_group():
     """A one-member group must not change that row's output (the
     tracker never emits one, but the kernel tolerates it)."""
-    rng = np.random.default_rng(3)
-    pg, hkv, d, g, b, p_per = 8, 2, 32, 2, 3, 4
-    kp, vp = _pool(rng, pg=pg, hkv=hkv, d=d)
-    q = jnp.asarray(rng.standard_normal((b, hkv * g, d)), jnp.bfloat16)
-    tbl = jnp.asarray(
-        rng.permutation(np.arange(1, 40))[: b * p_per].reshape(b, p_per),
-        jnp.int32,
+    _within_tol(
+        parity.ragged_attention_error(
+            **{**_TOY, "p_per": 4}, seed=3, g=2, valid_len=[20, 11, 30],
+            group_rows=(1,),
+        )
     )
-    vl = jnp.asarray([20, 11, 30], jnp.int32)
-    groups = (
-        jnp.asarray([-1, 0, -1], jnp.int32),
-        jnp.asarray([1], jnp.int32),
-        jnp.asarray([pg], jnp.int32),
-        jnp.asarray([0, pg, 0], jnp.int32),
-    )
-    got = ragged_paged_attention(
-        q, kp, vp, tbl, vl, groups=groups, interpret=True
-    )
-    ref = ragged_paged_attention_reference(q, kp, vp, tbl, vl)
-    _check(got, ref)
 
 
 def test_ragged_all_prefill_and_dead_decode_rows():
     """kv_len 0 decode rows (an idle batcher's slots) stay finite while
     the chunk row — the only live work — still matches the reference."""
-    rng = np.random.default_rng(4)
-    pg, hkv, d, g, b, p_per, cq = 8, 2, 32, 2, 3, 4, 8
-    h = hkv * g
-    kp, vp = _pool(rng, pg=pg, hkv=hkv, d=d)
-    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.bfloat16)
-    qc = jnp.asarray(rng.standard_normal((cq, h, d)), jnp.bfloat16)
-    perm = rng.permutation(np.arange(1, 40))
-    tbl = jnp.zeros((b, p_per), jnp.int32)  # all-NULL tables
-    ctbl = jnp.asarray(perm[:p_per], jnp.int32)
-    vl = jnp.zeros((b,), jnp.int32)
-    got_d, got_c = ragged_paged_attention(
-        q, kp, vp, tbl, vl, q_chunk=qc, chunk_table=ctbl,
-        chunk_start=jnp.int32(0), interpret=True,
+    errs = parity.ragged_attention_error(
+        **{**_TOY, "p_per": 4}, seed=4, g=2, valid_len=[0, 0, 0], cq=8,
+        chunk_start=0, null_tables=True,
     )
-    _, ref_c = ragged_paged_attention_reference(
-        q, kp, vp, tbl, vl, q_chunk=qc, chunk_table=ctbl,
-        chunk_start=jnp.int32(0),
-    )
-    _check(got_c, ref_c)
-    assert np.isfinite(np.asarray(got_d, np.float32)).all()
+    assert set(errs) == {"chunk"}
+    _within_tol(errs)
 
 
 def test_ragged_stacked_q8_shared_prefix_matches_reference():

@@ -203,85 +203,36 @@ def test_verify_tokens_greedy_is_argmax_chain_and_filter_invariant():
 # ---------------------------------------------------------------------------
 
 
-def test_ragged_verify_rows_match_reference():
+@pytest.mark.parametrize("window", [0, 9])
+def test_ragged_verify_rows_match_reference(window):
     """[B, NQ, H, D] verify rows — with a chunk lane riding along and a
     sliding window — match the reference's chunk_decode_attention rule
-    per row (the kernel's nq > 1 decode lane, PR 9)."""
-    from llm_consensus_tpu.ops.attention import (
-        ragged_paged_attention_reference,
+    per row (the kernel's nq > 1 decode lane, PR 9). Fills >= nq,
+    mid-block."""
+    from llm_consensus_tpu.ops.pallas import parity
+
+    errs = parity.ragged_attention_error(
+        seed=11, pg=8, hkv=2, g=3, d=32, p_per=6, n_pages=40,
+        valid_len=[13, 5, 40, 23], nq=3, cq=8, chunk_start=11,
+        window=window, interpret=True,
     )
-    from llm_consensus_tpu.ops.pallas.attention import ragged_paged_attention
-
-    rng = np.random.default_rng(11)
-    pg, hkv, d, g, b, p_per, nq, cq = 8, 2, 32, 3, 4, 6, 3, 8
-    h = hkv * g
-    kp = jnp.asarray(rng.standard_normal((40, pg, hkv, d)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((40, pg, hkv, d)), jnp.bfloat16)
-    q = jnp.asarray(rng.standard_normal((b, nq, h, d)), jnp.bfloat16)
-    qc = jnp.asarray(rng.standard_normal((cq, h, d)), jnp.bfloat16)
-    perm = rng.permutation(np.arange(1, 40))
-    tbl = jnp.asarray(perm[: b * p_per].reshape(b, p_per), jnp.int32)
-    ctbl = jnp.asarray(perm[b * p_per : b * p_per + p_per], jnp.int32)
-    vl = jnp.asarray([13, 5, 40, 23], jnp.int32)  # >= nq, mid-block
-    for window in (0, 9):
-        got_d, got_c = ragged_paged_attention(
-            q, kp, vp, tbl, vl, q_chunk=qc, chunk_table=ctbl,
-            chunk_start=jnp.int32(11), window=window, interpret=True,
-        )
-        ref_d, ref_c = ragged_paged_attention_reference(
-            q, kp, vp, tbl, vl, q_chunk=qc, chunk_table=ctbl,
-            chunk_start=jnp.int32(11), window=window,
-        )
-        np.testing.assert_allclose(
-            np.asarray(got_d, np.float32), np.asarray(ref_d, np.float32),
-            rtol=2e-2, atol=2e-2,
-        )
-        np.testing.assert_allclose(
-            np.asarray(got_c, np.float32), np.asarray(ref_c, np.float32),
-            rtol=2e-2, atol=2e-2,
-        )
+    for lane in ("decode", "chunk"):
+        parity.check(lane, errs[lane], parity.ATTENTION_TOL)
 
 
-def test_ragged_verify_rows_grouped_match_reference():
+@pytest.mark.parametrize("window", [0, 9])
+def test_ragged_verify_rows_grouped_match_reference(window):
     """Verify rows through the GROUP phase: every member query stacks
     against one read of the shared run; output equals the ungrouped
     reference."""
-    from llm_consensus_tpu.ops.attention import (
-        ragged_paged_attention_reference,
-    )
-    from llm_consensus_tpu.ops.pallas.attention import ragged_paged_attention
+    from llm_consensus_tpu.ops.pallas import parity
 
-    rng = np.random.default_rng(12)
-    pg, hkv, d, g, b, p_per, nq = 8, 2, 32, 3, 4, 6, 3
-    h = hkv * g
-    kp = jnp.asarray(rng.standard_normal((40, pg, hkv, d)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((40, pg, hkv, d)), jnp.bfloat16)
-    q = jnp.asarray(rng.standard_normal((b, nq, h, d)), jnp.bfloat16)
-    tbl = np.asarray(
-        rng.permutation(np.arange(1, 40))[: b * p_per].reshape(b, p_per),
-        np.int32,
+    errs = parity.ragged_attention_error(
+        seed=12, pg=8, hkv=2, g=3, d=32, p_per=6, n_pages=40,
+        valid_len=[13, 9, 40, 23], nq=3, group_rows=(0, 2, 3),
+        window=window, interpret=True,
     )
-    tbl[2, 0] = tbl[0, 0]
-    tbl[3, 0] = tbl[0, 0]
-    tbl = jnp.asarray(tbl)
-    vl = jnp.asarray([13, 9, 40, 23], jnp.int32)
-    groups = (
-        jnp.asarray([0, -1, 0, 0], jnp.int32),
-        jnp.asarray([0], jnp.int32),
-        jnp.asarray([pg], jnp.int32),
-        jnp.asarray([pg, 0, pg, pg], jnp.int32),
-    )
-    for window in (0, 9):
-        got = ragged_paged_attention(
-            q, kp, vp, tbl, vl, groups=groups, window=window, interpret=True
-        )
-        ref = ragged_paged_attention_reference(
-            q, kp, vp, tbl, vl, window=window
-        )
-        np.testing.assert_allclose(
-            np.asarray(got, np.float32), np.asarray(ref, np.float32),
-            rtol=2e-2, atol=2e-2,
-        )
+    parity.check("decode", errs["decode"], parity.ATTENTION_TOL)
 
 
 # ---------------------------------------------------------------------------

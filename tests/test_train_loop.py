@@ -115,12 +115,6 @@ def test_loop_on_sharded_mesh(shard, cpu_devices):
 
 
 def test_loop_on_pipeline_mesh(shard, cpu_devices):
-    from llm_consensus_tpu.parallel.compat import SUPPORTS_PARTIAL_AUTO
-
-    if not SUPPORTS_PARTIAL_AUTO:
-        # data/pipe manual + model auto: the old shard_map's ``auto=``
-        # lowering aborts XLA's partitioner (see parallel/compat.py).
-        pytest.skip("partial-auto shard_map unsupported on this jax")
     cfg = CFG.with_(n_layers=4)
     mesh = make_mesh(MeshConfig(data=2, pipe=2, model=2), cpu_devices)
     state, report = run_training(
