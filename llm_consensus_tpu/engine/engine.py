@@ -149,7 +149,6 @@ class InferenceEngine:
         engine_config: EngineConfig | None = None,
         mesh=None,
         draft: tuple[ModelConfig, dict] | None = None,
-        tracer=None,
     ):
         # Kernel choice is observed here, once (ops.kernels): compiled
         # Pallas on a TPU with no multi-device mesh — these kernels have
@@ -161,10 +160,6 @@ class InferenceEngine:
             draft = (resolve_kernels(draft[0], mesh), draft[1])
         self.cfg = cfg
         self.params = params
-        # Optional utils.tracing.Tracer: generate calls record
-        # "engine.generate" / "engine.generate_speculative" spans
-        # (batch shape + real request count).
-        self.tracer = tracer
         self.tokenizer = tokenizer or ByteTokenizer()
         if self.tokenizer.vocab_size > cfg.vocab_size:
             raise ValueError(
@@ -745,27 +740,14 @@ class InferenceEngine:
         return results
 
     def _span(self, name: str, **meta):
-        """Engine instrumentation site: the span lands on the engine's
-        optional flat Tracer AND on the caller's request-scoped trace
-        (propagated here through asyncio.to_thread's context copy) —
-        gateway-driven engine calls show up in ``GET /debug/traces``
-        with no per-call plumbing. Untraced engines keep the free
-        nullcontext fast path."""
-        import contextlib
-
+        """Engine instrumentation site: the span lands on the caller's
+        request-scoped trace (propagated here through
+        asyncio.to_thread's context copy) — gateway-driven engine calls
+        show up in ``GET /debug/traces`` with no per-call plumbing, and
+        ``request_span`` is a no-op when no trace is active."""
         from llm_consensus_tpu.utils import tracing as _tracing
 
-        traced = _tracing.current_trace() is not None
-        if self.tracer is None:
-            if not traced:
-                return contextlib.nullcontext()
-            return _tracing.request_span(name, **meta)
-        if not traced:
-            return self.tracer.span(name, **meta)
-        stack = contextlib.ExitStack()
-        stack.enter_context(_tracing.request_span(name, **meta))
-        stack.enter_context(self.tracer.span(name, **meta))
-        return stack
+        return _tracing.request_span(name, **meta)
 
     def _generate_prepared(
         self,

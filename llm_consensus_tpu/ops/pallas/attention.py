@@ -1172,6 +1172,7 @@ def _ragged_attention(
         ),
         out_shape=tuple(out_shapes),
         interpret=interpret,
+        name="ragged_attention",
     )(*pf, *inputs)
 
     md, ld, od = outs[0][:b], outs[1][:b], outs[2][:b]

@@ -56,6 +56,7 @@ def fused_rms_norm(
             (blk, d), lambda i: (i, 0), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="rms_norm",
     )(x2, weight.reshape(1, d))
     if pad:
         out = out[:n]

@@ -92,6 +92,7 @@ def quant_matmul_2d(
             (m, blk_n), lambda i: (0, i), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="quant_matmul",
     )(x.astype(jnp.bfloat16), w_q, scale.astype(jnp.float32))
 
 
@@ -287,6 +288,7 @@ def quant_matmul_stacked(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=interpret,
+        name="quant_matmul_stacked",
     )(
         jnp.atleast_1d(layer).astype(jnp.int32),
         x.astype(jnp.bfloat16),

@@ -226,7 +226,8 @@ class GatewayConfig:
         # Opt-in JAX device profiling: a request carrying
         # ``X-Profile: 1`` wraps its backend work in
         # ``jax.profiler.trace(profile_dir)`` (one at a time; TensorBoard
-        # format, aligned with the request's host spans). None = off.
+        # format; its ``profile.anchor`` event places the request's
+        # host spans on its clock). None = off.
         profile_dir: str | None = None,
         # Cross-host peer tier (PR 16): base URLs of downstream peer
         # gateways ("http://host:port"). Non-empty => this gateway is a
@@ -1203,9 +1204,13 @@ class Gateway:
     def _maybe_profile(self, headers: dict):
         """``X-Profile: 1`` (with ``GatewayConfig.profile_dir`` set)
         captures a JAX device profile around this request's backend
-        work — a TensorBoard trace in ``profile_dir`` aligned with the
-        request's host spans (a ``jax_profile`` span marks the window
-        on the trace). One capture at a time: concurrent flagged
+        work — a TensorBoard trace in ``profile_dir``. A ``jax_profile``
+        span marks the window on the request's trace, and the profile
+        opens with a ``profile.anchor`` event that carries
+        ``perf_counter_ns`` (:func:`tracing.trace_jax_profile`): the
+        stamp that places the request's host spans on the profile's
+        clock, beside the batcher's ``batcher.<phase>`` events. One
+        capture at a time: concurrent flagged
         requests run unprofiled rather than queueing on the profiler's
         process-global state. SSE streaming requests are not profiled
         (their backend work outlives the handler's await points)."""

@@ -1,9 +1,8 @@
 """Process-wide metrics registry with Prometheus text exposition.
 
-The repo's only observability before this module was the ad-hoc
-:class:`llm_consensus_tpu.utils.tracing.Tracer` (in-process spans, pull
-by Python API). Serving needs the standard scrape surface instead: a
-registry of counters/gauges/histograms that the gateway exports at
+The repo's only observability before this module was in-process spans
+pulled by Python API. Serving needs the standard scrape surface
+instead: a registry of counters/gauges/histograms that the gateway exports at
 ``GET /metrics`` in the Prometheus text format (version 0.0.4), so the
 same dashboards that watch any other fleet watch this one.
 
@@ -55,6 +54,10 @@ __all__ = [
     "PIPELINE_FLUSHES",
     "DISPATCH_INFLIGHT",
     "DEVICE_PROGRAMS",
+    "BATCHER_PHASE_SECONDS",
+    "GENERATED_TOKENS",
+    "PREFILL_TOKENS",
+    "DEVICE_MEMORY_BYTES",
     "RAGGED_ROWS",
     "SPEC_DRAFT_TOKENS",
     "SPEC_ACCEPTED_TOKENS",
@@ -316,6 +319,7 @@ class MetricsRegistry:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._families: dict[str, _Family] = {}
+        self._render_hooks: list = []
 
     def _get(self, name: str, help_: str, kind: str, **kw) -> _Family:
         with self._lock:
@@ -345,10 +349,25 @@ class MetricsRegistry:
     def get(self, name: str) -> _Family | None:
         return self._families.get(name)
 
+    def add_render_hook(self, hook) -> None:
+        """Call ``hook()`` at the start of every :meth:`render`: for a
+        gauge whose value is read from somewhere else when it is asked
+        for (device memory), never from a serving loop. Adding the
+        same function twice keeps one."""
+        with self._lock:
+            if hook not in self._render_hooks:
+                self._render_hooks.append(hook)
+
     def render(self) -> str:
         """The full exposition — Prometheus text format 0.0.4."""
         with self._lock:
+            hooks = list(self._render_hooks)
             fams = sorted(self._families.values(), key=lambda f: f.name)
+        for hook in hooks:
+            try:
+                hook()
+            except Exception:  # noqa: BLE001 - a scrape must not fail
+                pass
         lines: list[str] = []
         for fam in fams:
             lines.extend(fam.render())
@@ -582,6 +601,53 @@ DEVICE_PROGRAMS = REGISTRY.counter(
     "gateway_device_programs_total",
     "Device programs dispatched by the continuous-batcher scheduler loop",
 )
+#: Where the batcher thread's time goes (PR 26), labeled
+#: ``phase="admit"|"restore"|"dispatch"|"device_wait"|"retire"|"idle"``.
+#: The phases never overlap and leave nothing out: where one runs
+#: inside another (a pipeline flush fetching from inside a dispatch)
+#: the outer one's time stops while the inner one runs, and the few
+#: microseconds between two phases go to the one that follows. So over
+#: any window the phases sum to the window. ``device_wait`` is every
+#: blocking wait on the device (the token fetch's host sync, each
+#: ``block_until_ready``, the first-token sample's ``int()``); the
+#: other four working phases are host work, whether the device is busy
+#: behind them or not. Each stretch is also a ``batcher.<phase>``
+#: ``jax.profiler.TraceAnnotation`` on the profiler's host plane, from
+#: the same two clock reads.
+BATCHER_PHASE_SECONDS = REGISTRY.counter(
+    "gateway_batcher_phase_seconds_total",
+    "Seconds of the continuous-batcher thread by loop phase",
+)
+#: Output tokens credited to live rows, counted where they are
+#: credited: the first at activation (sampled from prefill logits), the
+#: rest at the fetch that appends them. Tokens a program decoded for a
+#: row that had already finished are discarded there and not counted.
+#: Over any interval this equals the summaries' ``new_tokens``, up to
+#: what is in flight at the edges (``serving_generated_tokens_total``
+#: moves only at retirement, a whole generation at a time).
+GENERATED_TOKENS = REGISTRY.counter(
+    "gateway_generated_tokens_total",
+    "Output tokens credited to live rows (at activation and at fetch)",
+)
+#: Real prompt tokens computed by prefill programs: chunk lanes
+#: (standalone and fused) and the legacy dense path. Padding past the
+#: prompt's end and tokens whose pages came from the prefix registry,
+#: a boundary copy or the host tier are not computed and not counted.
+#: Over ``gateway_device_programs_total{kind="prefill"|"fused"}`` it is
+#: the prompt tokens one chunk program really carries.
+PREFILL_TOKENS = REGISTRY.counter(
+    "gateway_prefill_tokens_total",
+    "Prompt tokens computed by prefill programs (no padding, no cached)",
+)
+#: Device memory as the allocator reports it, labeled
+#: ``kind="in_use"|"peak"|"limit"``: the largest value over the local
+#: devices. Filled when ``/metrics`` is rendered (a render hook the
+#: first batcher installs), never from the serving loop; a backend
+#: without ``memory_stats`` (the CPU) exports no sample.
+DEVICE_MEMORY_BYTES = REGISTRY.gauge(
+    "gateway_device_memory_bytes",
+    "Device memory by kind (in_use, peak, limit), largest local device",
+)
 #: Rows sharing one ragged device program: active decode rows plus the
 #: fused prefill-chunk lane (fused/decode programs only). The mixed
 #: prefill+decode occupancy of the one kernel.
@@ -678,9 +744,9 @@ CONSENSUS_ROUND_SECONDS = REGISTRY.histogram(
     "Consensus phase latency by phase (propose/evaluate/refine)",
 )
 #: Ring-buffer pressure in the tracing layer, labeled
-#: ``kind="span"`` (a span evicted/refused by a full Tracer ring or a
-#: full per-trace span budget) or ``kind="trace"`` (a whole trace
-#: evicted from the bounded TraceStore). Fed via the tracing drop hook
+#: ``kind="span"`` (a span refused by a full per-trace span budget) or
+#: ``kind="trace"`` (a whole trace evicted from the bounded
+#: TraceStore). Fed via the tracing drop hook
 #: wired below — the lockstep contract between the two surfaces.
 TRACE_DROPPED = REGISTRY.counter(
     "gateway_trace_dropped_total",

@@ -84,6 +84,8 @@ is the TPU-native throughput-serving counterpart.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import itertools
 import logging
 import threading
@@ -201,6 +203,21 @@ from llm_consensus_tpu.server.metrics import (
     DEVICE_PROGRAMS as _M_DEVICE_PROGRAMS,
 )
 from llm_consensus_tpu.server.metrics import (
+    BATCHER_PHASE_SECONDS as _M_PHASE_SECONDS,
+)
+from llm_consensus_tpu.server.metrics import (
+    GENERATED_TOKENS as _M_GENERATED,
+)
+from llm_consensus_tpu.server.metrics import (
+    PREFILL_TOKENS as _M_PREFILL_TOKENS,
+)
+from llm_consensus_tpu.server.metrics import (
+    DEVICE_MEMORY_BYTES as _M_DEVICE_MEMORY,
+)
+from llm_consensus_tpu.server.metrics import (
+    REGISTRY as _M_REGISTRY,
+)
+from llm_consensus_tpu.server.metrics import (
     RAGGED_ROWS as _M_RAGGED_ROWS,
 )
 from llm_consensus_tpu.server.metrics import (
@@ -276,6 +293,47 @@ _SCREEN_W = 8
 # Bound on the per-batcher derived-screen memo (stop tuples are
 # client-supplied; see _screen_cache).
 _SCREEN_CACHE_MAX = 512
+
+# The batcher thread's phases: the labels of
+# gateway_batcher_phase_seconds_total, and "batcher.<phase>" on the
+# profiler's host plane.
+_PHASES = ("admit", "restore", "dispatch", "device_wait", "retire", "idle")
+
+
+def _step_program(name: str, fn):
+    """``fn`` under the fixed function name ``name``: ``jax.jit`` names
+    the XLA module after the function it is given (``jit_<name>``, and
+    ``PjitFunction(<name>)`` on the profiler's host plane), and a bound
+    method or a ``functools.partial`` would read ``jit__decode_sample``
+    or ``jit__unknown``. The name is what a trace reduction finds a
+    step program by, whatever its bucket: keep it when the body
+    changes."""
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
+def _fill_device_memory() -> None:
+    """gateway_device_memory_bytes from the allocator's own numbers:
+    the largest value over the local devices. A render hook of the
+    metrics registry (installed by the first batcher, so the backend is
+    up): it runs when ``/metrics`` is asked for, never in the loop."""
+    largest: dict[str, int] = {}
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        for kind, key in (
+            ("in_use", "bytes_in_use"),
+            ("peak", "peak_bytes_in_use"),
+            ("limit", "bytes_limit"),
+        ):
+            if key in stats:
+                largest[kind] = max(largest.get(kind, 0), int(stats[key]))
+    for kind, value in largest.items():
+        _M_DEVICE_MEMORY.labels(kind=kind).set(value)
 
 # Cap on prefix_probe's host-tier extension walk (PR 14): each probed
 # page hashes a fresh chain-prefix tuple (O(chain) per lookup — the
@@ -1162,6 +1220,16 @@ class ContinuousBatcher:
         self._sched_overhead_sum = 0.0
         self._sched_overhead_count = 0
         self._last_step_end: float | None = None
+        # Loop phases (PR 26; worker thread only): seconds gathered
+        # since the last flush into gateway_batcher_phase_seconds_total,
+        # the phases open right now (innermost last), and the clock at
+        # the last phase boundary.
+        self._phase_s = dict.fromkeys(_PHASES, 0.0)
+        self._phase_children = {
+            name: _M_PHASE_SECONDS.labels(phase=name) for name in _PHASES
+        }
+        self._phase_open: list[str] = []
+        self._phase_t = time.perf_counter()
         # Liveness heartbeat: stamped at the top of every host-loop
         # iteration (the idle loop ticks at >= 10 Hz), and after each
         # decode step. The gateway's readiness probe compares the tick
@@ -1179,13 +1247,17 @@ class ContinuousBatcher:
         # params ride as a jit argument (not a closure constant) so the
         # weights aren't baked into the executable.
         self._jit_decode = jax.jit(
-            self._decode_sample, donate_argnums=(1,), static_argnums=(8,)
+            _step_program("decode_step", self._decode_sample),
+            donate_argnums=(1,),
+            static_argnums=(8,),
         )
         # Multi-round decode program (PR 12): rounds is static (the
         # scan length; two cached traces per variant — R, and the
         # stop-bound 1), filters_active as in _jit_decode.
         self._jit_rounds = jax.jit(
-            self._rounds_sample, donate_argnums=(2,), static_argnums=(0, 9)
+            _step_program("rounds_step", self._rounds_sample),
+            donate_argnums=(2,),
+            static_argnums=(0, 9),
         )
         # Derived stop screens memoized per stop tuple: the derivation
         # scans the vocabulary once, and submit() runs on caller
@@ -1225,7 +1297,7 @@ class ContinuousBatcher:
                 c.max_slots, c.max_slots * (c.spec_k + 1)
             )
             self._jit_spec = jax.jit(
-                self._spec_sample,
+                _step_program("verify_step", self._spec_sample),
                 static_argnums=(0, 11, 12),
                 donate_argnums=(3, 4),
             )
@@ -1238,10 +1310,48 @@ class ContinuousBatcher:
         # several prompts fill concurrently).
         self._prefill_rr = 0
         self._dense_pending = -1
+        _M_REGISTRY.add_render_hook(_fill_device_memory)
         self._thread = threading.Thread(
             target=self._run, name="continuous-batcher", daemon=True
         )
         self._thread.start()
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **meta):
+        """One stretch of the batcher thread under a phase name, twice
+        over from the same two clock reads: a ``batcher.<name>``
+        ``jax.profiler.TraceAnnotation`` (the profiler's host plane, on
+        the device planes' clock; one flag check when no profile is
+        being taken; ``meta`` rides as the event's stats), and seconds
+        towards ``gateway_batcher_phase_seconds_total{phase=name}``.
+
+        Annotations nest as they ran; the seconds never overlap. The
+        clock runs for the innermost open phase only, so an outer phase
+        leaves out what ran inside it, and the time between two phases
+        (the loop's own branches) goes to the one that opens next: over
+        any stretch of the thread's life the phases sum to its length.
+        Worker thread only."""
+        open_ = self._phase_open
+        now = time.perf_counter()
+        self._phase_s[open_[-1] if open_ else name] += now - self._phase_t
+        self._phase_t = now
+        open_.append(name)
+        try:
+            with jax.profiler.TraceAnnotation("batcher." + name, **meta):
+                yield
+        finally:
+            now = time.perf_counter()
+            open_.pop()
+            self._phase_s[name] += now - self._phase_t
+            self._phase_t = now
+
+    def _flush_phases(self) -> None:
+        """Move the gathered phase seconds into the counter family:
+        once per loop iteration, not once per phase."""
+        for name, seconds in self._phase_s.items():
+            if seconds:
+                self._phase_children[name].inc(seconds)
+                self._phase_s[name] = 0.0
 
     def _named(self, spec) -> "object":
         """NamedSharding over this batcher's mesh for an axis tuple."""
@@ -1843,7 +1953,7 @@ class ContinuousBatcher:
         """
         if s_bucket not in self._jit_prefill:
 
-            def f(params, cache, tokens, length, seq_id):
+            def prefill_dense(params, cache, tokens, length, seq_id):
                 dense = KVCache.create(self.cfg, 1, s_bucket)
                 logits, dense = prefill(
                     self.cfg, params, tokens, length[None], dense,
@@ -1854,7 +1964,9 @@ class ContinuousBatcher:
                 )
                 return logits[0], cache
 
-            self._jit_prefill[s_bucket] = jax.jit(f, donate_argnums=(1,))
+            self._jit_prefill[s_bucket] = jax.jit(
+                prefill_dense, donate_argnums=(1,)
+            )
         return self._jit_prefill[s_bucket]
 
     def _chunk_fn(self, chunk: int, s_bucket: int):
@@ -1872,7 +1984,10 @@ class ContinuousBatcher:
         if key not in self._jit_chunk:
             cfg = self.cfg.moe_pin_for(s_bucket, chunk)
             self._jit_chunk[key] = jax.jit(
-                partial(prefill_chunk_paged, cfg, mesh=self.mesh),
+                _step_program(
+                    "prefill_chunk",
+                    partial(prefill_chunk_paged, cfg, mesh=self.mesh),
+                ),
                 donate_argnums=(4,),
             )
         return self._jit_chunk[key]
@@ -1888,7 +2003,7 @@ class ContinuousBatcher:
             dcfg = self._draft_cfg.moe_pin_for(s_bucket, chunk)
             t2d = self._t2d
 
-            def f(params, tokens, table, pos, dcache):
+            def prefill_chunk_draft(params, tokens, table, pos, dcache):
                 # Cross-model remap (PR 18): the chunk arrives in
                 # TARGET ids (the one prompt tokenization both pools
                 # share); the draft model reads its t2d image. The
@@ -1900,7 +2015,9 @@ class ContinuousBatcher:
                     mesh=self.mesh,
                 )
 
-            self._jit_chunk_d[key] = jax.jit(f, donate_argnums=(4,))
+            self._jit_chunk_d[key] = jax.jit(
+                prefill_chunk_draft, donate_argnums=(4,)
+            )
         return self._jit_chunk_d[key]
 
     def _prefill_fn_d(self, s_bucket: int):
@@ -1910,7 +2027,7 @@ class ContinuousBatcher:
             dcfg = self._draft_cfg
             t2d = self._t2d
 
-            def f(params, cache, tokens, length, seq_id):
+            def prefill_dense_draft(params, cache, tokens, length, seq_id):
                 if t2d is not None:
                     # Cross-model remap (PR 18): target-id prompt, t2d
                     # image into the draft (see _chunk_fn_d).
@@ -1925,7 +2042,9 @@ class ContinuousBatcher:
                 )
                 return cache
 
-            self._jit_prefill_d[s_bucket] = jax.jit(f, donate_argnums=(1,))
+            self._jit_prefill_d[s_bucket] = jax.jit(
+                prefill_dense_draft, donate_argnums=(1,)
+            )
         return self._jit_prefill_d[s_bucket]
 
     def _draft_prefill_chunk(self, slot: _Slot, chunk_ids, pos: int) -> None:
@@ -2032,7 +2151,9 @@ class ContinuousBatcher:
         if key not in self._jit_fused:
             cfg_chunk = self.cfg.moe_pin_for(s_bucket, chunk)
             self._jit_fused[key] = jax.jit(
-                partial(self._fused_sample, cfg_chunk),
+                _step_program(
+                    "fused_step", partial(self._fused_sample, cfg_chunk)
+                ),
                 donate_argnums=(1,),
                 static_argnums=(8, 14, 15),
             )
@@ -3444,7 +3565,8 @@ class ContinuousBatcher:
         # evenly over its pages (dur/n observed n times), keeping the
         # family's count == restored-pages lockstep with
         # offload_restored_total.
-        jax.block_until_ready(self.cache.length)
+        with self._phase("device_wait"):
+            jax.block_until_ready(self.cache.length)
         dur = time.perf_counter() - t0
         per = dur / len(group)
         for i, (node, _, trace) in enumerate(group):
@@ -3658,77 +3780,88 @@ class ContinuousBatcher:
             # stall histogram times ONLY this chunk. A device-order
             # wait, NOT a flush: the pending fetches stay pipelined
             # and cost ~nothing afterwards.
-            jax.block_until_ready(self.cache.length)
-        t0 = time.perf_counter()
-        ev = self._count_program("prefill")
-        chunk_ids = slot.padded_ids[slot.next_pos : slot.next_pos + slot.chunk]
-        hidden, self.cache = self._chunk_fn(slot.chunk, slot.s_bucket)(
-            self.params,
-            jnp.asarray(chunk_ids[None]),
-            jnp.asarray(slot.table),
-            jnp.int32(slot.next_pos),
-            self.cache,
-        )
-        if self.draft_cache is not None:
-            self._draft_prefill_chunk(slot, chunk_ids, slot.next_pos)
-        written_end = slot.next_pos + slot.chunk
-        done = written_end >= slot.prompt_len
-        if done:
-            # Sample the first token from the last REAL position's
-            # hidden state (a [D] gather + D x V unembed — never a
-            # [C, V] logits buffer per chunk).
-            h = hidden[0, slot.prompt_len - 1 - slot.next_pos]
-            logits = self._jit_unembed(self.params, h)
-            first = self._sample_first(slot.request, logits)
+            with self._phase("device_wait"):
+                jax.block_until_ready(self.cache.length)
+        with self._phase("dispatch", kind="prefill"):
+            t0 = time.perf_counter()
+            ev = self._count_program("prefill")
+            chunk_ids = slot.padded_ids[
+                slot.next_pos : slot.next_pos + slot.chunk
+            ]
+            hidden, self.cache = self._chunk_fn(slot.chunk, slot.s_bucket)(
+                self.params,
+                jnp.asarray(chunk_ids[None]),
+                jnp.asarray(slot.table),
+                jnp.int32(slot.next_pos),
+                self.cache,
+            )
+            if self.draft_cache is not None:
+                self._draft_prefill_chunk(slot, chunk_ids, slot.next_pos)
+            written_end = slot.next_pos + slot.chunk
+            done = written_end >= slot.prompt_len
+            if done:
+                # Sample the first token from the last REAL position's
+                # hidden state (a [D] gather + D x V unembed — never a
+                # [C, V] logits buffer per chunk).
+                h = hidden[0, slot.prompt_len - 1 - slot.next_pos]
+                logits = self._jit_unembed(self.params, h)
+                first = self._sample_first(slot.request, logits)
         # The device work above must COMPLETE before (a) the stall
         # histogram records it and (b) successors read the pages this
         # chunk wrote.
-        jax.block_until_ready(self.cache.length)
-        dur = time.perf_counter() - t0
-        _M_PREFILL_STALL.observe(dur)
-        if ev is not None:
-            # Standalone chunk programs are host-blocking: the device
-            # window IS [t0, t0 + dur] — fill the flight event now.
-            # Meta is REPLACED, not mutated: a concurrent /debug/flight
-            # export may be iterating the old dict.
-            ev.t0 = t0
-            ev.dur = dur
-            ev.meta = {
-                **ev.meta, "slot": idx, "pos": slot.next_pos,
-                "width": slot.chunk,
-            }
-        self._mbu_account(
-            "prefill",
-            self._program_cost(
-                "prefill", [], 0, chunk_ext=(written_end, slot.chunk)
-            ),
-            dur,
-        )
-        trace = slot.request.trace
-        if trace is not None:
-            trace.add_span(
-                "prefill_chunk", t0, dur, pos=slot.next_pos, chunk=slot.chunk
+        with self._phase("device_wait"):
+            jax.block_until_ready(self.cache.length)
+        # What the fetch does for a fused chunk: credit it, and on the
+        # last one activate the row.
+        with self._phase("retire"):
+            dur = time.perf_counter() - t0
+            _M_PREFILL_STALL.observe(dur)
+            if ev is not None:
+                # Standalone chunk programs are host-blocking: the
+                # device window IS [t0, t0 + dur] — fill the flight
+                # event now. Meta is REPLACED, not mutated: a concurrent
+                # /debug/flight export may be iterating the old dict.
+                ev.t0 = t0
+                ev.dur = dur
+                ev.meta = {
+                    **ev.meta, "slot": idx, "pos": slot.next_pos,
+                    "width": slot.chunk,
+                }
+            self._mbu_account(
+                "prefill",
+                self._program_cost(
+                    "prefill", [], 0, chunk_ext=(written_end, slot.chunk)
+                ),
+                dur,
             )
-        written_real = min(written_end, slot.prompt_len)
-        for node, end_pos in slot.reg_nodes:
-            if not node.ready and end_pos <= written_real:
-                node.ready = True
-        slot.next_pos = written_end
-        with self._lock:
-            self._prefill_chunks += 1
-        if not done:
+            trace = slot.request.trace
+            if trace is not None:
+                trace.add_span(
+                    "prefill_chunk", t0, dur,
+                    pos=slot.next_pos, chunk=slot.chunk,
+                )
+            written_real = min(written_end, slot.prompt_len)
+            _M_PREFILL_TOKENS.inc(written_real - slot.next_pos)
+            for node, end_pos in slot.reg_nodes:
+                if not node.ready and end_pos <= written_real:
+                    node.ready = True
+            slot.next_pos = written_end
+            with self._lock:
+                self._prefill_chunks += 1
+            if not done:
+                return True
+            # Final chunk landed: make the row visible to the decode
+            # program (table + true length in one pass) and flip to
+            # decoding.
+            self.cache = install_seq(
+                self.cache,
+                jnp.int32(idx),
+                jnp.asarray(slot.table),
+                jnp.int32(slot.prompt_len),
+            )
+            self._install_draft_seq(idx, slot)
+            self._activate(idx, slot, first)
             return True
-        # Final chunk landed: make the row visible to the decode program
-        # (table + true length in one pass) and flip to decoding.
-        self.cache = install_seq(
-            self.cache,
-            jnp.int32(idx),
-            jnp.asarray(slot.table),
-            jnp.int32(slot.prompt_len),
-        )
-        self._install_draft_seq(idx, slot)
-        self._activate(idx, slot, first)
-        return True
 
     def _install_draft_seq(self, idx: int, slot: _Slot) -> None:
         """Mirror a slot activation into the draft pool: same table,
@@ -3745,22 +3878,31 @@ class ContinuousBatcher:
 
     def _sample_first(self, req: _Request, logits) -> int:
         """First generated token, sampled from prefill logits — the
-        same (seed, 0) PRNG draw both admission paths share."""
-        key = jax.random.fold_in(jax.random.PRNGKey(req.seed), 0)
-        tok, _ = sample_token_per_request(
-            logits[None],
-            key[None],
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_k], jnp.int32),
-            jnp.asarray([req.top_p], jnp.float32),
-            filters_active=(req.top_k != 0 or req.top_p != 1.0),
-        )
-        return int(tok[0])
+        same (seed, 0) PRNG draw both admission paths share.
+
+        All of it is a wait on the device (the ``device_wait`` phase),
+        not only the ``int()`` at its end: the sampling ops are eager
+        programs of their own, and with a step program in flight the
+        runtime holds the second of them back in its enqueue until
+        that program ends (PERF.md, Findings PR 26: ~50 ms a call on
+        the chip)."""
+        with self._phase("device_wait"):
+            key = jax.random.fold_in(jax.random.PRNGKey(req.seed), 0)
+            tok, _ = sample_token_per_request(
+                logits[None],
+                key[None],
+                jnp.asarray([req.temperature], jnp.float32),
+                jnp.asarray([req.top_k], jnp.int32),
+                jnp.asarray([req.top_p], jnp.float32),
+                filters_active=(req.top_k != 0 or req.top_p != 1.0),
+            )
+            return int(tok[0])
 
     def _activate(self, idx: int, slot: _Slot, first: int) -> None:
         """Flip a slot to decoding with its first sampled token."""
         req = slot.request
         slot.generated.append(first)
+        _M_GENERATED.inc()
         slot.phase = "decode"
         slot.deps = []
         # First generated token: the request's TTFT anchor (batcher
@@ -3874,41 +4016,44 @@ class ContinuousBatcher:
         idx = self._dense_pending
         slot = self._slots[idx]
         req = slot.request
-        t0 = time.perf_counter()
-        ev = self._count_program("prefill")
-        s_bucket = self._bucket(len(req.prompt_ids))
-        slot.s_bucket = s_bucket  # program-family key (draft catch-up)
-        padded = np.full((1, s_bucket), self.tokenizer.pad_id, np.int32)
-        padded[0, : len(req.prompt_ids)] = req.prompt_ids
-        table = np.full((c.pages_per_seq,), NULL_PAGE, np.int32)
-        table[: len(slot.pages)] = slot.pages
-        self.cache = assign_pages(
-            self.cache, jnp.int32(idx), jnp.asarray(table)
-        )
-        logits, self.cache = self._prefill_fn(s_bucket)(
-            self.params,
-            self.cache,
-            jnp.asarray(padded),
-            jnp.int32(len(req.prompt_ids)),
-            jnp.int32(idx),
-        )
-        if self.draft_cache is not None:
-            # Mirror the legacy dense admission into the draft pool:
-            # same table, the draft's own dense prefill + scatter.
-            self._count_program("draft")
-            self.draft_cache = assign_pages(
-                self.draft_cache, jnp.int32(idx), jnp.asarray(table)
+        with self._phase("dispatch", kind="prefill"):
+            t0 = time.perf_counter()
+            ev = self._count_program("prefill")
+            s_bucket = self._bucket(len(req.prompt_ids))
+            slot.s_bucket = s_bucket  # program-family key (draft catch-up)
+            padded = np.full((1, s_bucket), self.tokenizer.pad_id, np.int32)
+            padded[0, : len(req.prompt_ids)] = req.prompt_ids
+            table = np.full((c.pages_per_seq,), NULL_PAGE, np.int32)
+            table[: len(slot.pages)] = slot.pages
+            self.cache = assign_pages(
+                self.cache, jnp.int32(idx), jnp.asarray(table)
             )
-            self.draft_cache = self._prefill_fn_d(s_bucket)(
-                self._draft_params,
-                self.draft_cache,
+            logits, self.cache = self._prefill_fn(s_bucket)(
+                self.params,
+                self.cache,
                 jnp.asarray(padded),
                 jnp.int32(len(req.prompt_ids)),
                 jnp.int32(idx),
             )
-        first = self._sample_first(req, logits)
-        jax.block_until_ready(self.cache.length)
+            if self.draft_cache is not None:
+                # Mirror the legacy dense admission into the draft pool:
+                # same table, the draft's own dense prefill + scatter.
+                self._count_program("draft")
+                self.draft_cache = assign_pages(
+                    self.draft_cache, jnp.int32(idx), jnp.asarray(table)
+                )
+                self.draft_cache = self._prefill_fn_d(s_bucket)(
+                    self._draft_params,
+                    self.draft_cache,
+                    jnp.asarray(padded),
+                    jnp.int32(len(req.prompt_ids)),
+                    jnp.int32(idx),
+                )
+            first = self._sample_first(req, logits)
+        with self._phase("device_wait"):
+            jax.block_until_ready(self.cache.length)
         dur = time.perf_counter() - t0
+        _M_PREFILL_TOKENS.inc(len(req.prompt_ids))
         # The whole-prompt stall this path pays per admission — the
         # number the chunked scheduler bounds to one chunk.
         _M_PREFILL_STALL.observe(dur)
@@ -4439,6 +4584,7 @@ class ContinuousBatcher:
                 # between them, only their fetch/flush consumers care).
                 self._draft_prefill_chunk(slot, chunk_ids, slot.next_pos)
             written_real = min(written_end, slot.prompt_len)
+            _M_PREFILL_TOKENS.inc(written_real - slot.next_pos)
             # Device-stream readiness: the pages this chunk covers are
             # written by an ALREADY-DISPATCHED program, and every
             # consumer is either a later program on the same stream
@@ -4514,12 +4660,21 @@ class ContinuousBatcher:
         pre-budgeted by :meth:`_table_pages`.
         """
         rec = self._inflight.popleft()
-        next_np = np.asarray(rec.tokens)  # [slots, k] — THE host sync
-        cnt_np = (
-            np.asarray(rec.emit_cnt)
-            if (rec.spec or rec.rounds)
-            else None
-        )
+        with self._phase("device_wait"):
+            next_np = np.asarray(rec.tokens)  # [slots, k] — THE host sync
+            cnt_np = (
+                np.asarray(rec.emit_cnt)
+                if (rec.spec or rec.rounds)
+                else None
+            )
+        with self._phase("retire"):
+            self._credit_fetched(rec, next_np, cnt_np)
+
+    def _credit_fetched(self, rec: "_Inflight", next_np, cnt_np) -> None:
+        """The host bookkeeping of one fetched program (the ``retire``
+        phase): step telemetry, crediting its tokens to the rows still
+        alive, stop scans, retirement, and a fused chunk's deferred
+        activation."""
         step_end = time.perf_counter()
         # Device-step latency: at depth 1 the program started at its
         # own dispatch; deeper, it started when its predecessor
@@ -4673,6 +4828,8 @@ class ContinuousBatcher:
                     break
             if done:
                 self._retire(i)
+        if emitted_total:
+            _M_GENERATED.inc(emitted_total)
         if tbt_count:
             with self._lock:
                 self._tbt_sum += tbt_sum
@@ -4752,12 +4909,14 @@ class ContinuousBatcher:
     def _serve_loop(self) -> None:
         while not self._stop.is_set():
             self._hb_tick = time.monotonic()
+            self._flush_phases()
             # Fleet requests first (PR 14): preemption frees pages the
             # admission below may need; exports are bounded spills.
-            self._steer_step()
-            self._preempt_step()
-            self._export_step()
-            self._admit()
+            with self._phase("admit"):
+                self._steer_step()
+                self._preempt_step()
+                self._export_step()
+                self._admit()
             progress = False
             ran_program = False
             # At most ONE prefill work unit per iteration — a host-tier
@@ -4766,9 +4925,10 @@ class ContinuousBatcher:
             # instead of a whole prompt's prefill.
             chunk_idx = None
             if self.config.prefill_chunk > 0:
-                if self._restore_step():
-                    progress = True
-                else:
+                if self._restores:
+                    with self._phase("restore"):
+                        progress = self._restore_step()
+                if not progress:
                     chunk_idx = self._pick_prefill_slot()
             # Speculative decoding (PR 9): read the engage state once
             # per iteration (the bench flips config.spec_decode between
@@ -4897,17 +5057,26 @@ class ContinuousBatcher:
                     )
                     if tail_mode != mode_now:
                         self._flush_pipeline()
-                if spec_now:
-                    # Rows that decoded through an off window need
-                    # their draft mirror replayed first — no-op in the
-                    # steady state (every lag-free iteration).
-                    self._spec_catch_up()
-                self._dispatch(
-                    chunk_idx if fused else None,
-                    spec=spec_now,
-                    rounds=rounds_now,
-                    rounds_choice=rounds_choice,
-                )
+                with self._phase(
+                    "dispatch",
+                    kind=(
+                        "spec"
+                        if spec_now
+                        else ("fused" if fused else "decode")
+                    ),
+                    rows=self._decoding(),
+                ):
+                    if spec_now:
+                        # Rows that decoded through an off window need
+                        # their draft mirror replayed first — no-op in
+                        # the steady state (every lag-free iteration).
+                        self._spec_catch_up()
+                    self._dispatch(
+                        chunk_idx if fused else None,
+                        spec=spec_now,
+                        rounds=rounds_now,
+                        rounds_choice=rounds_choice,
+                    )
                 while len(self._inflight) >= self._depth:
                     self._fetch_one()
                 progress = True
@@ -4930,7 +5099,8 @@ class ContinuousBatcher:
                     self._work_iterations += 1
             if not progress:
                 self._last_step_end = None
-                self._work.wait(timeout=0.1)
+                with self._phase("idle"):
+                    self._work.wait(timeout=0.1)
                 self._work.clear()
 
 

@@ -1,7 +1,7 @@
 """Utilities: logging, tracing/profiling, deterministic RNG streams.
 
 The reference's observability is ``log``+``env_logger`` only, and its
-only timing is the REPL poll pacing (SURVEY.md §5). Here: structured
+only timing is the REPL poll pacing (SURVEY.md §5). Here: request-scoped
 span tracing with wall-clock + optional JAX profiler integration, and
 RUST_LOG-convention logging setup.
 """
@@ -9,11 +9,9 @@ RUST_LOG-convention logging setup.
 from llm_consensus_tpu.utils.logging import setup_logging
 from llm_consensus_tpu.utils.tracing import (
     Trace,
-    Tracer,
     TraceStore,
     current_trace,
     request_span,
-    span,
     trace_jax_profile,
     trace_store,
     use_trace,
@@ -21,12 +19,10 @@ from llm_consensus_tpu.utils.tracing import (
 
 __all__ = [
     "Trace",
-    "Tracer",
     "TraceStore",
     "current_trace",
     "request_span",
     "setup_logging",
-    "span",
     "trace_jax_profile",
     "trace_store",
     "use_trace",

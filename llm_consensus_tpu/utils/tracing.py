@@ -1,13 +1,8 @@
-"""Span tracing + JAX profiler hooks.
+"""Request-scoped span tracing + the JAX profiler hook.
 
 Tracing is ~absent in the reference (wall-clock only paces the readiness
 poll, ``src/main.rs:449-454``; SURVEY.md §5). Two layers here:
 
-- :class:`Tracer` / :func:`span` — lightweight flat wall-clock spans
-  recorded as structured events (name, start, duration, metadata),
-  queryable and dumpable to JSON; the engine's per-call instrumentation
-  reports through this. Bounded by a ring buffer (``max_records``,
-  evict-oldest) so a long-lived process cannot grow it without limit.
 - **Request-scoped traces** (PR 5) — :class:`TraceStore` /
   :class:`Trace`: every gateway request gets a trace id at admission;
   the id propagates through the serving stack via a
@@ -20,12 +15,16 @@ poll, ``src/main.rs:449-454``; SURVEY.md §5). Two layers here:
   registry through :func:`set_drop_hook` (wired by
   :mod:`llm_consensus_tpu.server.metrics` on import, so the two
   surfaces move in lockstep). ``GET /debug/traces`` on the gateway
-  renders :meth:`Trace.to_dict` span trees.
+  renders :meth:`Trace.to_dict` span trees. All of it is on
+  ``time.perf_counter``.
 - :func:`trace_jax_profile` — context manager around
-  ``jax.profiler.trace`` producing a TensorBoard-loadable device trace
-  for the real TPU hot loop; the gateway's ``X-Profile: 1`` header
-  (with ``serve --profile-dir``) drops one aligned with a request's
-  host spans.
+  ``jax.profiler.start_trace`` producing a TensorBoard-loadable device
+  trace for the real TPU hot loop; the gateway's ``X-Profile: 1``
+  header (with ``serve --profile-dir``) drops one. Its first host event
+  is ``profile.anchor``, which carries ``perf_counter_ns`` at that
+  instant: the one stamp that places request spans and flight events
+  (``perf_counter``) on the profile's clock, beside the device planes
+  and the batcher's own ``batcher.<phase>`` annotations.
 
 Process-wide tracing can be disabled entirely (:func:`set_enabled`,
 ``serve --no-trace``): :meth:`TraceStore.start` then returns ``None``
@@ -37,105 +36,12 @@ from __future__ import annotations
 
 import contextlib
 import itertools
-import json
 import threading
 import time
 import uuid
-from collections import OrderedDict, deque
+from collections import OrderedDict
 from contextvars import ContextVar
 from dataclasses import dataclass, field
-
-
-@dataclass
-class SpanRecord:
-    name: str
-    start: float
-    duration: float
-    meta: dict = field(default_factory=dict)
-
-
-class Tracer:
-    """Collects timed spans; thread-safe (backend calls run in threads).
-
-    ``max_records`` bounds memory: the oldest span is evicted when the
-    ring is full, and :attr:`dropped` counts evictions (also mirrored
-    into the Prometheus drop counter via the module drop hook).
-    """
-
-    def __init__(self, max_records: int = 4096) -> None:
-        if max_records <= 0:
-            raise ValueError(f"max_records must be > 0, got {max_records}")
-        self.max_records = max_records
-        self._records: deque[SpanRecord] = deque()
-        self._dropped = 0
-        self._lock = threading.Lock()
-
-    @contextlib.contextmanager
-    def span(self, name: str, **meta):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dur = time.perf_counter() - t0
-            with self._lock:
-                if len(self._records) >= self.max_records:
-                    self._records.popleft()
-                    self._dropped += 1
-                    _notify_drop("span", 1)
-                self._records.append(
-                    SpanRecord(name=name, start=t0, duration=dur, meta=meta)
-                )
-
-    @property
-    def records(self) -> list[SpanRecord]:
-        with self._lock:
-            return list(self._records)
-
-    @property
-    def dropped(self) -> int:
-        """Spans evicted from the ring (recorded-then-lost count)."""
-        return self._dropped
-
-    def total(self, name: str) -> float:
-        return sum(r.duration for r in self.records if r.name == name)
-
-    def summary(self) -> dict[str, dict]:
-        out: dict[str, dict] = {}
-        for r in self.records:
-            agg = out.setdefault(
-                r.name, {"count": 0, "total_s": 0.0, "max_s": 0.0}
-            )
-            agg["count"] += 1
-            agg["total_s"] += r.duration
-            agg["max_s"] = max(agg["max_s"], r.duration)
-        return out
-
-    def dump_json(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(
-                [
-                    {
-                        "name": r.name,
-                        "start": r.start,
-                        "duration": r.duration,
-                        **({"meta": r.meta} if r.meta else {}),
-                    }
-                    for r in self.records
-                ],
-                f,
-            )
-
-
-_GLOBAL = Tracer()
-
-
-def span(name: str, **meta):
-    """Span on the process-global tracer."""
-    return _GLOBAL.span(name, **meta)
-
-
-def global_tracer() -> Tracer:
-    return _GLOBAL
 
 
 # ---------------------------------------------------------------------------
@@ -499,11 +405,20 @@ def request_span(name: str, **meta):
 @contextlib.contextmanager
 def trace_jax_profile(logdir: str):
     """Capture a JAX/XLA device profile (TensorBoard format) around a
-    block — the real profiling story for the TPU hot loop."""
+    block — the real profiling story for the TPU hot loop. The profile
+    opens with one ``profile.anchor`` host event whose
+    ``perf_counter_ns`` stat is this process's ``time.perf_counter_ns``
+    at the event's start: subtract the two and every span, flight event
+    and summary stamped on ``perf_counter`` has a place on the
+    profile's clock."""
     import jax
 
     jax.profiler.start_trace(logdir)
     try:
+        with jax.profiler.TraceAnnotation(
+            "profile.anchor", perf_counter_ns=time.perf_counter_ns()
+        ):
+            pass
         yield
     finally:
         jax.profiler.stop_trace()

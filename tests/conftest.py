@@ -126,7 +126,7 @@ _SMOKE_TESTS = {
     "test_submit_after_close_raises",
     # native runtime / utils / cli
     "test_batch_encode_matches_python_tokenizer",
-    "test_tracer_spans_and_summary",
+    "test_request_spans_tree_and_summary",
     "test_parser_defaults",
     "test_one_shot_question_fake_backend",
 }

@@ -1,6 +1,6 @@
 """Request-scoped tracing + step-time telemetry (PR 5).
 
-Covers the trace subsystem end to end: bounded Tracer/TraceStore rings
+Covers the trace subsystem end to end: bounded TraceStore/Trace rings
 with drop counters mirrored into the metrics registry (lockstep), the
 contextvars span protocol, Prometheus label escaping (incl. the newline
 case that corrupts an exposition), the gateway acceptance path — one
@@ -82,16 +82,20 @@ def _dropped(kind: str) -> float:
     return TRACE_DROPPED.labels(kind=kind).value
 
 
-def test_tracer_ring_cap_and_drop_counter():
+def test_request_span_budget_and_drop_counter():
+    """Spans opened through ``request_span`` past a trace's budget are
+    refused, counted on the trace and mirrored into the registry (was:
+    the flat Tracer's evict-oldest ring)."""
     before = _dropped("span")
-    tr = tracing.Tracer(max_records=4)
-    for i in range(10):
-        with tr.span("s", i=i):
-            pass
-    assert len(tr.records) == 4
-    assert tr.dropped == 6
-    # Evict-oldest: the survivors are the newest four.
-    assert [r.meta["i"] for r in tr.records] == [6, 7, 8, 9]
+    trace = tracing.TraceStore(max_spans=4).start("t")
+    with tracing.use_trace(trace):
+        for i in range(10):
+            with tracing.request_span("s", i=i):
+                pass
+    assert trace.n_spans == 4
+    assert trace.dropped_spans == 6
+    # Refuse-newest: the survivors are the first four.
+    assert [s.meta["i"] for s in trace.spans()] == [0, 1, 2, 3]
     assert _dropped("span") - before == 6
 
 

@@ -4,7 +4,11 @@ import json
 import logging
 
 from llm_consensus_tpu.utils.logging import setup_logging
-from llm_consensus_tpu.utils.tracing import Tracer
+from llm_consensus_tpu.utils.tracing import (
+    TraceStore,
+    request_span,
+    use_trace,
+)
 
 
 def test_setup_logging_levels():
@@ -19,76 +23,79 @@ def test_setup_logging_levels():
     assert logging.getLogger().level == logging.INFO
 
 
-def test_tracer_spans_and_summary(tmp_path):
-    tr = Tracer()
-    with tr.span("evaluate", round=1):
-        with tr.span("decode"):
+def test_request_spans_tree_and_summary():
+    """Nested ``request_span``s under ``use_trace`` land on the trace as
+    a tree (was: the flat Tracer's records/summary/dump_json)."""
+    trace = TraceStore().start("question")
+    with use_trace(trace):
+        with request_span("evaluate", round=1):
+            with request_span("decode"):
+                pass
+        with request_span("decode"):
             pass
-    with tr.span("decode"):
-        pass
-    assert len(tr.records) == 3
-    s = tr.summary()
-    assert s["decode"]["count"] == 2
-    assert s["evaluate"]["count"] == 1
-    assert tr.total("decode") >= 0.0
+    trace.finish()
+    assert trace.n_spans == 3
+    names = [s.name for s in trace.spans()]
+    assert names.count("decode") == 2 and names.count("evaluate") == 1
+    assert all(s.duration >= 0.0 for s in trace.spans())
 
-    out = tmp_path / "trace.json"
-    tr.dump_json(str(out))
-    data = json.loads(out.read_text())
-    assert len(data) == 3
-    assert {d["name"] for d in data} == {"evaluate", "decode"}
-    assert any(d.get("meta") == {"round": 1} for d in data)
+    doc = json.loads(json.dumps(trace.to_dict()))  # what /debug/traces sends
+    assert doc["n_spans"] == 3 and doc["finished"]
+    top = {n["name"]: n for n in doc["spans"]}
+    assert set(top) == {"evaluate", "decode"}
+    assert top["evaluate"]["meta"] == {"round": 1}
+    assert [c["name"] for c in top["evaluate"]["children"]] == ["decode"]
+    # Outside use_trace a span is a silent no-op.
+    with request_span("orphan"):
+        pass
+    assert trace.n_spans == 3
+
+
+def _tiny_engine(draft_seed=None):
+    import jax
+    import jax.numpy as jnp
+
+    from llm_consensus_tpu.engine.engine import EngineConfig, InferenceEngine
+    from llm_consensus_tpu.models.configs import get_config
+    from llm_consensus_tpu.models.transformer import init_params
+
+    cfg = get_config("test-tiny")
+
+    def weights(seed):
+        return init_params(cfg, jax.random.PRNGKey(seed), dtype=jnp.float32)
+
+    return InferenceEngine(
+        cfg,
+        weights(0),
+        engine_config=EngineConfig(
+            max_new_tokens=3, seq_buckets=(16,), batch_buckets=(1, 2)
+        ),
+        draft=None if draft_seed is None else (cfg, weights(draft_seed)),
+    )
 
 
 def test_engine_records_generate_spans():
-    import jax
-    import jax.numpy as jnp
-
-    from llm_consensus_tpu.engine.engine import EngineConfig, InferenceEngine
-    from llm_consensus_tpu.models.configs import get_config
-    from llm_consensus_tpu.models.transformer import init_params
-    from llm_consensus_tpu.utils.tracing import Tracer
-
-    cfg = get_config("test-tiny")
-    tracer = Tracer()
-    eng = InferenceEngine(
-        cfg,
-        init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32),
-        engine_config=EngineConfig(
-            max_new_tokens=3, seq_buckets=(16,), batch_buckets=(1, 2)
-        ),
-        tracer=tracer,
-    )
-    eng.generate_texts(["one", "two"])
-    spans = [r for r in tracer.records if r.name == "engine.generate"]
+    """The engine's one instrumentation site reports to the caller's
+    request trace (was: to an ``InferenceEngine(tracer=...)``)."""
+    eng = _tiny_engine()
+    trace = TraceStore().start("request")
+    with use_trace(trace):
+        eng.generate_texts(["one", "two"])
+    spans = [s for s in trace.spans() if s.name == "engine.generate"]
     assert len(spans) == 1
     assert spans[0].meta["n_real"] == 2
     assert spans[0].duration > 0
+    eng.generate_texts(["three"])  # untraced: nothing recorded, no error
+    assert len(trace.spans()) == len(spans)
 
 
 def test_engine_records_speculative_spans():
-    import jax
-    import jax.numpy as jnp
-
-    from llm_consensus_tpu.engine.engine import EngineConfig, InferenceEngine
-    from llm_consensus_tpu.models.configs import get_config
-    from llm_consensus_tpu.models.transformer import init_params
-    from llm_consensus_tpu.utils.tracing import Tracer
-
-    cfg = get_config("test-tiny")
-    tracer = Tracer()
-    eng = InferenceEngine(
-        cfg,
-        init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32),
-        engine_config=EngineConfig(
-            max_new_tokens=3, seq_buckets=(16,), batch_buckets=(1, 2)
-        ),
-        draft=(cfg, init_params(cfg, jax.random.PRNGKey(7), dtype=jnp.float32)),
-        tracer=tracer,
-    )
-    eng.generate_texts_speculative(["one"])
+    eng = _tiny_engine(draft_seed=7)
+    trace = TraceStore().start("request")
+    with use_trace(trace):
+        eng.generate_texts_speculative(["one"])
     spans = [
-        r for r in tracer.records if r.name == "engine.generate_speculative"
+        s for s in trace.spans() if s.name == "engine.generate_speculative"
     ]
     assert len(spans) == 1
     assert spans[0].meta["k_spec"] == 4
