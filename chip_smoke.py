@@ -405,6 +405,15 @@ def kernels_child(dry_run: bool) -> int:
            window=window)
     ragged("chunk only, idle decode rows", valid_len=[0] * b, cq=cq,
            chunk_start=0, null_tables=True)
+    # The step programs' call: layer l of the stacked pools, indexed by
+    # the kernel. Bit-equal to the call on the slice, or the case raises.
+    for at in (0, 2):
+        ragged(f"grouped+chunk, layer {at} of 3 stacked", valid_len=grouped,
+               cq=cq, chunk_start=pg + 11, group_rows=(0, 2, 3, 5),
+               shared_pages=2, window=window, layer=(at, 3))
+    ragged("verify nq=5, layer 2 of 3 stacked",
+           valid_len=[max(f, 5) for f in fills], nq=5, window=win_small,
+           layer=(2, 3))
     for rows in (1, b, b + cq):
         cases.append((
             f"fused_rms_norm[{rows}x{d_model} bf16]", parity.NORM_BF16_TOL,

@@ -862,12 +862,8 @@ def _run_layers(
     # dynamic_index XLA fuses into its consumer.
     # Quant-cache decode via the WHOLE stacked cache + layer index (the
     # token write and attention read happen on the resident buffers with
-    # no per-layer slice or write-back). MEASURED SLOWER than
-    # slice+row-kernel on v5e at bench shapes (24.7k vs 25.5k tok/s/chip
-    # — the materialized slice feeds the row kernel with better DMA
-    # locality than the scalar-prefetch 5-d blocks) and its standalone
-    # compile is pathologically slow; opt-in via set_stacked_decode for
-    # experimentation on other topologies.
+    # no per-layer slice or write-back). Opt-in via set_stacked_decode:
+    # not measured on the installed jax (0.9.0).
     stacked_decode = (
         _STACKED_DECODE and mode == "decode" and isinstance(cache, QuantKVCache)
     )
@@ -1139,8 +1135,9 @@ def _attn_paged(
     cfg: ModelConfig,
     q_dec,
     q_chunk,
-    k_pool,
-    v_pool,
+    k_pools,
+    v_pools,
+    layer,
     tables,
     valid,
     chunk_table=None,
@@ -1155,6 +1152,14 @@ def _attn_paged(
     ragged semantics. Window, groups, and mixed rows are all cases of
     the one kernel — the old per-feature fallback matrix is gone.
 
+    k_pools/v_pools are the WHOLE stacked pools [L, n_pages, page, Hkv,
+    Dh] and ``layer`` a traced index. The single-device kernel indexes
+    the stack itself (the layer rides scalar prefetch: a page's DMA
+    starts from the resident buffer). A Pallas operand must be a whole
+    buffer, so a layer sliced out for it would be copied every layer of
+    every step. The mesh kernel (``shard_map`` wants the pool's own
+    partitioning) and the XLA reference take that layer's view.
+
     ``mesh`` (trace-time constant, PR 13): on a dp×mp mesh the Pallas
     kernel runs under ``shard_map`` — kv heads partitioned over
     ``model``, decode rows and the page pool over ``data`` (the page
@@ -1168,38 +1173,38 @@ def _attn_paged(
     [B, H, D] (and out_chunk [C, H, D] when q_chunk is given).
     """
     window = cfg.sliding_window
-    if cfg.use_pallas:
-        gtuple = None
-        if groups is not None:
-            gtuple = (
-                groups.group_id,
-                groups.group_rep,
-                groups.group_pages.astype(jnp.int32) * k_pool.shape[1],
-                groups.shared_start,
-            )
-        if mesh is not None:
-            if ragged_mesh_shardable(
-                cfg, mesh, q_dec.shape[0], k_pool.shape[0]
-            ):
-                from llm_consensus_tpu.ops.pallas.attention import (
-                    ragged_paged_attention_sharded,
-                )
+    gtuple = None
+    if cfg.use_pallas and groups is not None:
+        gtuple = (
+            groups.group_id,
+            groups.group_rep,
+            groups.group_pages.astype(jnp.int32) * k_pools.shape[2],
+            groups.shared_start,
+        )
+    if cfg.use_pallas and mesh is None:
+        from llm_consensus_tpu.ops.pallas.attention import (
+            ragged_paged_attention,
+        )
 
-                return ragged_paged_attention_sharded(
-                    mesh, q_dec, k_pool, v_pool, tables, valid,
-                    q_chunk=q_chunk, chunk_table=chunk_table,
-                    chunk_start=chunk_start, groups=gtuple, window=window,
-                )
-        else:
-            from llm_consensus_tpu.ops.pallas.attention import (
-                ragged_paged_attention,
-            )
+        return ragged_paged_attention(
+            q_dec, k_pools, v_pools, tables, valid, layer=layer,
+            q_chunk=q_chunk, chunk_table=chunk_table,
+            chunk_start=chunk_start, groups=gtuple, window=window,
+        )
+    k_pool = jax.lax.dynamic_index_in_dim(k_pools, layer, 0, keepdims=False)
+    v_pool = jax.lax.dynamic_index_in_dim(v_pools, layer, 0, keepdims=False)
+    if cfg.use_pallas and ragged_mesh_shardable(
+        cfg, mesh, q_dec.shape[0], k_pool.shape[0]
+    ):
+        from llm_consensus_tpu.ops.pallas.attention import (
+            ragged_paged_attention_sharded,
+        )
 
-            return ragged_paged_attention(
-                q_dec, k_pool, v_pool, tables, valid,
-                q_chunk=q_chunk, chunk_table=chunk_table,
-                chunk_start=chunk_start, groups=gtuple, window=window,
-            )
+        return ragged_paged_attention_sharded(
+            mesh, q_dec, k_pool, v_pool, tables, valid,
+            q_chunk=q_chunk, chunk_table=chunk_table,
+            chunk_start=chunk_start, groups=gtuple, window=window,
+        )
     from llm_consensus_tpu.ops.attention import (
         ragged_paged_attention_reference,
     )
@@ -1209,6 +1214,60 @@ def _attn_paged(
         q_chunk=q_chunk, chunk_table=chunk_table, chunk_start=chunk_start,
         window=window,
     )
+
+
+def _paged_layers(
+    cfg: ModelConfig,
+    params: dict,
+    x: jnp.ndarray,
+    cos: jnp.ndarray,
+    sin: jnp.ndarray,
+    cache,
+    pages: jnp.ndarray,
+    offs: jnp.ndarray,
+    attend,
+    mesh=None,
+    mlp=None,
+):
+    """The layer loop of the four paged step programs.
+
+    Scans over the layer INDEX with the weight stacks and both pools
+    resident, as :func:`_run_layers` does for the engine's caches — not
+    over the stacks themselves. The pools ride the carry and the new
+    rows are scattered into them at ``[layer, page, offset]``, so
+    nothing pool-shaped enters as ``xs`` or leaves as ``ys`` and the
+    caller's donated cache is the buffer the result lives in; weights
+    are :func:`_layer_view` views, so the int8 kernel reads its tiles
+    from the resident stack. (Scanned slices would each be copied out
+    for the Pallas calls, and stacked ``ys`` are a fresh pool a step.)
+
+    x: [b, s, D] token grid; pages/offs: [b, s] destination page and
+    in-page offset of each token's K/V; ``attend(q, k_pools, v_pools,
+    layer)`` -> [b, s, H, Dh] is the program's own call of
+    :func:`_attn_paged` on q [b, s, H, Dh]; ``mlp(p, h)`` replaces the
+    plain :func:`_mlp` where the program splits it. Returns (x, k, v).
+    """
+    blocks = params["blocks"]
+
+    def body(carry, layer):
+        y, k_pools, v_pools = carry
+        p = _layer_view(blocks, layer)
+        h = _rms(cfg, y, p["attn_norm"], mesh)
+        q, k, v = _project_qkv(cfg, p, h)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        k_pools = k_pools.at[layer, pages, offs].set(k.astype(k_pools.dtype))
+        v_pools = v_pools.at[layer, pages, offs].set(v.astype(v_pools.dtype))
+        attn = attend(q, k_pools, v_pools, layer)
+        y = y + _qmm(attn.reshape(*y.shape[:-1], -1), p["wo"])
+        h2 = _rms(cfg, y, p["mlp_norm"], mesh)
+        y = y + (_mlp(cfg, p, h2) if mlp is None else mlp(p, h2))
+        return (y, k_pools, v_pools), None
+
+    (x, new_k, new_v), _ = jax.lax.scan(
+        body, (x, cache.k, cache.v), jnp.arange(cache.k.shape[0])
+    )
+    return x, new_k, new_v
 
 
 def decode_step_paged(
@@ -1275,25 +1334,15 @@ def decode_step_paged(
         adv = write_mask.astype(pos.dtype)
     tables = cache.page_table  # [B, P]
 
-    def body(carry, layer_in):
-        p, k_pool, v_pool = layer_in  # pools [n_pages, page, Hkv, Dh]
-        h = _rms(cfg, carry, p["attn_norm"], mesh)
-        q, k, v = _project_qkv(cfg, p, h)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        k_pool = k_pool.at[pages_now, offset].set(k[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[pages_now, offset].set(v[:, 0].astype(v_pool.dtype))
-        attn = _attn_paged(
-            cfg, q[:, 0], None, k_pool, v_pool, tables, pos + adv,
+    def attend(q, k_pools, v_pools, layer):
+        return _attn_paged(
+            cfg, q[:, 0], None, k_pools, v_pools, layer, tables, pos + adv,
             groups=groups, mesh=mesh,
         )[:, None]  # [B, H, D] -> [B, 1, H, D] (seq axis restored)
-        y = carry + _qmm(attn.reshape(*carry.shape[:-1], -1), p["wo"])
-        h2 = _rms(cfg, y, p["mlp_norm"], mesh)
-        y = y + _mlp(cfg, p, h2)
-        return y, (k_pool, v_pool)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache.k, cache.v)
+    x, new_k, new_v = _paged_layers(
+        cfg, params, x, cos, sin, cache, pages_now[:, None],
+        offset[:, None], attend, mesh=mesh,
     )
     logits = _unembed(cfg, params, x[:, 0], mesh)
     new_cache = PagedKVCache(
@@ -1353,25 +1402,14 @@ def verify_step_paged(
     offs = pos % pg
     tables = cache.page_table
 
-    def body(carry, layer_in):
-        p, k_pool, v_pool = layer_in  # pools [n_pages, page, Hkv, Dh]
-        h = _rms(cfg, carry, p["attn_norm"], mesh)
-        q, k, v = _project_qkv(cfg, p, h)  # [B, NQ, H, Dh]
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        k_pool = k_pool.at[pages, offs].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[pages, offs].set(v.astype(v_pool.dtype))
-        attn = _attn_paged(
-            cfg, q, None, k_pool, v_pool, tables, pos0 + nq, groups=groups,
-            mesh=mesh,
+    def attend(q, k_pools, v_pools, layer):
+        return _attn_paged(
+            cfg, q, None, k_pools, v_pools, layer, tables, pos0 + nq,
+            groups=groups, mesh=mesh,
         )  # [B, NQ, H, D]
-        y = carry + _qmm(attn.reshape(*carry.shape[:-1], -1), p["wo"])
-        h2 = _rms(cfg, y, p["mlp_norm"], mesh)
-        y = y + _mlp(cfg, p, h2)
-        return y, (k_pool, v_pool)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache.k, cache.v)
+    x, new_k, new_v = _paged_layers(
+        cfg, params, x, cos, sin, cache, pages, offs, attend, mesh=mesh
     )
     logits = _unembed(cfg, params, x, mesh)  # [B, NQ, V]
     new_cache = PagedKVCache(
@@ -1428,14 +1466,7 @@ def prefill_chunk_paged(
     offs = pos % pg
     nb = 1 if mesh is None else int(mesh.shape.get("data", 1))
 
-    def body(carry, layer_in):
-        p, k_pool, v_pool = layer_in  # pools [n_pages, page, Hkv, Dh]
-        h = _rms(cfg, carry, p["attn_norm"], mesh)
-        q, k, v = _project_qkv(cfg, p, h)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        k_pool = k_pool.at[pages, offs].set(k[0].astype(k_pool.dtype))
-        v_pool = v_pool.at[pages, offs].set(v[0].astype(v_pool.dtype))
+    def attend(q, k_pools, v_pools, layer):
         # Chunk-only ragged call through the SAME kernel seam as the
         # fused step (dead decode rows: NULL table, valid 0) — a
         # standalone chunk and a fused chunk must write bit-identical
@@ -1448,25 +1479,23 @@ def prefill_chunk_paged(
         # route the STANDALONE chunk to the reference while fused
         # chunks run the sharded kernel — the same mixed-arithmetic
         # hazard, reintroduced by topology instead of by feature flag.
-        attn = _attn_paged(
+        return _attn_paged(
             cfg,
             jnp.zeros((nb, cfg.n_heads, cfg.head_dim), q.dtype),
             q[0],
-            k_pool,
-            v_pool,
+            k_pools,
+            v_pools,
+            layer,
             jnp.zeros((nb, table.shape[0]), jnp.int32),
             jnp.zeros((nb,), jnp.int32),
             chunk_table=table,
             chunk_start=start,
             mesh=mesh,
         )[1][None]  # out_chunk [C, H, D] -> [1, C, H, D]
-        y = carry + _qmm(attn.reshape(*carry.shape[:-1], -1), p["wo"])
-        h2 = _rms(cfg, y, p["mlp_norm"], mesh)
-        y = y + _mlp(cfg, p, h2)
-        return y, (k_pool, v_pool)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache.k, cache.v)
+    x, new_k, new_v = _paged_layers(
+        cfg, params, x, cos, sin, cache, pages[None], offs[None], attend,
+        mesh=mesh,
     )
     new_cache = PagedKVCache(
         k=new_k, v=new_v, page_table=cache.page_table, length=cache.length
@@ -1535,36 +1564,25 @@ def fused_step_paged(
     tables = cache.page_table
     mlp_split = cfg.is_moe and cfg_chunk is not cfg
 
-    def body(carry, layer_in):
-        p, k_pool, v_pool = layer_in  # pools [n_pages, page, Hkv, Dh]
-        h = _rms(cfg, carry, p["attn_norm"], mesh)
-        q, k, v = _project_qkv(cfg, p, h)  # [1, B+C, H, Dh]
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        # One scatter over DISJOINT real pages: decode rows write their
-        # private pages, the chunk writes positions >= chunk_start of
-        # its own table.
-        k_pool = k_pool.at[pages_all, offs_all].set(k[0].astype(k_pool.dtype))
-        v_pool = v_pool.at[pages_all, offs_all].set(v[0].astype(v_pool.dtype))
+    def attend(q, k_pools, v_pools, layer):
         attn_dec, attn_ch = _attn_paged(
-            cfg, q[0, :b], q[0, b:], k_pool, v_pool, tables, pos + 1,
-            chunk_table=chunk_table, chunk_start=chunk_start, groups=groups,
-            mesh=mesh,
+            cfg, q[0, :b], q[0, b:], k_pools, v_pools, layer, tables,
+            pos + 1, chunk_table=chunk_table, chunk_start=chunk_start,
+            groups=groups, mesh=mesh,
         )
-        attn = jnp.concatenate([attn_dec, attn_ch])[None]  # [1, B+C, H, Dh]
-        y = carry + _qmm(attn.reshape(1, b + c, -1), p["wo"])
-        h2 = _rms(cfg, y, p["mlp_norm"], mesh)
-        if mlp_split:
-            y = y + jnp.concatenate(
-                [_mlp(cfg, p, h2[:, :b]), _mlp(cfg_chunk, p, h2[:, b:])],
-                axis=1,
-            )
-        else:
-            y = y + _mlp(cfg, p, h2)
-        return y, (k_pool, v_pool)
+        return jnp.concatenate([attn_dec, attn_ch])[None]  # [1, B+C, H, Dh]
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache.k, cache.v)
+    def mlp_by_side(p, h2):
+        return jnp.concatenate(
+            [_mlp(cfg, p, h2[:, :b]), _mlp(cfg_chunk, p, h2[:, b:])], axis=1
+        )
+
+    # One scatter over DISJOINT real pages: decode rows write their
+    # private pages, the chunk writes positions >= chunk_start of its
+    # own table.
+    x, new_k, new_v = _paged_layers(
+        cfg, params, x, cos, sin, cache, pages_all[None], offs_all[None],
+        attend, mesh=mesh, mlp=mlp_by_side if mlp_split else None,
     )
     logits = _unembed(cfg, params, x[0, :b], mesh)
     hidden_chunk = x[:, b:]  # [1, C, D]

@@ -952,11 +952,14 @@ def _ragged_attention(
     (row B is the chunk's table when ``q_chunk`` [C, H, D] rides
     along); kv_len/suffix_start: [B + nc]. K/V layout is static: the
     pool [n_pages, pg, Hkv, D] (``k_scale`` None), the int8 head-major
-    cache [B, Hkv, S, D] with [B, Hkv, S] scales, or the stacked int8
-    cache [L, B, Hkv, S, D] (``layer`` a traced index) — the dense
-    layouts are addressed as identity-tabled virtual pages of width
-    ``pg``. Returns out_dec shaped like q_dec (and out_chunk [C, H, D]
-    when ``q_chunk``) in q's dtype.
+    cache [B, Hkv, S, D] with [B, Hkv, S] scales, or either of them
+    stacked over layers — pools [L, n_pages, pg, Hkv, D], int8 cache
+    [L, B, Hkv, S, D] — with ``layer`` a traced index that rides scalar
+    prefetch into the index maps, so the blocks the kernel sees are the
+    unstacked layout's. The dense layouts are addressed as
+    identity-tabled virtual pages of width ``pg``. Returns out_dec
+    shaped like q_dec (and out_chunk [C, H, D] when ``q_chunk``) in q's
+    dtype.
     """
     squeeze_nq = q_dec.ndim == 3
     if squeeze_nq:
@@ -973,7 +976,7 @@ def _ragged_attention(
         if s_len % pg:
             raise ValueError(f"cache len {s_len} not a multiple of {pg}")
     else:
-        hkv = k_kv.shape[2]
+        hkv = k_kv.shape[-2]
         npp = 0  # unused
     g = h // hkv
     nc = 0 if q_chunk is None else 1
@@ -1018,10 +1021,12 @@ def _ragged_attention(
 
     def _kv_map(s, j, *pf):
         page = _page_of(s, j, pf)
-        if stacked:
+        if stacked and quant:
             return (pf[0][0], page // npp, 0, page % npp, 0)
         if quant:
             return (page // npp, 0, page % npp, 0)
+        if stacked:
+            return (pf[0][0], page, 0, 0, 0)
         return (page, 0, 0, 0)
 
     def _scale_map(s, j, *pf):
@@ -1086,7 +1091,11 @@ def _ragged_attention(
         inputs += [k_kv, k_scale, v_kv, v_scale]
         in_specs += [kv_spec, sc_spec, kv_spec, sc_spec]
     else:
-        kv_spec = pl.BlockSpec((1, pg, hkv, d), _kv_map)
+        # The layer dimension of a stacked pool is squeezed out of the
+        # block: the kernel sees one page, [1, pg, Hkv, D], either way.
+        kv_spec = pl.BlockSpec(
+            (None, 1, pg, hkv, d) if stacked else (1, pg, hkv, d), _kv_map
+        )
         inputs += [k_kv, v_kv]
         in_specs += [kv_spec, kv_spec]
 
@@ -1220,6 +1229,7 @@ def ragged_paged_attention(
     chunk_start=None,
     groups: tuple | None = None,
     window: int = 0,
+    layer=None,
     interpret: bool | None = None,
 ):
     """Mixed prefill+decode attention over the page pool — ONE program.
@@ -1230,7 +1240,10 @@ def ragged_paged_attention(
     readable" — the NQ new tokens' K/V already written), masked by the
     chunk lane's ragged-causal rule per row. k_pool/v_pool: [n_pages,
     page, Hkv, D]; page_table: [B, P]; valid_len: [B] tokens readable
-    per decode row.
+    per decode row. With ``layer`` (a traced index) the pools are the
+    stacked [L, n_pages, page, Hkv, D] and that layer of them is read
+    in place — what the step programs' layer scan passes, since a layer
+    sliced out for a Pallas call is a copy of it.
 
     ``q_chunk`` [C, H, D] adds ONE prefill-chunk row: C queries at
     absolute positions ``chunk_start + i``, walking ``chunk_table``
@@ -1246,7 +1259,7 @@ def ragged_paged_attention(
     out_dec [B, H, D] (and out_chunk [C, H, D] when ``q_chunk``).
     """
     b = q.shape[0]
-    pg = k_pool.shape[1]
+    pg = k_pool.shape[-3]
     kvlen = valid_len.astype(jnp.int32)
     if groups is not None:
         gid, rep, gend, sstart = groups
@@ -1277,6 +1290,7 @@ def ragged_paged_attention(
         rep=rep,
         gend=gend,
         window=window,
+        layer=layer,
         interpret=interpret,
     )
 

@@ -70,6 +70,7 @@ def ragged_attention_error(
     shared_pages: int = 1,
     window: int = 0,
     null_tables: bool = False,
+    layer: tuple[int, int] | None = None,
     interpret: bool | None = None,
 ) -> dict[str, float]:
     """Ragged paged attention kernel vs
@@ -82,7 +83,11 @@ def ragged_attention_error(
     kernel's group phase (the reference has no groups — grouped output
     must equal ungrouped math). ``null_tables``: all-NULL decode tables
     (an idle batcher's rows next to a live chunk); their output only has
-    to be finite. Returns {"decode": err[, "chunk": err]}.
+    to be finite. ``layer`` = (l, L): the pools are layer l of stacked
+    [L, ...] pools and the kernel indexes the stack (a traced
+    ``layer=``), as the step programs' layer scan calls it; its output
+    must also equal, bit for bit, the call on the slice ``pool[l]``.
+    Returns {"decode": err[, "chunk": err]}.
     """
     from llm_consensus_tpu.ops.attention import (
         ragged_paged_attention_reference,
@@ -124,12 +129,27 @@ def ragged_attention_error(
             ),
             chunk_start=jnp.int32(chunk_start),
         )
-    got = jax.jit(
-        lambda: ragged_paged_attention(
-            q, kp, vp, jnp.asarray(tbl), vl, groups=groups,
-            interpret=interpret, **kw,
+    def kernel(k_pool, v_pool, layer_idx=None):
+        return ragged_paged_attention(
+            q, k_pool, v_pool, jnp.asarray(tbl), vl, groups=groups,
+            layer=layer_idx, interpret=interpret, **kw,
         )
-    )()
+
+    got = jax.jit(lambda: kernel(kp, vp))()
+    if layer is not None:
+        at, n_layers = layer
+        shape = (n_layers, *kp.shape)
+        k_stack = jnp.asarray(rng.standard_normal(shape), kp.dtype)
+        v_stack = jnp.asarray(rng.standard_normal(shape), vp.dtype)
+        on_slice, got = got, jax.jit(kernel)(
+            k_stack.at[at].set(kp), v_stack.at[at].set(vp), jnp.int32(at)
+        )
+        for a, b_ in zip(jax.tree.leaves(got), jax.tree.leaves(on_slice)):
+            if not np.array_equal(np.asarray(a), np.asarray(b_)):
+                raise AssertionError(
+                    f"layer {at} of {n_layers} stacked pools reads other "
+                    f"bytes than its slice: max diff {max_err(a, b_)}"
+                )
     with jax.default_matmul_precision("highest"):
         ref = ragged_paged_attention_reference(
             q, kp, vp, jnp.asarray(tbl), vl, **kw

@@ -317,6 +317,17 @@ def _step_program(name: str, fn):
     return program
 
 
+def _abstract(x):
+    """Shape, dtype and (where the array was placed on purpose) sharding
+    of ``x``, for compiling ahead; anything that is no array as it is."""
+    if not isinstance(x, jax.Array):
+        return x
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding if x.committed else None,
+        weak_type=x.weak_type,
+    )
+
+
 def _fill_device_memory() -> None:
     """gateway_device_memory_bytes from the allocator's own numbers:
     the largest value over the local devices. A render hook of the
@@ -1271,6 +1282,11 @@ class ContinuousBatcher:
         self._jit_prefill = {}
         self._jit_chunk = {}  # (chunk, s_bucket) -> compiled chunk prefill
         self._jit_fused = {}  # (chunk, s_bucket) -> compiled fused step
+        # Buckets whose fused step was built ahead, and the shapes of a
+        # plain dispatch's arguments to build one from
+        # (:meth:`_build_fused_ahead`).
+        self._fused_ahead: set[tuple[int, int]] = set()
+        self._plain_shapes: tuple | None = None
         self._jit_copy_page = jax.jit(copy_page, donate_argnums=(0,))
         self._jit_install_page = jax.jit(install_page, donate_argnums=(0,))
         # Batched restore install (PR 17): one scatter per restore
@@ -1654,11 +1670,12 @@ class ContinuousBatcher:
         plus ``chunk_logits`` [V] — the unembedded hidden state of the
         prompt position ``chunk_last`` (the host samples the request's
         first token from it at fetch, exactly as the standalone path
-        does after its final chunk). ``chunk_done`` is STATIC (the
-        host knows finality at dispatch): non-final chunks skip the
-        full-vocab unembed entirely and return ``None`` — one extra
-        cached trace per (chunk, bucket), no wasted [D]x[D,V] matvec
-        per intermediate chunk.
+        does after its final chunk). ``chunk_done`` is a traced bool
+        under a ``lax.cond``: a non-final chunk skips the full-vocab
+        unembed at run time (its ``chunk_logits`` are zeros nobody
+        reads) without being a program of its own — a program costs
+        seconds to trace and load in every process, and a last-chunk
+        variant first met under load would stall every row for them.
         """
         k = self._sync_chunk
         logits, hidden, cache = fused_step_paged(
@@ -1679,15 +1696,13 @@ class ContinuousBatcher:
         tok1, logp1 = sample_token_per_request(
             logits, keys, temps, topks, topps, filters_active=filters_active
         )
-        chunk_logits = None
-        if chunk_done:
-            c = chunk_tokens.shape[1]
-            h_last = hidden[
-                0, jnp.clip(chunk_last - chunk_start, 0, c - 1)
-            ]
-            chunk_logits = unembed_one(
-                self.cfg, params, h_last, mesh=self.mesh
-            )
+        c = chunk_tokens.shape[1]
+        chunk_logits = jax.lax.cond(
+            chunk_done,
+            lambda h: unembed_one(self.cfg, params, h, mesh=self.mesh),
+            lambda h: jnp.zeros((self.cfg.vocab_size,), jnp.float32),
+            hidden[0, jnp.clip(chunk_last - chunk_start, 0, c - 1)],
+        )
         if stop_rounds:
             # Multi-round tail (PR 12): round 1 was the fused step
             # above (all rows alive by the dispatch invariant); apply
@@ -2155,9 +2170,39 @@ class ContinuousBatcher:
                     "fused_step", partial(self._fused_sample, cfg_chunk)
                 ),
                 donate_argnums=(1,),
-                static_argnums=(8, 14, 15),
+                static_argnums=(8, 15),
             )
         return self._jit_fused[key]
+
+    def _build_fused_ahead(self, chunk: int, s_bucket: int) -> None:
+        """Trace and compile the bucket's ungrouped fused step, once.
+
+        Called before a chunk of the bucket runs alone because nothing
+        decodes, so no decoding row waits for the build: the prompt
+        being prefilled does, seconds, once a bucket and process (and
+        not the process's first, which comes before the first plain
+        dispatch, whose arguments give the shapes). Without it the
+        fused step is first built when a chunk of the bucket first
+        rides a dispatch, with every decoding row stalled behind it; a
+        bucket first met under load is still built there, as is a
+        bucket's grouped step, since rows group only under load.
+        Lowering the jitted function from shapes fills the caches its
+        call reads, and runs nothing."""
+        key = (chunk, s_bucket)
+        if key in self._fused_ahead or self._plain_shapes is None:
+            return
+        self._fused_ahead.add(key)
+        t0 = time.perf_counter()
+        i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+        self._fused_fn(chunk, s_bucket).lower(
+            *self._plain_shapes, None, i32((1, chunk)),
+            i32((self.config.pages_per_seq,)), i32(()), i32(()),
+            jax.ShapeDtypeStruct((), jnp.bool_),
+        ).compile()
+        log.info(
+            "fused step program for a chunk of %d, bucket %d: built in "
+            "%.1f s", chunk, s_bucket, time.perf_counter() - t0,
+        )
 
     @property
     def _fused_ok(self) -> bool:
@@ -3783,6 +3828,8 @@ class ContinuousBatcher:
             with self._phase("device_wait"):
                 jax.block_until_ready(self.cache.length)
         with self._phase("dispatch", kind="prefill"):
+            if self._fused_ok and not self._decoding():
+                self._build_fused_ahead(slot.chunk, slot.s_bucket)
             t0 = time.perf_counter()
             ev = self._count_program("prefill")
             chunk_ids = slot.padded_ids[
@@ -4517,6 +4564,8 @@ class ContinuousBatcher:
             filters_active,
             groups,
         )
+        if self._plain_shapes is None and self._fused_ok and not rounds_now:
+            self._plain_shapes = jax.tree.map(_abstract, args[:9])
         chunk_rec = None
         if chunk_idx is None:
             if rounds_now:
@@ -4548,7 +4597,7 @@ class ContinuousBatcher:
                 jnp.asarray(slot.table),
                 jnp.int32(slot.next_pos),
                 jnp.int32(slot.prompt_len - 1),
-                chunk_done,
+                np.bool_(chunk_done),
                 *(
                     (rounds_now, budgets_dev, screen_dev)
                     if rounds_now

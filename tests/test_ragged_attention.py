@@ -76,16 +76,31 @@ def _within_tol(errs: dict):
         parity.check(lane, err, parity.ATTENTION_TOL)
 
 
-@pytest.mark.parametrize("window", [0, 9])
-def test_ragged_mixed_rows_match_reference(window):
-    """Decode rows at mid-block lengths + one chunk row (starting
-    mid-block too), one program."""
-    errs = parity.ragged_attention_error(
-        **_TOY, seed=0, g=3, valid_len=[13, 1, 40, 23], cq=16,
-        chunk_start=11, window=window,
-    )
-    assert set(errs) == {"decode", "chunk"}
-    _within_tol(errs)
+# Stacked: the same rows read out of layer l of stacked pools through a
+# traced ``layer=`` (what the step programs' layer scan passes), at the
+# first layer and the last; each must return the bytes the call on
+# ``pool[l]`` returns, or ``ragged_attention_error`` raises.
+_SLICE = (None,)
+_STACKED = ((0, 3), (2, 3))
+
+
+@pytest.mark.parametrize(
+    "window, nq, layers",
+    [
+        (0, 1, _SLICE), (9, 1, _SLICE),
+        (0, 1, _STACKED), (9, 1, _STACKED), (9, 3, _STACKED),
+    ],
+)
+def test_ragged_mixed_rows_match_reference(window, nq, layers):
+    """Decode rows (``nq`` > 1: verify rows) at mid-block lengths + one
+    chunk row (starting mid-block too), one program."""
+    for layer in layers:
+        errs = parity.ragged_attention_error(
+            **_TOY, seed=0, g=3, valid_len=[13, nq, 40, 23], cq=16,
+            chunk_start=11, window=window, nq=nq, layer=layer,
+        )
+        assert set(errs) == {"decode", "chunk"}
+        _within_tol(errs)
 
 
 def test_ragged_mqa_single_kv_head():
@@ -97,18 +112,23 @@ def test_ragged_mqa_single_kv_head():
     )
 
 
-@pytest.mark.parametrize("window", [0, 9])
-def test_ragged_grouped_rows_with_chunk(window):
+@pytest.mark.parametrize(
+    "window, layers",
+    [(0, _SLICE), (9, _SLICE), (0, _STACKED), (9, _STACKED)],
+)
+def test_ragged_grouped_rows_with_chunk(window, layers):
     """Groups + ungrouped rows + a chunk lane in the same program —
     grouping is a bandwidth optimization, output must equal the
     ungrouped reference (including under a sliding window, the config
     that used to fall back). Rows 0, 2, 3 share their first page."""
-    _within_tol(
-        parity.ragged_attention_error(
-            **_TOY, seed=2, g=3, valid_len=[13, 9, 40, 23], cq=16,
-            chunk_start=11, group_rows=(0, 2, 3), window=window,
+    for layer in layers:
+        _within_tol(
+            parity.ragged_attention_error(
+                **_TOY, seed=2, g=3, valid_len=[13, 9, 40, 23], cq=16,
+                chunk_start=11, group_rows=(0, 2, 3), window=window,
+                layer=layer,
+            )
         )
-    )
 
 
 def test_ragged_degenerate_single_member_group():
