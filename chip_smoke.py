@@ -414,6 +414,32 @@ def kernels_child(dry_run: bool) -> int:
     ragged("verify nq=5, layer 2 of 3 stacked",
            valid_len=[max(f, 5) for f in fills], nq=5, window=win_small,
            layer=(2, 3))
+    # A latent (MLA) pool: one 640-lane key a token for 16 heads, the
+    # value its first 512 lanes (DeepSeek-V2-Lite's, padded; toy sizes
+    # in the dry run), on the stacked pool as the layer scan calls it.
+    lat = (dict(d=64, latent_dv=32, g=4) if dry_run
+           else dict(d=640, latent_dv=512, g=16))
+    for name, kw in (
+        ("decode", dict(valid_len=fills)),
+        ("grouped+chunk, layer 1 of 2 stacked", dict(
+            valid_len=grouped, cq=cq, chunk_start=pg + 11,
+            group_rows=(0, 2, 3, 5), shared_pages=2, layer=(1, 2))),
+    ):
+        cases.append((
+            f"ragged_attention[latent {name}]", parity.ATTENTION_TOL,
+            lambda kw=kw: max(parity.ragged_attention_error(
+                **{**base, "hkv": 1, **lat}, **kw).values()),
+        ))
+    # The grouped expert matmul at DeepSeek-V2-Lite's two shapes: 8
+    # experts of which two hold no row and one holds three tiles.
+    for k_, n_ in (((128, 256), (256, 128)) if dry_run
+                   else ((2048, 1408), (1408, 2048))):
+        cases.append((
+            f"moe_grouped_matmul[{k_}x{n_}]", parity.QUANT_MATMUL_TOL,
+            lambda k_=k_, n_=n_: parity.moe_grouped_matmul_error(
+                seed=4, rows=[5, 0, 40, 1, 16, 0, 17, 2], k=k_, n=n_,
+                interpret=interpret),
+        ))
     for rows in (1, b, b + cq):
         cases.append((
             f"fused_rms_norm[{rows}x{d_model} bf16]", parity.NORM_BF16_TOL,

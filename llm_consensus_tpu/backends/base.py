@@ -36,6 +36,11 @@ class SamplingParams:
     # host checks; continuous batcher: every token is host-checked).
     # A tuple so the dataclass stays frozen/hashable.
     stop: tuple[str, ...] = ()
+    # > 0: also return the float32 logits of the first ``logits``
+    # generated positions as the serving programs computed them (the
+    # continuous batcher, greedy requests; ``meta["logits"]``). Other
+    # backends ignore it.
+    logits: int = 0
 
 
 @dataclass(frozen=True)

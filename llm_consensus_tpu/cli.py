@@ -154,12 +154,18 @@ def _build_backend(args):
         params = _load_checkpoint_params(cfg, args.checkpoint)
     else:
         cfg = get_config(args.model)
+        if args.layers:
+            cfg = cfg.with_layers(args.layers)
         log.warning(
             "No --checkpoint given: using RANDOM weights for %s "
             "(protocol/e2e plumbing only; text will be gibberish).",
             cfg.name,
         )
         params = random_params(cfg, jax.random.PRNGKey(0), args.quant)
+    if args.layers and args.layers != cfg.n_layers:
+        from llm_consensus_tpu.models.transformer import first_layers
+
+        cfg, params = first_layers(cfg, params, args.layers)
     draft = None
     if args.draft_checkpoint and not args.draft_model:
         raise SystemExit(
@@ -580,6 +586,15 @@ def _add_backend_args(p: argparse.ArgumentParser) -> None:
         "is a TPU",
     )
     p.add_argument("--model", default="llama-1b", help="model preset name")
+    p.add_argument(
+        "--layers",
+        type=int,
+        default=0,
+        help="serve the FIRST N layers of the model (leading dense "
+        "layers count): one stage of a pipeline, or a deep model cut to "
+        "one chip. 0 = all. Random weights are born at that depth; a "
+        "checkpoint's stacks are cut after loading",
+    )
     p.add_argument("--checkpoint", default=None, help="orbax checkpoint dir")
     p.add_argument(
         "--hf-checkpoint",

@@ -55,7 +55,7 @@ from llm_consensus_tpu.consensus.messages import (
     RefineAnswer,
     TranscriptEvent,
 )
-from llm_consensus_tpu.consensus.parsing import parse_evaluation
+from llm_consensus_tpu.consensus.parsing import loggable, parse_evaluation
 from llm_consensus_tpu.consensus.personas import Persona
 from llm_consensus_tpu.consensus.prompts import (
     answer_prompt,
@@ -266,7 +266,7 @@ class Coordinator:
             "%s evaluated the answer as %s. %s",
             msg.name,
             msg.evaluation.value,
-            msg.reasoning,
+            loggable(msg.reasoning),
         )
         self.feedback[msg.name] = msg.evaluation
         self._event(
@@ -428,7 +428,7 @@ class Coordinator:
         _M_QUESTIONS.inc()
         _M_ROUNDS.observe(final.rounds)
         (_M_UNANIMOUS if final.endorsed else _M_FORCED).inc()
-        log.info("Final answer: %s", final.answer)
+        log.info("Final answer: %s", loggable(final.answer))
         return final
 
     # REPL-parity surface (reference src/main.rs:442-470) -----------------

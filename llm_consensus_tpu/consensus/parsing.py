@@ -16,6 +16,16 @@ from llm_consensus_tpu.consensus.messages import Feedback
 log = logging.getLogger(__name__)
 
 
+def loggable(text: str) -> str:
+    """Model text for a log record. A byte-level tokenizer decodes
+    invalid UTF-8 to lone surrogates (``errors="surrogateescape"``, kept:
+    it is what makes decode reversible), and a record that holds one
+    cannot be encoded: pytest-xdist's workers die shipping the captured
+    log (ROADMAP C1), and a strict UTF-8 log handler raises. Escaped
+    here (``\\udcXX``); the text itself goes on as it is."""
+    return text.encode("utf-8", "backslashreplace").decode("utf-8")
+
+
 def parse_evaluation(text: str) -> tuple[Feedback, str]:
     """Parse a judge's raw reply into (verdict, reasoning).
 
@@ -34,5 +44,5 @@ def parse_evaluation(text: str) -> tuple[Feedback, str]:
         return Feedback.GOOD, reasoning
     if verdict_raw == "NeedsRefinement":
         return Feedback.NEEDS_REFINEMENT, reasoning
-    log.error("Unexpected response from EvaluateAnswer: %s", text)
+    log.error("Unexpected response from EvaluateAnswer: %s", loggable(text))
     return Feedback.NEEDS_REFINEMENT, reasoning

@@ -4,13 +4,18 @@ The reference has no model code (its model is the remote Gemini API,
 ``src/main.rs:82-86``). The families here are the ones BASELINE.md's target
 configs name: Llama-3-8B (north star), Mistral-7B and Qwen2-7B
 (heterogeneous panel, config[3]), Mixtral-8x7B MoE (config[2]), plus small
-test/bench presets. All are one architecture family — pre-norm transformer,
+test/bench presets. Those are one architecture family — pre-norm transformer,
 GQA attention, RoPE, SwiGLU — differing in dims and two flags (qkv bias for
 Qwen2, MoE for Mixtral), so one functional implementation serves all.
+DeepSeek-V2-Lite (PR 28) is the first that is not: latent attention (MLA), a
+leading dense layer before the expert layers, shared experts, a
+softmax-then-top-k router and YaRN — each a field below, read by the same
+functions.
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 
 
@@ -26,6 +31,24 @@ class RopeScaling:
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN frequency blending (HF rope_scaling type 'yarn', as
+    DeepSeek-V2 applies it): rotary pairs whose wavelength outlasts the
+    original context divide their frequency by ``factor``, short ones
+    stay, a linear ramp between ``low`` and ``high`` (pair indices
+    found from ``beta_fast``/``beta_slow`` rotations over the original
+    context) blends the two. ``mscale_all_dim`` also scales the
+    attention logits (:attr:`ModelConfig.attn_scale`)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     vocab_size: int
@@ -35,16 +58,50 @@ class ModelConfig:
     n_kv_heads: int
     d_ff: int
     rope_theta: float = 10000.0
-    rope_scaling: RopeScaling | None = None  # Llama-3.1 long-context
+    # Llama-3.1 rescaling (RopeScaling) or YaRN (YarnScaling).
+    rope_scaling: RopeScaling | YarnScaling | None = None
     rms_norm_eps: float = 1e-5
     max_seq_len: int = 8192
     # Sliding-window attention (Mistral): 0 = full causal.
     sliding_window: int = 0
     qkv_bias: bool = False  # Qwen2 uses bias on q/k/v projections
     tie_embeddings: bool = False
-    # MoE (Mixtral): 0 experts = dense MLP.
+    # Attention kind. "gqa": per-head K/V pairs of ``d_model //
+    # n_heads``. "mla" (DeepSeek-V2): one compressed latent of
+    # ``kv_lora_rank`` values plus ONE rotary key of ``qk_rope_head_dim``
+    # a token, shared by all heads; queries are ``qk_nope_head_dim``
+    # unrotated + ``qk_rope_head_dim`` rotated dims a head, values
+    # ``v_head_dim``. The cache holds the latent only and attention runs
+    # in the absorbed form (models.transformer._mla_block).
+    attention: str = "gqa"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # MoE: 0 experts = dense MLP.
     n_experts: int = 0
     n_experts_per_token: int = 2
+    # Layers [0, n_dense_layers) of an MoE model are dense SwiGLU of
+    # width ``d_ff`` and live in their own stack (params["dense_blocks"]);
+    # the expert layers' width is ``moe_d_ff`` (0 = ``d_ff``).
+    n_dense_layers: int = 0
+    moe_d_ff: int = 0
+    # Shared experts every token takes beside its routed ones: one
+    # SwiGLU of width ``n_shared_experts * expert width``.
+    n_shared_experts: int = 0
+    # Router: "topk_softmax" (Mixtral) takes the top k logits and
+    # softmaxes those; "softmax_topk" (DeepSeek-V2) softmaxes all
+    # experts in float32, takes the top k probabilities as they are
+    # (``moe_renormalize`` divides them by their sum) and multiplies
+    # them by ``moe_routed_scale``.
+    moe_router: str = "topk_softmax"
+    moe_renormalize: bool = False
+    moe_routed_scale: float = 1.0
+    # Dropless expert layer (models.transformer._moe_dropless): every
+    # token's k experts computed exactly through the grouped matmul,
+    # which reads only the experts a step's tokens reach. The presets
+    # that predate it keep the dense / capacity paths below.
+    moe_dropless: bool = False
     # > 0 enables capacity-bounded GShard-style dispatch (compute only
     # routed tokens, capacity = ceil(T*k/E * factor)); 0 = dense
     # all-experts compute (exact, E/k x the FLOPs).
@@ -81,12 +138,76 @@ class ModelConfig:
     use_ring: bool = False
 
     @property
+    def is_mla(self) -> bool:
+        return self.attention == "mla"
+
+    @property
     def head_dim(self) -> int:
+        """Width of one query head's key: what a head's dot product
+        contracts over, and the rotary width of a GQA model."""
+        if self.is_mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.d_model // self.n_heads
+
+    @property
+    def rope_dim(self) -> int:
+        """Rotated dims of a head: all of a GQA head, the rotary part
+        of an MLA head."""
+        return self.qk_rope_head_dim if self.is_mla else self.head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """What one token costs an MLA pool a layer: the compressed
+        latent followed by the shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_pool_dim(self) -> int:
+        """Lane width of an MLA pool: ``latent_dim`` rounded up to whole
+        128-lane tiles (576 -> 640). The TPU keeps a 576-wide minor
+        axis in 640 lanes anyway, and for an array whose minor axis is
+        no multiple of 128 XLA prefers another axis order than the
+        kernel's, so every kernel call would copy the whole pool (1.6
+        GB, seen in the compiled program); padded, the pool has the
+        one layout both want. Pad lanes hold zeros."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def attn_scale(self) -> float:
+        """Softmax scale: ``head_dim ** -0.5``, times YaRN's
+        ``mscale_all_dim`` correction squared where the config has it."""
+        scale = self.head_dim**-0.5
+        ys = self.rope_scaling
+        if isinstance(ys, YarnScaling) and ys.mscale_all_dim:
+            m = 0.1 * ys.mscale_all_dim * math.log(ys.factor) + 1.0
+            scale *= m * m
+        return scale
 
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.is_moe else 0
+
+    def with_layers(self, n: int) -> "ModelConfig":
+        """The first ``n`` layers of this model (leading dense layers
+        count): ``serve --layers``, a pipeline's first stage."""
+        if not 0 < n <= self.n_layers:
+            raise ValueError(
+                f"--layers {n}: {self.name} has {self.n_layers} layers"
+            )
+        if self.is_moe and self.n_dense_layers and n <= self.n_dense_layers:
+            raise ValueError(
+                f"--layers {n}: {self.name} has {self.n_dense_layers} leading "
+                "dense layer(s); keep at least one expert layer"
+            )
+        return self.with_(n_layers=n)
 
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
@@ -326,6 +447,74 @@ PRESETS: dict[str, ModelConfig] = {
         n_kv_heads=1,
         d_ff=64,
         max_seq_len=128,
+    ),
+    # DeepSeek-V2-Lite at its published sizes
+    # (huggingface.co/deepseek-ai/DeepSeek-V2-Lite config.json): latent
+    # attention, one leading dense layer, 64 routed experts top-6 plus
+    # 2 shared, YaRN over the 64 rotary dims. max_seq_len is the repo's
+    # cap, not the published 163840.
+    "deepseek-v2-lite": ModelConfig(
+        name="deepseek-v2-lite",
+        vocab_size=102400,
+        d_model=2048,
+        n_layers=27,
+        n_heads=16,
+        n_kv_heads=1,
+        d_ff=10944,
+        rope_theta=10000.0,
+        rope_scaling=YarnScaling(
+            factor=40.0,
+            original_max_position_embeddings=4096,
+            beta_fast=32.0,
+            beta_slow=1.0,
+            mscale=0.707,
+            mscale_all_dim=0.707,
+        ),
+        rms_norm_eps=1e-6,
+        max_seq_len=8192,
+        attention="mla",
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        n_experts=64,
+        n_experts_per_token=6,
+        n_dense_layers=1,
+        moe_d_ff=1408,
+        n_shared_experts=2,
+        moe_router="softmax_topk",
+        moe_dropless=True,
+    ),
+    # The same shape at CPU-test size: 3 layers of which 1 dense, 8
+    # experts top-3 plus 2 shared, latent 32 + rotary 8.
+    "test-tiny-mla": ModelConfig(
+        name="test-tiny-mla",
+        vocab_size=384,
+        d_model=64,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=1,
+        d_ff=128,
+        rope_scaling=YarnScaling(
+            factor=40.0,
+            original_max_position_embeddings=32,
+            mscale=0.707,
+            mscale_all_dim=0.707,
+        ),
+        rms_norm_eps=1e-6,
+        max_seq_len=256,
+        attention="mla",
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        n_experts=8,
+        n_experts_per_token=3,
+        n_dense_layers=1,
+        moe_d_ff=32,
+        n_shared_experts=2,
+        moe_router="softmax_topk",
+        moe_dropless=True,
     ),
     "test-tiny-moe": ModelConfig(
         name="test-tiny-moe",

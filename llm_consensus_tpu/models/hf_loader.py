@@ -12,6 +12,9 @@ layout:
   transposed and the per-layer tensors stacked.
 - HF RoPE uses the rotate-half convention, as does
   :mod:`llm_consensus_tpu.ops.rope` — weights map 1:1, no permutation.
+  The exception is ``DeepseekV2ForCausalLM``, whose rotation pairs
+  neighbouring lanes: its rotary columns are permuted on the way in
+  (:func:`rotary_column_permutation`).
 - bf16 tensors cross torch→numpy via a uint16 view (numpy itself has no
   bfloat16; ml_dtypes supplies the dtype on the jax side).
 
@@ -30,7 +33,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from llm_consensus_tpu.models.configs import ModelConfig, RopeScaling
+from llm_consensus_tpu.models.configs import (
+    ModelConfig,
+    RopeScaling,
+    YarnScaling,
+)
 
 # name templates: ours -> HF (dense). {i} = layer index.
 _DENSE_MAP = {
@@ -54,10 +61,62 @@ _MOE_MAP = {
     "w_up": "model.layers.{i}.block_sparse_moe.experts.{e}.w3.weight",
     "w_down": "model.layers.{i}.block_sparse_moe.experts.{e}.w2.weight",
 }
+# DeepseekV2ForCausalLM (MLA, no q_lora): attention, then a dense MLP on
+# the leading layers (_DENSE_MAP's names) or routed + shared experts.
+_MLA_MAP = {
+    "attn_norm": _DENSE_MAP["attn_norm"],
+    "mlp_norm": _DENSE_MAP["mlp_norm"],
+    "wq": "model.layers.{i}.self_attn.q_proj.weight",
+    "w_kva": "model.layers.{i}.self_attn.kv_a_proj_with_mqa.weight",
+    "kv_a_norm": "model.layers.{i}.self_attn.kv_a_layernorm.weight",
+    "w_kvb": "model.layers.{i}.self_attn.kv_b_proj.weight",
+    "wo": "model.layers.{i}.self_attn.o_proj.weight",
+}
+_DEEPSEEK_MOE_MAP = {
+    "router": "model.layers.{i}.mlp.gate.weight",
+    "w_gate": "model.layers.{i}.mlp.experts.{e}.gate_proj.weight",
+    "w_up": "model.layers.{i}.mlp.experts.{e}.up_proj.weight",
+    "w_down": "model.layers.{i}.mlp.experts.{e}.down_proj.weight",
+    "ws_gate": "model.layers.{i}.mlp.shared_experts.gate_proj.weight",
+    "ws_up": "model.layers.{i}.mlp.shared_experts.up_proj.weight",
+    "ws_down": "model.layers.{i}.mlp.shared_experts.down_proj.weight",
+}
 # Linear weights stored [out, in] by torch; transpose to our [in, out].
 _TRANSPOSED = {
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "router", "lm_head",
+    "w_kva", "w_kvb", "ws_gate", "ws_up", "ws_down",
 }
+
+
+def rotary_column_permutation(rope_dim: int) -> np.ndarray:
+    """DeepSeek-V2 rotates lane pairs (2t, 2t + 1); :mod:`ops.rope`
+    pairs (t, t + rope_dim / 2). Taking the published rotary columns in
+    this order — evens, then odds — makes the repo's rotation of the
+    result the published rotation of the original: the same permutation
+    on queries and on the shared rotary key leaves every score as it
+    was."""
+    return np.concatenate(
+        [np.arange(0, rope_dim, 2), np.arange(1, rope_dim, 2)]
+    )
+
+
+def _permute_rotary(cfg: ModelConfig, ours: str, w: np.ndarray) -> np.ndarray:
+    """Apply :func:`rotary_column_permutation` to the rotary output
+    columns of ``wq`` (each head's last ``rope`` of ``nope + rope``)
+    and ``w_kva`` (the last ``rope`` of ``latent + rope``); [in, out]."""
+    perm = rotary_column_permutation(cfg.qk_rope_head_dim)
+    if ours == "wq":
+        per = cfg.head_dim
+        cols = np.arange(cfg.n_heads * per).reshape(cfg.n_heads, per)
+        cols[:, cfg.qk_nope_head_dim :] = cols[:, cfg.qk_nope_head_dim :][
+            :, perm
+        ]
+        return w[:, cols.reshape(-1)]
+    if ours == "w_kva":
+        cols = np.arange(cfg.latent_dim)
+        cols[cfg.kv_lora_rank :] = cols[cfg.kv_lora_rank :][perm]
+        return w[:, cols]
+    return w
 
 
 def _to_numpy(t) -> np.ndarray:
@@ -135,7 +194,59 @@ def load_hf_params(
         ckpt.close()
 
 
+def _load_mla_params(cfg: ModelConfig, ckpt: _ShardedCheckpoint, dtype) -> dict:
+    """DeepseekV2ForCausalLM -> the two-stack tree of
+    ``transformer.init_params``: ``dense_blocks`` (the leading
+    ``n_dense_layers``) and ``blocks`` (the expert layers)."""
+    np_dtype = jnp.dtype(dtype)
+
+    def one(template: str, ours: str, **at) -> np.ndarray:
+        name = template.format(**at)
+        if name not in ckpt:
+            raise KeyError(f"checkpoint missing {name!r} (for param {ours!r})")
+        return _permute_rotary(cfg, ours, _fetch(ckpt, name, ours, np_dtype))
+
+    def stack(layers, mlp_map, expert_names=()) -> dict:
+        blocks = {
+            ours: np.stack([one(t, ours, i=i) for i in layers])
+            for ours, t in {**_MLA_MAP, **mlp_map}.items()
+            if ours not in expert_names
+        }
+        for ours in expert_names:
+            blocks[ours] = np.stack([
+                np.stack([
+                    one(mlp_map[ours], ours, i=i, e=e)
+                    for e in range(cfg.n_experts)
+                ])
+                for i in layers
+            ])
+        return blocks
+
+    nd = cfg.n_dense_layers if cfg.is_moe else cfg.n_layers
+    dense_map = {k: _DENSE_MAP[k] for k in ("w_gate", "w_up", "w_down")}
+    params: dict = {}
+    if nd:
+        params["dense_blocks"] = stack(range(nd), dense_map)
+    if cfg.n_layers > nd:
+        moe_map = dict(_DEEPSEEK_MOE_MAP)
+        if not cfg.n_shared_experts:
+            for k in ("ws_gate", "ws_up", "ws_down"):
+                del moe_map[k]
+        params["blocks"] = stack(
+            range(nd, cfg.n_layers), moe_map, ("w_gate", "w_up", "w_down")
+        )
+    else:
+        params["blocks"] = params.pop("dense_blocks")
+    params["embed"] = ckpt.get("model.embed_tokens.weight").astype(np_dtype)
+    params["norm_f"] = ckpt.get("model.norm.weight").astype(np_dtype)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _fetch(ckpt, "lm_head.weight", "lm_head", np_dtype)
+    return jax.tree_util.tree_map(jnp.asarray, params)
+
+
 def _load_hf_params(cfg: ModelConfig, ckpt: _ShardedCheckpoint, dtype) -> dict:
+    if cfg.is_mla:
+        return _load_mla_params(cfg, ckpt, dtype)
     np_dtype = jnp.dtype(dtype)
 
     def stack_layers(ours: str, template: str) -> np.ndarray:
@@ -228,6 +339,8 @@ def config_from_hf(path: str | Path, name: str = "hf") -> ModelConfig:
     """
     hf = json.loads((Path(path) / "config.json").read_text())
     arch = (hf.get("architectures") or [""])[0]
+    if "DeepseekV2" in arch or hf.get("model_type") == "deepseek_v2":
+        return _deepseek_v2_config(hf, name)
     is_moe = "Mixtral" in arch or "num_local_experts" in hf
 
     rope_scaling = None
@@ -271,4 +384,70 @@ def config_from_hf(path: str | Path, name: str = "hf") -> ModelConfig:
         tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
         n_experts=int(hf.get("num_local_experts", 0)) if is_moe else 0,
         n_experts_per_token=int(hf.get("num_experts_per_tok", 2)),
+    )
+
+
+def _deepseek_v2_config(hf: dict, name: str) -> ModelConfig:
+    """DeepseekV2ForCausalLM's ``config.json`` -> ModelConfig. What the
+    layer equations here do not cover raises: a query latent
+    (``q_lora_rank``), grouped or non-softmax routing, expert layers
+    other than every layer after the leading dense ones."""
+    unsupported = {
+        "q_lora_rank": hf.get("q_lora_rank") is not None,
+        "scoring_func": hf.get("scoring_func", "softmax") != "softmax",
+        "topk_method": hf.get("topk_method", "greedy") != "greedy"
+        or hf.get("n_group", 1) not in (None, 1),
+        "moe_layer_freq": hf.get("moe_layer_freq", 1) != 1,
+    }
+    bad = [k for k, v in unsupported.items() if v]
+    if bad:
+        raise ValueError(
+            f"DeepseekV2 config uses {bad}, which the MLA/MoE layers here "
+            "do not implement (DeepSeek-V2-Lite's values are supported)"
+        )
+    rope_scaling = None
+    rs = hf.get("rope_scaling")
+    if rs:
+        if (rs.get("rope_type") or rs.get("type")) != "yarn":
+            raise ValueError(f"unsupported rope_scaling {rs!r}: want 'yarn'")
+        rope_scaling = YarnScaling(
+            factor=float(rs["factor"]),
+            original_max_position_embeddings=int(
+                rs["original_max_position_embeddings"]
+            ),
+            beta_fast=float(rs.get("beta_fast", 32)),
+            beta_slow=float(rs.get("beta_slow", 1)),
+            mscale=float(rs.get("mscale", 1.0)),
+            mscale_all_dim=float(rs.get("mscale_all_dim", 0.0)),
+        )
+    n_experts = int(hf.get("n_routed_experts") or 0)
+    return ModelConfig(
+        name=name,
+        vocab_size=hf["vocab_size"],
+        d_model=hf["hidden_size"],
+        n_layers=hf["num_hidden_layers"],
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=1,
+        d_ff=hf["intermediate_size"],
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rope_scaling=rope_scaling,
+        rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        max_seq_len=int(hf.get("max_position_embeddings", 8192)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        attention="mla",
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        n_experts=n_experts,
+        n_experts_per_token=int(hf.get("num_experts_per_tok") or 2),
+        n_dense_layers=int(hf.get("first_k_dense_replace", 0))
+        if n_experts
+        else 0,
+        moe_d_ff=int(hf.get("moe_intermediate_size") or 0),
+        n_shared_experts=int(hf.get("n_shared_experts") or 0),
+        moe_router="softmax_topk",
+        moe_renormalize=bool(hf.get("norm_topk_prob", False)),
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_dropless=bool(n_experts),
     )

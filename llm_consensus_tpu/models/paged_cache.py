@@ -13,7 +13,15 @@ is infrastructure for the serving path the build adds (SURVEY.md §7
 step 5-6, BASELINE.json throughput targets).
 
 Layout:
-- pool k/v: ``[L, n_pages, page_size, Hkv, Dh]``
+- pool k/v: ``[L, n_pages, page_size, Hkv, Dh]``; for a latent-attention
+  (MLA) model ONE latent plane ``k``: ``[L, n_pages, page_size,
+  kv_lora_rank + qk_rope_head_dim]`` (the compressed latent, then the
+  shared rotary key: 576 lanes for DeepSeek-V2-Lite, zero-padded to
+  whole 128-lane tiles, 640: ``ModelConfig.latent_pool_dim``), and ``v``
+  an empty ``[L, n_pages, page_size, 0]`` plane — the value is the first
+  ``kv_lora_rank`` lanes of the key, read from the same page. The page
+  movers below index ``[:, page]`` and never look past that axis, so
+  they carry latent pages as they carry K/V pairs.
 - page_table: ``[max_seqs, pages_per_seq]`` int32 page ids (unused
   entries can hold any valid id; masking is by ``length``).
 - length: ``[max_seqs]`` tokens written per sequence.
@@ -78,8 +86,8 @@ def prefix_chain_key(
 @jax.tree_util.register_dataclass
 @dataclass
 class PagedKVCache:
-    k: jnp.ndarray  # [L, n_pages, page_size, Hkv, Dh]
-    v: jnp.ndarray
+    k: jnp.ndarray  # [L, n_pages, page_size, Hkv, Dh]; MLA: [.., latent]
+    v: jnp.ndarray  # as k; MLA: [L, n_pages, page_size, 0] (empty)
     page_table: jnp.ndarray  # [max_seqs, pages_per_seq] int32
     length: jnp.ndarray  # [max_seqs] int32
 
@@ -92,10 +100,16 @@ class PagedKVCache:
         pages_per_seq: int,
         dtype=jnp.bfloat16,
     ) -> "PagedKVCache":
-        shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+        if cfg.is_mla:
+            lead = (cfg.n_layers, n_pages, page_size)
+            k_shape, v_shape = lead + (cfg.latent_pool_dim,), lead + (0,)
+        else:
+            k_shape = v_shape = (
+                cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim
+            )
         return PagedKVCache(
-            k=jnp.zeros(shape, dtype),
-            v=jnp.zeros(shape, dtype),
+            k=jnp.zeros(k_shape, dtype),
+            v=jnp.zeros(v_shape, dtype),
             page_table=jnp.full((max_seqs, pages_per_seq), NULL_PAGE, jnp.int32),
             length=jnp.zeros((max_seqs,), jnp.int32),
         )

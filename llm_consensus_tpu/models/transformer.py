@@ -14,8 +14,12 @@ BASELINE.json's north star. Design choices are XLA-first:
 - GQA is computed without materializing repeated KV heads
   (see :mod:`llm_consensus_tpu.ops.attention`).
 - Mixtral-style MoE computes all experts densely and combines with the
-  top-k router weights — correct and simple; the ragged-dispatch
-  optimization is a later kernel (tracked in ops/pallas).
+  top-k router weights (or the capacity dispatch); a ``moe_dropless``
+  config (DeepSeek-V2-Lite) sorts tokens by expert and runs a grouped
+  int8 matmul that reads only the experts reached (``_moe_dropless``).
+- A latent-attention (MLA) model keeps one compressed latent a token in
+  the page pool and attends in the absorbed form (``_paged_layers``);
+  layers of two shapes are two stacks (``layer_stacks``).
 
 Three entry points:
 - :func:`forward` — full causal forward, logits for every position
@@ -45,7 +49,7 @@ from llm_consensus_tpu.ops.kernels import single_device
 from llm_consensus_tpu.ops.norms import rms_norm
 from llm_consensus_tpu.ops.quant import matmul as _qmm
 from llm_consensus_tpu.ops.quant import maybe_dequantize as _w
-from llm_consensus_tpu.ops.rope import apply_rope, rope_cos_sin
+from llm_consensus_tpu.ops.rope import apply_rope, apply_rope_tail, rope_cos_sin
 
 
 def _local_kernels(cfg: ModelConfig, mesh) -> bool:
@@ -233,7 +237,7 @@ def init_params(
     quantized, generated a layer at a time."""
     from llm_consensus_tpu.ops.quant import quant_axis
 
-    keys = iter(jax.random.split(key, 16))
+    keys = iter(jax.random.split(key, 32 if _two_stacks(cfg) else 16))
 
     def normal(name, shape, scale=0.02):
         k = next(keys)
@@ -252,6 +256,25 @@ def init_params(
     )
     Dh = cfg.head_dim
     resid_scale = 0.02 / math.sqrt(2 * L)
+
+    if _two_stacks(cfg):
+        Ld = cfg.n_dense_layers if cfg.is_moe else L
+        params = {}
+        if Ld:
+            params["dense_blocks"] = _init_stack(
+                cfg, Ld, normal, resid_scale, dtype, moe=False
+            )
+        if L - Ld:
+            params["blocks"] = _init_stack(
+                cfg, L - Ld, normal, resid_scale, dtype, moe=True
+            )
+        elif Ld:  # a dense MLA model: its one stack under the usual name
+            params["blocks"] = params.pop("dense_blocks")
+        params["embed"] = normal("embed", (V, D))
+        params["norm_f"] = jnp.ones((D,), dtype)
+        if not cfg.tie_embeddings:
+            params["lm_head"] = normal("lm_head", (D, V))
+        return params
 
     blocks: dict = {
         "attn_norm": jnp.ones((L, D), dtype),
@@ -284,6 +307,59 @@ def init_params(
     if not cfg.tie_embeddings:
         params["lm_head"] = normal("lm_head", (D, V))
     return params
+
+
+def _two_stacks(cfg: ModelConfig) -> bool:
+    """Models :func:`_init_stack` builds: latent attention, leading
+    dense layers, shared experts. Every older preset keeps the one
+    stack, and the key order, it always had."""
+    return cfg.is_mla or cfg.n_dense_layers > 0 or cfg.n_shared_experts > 0
+
+
+def _init_stack(cfg: ModelConfig, n: int, normal, resid_scale, dtype, moe):
+    """``n`` stacked layers of one shape: attention (MLA or GQA) and
+    either a dense SwiGLU of width ``d_ff`` or routed experts of width
+    ``expert_d_ff`` with their router and shared experts."""
+    D, H = cfg.d_model, cfg.n_heads
+    blocks = {
+        "attn_norm": jnp.ones((n, D), dtype),
+        "mlp_norm": jnp.ones((n, D), dtype),
+    }
+    if cfg.is_mla:
+        r = cfg.kv_lora_rank
+        per = cfg.qk_nope_head_dim + cfg.v_head_dim
+        blocks["wq"] = normal("wq", (n, D, H * cfg.head_dim))
+        blocks["w_kva"] = normal("w_kva", (n, D, cfg.latent_dim))
+        blocks["kv_a_norm"] = jnp.ones((n, r), dtype)
+        blocks["w_kvb"] = normal("w_kvb", (n, r, H * per))
+        blocks["wo"] = normal("wo", (n, H * cfg.v_head_dim, D), resid_scale)
+    else:
+        Dh, Hkv = cfg.head_dim, cfg.n_kv_heads
+        blocks["wq"] = normal("wq", (n, D, H * Dh))
+        blocks["wk"] = normal("wk", (n, D, Hkv * Dh))
+        blocks["wv"] = normal("wv", (n, D, Hkv * Dh))
+        blocks["wo"] = normal("wo", (n, H * Dh, D), resid_scale)
+        if cfg.qkv_bias:
+            blocks["bq"] = jnp.zeros((n, H * Dh), dtype)
+            blocks["bk"] = jnp.zeros((n, Hkv * Dh), dtype)
+            blocks["bv"] = jnp.zeros((n, Hkv * Dh), dtype)
+    if not moe:
+        F = cfg.d_ff
+        blocks["w_gate"] = normal("w_gate", (n, D, F))
+        blocks["w_up"] = normal("w_up", (n, D, F))
+        blocks["w_down"] = normal("w_down", (n, F, D), resid_scale)
+        return blocks
+    E, F = cfg.n_experts, cfg.expert_d_ff
+    blocks["router"] = normal("router", (n, D, E))
+    blocks["w_gate"] = normal("w_gate", (n, E, D, F))
+    blocks["w_up"] = normal("w_up", (n, E, D, F))
+    blocks["w_down"] = normal("w_down", (n, E, F, D), resid_scale)
+    if cfg.n_shared_experts:
+        Fs = cfg.n_shared_experts * F
+        blocks["ws_gate"] = normal("ws_gate", (n, D, Fs))
+        blocks["ws_up"] = normal("ws_up", (n, D, Fs))
+        blocks["ws_down"] = normal("ws_down", (n, Fs, D), resid_scale)
+    return blocks
 
 
 @partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
@@ -339,7 +415,12 @@ def model_param_bytes(params) -> tuple[int, int]:
 def kv_plane_token_bytes(cfg: ModelConfig, kv_dtype) -> int:
     """HBM bytes one token position costs per full K+V read/write across
     all layers at the pool's dtype — the cost model's KV unit (and the
-    unit of ``gateway_shared_kv_bytes_saved_total``, same formula)."""
+    unit of ``gateway_shared_kv_bytes_saved_total``, same formula). An MLA
+    pool holds one latent a token a layer, at its padded lane width."""
+    if cfg.is_mla:
+        return (
+            cfg.n_layers * cfg.latent_pool_dim * jnp.dtype(kv_dtype).itemsize
+        )
     return (
         cfg.n_layers
         * cfg.n_kv_heads
@@ -387,9 +468,16 @@ def program_hbm_cost(
     hbm_bytes = int(
         weight_bytes + (kv_read_tokens + kv_write_tokens) * kv_token_bytes
     )
+    # A (query, key) pair: head_dim products for the score and as many
+    # for the value, a head; absorbed MLA scores over the latent and the
+    # rotary key and sums values of the latent's width.
+    pair = (
+        2 * (cfg.latent_dim + cfg.kv_lora_rank)
+        if cfg.is_mla
+        else 4 * cfg.head_dim
+    )
     flops = int(
-        2 * weight_params * tokens
-        + 4 * cfg.n_heads * cfg.head_dim * kv_read_tokens
+        2 * weight_params * tokens + cfg.n_heads * pair * kv_read_tokens
     )
     return {
         "hbm_bytes": hbm_bytes,
@@ -470,11 +558,24 @@ def _zero_aux() -> dict:
 
 
 def _mlp(
-    cfg: ModelConfig, p: dict, h: jnp.ndarray, collect_aux: bool = False
+    cfg: ModelConfig,
+    p: dict,
+    h: jnp.ndarray,
+    collect_aux: bool = False,
+    active=None,
 ):
-    if not cfg.is_moe:
+    """The block's feed-forward. A layer without a router is dense
+    SwiGLU, whatever the model (an MoE model's leading dense layers);
+    ``cfg.moe_dropless`` takes :func:`_moe_dropless` (``active``: see
+    there), every older MoE preset the two paths below."""
+    if not cfg.is_moe or "router" not in p:
         y = swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
         return (y, _zero_aux()) if collect_aux else y
+    if cfg.moe_dropless:
+        y, logits, top_idx, _ = _moe_dropless(cfg, p, h, active=active)
+        if collect_aux:
+            return y, moe_router_aux(cfg, logits, top_idx)
+        return y
     if not cfg.moe_dense_at(h.shape[0] * h.shape[1]):
         return _moe_dispatch(cfg, p, h, collect_aux=collect_aux)
     # Mixtral MoE: top-k routing, dense all-experts compute, weighted combine.
@@ -496,6 +597,162 @@ def _mlp(
     if collect_aux:
         return y, moe_router_aux(cfg, router_logits, top_idx)
     return y
+
+
+def moe_route(cfg: ModelConfig, router, x: jnp.ndarray):
+    """Router of the dropless layer: x [T, D] -> (logits [T, E] f32,
+    weights [T, k] f32, experts [T, k]).
+
+    ``softmax_topk`` (DeepSeek-V2): softmax over ALL experts in float32
+    (the product too: which experts a token takes is a discrete choice,
+    and the system's k-th expert should be the reference's wherever
+    their inputs agree), the k largest probabilities as they are —
+    divided by their sum only under ``moe_renormalize`` — times
+    ``moe_routed_scale``. ``topk_softmax`` (Mixtral): the k largest
+    logits, softmaxed among themselves."""
+    k = cfg.n_experts_per_token
+    if cfg.moe_router == "softmax_topk":
+        logits = jnp.einsum(
+            "td,de->te",
+            x.astype(jnp.float32),
+            router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        top_w, top_idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        if cfg.moe_renormalize:
+            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        return logits, top_w * cfg.moe_routed_scale, top_idx
+    logits = (x @ router).astype(jnp.float32)
+    top_vals, top_idx = jax.lax.top_k(logits, k)
+    return logits, jax.nn.softmax(top_vals, axis=-1), top_idx
+
+
+def _grouped_matmul(x, leaf, tile_expert, n_live):
+    """``x [n_tiles * tm, K]`` times expert ``tile_expert[i]``'s matrix
+    for tile i, out of ``leaf``: one layer's experts [E, K, N] (an
+    array or a QuantizedTensor), or a ``StackedQuant`` view of the
+    [L, E, K, N] stack, which the Pallas kernel indexes in place at
+    ``layer * E + expert`` (the stack's leading axes merge for free).
+    Off the kernel: the tiles' matrices gathered and one batched dot —
+    the CPU path of the tests, exact but no bandwidth shaping."""
+    from llm_consensus_tpu.ops.pallas.moe_matmul import (
+        MOE_TILE,
+        moe_grouped_matmul,
+        moe_grouped_matmul_supported,
+    )
+    from llm_consensus_tpu.ops.quant import (
+        QuantizedTensor,
+        StackedQuant,
+        _use_kernel,
+    )
+
+    full = leaf.full if isinstance(leaf, StackedQuant) else leaf
+    if (
+        isinstance(full, QuantizedTensor)
+        and _use_kernel(full)
+        and moe_grouped_matmul_supported(*full.q.shape[-2:])
+    ):
+        k, n = full.q.shape[-2:]
+        group = tile_expert
+        if isinstance(leaf, StackedQuant):
+            group = tile_expert + leaf.layer * full.q.shape[1]
+        return moe_grouped_matmul(
+            x,
+            full.q.reshape(-1, k, n),
+            full.scale.reshape(-1, 1, n),
+            group,
+            n_live,
+        )
+    if isinstance(leaf, StackedQuant):
+        leaf = leaf.sliced()
+    xt = x.reshape(tile_expert.shape[0], MOE_TILE, x.shape[-1])
+    if isinstance(leaf, QuantizedTensor):
+        out = jnp.einsum(
+            "tmk,tkn->tmn", xt, leaf.q[tile_expert].astype(x.dtype),
+            preferred_element_type=jnp.float32,
+        ) * leaf.scale[tile_expert]
+    else:
+        out = jnp.einsum(
+            "tmk,tkn->tmn", xt, _w(leaf)[tile_expert].astype(x.dtype),
+            preferred_element_type=jnp.float32,
+        )
+    return out.reshape(x.shape[0], -1).astype(x.dtype)
+
+
+def _moe_dropless(cfg: ModelConfig, p: dict, h: jnp.ndarray, active=None):
+    """Dropless expert layer: every token's k experts, exactly.
+
+    Assignments (token, expert) are sorted by expert and each expert's
+    rows padded to whole tiles (``ops.pallas.moe_matmul``); three
+    grouped matmuls (gate, up, down) then read only the experts some
+    token reached, each once. No capacity, so nothing is dropped and a
+    1-row decode step and an 80-token fused step run the same code.
+    The shared experts are one SwiGLU every token takes.
+
+    ``active`` ([T] bool or None): rows that carry no request (idle
+    decode slots) take no expert — they would reach up to k experts
+    each that nobody needs read; their routed output is zero.
+
+    Returns (y, router logits, chosen experts, stats) with ``stats`` =
+    int32 [experts reached, assignments] of this call.
+    """
+    from llm_consensus_tpu.ops.pallas.moe_matmul import MOE_TILE, n_tiles_for
+
+    b, s, d = h.shape
+    t = b * s
+    e, k = cfg.n_experts, cfg.n_experts_per_token
+    tm = MOE_TILE
+    x = h.reshape(t, d)
+    logits, top_w, top_idx = moe_route(cfg, _w(p["router"]), x)
+
+    a = t * k
+    n_tiles = n_tiles_for(a, e, tm)
+    e_flat = top_idx.reshape(a).astype(jnp.int32)
+    if active is not None:
+        # Expert id E sorts last and owns no tile.
+        e_flat = jnp.where(jnp.repeat(active.reshape(t), k), e_flat, e)
+    order = jnp.argsort(e_flat, stable=True)
+    e_sorted = e_flat[order]
+    counts = jnp.zeros((e + 1,), jnp.int32).at[e_flat].add(1)[:e]
+    tiles_e = (counts + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles_e)
+    n_live = tile_end[-1]
+    row0 = (tile_end - tiles_e) * tm  # first padded row of each expert
+    first = jnp.cumsum(counts) - counts  # first sorted assignment
+    es = jnp.minimum(e_sorted, e - 1)
+    dest_sorted = jnp.where(
+        e_sorted < e,
+        row0[es] + jnp.arange(a, dtype=jnp.int32) - first[es],
+        n_tiles * tm,  # out of range: dropped by the scatter below
+    )
+    tiles = jnp.arange(n_tiles, dtype=jnp.int32)
+    tile_expert = jnp.searchsorted(
+        tile_end, jnp.minimum(tiles, jnp.maximum(n_live - 1, 0)), side="right"
+    ).astype(jnp.int32)
+    tile_expert = jnp.minimum(tile_expert, e - 1)
+    src_tok = (
+        jnp.zeros((n_tiles * tm,), jnp.int32)
+        .at[dest_sorted]
+        .set(order // k, mode="drop")
+    )
+    x_sorted = x[src_tok]
+    gate = _grouped_matmul(x_sorted, p["w_gate"], tile_expert, n_live)
+    up = _grouped_matmul(x_sorted, p["w_up"], tile_expert, n_live)
+    y_sorted = _grouped_matmul(
+        jax.nn.silu(gate) * up, p["w_down"], tile_expert, n_live
+    )
+    dest = jnp.zeros((a,), jnp.int32).at[order].set(dest_sorted)
+    live = dest < n_tiles * tm
+    y_tok = jnp.where(
+        live[:, None],
+        y_sorted[jnp.minimum(dest, n_tiles * tm - 1)].astype(jnp.float32),
+        0.0,
+    ).reshape(t, k, d)
+    y = jnp.einsum("tkd,tk->td", y_tok, top_w).astype(h.dtype).reshape(b, s, d)
+    if cfg.n_shared_experts:
+        y = y + swiglu(h, p["ws_gate"], p["ws_up"], p["ws_down"])
+    stats = jnp.stack([jnp.sum(counts > 0), jnp.sum(counts)]).astype(jnp.int32)
+    return y, logits, top_idx, stats
 
 
 def _moe_dispatch(
@@ -557,6 +814,93 @@ def _moe_dispatch(
     return y
 
 
+def _mla_project(cfg: ModelConfig, p: dict, h: jnp.ndarray, cos, sin):
+    """MLA projections of h [b, s, D]: queries [b, s, H, nope + rope]
+    with their rotary tail rotated, the normalised latent c [b, s, r]
+    and the ONE rotated rotary key a token k_pe [b, s, rope]."""
+    b, s, _ = h.shape
+    r = cfg.kv_lora_rank
+    q = _qmm(h, p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+    q = apply_rope_tail(q, cos, sin)
+    kva = _qmm(h, p["w_kva"])
+    c = rms_norm(kva[..., :r], p["kv_a_norm"], cfg.rms_norm_eps)
+    k_pe = apply_rope(kva[..., None, r:], cos, sin)[..., 0, :]
+    return q, c, k_pe
+
+
+def _kvb_heads(cfg: ModelConfig, leaf, dtype):
+    """``w_kvb`` [r, H * (nope + v)] as (w [r, H, nope + v] in
+    ``dtype``, scale [H, nope + v] or None): an int8 leaf stays
+    unscaled integers beside its per-column scale, so that the absorbed
+    products can fold the scale into the side it belongs to."""
+    from llm_consensus_tpu.ops.quant import QuantizedTensor, StackedQuant
+
+    if isinstance(leaf, StackedQuant):
+        leaf = leaf.sliced()
+    h, per = cfg.n_heads, cfg.qk_nope_head_dim + cfg.v_head_dim
+    if isinstance(leaf, QuantizedTensor):
+        return (
+            leaf.q.astype(dtype).reshape(-1, h, per),
+            leaf.scale.reshape(h, per),
+        )
+    return _w(leaf, dtype).reshape(-1, h, per), None
+
+
+def mla_absorb_q(cfg: ModelConfig, w_kvb, q_nope: jnp.ndarray):
+    """q_lat = q_nope · W_uk^T: [.., H, nope] -> [.., H, r]. A query in
+    latent space scores against the cached latent directly, so no
+    per-head key is ever expanded."""
+    w, scale = _kvb_heads(cfg, w_kvb, q_nope.dtype)
+    dn = cfg.qk_nope_head_dim
+    if scale is not None:
+        q_nope = (q_nope * scale[:, :dn]).astype(q_nope.dtype)
+    return jnp.einsum(
+        "...hd,rhd->...hr", q_nope, w[..., :dn],
+        preferred_element_type=jnp.float32,
+    ).astype(q_nope.dtype)
+
+
+def mla_expand_o(cfg: ModelConfig, w_kvb, o_lat: jnp.ndarray):
+    """o = o_lat · W_uv: [.., H, r] -> [.., H, v]."""
+    w, scale = _kvb_heads(cfg, w_kvb, o_lat.dtype)
+    dn = cfg.qk_nope_head_dim
+    o = jnp.einsum(
+        "...hr,rhd->...hd", o_lat, w[..., dn:],
+        preferred_element_type=jnp.float32,
+    )
+    if scale is not None:
+        o = o * scale[:, dn:]
+    return o.astype(o_lat.dtype)
+
+
+def _mla_attn_full(cfg: ModelConfig, p: dict, q, c, k_pe, positions):
+    """Full causal MLA in the EXPANDED form (per-head keys and values
+    from ``w_kvb``): the non-paged :func:`forward`. The paged programs
+    run the absorbed form; tests hold the two to each other."""
+    b, s, _ = c.shape
+    dn = cfg.qk_nope_head_dim
+    kv = _qmm(c, p["w_kvb"]).reshape(b, s, cfg.n_heads, dn + cfg.v_head_dim)
+    k = jnp.concatenate(
+        [
+            kv[..., :dn],
+            jnp.broadcast_to(
+                k_pe[:, :, None, :], (b, s, cfg.n_heads, cfg.qk_rope_head_dim)
+            ),
+        ],
+        axis=-1,
+    )
+    return causal_attention(
+        q, k, kv[..., dn:], positions, scale=cfg.attn_scale
+    )
+
+
+def _pad_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    pad = width - x.shape[-1]
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def _block(
     cfg: ModelConfig,
     p: dict,
@@ -589,6 +933,21 @@ def _block(
     shared-prefix kernels (see :func:`_attn_decode`).
     """
     h = _rms(cfg, x, p["attn_norm"], mesh)
+    if cfg.is_mla:
+        if mode != "full":
+            raise NotImplementedError(
+                f"{cfg.name}: latent attention runs through forward() and "
+                "the paged step programs (serve --backend continuous); the "
+                f"dense-cache {mode!r} path has no latent layout"
+            )
+        q, c, k_pe = _mla_project(cfg, p, h, cos, sin)
+        attn = _mla_attn_full(cfg, p, q, c, k_pe, positions)
+        x = x + _qmm(attn.reshape(*x.shape[:-1], -1), p["wo"])
+        h2 = _rms(cfg, x, p["mlp_norm"], mesh)
+        if collect_aux:
+            y, aux = _mlp(cfg, p, h2, collect_aux=True)
+            return x + y, None, aux
+        return x + _mlp(cfg, p, h2), None
     q, k, v = _project_qkv(cfg, p, h)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
@@ -763,6 +1122,39 @@ def _block(
     return x, new_kv
 
 
+def layer_stacks(params: dict) -> list[tuple[dict, int]]:
+    """The model's layer stacks in order, each with the absolute index
+    of its first layer: leading dense layers of an MoE model
+    (``dense_blocks``) and the rest (``blocks``). One stack holds
+    layers of one shape, which is what a scan over it needs."""
+    stacks = []
+    first = 0
+    for name in ("dense_blocks", "blocks"):
+        if name in params:
+            stacks.append((params[name], first))
+            first += len(jax.tree_util.tree_leaves(params[name])[0])
+    return stacks
+
+
+def first_layers(cfg: ModelConfig, params: dict, n: int):
+    """``(cfg, params)`` of the model's first ``n`` layers: the stacks
+    cut to them (leading dense layers count), embedding, final norm and
+    head kept (``serve --layers`` on a loaded checkpoint)."""
+    cut = cfg.with_layers(n)
+    out = dict(params)
+    for name in ("dense_blocks", "blocks"):
+        if name not in params:
+            continue
+        have = len(jax.tree_util.tree_leaves(params[name])[0])
+        keep = min(n, have)
+        n -= keep
+        if keep:
+            out[name] = jax.tree.map(lambda a: a[:keep], params[name])
+        else:
+            del out[name]
+    return cut, out
+
+
 def unstack_blocks(params: dict) -> dict:
     """Per-layer weight buffers: "blocks" [L, ...] -> tuple of L dicts.
 
@@ -779,13 +1171,13 @@ def unstack_blocks(params: dict) -> dict:
     ``_run_layers``). Training and sharded paths always use the stacked
     layout (compile time, pspecs).
     """
-    blocks = params["blocks"]
-    if isinstance(blocks, (list, tuple)):
+    if isinstance(params["blocks"], (list, tuple)):
         return params
-    n_layers = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-    out = dict(params)
+    out = {k: v for k, v in params.items() if k != "dense_blocks"}
     out["blocks"] = tuple(
-        jax.tree.map(lambda a: a[i], blocks) for i in range(n_layers)
+        jax.tree.map(lambda a: a[i], blocks)
+        for blocks, _ in layer_stacks(params)
+        for i in range(jax.tree_util.tree_leaves(blocks)[0].shape[0])
     )
     return out
 
@@ -838,12 +1230,29 @@ def _run_layers(
 
         if remat:
             body = jax.checkpoint(body)
-        x, auxes = jax.lax.scan(body, x, blocks)
+        stacks = layer_stacks(params)
+        if len(stacks) == 1:
+            x, auxes = jax.lax.scan(body, x, blocks)
+        else:
+            per_stack = []
+            for stack, _ in stacks:
+                x, a = jax.lax.scan(body, x, stack)
+                per_stack.append(a)
+            auxes = (
+                jax.tree.map(lambda *xs: jnp.concatenate(xs), *per_stack)
+                if collect_aux
+                else None
+            )
         if collect_aux:
             aux = jax.tree.map(jnp.mean, auxes)
             return x, cache, aux
         return x, cache
 
+    if cfg.is_mla or "dense_blocks" in params:
+        raise NotImplementedError(
+            f"{cfg.name}: only forward() and the paged step programs run "
+            f"layers of two shapes or latent attention (mode {mode!r})"
+        )
     if isinstance(cache, QuantKVCache):
         kv_leaves = (cache.k_q, cache.v_q, cache.k_scale, cache.v_scale)
     else:
@@ -934,7 +1343,9 @@ def _layer_view(blocks: dict, layer_idx) -> dict:
 
     view = {}
     for name, leaf in blocks.items():
-        if isinstance(leaf, QuantizedTensor) and leaf.q.ndim == 3:
+        if isinstance(leaf, QuantizedTensor) and leaf.q.ndim in (3, 4):
+            # [L, K, N], or a layer's experts [L, E, K, N]: the grouped
+            # expert matmul indexes the stack at layer * E + expert.
             view[name] = StackedQuant(full=leaf, layer=layer_idx)
         else:
             view[name] = jax.tree.map(
@@ -1062,7 +1473,7 @@ def forward(
     else:
         positions_arr = positions
     cos, sin = rope_cos_sin(
-        positions_arr, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        positions_arr, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
     )
     out = _run_layers(
         cfg, params, x, cos, sin, None, "full", None, positions,
@@ -1097,7 +1508,7 @@ def prefill(
     x = params["embed"][tokens]
     positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
     cos, sin = rope_cos_sin(
-        positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        positions, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
     )
     x, cache = _run_layers(
         cfg, params, x, cos, sin, cache, "prefill", None, None, mesh=mesh
@@ -1173,6 +1584,14 @@ def _attn_paged(
     [B, H, D] (and out_chunk [C, H, D] when q_chunk is given).
     """
     window = cfg.sliding_window
+    # An MLA pool is one latent plane [L, n_pages, page, lanes]: q is in
+    # latent space (padded to the pool's lanes), the value is the key's
+    # first kv_lora_rank lanes and the outputs are that wide.
+    latent = (
+        dict(scale=cfg.attn_scale, latent_dv=cfg.kv_lora_rank)
+        if cfg.is_mla
+        else {}
+    )
     gtuple = None
     if cfg.use_pallas and groups is not None:
         gtuple = (
@@ -1189,11 +1608,11 @@ def _attn_paged(
         return ragged_paged_attention(
             q_dec, k_pools, v_pools, tables, valid, layer=layer,
             q_chunk=q_chunk, chunk_table=chunk_table,
-            chunk_start=chunk_start, groups=gtuple, window=window,
+            chunk_start=chunk_start, groups=gtuple, window=window, **latent,
         )
     k_pool = jax.lax.dynamic_index_in_dim(k_pools, layer, 0, keepdims=False)
     v_pool = jax.lax.dynamic_index_in_dim(v_pools, layer, 0, keepdims=False)
-    if cfg.use_pallas and ragged_mesh_shardable(
+    if not cfg.is_mla and cfg.use_pallas and ragged_mesh_shardable(
         cfg, mesh, q_dec.shape[0], k_pool.shape[0]
     ):
         from llm_consensus_tpu.ops.pallas.attention import (
@@ -1212,8 +1631,31 @@ def _attn_paged(
     return ragged_paged_attention_reference(
         q_dec, k_pool, v_pool, tables, valid,
         q_chunk=q_chunk, chunk_table=chunk_table, chunk_start=chunk_start,
-        window=window,
+        window=window, **latent,
     )
+
+
+def _mla_attend_paged(
+    cfg, p, h, cos, sin, k_pools, v_pools, layer, pages, offs, attend
+):
+    """One layer's latent attention in a paged step program: write each
+    token's latent ``[c | k_pe]`` (zero-padded to the pool's lanes) at
+    ``[layer, page, offset]``, attend in the absorbed form — queries
+    moved into latent space, values expanded after the sum — and return
+    ([b, s, H, v] attention output, the pool)."""
+    q, c, k_pe = _mla_project(cfg, p, h, cos, sin)
+    lanes = k_pools.shape[-1]
+    k_pools = k_pools.at[layer, pages, offs].set(
+        _pad_lanes(jnp.concatenate([c, k_pe], axis=-1), lanes).astype(
+            k_pools.dtype
+        )
+    )
+    dn = cfg.qk_nope_head_dim
+    q_lat = jnp.concatenate(
+        [mla_absorb_q(cfg, p["w_kvb"], q[..., :dn]), q[..., dn:]], axis=-1
+    )
+    o_lat = attend(_pad_lanes(q_lat, lanes), k_pools, v_pools, layer)
+    return mla_expand_o(cfg, p["w_kvb"], o_lat), k_pools
 
 
 def _paged_layers(
@@ -1228,6 +1670,7 @@ def _paged_layers(
     attend,
     mesh=None,
     mlp=None,
+    active=None,
 ):
     """The layer loop of the four paged step programs.
 
@@ -1246,28 +1689,80 @@ def _paged_layers(
     layer)`` -> [b, s, H, Dh] is the program's own call of
     :func:`_attn_paged` on q [b, s, H, Dh]; ``mlp(p, h)`` replaces the
     plain :func:`_mlp` where the program splits it. Returns (x, k, v).
+
+    A model whose layers have two shapes (leading dense layers, then
+    expert layers) is one scan a stack over ONE pool, indexed by
+    absolute layer. An MLA model writes one latent a token into ``k``
+    (``v`` is its empty plane and rides along untouched) and attends in
+    the absorbed form on every lane: ``attend`` then takes queries in
+    latent space and returns latent outputs. A ``moe_dropless`` model
+    also returns int32 [experts reached, assignments] summed over its
+    expert layers, for the batcher's counters; ``active`` ([b * s] bool
+    or None) marks the rows that carry a request (:func:`_moe_dropless`).
     """
-    blocks = params["blocks"]
+    stats0 = (jnp.zeros((2,), jnp.int32),) if cfg.moe_dropless else ()
 
-    def body(carry, layer):
-        y, k_pools, v_pools = carry
-        p = _layer_view(blocks, layer)
-        h = _rms(cfg, y, p["attn_norm"], mesh)
-        q, k, v = _project_qkv(cfg, p, h)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        k_pools = k_pools.at[layer, pages, offs].set(k.astype(k_pools.dtype))
-        v_pools = v_pools.at[layer, pages, offs].set(v.astype(v_pools.dtype))
-        attn = attend(q, k_pools, v_pools, layer)
-        y = y + _qmm(attn.reshape(*y.shape[:-1], -1), p["wo"])
-        h2 = _rms(cfg, y, p["mlp_norm"], mesh)
-        y = y + (_mlp(cfg, p, h2) if mlp is None else mlp(p, h2))
-        return (y, k_pools, v_pools), None
+    carry = (x, cache.k, cache.v, *stats0)
+    for blocks, first in layer_stacks(params):
 
-    (x, new_k, new_v), _ = jax.lax.scan(
-        body, (x, cache.k, cache.v), jnp.arange(cache.k.shape[0])
-    )
+        def body(carry, i, blocks=blocks, first=first):
+            y, k_pools, v_pools, *stats = carry
+            # The pool is indexed by ABSOLUTE layer, the stack by its own.
+            layer = i + first if first else i
+            p = _layer_view(blocks, i)
+            h = _rms(cfg, y, p["attn_norm"], mesh)
+            if cfg.is_mla:
+                attn, k_pools = _mla_attend_paged(
+                    cfg, p, h, cos, sin, k_pools, v_pools, layer, pages, offs,
+                    attend,
+                )
+            else:
+                q, k, v = _project_qkv(cfg, p, h)
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+                k_pools = k_pools.at[layer, pages, offs].set(
+                    k.astype(k_pools.dtype)
+                )
+                v_pools = v_pools.at[layer, pages, offs].set(
+                    v.astype(v_pools.dtype)
+                )
+                attn = attend(q, k_pools, v_pools, layer)
+            y = y + _qmm(attn.reshape(*y.shape[:-1], -1), p["wo"])
+            h2 = _rms(cfg, y, p["mlp_norm"], mesh)
+            if stats and "router" in p:
+                out, _, _, st = _moe_dropless(cfg, p, h2, active=active)
+                y = y + out
+                stats = [stats[0] + st]
+            else:
+                y = y + (_mlp(cfg, p, h2) if mlp is None else mlp(p, h2))
+            return (y, k_pools, v_pools, *stats), None
+
+        n = len(jax.tree_util.tree_leaves(blocks)[0])
+        carry, _ = jax.lax.scan(body, carry, jnp.arange(n))
+    x, new_k, new_v, *stats = carry
+    if cfg.moe_dropless:
+        return x, new_k, new_v, stats[0]
     return x, new_k, new_v
+
+
+def _live_rows(cfg: ModelConfig, cache, write_mask=None, repeat=1, extra=0):
+    """[rows * repeat + extra] bool: decode rows that carry a request
+    (an idle slot's table row is all NULL pages, and a mid-prefill one
+    stays so until its last chunk lands), each ``repeat`` times, then
+    ``extra`` live chunk tokens. None unless the model has a dropless
+    expert layer, the one thing that asks (:func:`_moe_dropless`)."""
+    from llm_consensus_tpu.models.paged_cache import NULL_PAGE
+
+    if not cfg.moe_dropless:
+        return None
+    live = cache.page_table[:, 0] != NULL_PAGE
+    if write_mask is not None:
+        live &= write_mask
+    if repeat > 1:
+        live = jnp.repeat(live, repeat)
+    if extra:
+        live = jnp.concatenate([live, jnp.ones((extra,), bool)])
+    return live
 
 
 def decode_step_paged(
@@ -1285,7 +1780,10 @@ def decode_step_paged(
     ``page_table[b, length[b] // page]`` offset ``length[b] % page`` and
     attends over its gathered pages. Inactive rows (empty tables) write
     into the reserved NULL page — harmless garbage, outputs discarded by
-    the serving layer. Returns (logits [max_seqs, V] fp32, new cache).
+    the serving layer. Returns (logits [max_seqs, V] fp32, new cache) —
+    and, for a ``moe_dropless`` config (all four step programs alike),
+    a third value: int32 [experts reached, assignments] summed over the
+    expert layers (:func:`_paged_layers`).
 
     ``groups`` (a :class:`~llm_consensus_tpu.models.paged_cache.
     DecodeGroupArrays` or None): sequences sharing a prefix page run
@@ -1322,7 +1820,7 @@ def decode_step_paged(
     pos = cache.length  # [B] current write position
     x = params["embed"][tokens]  # [B, 1, D]
     cos, sin = rope_cos_sin(
-        pos[:, None], cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        pos[:, None], cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
     )
     pg = cache.page_size
     pages_now = cache.page_table[jnp.arange(b), pos // pg]  # [B]
@@ -1340,15 +1838,16 @@ def decode_step_paged(
             groups=groups, mesh=mesh,
         )[:, None]  # [B, H, D] -> [B, 1, H, D] (seq axis restored)
 
-    x, new_k, new_v = _paged_layers(
+    x, new_k, new_v, *stats = _paged_layers(
         cfg, params, x, cos, sin, cache, pages_now[:, None],
         offset[:, None], attend, mesh=mesh,
+        active=_live_rows(cfg, cache, write_mask),
     )
     logits = _unembed(cfg, params, x[:, 0], mesh)
     new_cache = PagedKVCache(
         k=new_k, v=new_v, page_table=cache.page_table, length=pos + adv
     )
-    return logits, new_cache
+    return (logits, new_cache, *stats)
 
 
 def verify_step_paged(
@@ -1393,7 +1892,7 @@ def verify_step_paged(
     pos = pos0[:, None] + jnp.arange(nq)[None]  # [B, NQ]
     x = params["embed"][tokens]  # [B, NQ, D]
     cos, sin = rope_cos_sin(
-        pos, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        pos, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
     )
     pg = cache.page_size
     pages = jnp.take_along_axis(
@@ -1408,14 +1907,15 @@ def verify_step_paged(
             groups=groups, mesh=mesh,
         )  # [B, NQ, H, D]
 
-    x, new_k, new_v = _paged_layers(
-        cfg, params, x, cos, sin, cache, pages, offs, attend, mesh=mesh
+    x, new_k, new_v, *stats = _paged_layers(
+        cfg, params, x, cos, sin, cache, pages, offs, attend, mesh=mesh,
+        active=_live_rows(cfg, cache, repeat=nq),
     )
     logits = _unembed(cfg, params, x, mesh)  # [B, NQ, V]
     new_cache = PagedKVCache(
         k=new_k, v=new_v, page_table=cache.page_table, length=cache.length
     )
-    return logits, new_cache
+    return (logits, new_cache, *stats)
 
 
 def prefill_chunk_paged(
@@ -1459,7 +1959,7 @@ def prefill_chunk_paged(
     pos = start + jnp.arange(c)  # [C] absolute positions
     x = params["embed"][tokens]  # [1, C, D]
     cos, sin = rope_cos_sin(
-        pos[None], cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        pos[None], cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
     )
     pg = cache.page_size
     pages = table[pos // pg]  # [C] destination page per chunk token
@@ -1481,7 +1981,7 @@ def prefill_chunk_paged(
         # hazard, reintroduced by topology instead of by feature flag.
         return _attn_paged(
             cfg,
-            jnp.zeros((nb, cfg.n_heads, cfg.head_dim), q.dtype),
+            jnp.zeros((nb, cfg.n_heads, q.shape[-1]), q.dtype),
             q[0],
             k_pools,
             v_pools,
@@ -1493,14 +1993,14 @@ def prefill_chunk_paged(
             mesh=mesh,
         )[1][None]  # out_chunk [C, H, D] -> [1, C, H, D]
 
-    x, new_k, new_v = _paged_layers(
+    x, new_k, new_v, *stats = _paged_layers(
         cfg, params, x, cos, sin, cache, pages[None], offs[None], attend,
         mesh=mesh,
     )
     new_cache = PagedKVCache(
         k=new_k, v=new_v, page_table=cache.page_table, length=cache.length
     )
-    return x, new_cache
+    return (x, new_cache, *stats)
 
 
 def fused_step_paged(
@@ -1555,7 +2055,7 @@ def fused_step_paged(
         jnp.concatenate([tokens[:, 0], chunk_tokens[0]])
     ][None]  # [1, B+C, D]
     cos, sin = rope_cos_sin(
-        all_pos[None], cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        all_pos[None], cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
     )
     pg = cache.page_size
     pages_dec = cache.page_table[jnp.arange(b), pos // pg]  # [B]
@@ -1580,16 +2080,17 @@ def fused_step_paged(
     # One scatter over DISJOINT real pages: decode rows write their
     # private pages, the chunk writes positions >= chunk_start of its
     # own table.
-    x, new_k, new_v = _paged_layers(
+    x, new_k, new_v, *stats = _paged_layers(
         cfg, params, x, cos, sin, cache, pages_all[None], offs_all[None],
         attend, mesh=mesh, mlp=mlp_by_side if mlp_split else None,
+        active=_live_rows(cfg, cache, extra=c),
     )
     logits = _unembed(cfg, params, x[0, :b], mesh)
     hidden_chunk = x[:, b:]  # [1, C, D]
     new_cache = PagedKVCache(
         k=new_k, v=new_v, page_table=cache.page_table, length=pos + 1
     )
-    return logits, hidden_chunk, new_cache
+    return (logits, hidden_chunk, new_cache, *stats)
 
 
 def unembed_one(
@@ -1641,7 +2142,7 @@ def _chunk_hidden(
     x = params["embed"][tokens]  # [B, K, D]
     positions = cache.length[:, None] + jnp.arange(kq)[None, :]
     cos, sin = rope_cos_sin(
-        positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        positions, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
     )
     x, cache = _run_layers(
         cfg, params, x, cos, sin, cache, "chunk", cache.length, None
@@ -1726,7 +2227,7 @@ def decode_step(
     x = params["embed"][tokens]  # [B, 1, D]
     positions = cache.length[:, None]  # [B, 1]
     cos, sin = rope_cos_sin(
-        positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        positions, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
     )
     x, cache = _run_layers(
         cfg,

@@ -44,6 +44,7 @@ def causal_attention(
     v: jnp.ndarray,
     positions: jnp.ndarray | None = None,
     window: int = 0,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """Causal self-attention over a full (prefill) sequence.
 
@@ -52,8 +53,11 @@ def causal_attention(
     to query i iff pos_j <= pos_i (supports packed/offset layouts). Default
     is index-causal. ``window`` > 0 adds Mistral-style sliding-window
     masking: query i also ignores keys with pos_i - pos_j >= window.
+    ``scale`` (default ``D ** -0.5``) and a value width other than the
+    key's are what a latent-attention model passes.
     """
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     scores = _gqa_scores(q, k) * scale  # [B, Hkv, G, Sq, Sk] fp32
     sq, sk = scores.shape[-2], scores.shape[-1]
     if positions is None:
@@ -111,6 +115,7 @@ def decode_attention(
     v_cache: jnp.ndarray,
     valid_len: jnp.ndarray,
     window: int = 0,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """One-token decode attention against a fixed-size KV cache.
 
@@ -120,7 +125,8 @@ def decode_attention(
     ``window`` > 0: only the last ``window`` cache slots attend (cache slot
     index == token position; the query sits at position valid_len - 1).
     """
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     scores = _gqa_scores(q, k_cache) * scale  # [B, Hkv, G, 1, max_len]
     max_len = k_cache.shape[1]
     slot = jnp.arange(max_len)[None, :]  # [1, max_len]
@@ -276,6 +282,8 @@ def ragged_paged_attention_reference(
     chunk_table: jnp.ndarray | None = None,
     chunk_start=None,
     window: int = 0,
+    scale: float | None = None,
+    latent_dv: int = 0,
 ):
     """XLA reference for the ragged paged attention kernel — the parity
     oracle, the non-Pallas serving path, AND the mesh fallback: when
@@ -306,30 +314,40 @@ def ragged_paged_attention_reference(
     k_pool/v_pool: [n_pages, page, Hkv, D]; page_table: [B, P];
     valid_len: [B]. Returns out_dec shaped like ``q`` (and out_chunk
     [C, H, D] when ``q_chunk`` is given).
+
+    ``latent_dv`` > 0: the latent (MLA) pool. ``k_pool`` is
+    [n_pages, page, D] — one key a token, shared by all H query heads
+    (multi-query attention) — ``v_pool`` is ignored and the value is the
+    key's first ``latent_dv`` lanes; outputs are [.., H, latent_dv].
+    ``scale`` overrides ``D ** -0.5``.
     """
     nq = None
     if q.ndim == 4:
         b, nq, h, d = q.shape
     else:
         b, h, d = q.shape
+    if latent_dv:
+        k_pool = k_pool[:, :, None, :]
+        v_pool = k_pool[..., :latent_dv]
     hkv = k_pool.shape[2]
+    dv = v_pool.shape[-1]
     k_seq = k_pool[page_table].reshape(b, -1, hkv, d)
-    v_seq = v_pool[page_table].reshape(b, -1, hkv, d)
+    v_seq = v_pool[page_table].reshape(b, -1, hkv, dv)
     if nq is None:
         out = decode_attention(
-            q[:, None], k_seq, v_seq, valid_len, window=window
+            q[:, None], k_seq, v_seq, valid_len, window=window, scale=scale
         )[:, 0]
     else:
         out = chunk_decode_attention(
-            q, k_seq, v_seq, valid_len - nq, window=window
+            q, k_seq, v_seq, valid_len - nq, window=window, scale=scale
         )
     if q_chunk is None:
         return out
     kc = k_pool[chunk_table].reshape(1, -1, hkv, d)
-    vc = v_pool[chunk_table].reshape(1, -1, hkv, d)
+    vc = v_pool[chunk_table].reshape(1, -1, hkv, dv)
     start = jnp.asarray(chunk_start, jnp.int32).reshape(1)
     out_chunk = chunk_decode_attention(
-        q_chunk[None], kc, vc, start, window=window
+        q_chunk[None], kc, vc, start, window=window, scale=scale
     )[0]
     return out, out_chunk
 
@@ -340,6 +358,7 @@ def chunk_decode_attention(
     v_cache: jnp.ndarray,
     valid_len: jnp.ndarray,
     window: int = 0,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """K-token chunk decode against the cache (speculative verification).
 
@@ -352,7 +371,8 @@ def chunk_decode_attention(
     ``window`` > 0 (Mistral): token i also ignores slots
     <= valid_len + i - window (cache slot j holds position j).
     """
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     scores = _gqa_scores(q, k_cache) * scale  # [B, Hkv, G, K, S]
     kq = q.shape[1]
     s = k_cache.shape[1]

@@ -599,6 +599,9 @@ def _decode_q8_stacked_kernel(
 # the parity oracle and the non-Pallas path.
 
 
+_LATENT_VMEM_BYTES = 64 * 1024 * 1024  # of a v5e core's 128 MiB
+
+
 def _sp_block(s: int, cap: int = 128) -> int:
     """Largest divisor of ``s`` <= cap — the S-axis page width the
     DENSE-cache wrappers use to view a contiguous cache as pool pages.
@@ -686,8 +689,15 @@ def _ragged_kernel(
     window: int,
     quant: bool,
     stacked: bool,
+    dv: int = 0,
 ):
     """One (program-class row, page) step of the ragged kernel.
+
+    ``dv`` (static, default 0 = off): the LATENT pool of an MLA model.
+    There is one key a token, [pg, d] with no head axis, shared by all
+    ``g`` query heads (``hkv`` is 1), and no value plane: the value is
+    the key's first ``dv`` lanes, so a page is read once and used as
+    both. Accumulators and outputs are ``dv`` wide.
 
     ``nq`` (static, default 1): queries per DECODE row. > 1 is the
     speculative-verify lane (PR 9): row b carries its previous token
@@ -735,6 +745,9 @@ def _ragged_kernel(
     if quant:
         kq_ref, ks_ref, vq_ref, vs_ref = refs[i : i + 4]
         i += 4
+    elif dv:
+        k_ref = refs[i]
+        i += 1
     else:
         k_ref, v_ref = refs[i : i + 2]
         i += 2
@@ -769,6 +782,10 @@ def _ragged_kernel(
         if quant:
             k, ks = _kv_head(kq_ref, ks_ref, head)
             v, vs = _kv_head(vq_ref, vs_ref, head)
+        elif dv:
+            k = k_ref[0].astype(jnp.float32)  # [pg, d]: the page, once
+            v = k[:, :dv]
+            ks = vs = None
         else:
             k, ks = _kv_head(k_ref, None, head)
             v, vs = _kv_head(v_ref, None, head)
@@ -865,7 +882,7 @@ def _ragged_kernel(
         def _init_group():
             m2_s[...] = jnp.full((hkv, b * nq * g, 1), _NEG_INF, jnp.float32)
             l2_s[...] = jnp.zeros((hkv, b * nq * g, 1), jnp.float32)
-            acc2_s[...] = jnp.zeros((hkv, b * nq * g, d), jnp.float32)
+            acc2_s[...] = jnp.zeros(acc2_s.shape, jnp.float32)
 
         @pl.when(s >= R)
         def _group():
@@ -942,6 +959,8 @@ def _ragged_attention(
     k_scale=None,
     v_scale=None,
     layer=None,
+    scale: float | None = None,
+    latent_dv: int = 0,
     interpret: bool | None = None,
 ):
     """Assemble and launch ONE ragged program; merge group partials.
@@ -960,6 +979,12 @@ def _ragged_attention(
     identity-tabled virtual pages of width ``pg``. Returns out_dec
     shaped like q_dec (and out_chunk [C, H, D] when ``q_chunk``) in q's
     dtype.
+
+    ``latent_dv`` > 0: ``k_kv`` is the latent pool [n_pages, pg, D]
+    (stacked: [L, n_pages, pg, D]) of an MLA model and ``v_kv`` is
+    ignored — one key a token for all H heads, value = its first
+    ``latent_dv`` lanes; outputs are [.., H, latent_dv]. ``scale``
+    overrides ``D ** -0.5``.
     """
     squeeze_nq = q_dec.ndim == 3
     if squeeze_nq:
@@ -975,9 +1000,13 @@ def _ragged_attention(
         npp = s_len // pg
         if s_len % pg:
             raise ValueError(f"cache len {s_len} not a multiple of {pg}")
+    elif latent_dv:
+        hkv = 1
+        npp = 0  # unused
     else:
         hkv = k_kv.shape[-2]
         npp = 0  # unused
+    dv = latent_dv or d
     g = h // hkv
     nc = 0 if q_chunk is None else 1
     cq = q_chunk.shape[0] if nc else 1
@@ -987,7 +1016,8 @@ def _ragged_attention(
     total = R + gm
     if interpret is None:
         interpret = interpret_default()
-    scale = d**-0.5
+    if scale is None:
+        scale = d**-0.5
 
     kvlen = kv_len.astype(jnp.int32)
     sstart = suffix_start.astype(jnp.int32)
@@ -1025,6 +1055,8 @@ def _ragged_attention(
             return (pf[0][0], page // npp, 0, page % npp, 0)
         if quant:
             return (page // npp, 0, page % npp, 0)
+        if latent_dv:
+            return (pf[0][0], page, 0, 0) if stacked else (page, 0, 0)
         if stacked:
             return (pf[0][0], page, 0, 0, 0)
         return (page, 0, 0, 0)
@@ -1090,6 +1122,11 @@ def _ragged_attention(
             sc_spec = pl.BlockSpec((1, hkv, pg), _scale_map)
         inputs += [k_kv, k_scale, v_kv, v_scale]
         in_specs += [kv_spec, sc_spec, kv_spec, sc_spec]
+    elif latent_dv:
+        inputs.append(k_kv)
+        in_specs.append(
+            pl.BlockSpec((None, 1, pg, d) if stacked else (1, pg, d), _kv_map)
+        )
     else:
         # The layer dimension of a stacked pool is squeezed out of the
         # block: the kernel sees one page, [1, pg, Hkv, D], either way.
@@ -1112,20 +1149,20 @@ def _ragged_attention(
     out_shapes = [
         _out(b + 1, hkv, nq * g, 1),
         _out(b + 1, hkv, nq * g, 1),
-        _out(b + 1, hkv, nq * g, d),
+        _out(b + 1, hkv, nq * g, dv),
     ]
     out_specs = [
         pl.BlockSpec((1, hkv, nq * g, 1), _dec_out_map),
         pl.BlockSpec((1, hkv, nq * g, 1), _dec_out_map),
-        pl.BlockSpec((1, hkv, nq * g, d), _dec_out_map),
+        pl.BlockSpec((1, hkv, nq * g, dv), _dec_out_map),
     ]
     if nc:
         # The chunk lane never meets a group partial, so only its
         # normalized output leaves the kernel.
-        out_shapes.append(_out(2, hkv, cq * g, d))
+        out_shapes.append(_out(2, hkv, cq * g, dv))
         out_specs.append(
             pl.BlockSpec(
-                (1, hkv, cq * g, d),
+                (1, hkv, cq * g, dv),
                 lambda s, j, *pf: (jnp.where(s == b, 0, 1), 0, 0, 0),
             )
         )
@@ -1133,26 +1170,35 @@ def _ragged_attention(
         out_shapes += [
             _out(hkv, b * nq * g, 1),
             _out(hkv, b * nq * g, 1),
-            _out(hkv, b * nq * g, d),
+            _out(hkv, b * nq * g, dv),
         ]
         out_specs += [
             pl.BlockSpec((hkv, b * nq * g, 1), lambda s, j, *pf: (0, 0, 0)),
             pl.BlockSpec((hkv, b * nq * g, 1), lambda s, j, *pf: (0, 0, 0)),
-            pl.BlockSpec((hkv, b * nq * g, d), lambda s, j, *pf: (0, 0, 0)),
+            pl.BlockSpec((hkv, b * nq * g, dv), lambda s, j, *pf: (0, 0, 0)),
         ]
 
     qs = max(nq, cq if nc else 1)
     scratch = [
         pltpu.VMEM((hkv, qs * g, 1), jnp.float32),
         pltpu.VMEM((hkv, qs * g, 1), jnp.float32),
-        pltpu.VMEM((hkv, qs * g, d), jnp.float32),
+        pltpu.VMEM((hkv, qs * g, dv), jnp.float32),
     ]
     if gm:
         scratch += [
             pltpu.VMEM((hkv, b * nq * g, 1), jnp.float32),
             pltpu.VMEM((hkv, b * nq * g, 1), jnp.float32),
-            pltpu.VMEM((hkv, b * nq * g, d), jnp.float32),
+            pltpu.VMEM((hkv, b * nq * g, dv), jnp.float32),
         ]
+    # A latent chunk lane stacks 16 heads on every query: 64 queries are
+    # 1,024 rows of 576 f32 lanes in, 512 out and 512 of accumulator,
+    # double-buffered — past Mosaic's default 16 MiB of scoped VMEM.
+    params = (
+        dict(compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_LATENT_VMEM_BYTES))
+        if latent_dv
+        else {}
+    )
 
     outs = pl.pallas_call(
         functools.partial(
@@ -1171,6 +1217,7 @@ def _ragged_attention(
             window=window,
             quant=quant,
             stacked=stacked,
+            dv=latent_dv,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(pf),
@@ -1182,17 +1229,18 @@ def _ragged_attention(
         out_shape=tuple(out_shapes),
         interpret=interpret,
         name="ragged_attention",
+        **params,
     )(*pf, *inputs)
 
     md, ld, od = outs[0][:b], outs[1][:b], outs[2][:b]
-    od5 = od.reshape(b, hkv, nq, g, d)
+    od5 = od.reshape(b, hkv, nq, g, dv)
     if gm:
         from llm_consensus_tpu.ops.attention import merge_decode_partials
 
         mg, lg, og = outs[-3], outs[-2], outs[-1]
         m1r = mg.reshape(hkv, b, nq, g, 1).transpose(1, 0, 2, 3, 4)
         l1r = lg.reshape(hkv, b, nq, g, 1).transpose(1, 0, 2, 3, 4)
-        o1r = og.reshape(hkv, b, nq, g, d).transpose(1, 0, 2, 3, 4)
+        o1r = og.reshape(hkv, b, nq, g, dv).transpose(1, 0, 2, 3, 4)
         m2r = md.reshape(b, hkv, nq, g, 1)
         l2r = ld.reshape(b, hkv, nq, g, 1)
         out5 = merge_decode_partials(m1r, l1r, o1r, m2r, l2r, od5)
@@ -1200,7 +1248,7 @@ def _ragged_attention(
         out5 = od5
     out_dec = (
         out5.transpose(0, 2, 1, 3, 4)
-        .reshape(b, nq, h, d)
+        .reshape(b, nq, h, dv)
         .astype(q_dec.dtype)
     )
     if squeeze_nq:
@@ -1209,9 +1257,9 @@ def _ragged_attention(
         return out_dec
     oc = outs[3][0]  # [Hkv, cq*G, D]
     out_chunk = (
-        oc.reshape(hkv, cq, g, d)
+        oc.reshape(hkv, cq, g, dv)
         .transpose(1, 0, 2, 3)
-        .reshape(cq, h, d)
+        .reshape(cq, h, dv)
         .astype(q_dec.dtype)
     )
     return out_dec, out_chunk
@@ -1230,6 +1278,8 @@ def ragged_paged_attention(
     groups: tuple | None = None,
     window: int = 0,
     layer=None,
+    scale: float | None = None,
+    latent_dv: int = 0,
     interpret: bool | None = None,
 ):
     """Mixed prefill+decode attention over the page pool — ONE program.
@@ -1257,9 +1307,15 @@ def ragged_paged_attention(
     partials merge exactly via flash-decoding LSE. ``window`` > 0
     applies sliding-window masking to every row kind. Returns
     out_dec [B, H, D] (and out_chunk [C, H, D] when ``q_chunk``).
+
+    ``latent_dv`` > 0: the latent pool of an MLA model, [n_pages, page,
+    D] (stacked [L, n_pages, page, D]); ``v_pool`` is ignored, each page
+    is read once as key (D lanes) and value (its first ``latent_dv``),
+    and outputs are [.., H, latent_dv]. ``scale`` overrides
+    ``D ** -0.5``.
     """
     b = q.shape[0]
-    pg = k_pool.shape[-3]
+    pg = k_pool.shape[-2 if latent_dv else -3]
     kvlen = valid_len.astype(jnp.int32)
     if groups is not None:
         gid, rep, gend, sstart = groups
@@ -1291,6 +1347,8 @@ def ragged_paged_attention(
         gend=gend,
         window=window,
         layer=layer,
+        scale=scale,
+        latent_dv=latent_dv,
         interpret=interpret,
     )
 

@@ -71,6 +71,7 @@ def ragged_attention_error(
     window: int = 0,
     null_tables: bool = False,
     layer: tuple[int, int] | None = None,
+    latent_dv: int = 0,
     interpret: bool | None = None,
 ) -> dict[str, float]:
     """Ragged paged attention kernel vs
@@ -87,6 +88,9 @@ def ragged_attention_error(
     [L, ...] pools and the kernel indexes the stack (a traced
     ``layer=``), as the step programs' layer scan calls it; its output
     must also equal, bit for bit, the call on the slice ``pool[l]``.
+    ``latent_dv`` > 0: the latent pool of an MLA model — one key of
+    ``d`` lanes a token for all ``g`` heads (``hkv`` must be 1), no
+    value plane, values the key's first ``latent_dv`` lanes.
     Returns {"decode": err[, "chunk": err]}.
     """
     from llm_consensus_tpu.ops.attention import (
@@ -96,8 +100,16 @@ def ragged_attention_error(
 
     rng = np.random.default_rng(seed)
     b, h = len(valid_len), hkv * g
-    kp = jnp.asarray(rng.standard_normal((n_pages, pg, hkv, d)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((n_pages, pg, hkv, d)), jnp.bfloat16)
+    if latent_dv:
+        kp = jnp.asarray(rng.standard_normal((n_pages, pg, d)), jnp.bfloat16)
+        vp = jnp.zeros((n_pages, pg, 0), jnp.bfloat16)
+    else:
+        kp = jnp.asarray(
+            rng.standard_normal((n_pages, pg, hkv, d)), jnp.bfloat16
+        )
+        vp = jnp.asarray(
+            rng.standard_normal((n_pages, pg, hkv, d)), jnp.bfloat16
+        )
     q_shape = (b, h, d) if nq == 1 else (b, nq, h, d)
     q = jnp.asarray(rng.standard_normal(q_shape), jnp.bfloat16)
     perm = rng.permutation(np.arange(1, n_pages))
@@ -119,6 +131,8 @@ def ragged_attention_error(
         )
     vl = jnp.asarray(valid_len, jnp.int32)
     kw: dict = {"window": window}
+    if latent_dv:
+        kw.update(latent_dv=latent_dv, scale=1.3 * d**-0.5)
     if cq:
         kw.update(
             q_chunk=jnp.asarray(
@@ -138,9 +152,12 @@ def ragged_attention_error(
     got = jax.jit(lambda: kernel(kp, vp))()
     if layer is not None:
         at, n_layers = layer
-        shape = (n_layers, *kp.shape)
-        k_stack = jnp.asarray(rng.standard_normal(shape), kp.dtype)
-        v_stack = jnp.asarray(rng.standard_normal(shape), vp.dtype)
+        k_stack = jnp.asarray(
+            rng.standard_normal((n_layers, *kp.shape)), kp.dtype
+        )
+        v_stack = jnp.asarray(
+            rng.standard_normal((n_layers, *vp.shape)), vp.dtype
+        )
         on_slice, got = got, jax.jit(kernel)(
             k_stack.at[at].set(kp), v_stack.at[at].set(vp), jnp.int32(at)
         )
@@ -160,6 +177,57 @@ def ragged_attention_error(
         _finite(got[0])  # all a dead row owes
         return {"chunk": max_err(got[1], ref[1])}
     return {"decode": max_err(got[0], ref[0]), "chunk": max_err(got[1], ref[1])}
+
+
+def moe_grouped_matmul_error(
+    *, seed: int, rows: list[int], k: int, n: int, n_layers: int = 2,
+    interpret: bool | None = None,
+) -> float:
+    """The grouped int8 expert matmul vs a loop of dequantize + ``jnp``
+    dots. ``rows``: rows each expert holds (zeros are experts nobody
+    reached, whose matrices must not matter); the experts are those of
+    the LAST layer of an [n_layers, E, K, N] stack, as the layer scan
+    calls the kernel. Dead tiles' rows are not compared."""
+    from llm_consensus_tpu.ops.pallas.moe_matmul import (
+        MOE_TILE,
+        moe_grouped_matmul,
+        n_tiles_for,
+    )
+
+    tm, e = MOE_TILE, len(rows)
+    key = jax.random.PRNGKey(seed)
+    w = jax.random.randint(key, (n_layers, e, k, n), -127, 128, jnp.int8)
+    s = (
+        jnp.abs(jax.random.normal(key, (n_layers, e, 1, n), jnp.float32))
+        * 1e-4 + 5e-5
+    )
+    n_tiles = n_tiles_for(sum(rows), e, tm)
+    tiles_e = [-(-r // tm) for r in rows]
+    tile_expert = [i for i, t in enumerate(tiles_e) for _ in range(t)]
+    n_live = len(tile_expert)
+    tile_expert += [tile_expert[-1]] * (n_tiles - n_live)
+    x = jax.random.normal(
+        jax.random.fold_in(key, 1), (n_tiles * tm, k), jnp.bfloat16
+    )
+    layer = n_layers - 1
+    got = jax.jit(
+        lambda: moe_grouped_matmul(
+            x, w.reshape(-1, k, n), s.reshape(-1, 1, n),
+            jnp.asarray(tile_expert, jnp.int32) + layer * e,
+            jnp.asarray([n_live], jnp.int32),
+            out_dtype=jnp.float32, interpret=interpret,
+        )
+    )()
+    err = 0.0
+    with jax.default_matmul_precision("highest"):
+        for t in range(n_live):
+            ex = tile_expert[t]
+            ref = jnp.dot(
+                x[t * tm : (t + 1) * tm].astype(jnp.float32),
+                w[layer, ex].astype(jnp.float32),
+            ) * s[layer, ex]
+            err = max(err, max_err(got[t * tm : (t + 1) * tm], ref))
+    return err
 
 
 def rms_norm_error(

@@ -36,6 +36,12 @@ _QUANT_AXES_DENSE = {
     "w_gate": 1,
     "w_up": 1,
     "w_down": 1,
+    # MLA projections and the shared experts (models.transformer).
+    "w_kva": 1,
+    "w_kvb": 1,
+    "ws_gate": 1,
+    "ws_up": 1,
+    "ws_down": 1,
 }
 _QUANT_AXES_MOE = {"w_gate": 2, "w_up": 2, "w_down": 2}
 
@@ -141,7 +147,10 @@ def dequantize4(qt: Quantized4Tensor, dtype=jnp.bfloat16) -> jnp.ndarray:
 
 
 def maybe_dequantize(leaf, dtype=jnp.bfloat16):
-    """Pass-through for plain arrays; dequantize quantized leaves."""
+    """Pass-through for plain arrays; dequantize quantized leaves (a
+    ``StackedQuant`` view: that layer's slice of the stack)."""
+    if isinstance(leaf, StackedQuant):
+        leaf = leaf.sliced()
     if isinstance(leaf, QuantizedTensor):
         return dequantize(leaf, dtype)
     if isinstance(leaf, Quantized4Tensor):
@@ -243,7 +252,7 @@ class StackedQuant:
     jit boundary; :func:`matmul` consumes it in-trace.
     """
 
-    full: QuantizedTensor  # q [L, K, N], scale [L, 1, N]
+    full: QuantizedTensor  # q [L, K, N], scale [L, 1, N] (or [L, E, ..])
     layer: jnp.ndarray  # traced scalar int32
 
     def sliced(self) -> QuantizedTensor:
@@ -327,12 +336,15 @@ def quantize_params(
     qfn = quantizer(bits)
     qtypes = (QuantizedTensor, Quantized4Tensor)
     out = dict(params)
-    blocks = dict(params["blocks"])
-    for name, w in blocks.items():
-        axis = quant_axis(name, w.ndim)
-        if axis is not None and not isinstance(w, qtypes):
-            blocks[name] = qfn(w, axis)
-    out["blocks"] = blocks
+    for stack in ("dense_blocks", "blocks"):
+        if stack not in params:
+            continue
+        blocks = dict(params[stack])
+        for name, w in blocks.items():
+            axis = quant_axis(name, w.ndim)
+            if axis is not None and not isinstance(w, qtypes):
+                blocks[name] = qfn(w, axis)
+        out[stack] = blocks
     if quantize_lm_head and "lm_head" in params and not isinstance(
         params["lm_head"], qtypes
     ):

@@ -33,26 +33,78 @@ def _llama3_rescale(inv_freq: jnp.ndarray, scaling) -> jnp.ndarray:
     )
 
 
+def yarn_ramp_bounds(scaling, dim: int, theta: float) -> tuple[int, int]:
+    """``(low, high)`` rotary pair indices between which YaRN blends:
+    the pairs that turn ``beta_fast`` and ``beta_slow`` times over the
+    original context (transformers' ``yarn_find_correction_range``)."""
+    orig = scaling.original_max_position_embeddings
+
+    def pair_of(rotations: float) -> float:
+        return (
+            dim
+            * math.log(orig / (rotations * 2.0 * math.pi))
+            / (2.0 * math.log(theta))
+        )
+
+    low = max(math.floor(pair_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(pair_of(scaling.beta_slow)), dim - 1)
+    return low, high
+
+
+def yarn_inv_freq(scaling, dim: int, theta: float) -> jnp.ndarray:
+    """YaRN's blended frequencies over ``dim`` rotary dims: pair i keeps
+    ``theta^(-2i/dim)`` below ``low``, takes it over ``factor`` above
+    ``high``, and a linear ramp of the two between."""
+    half = dim // 2
+    base = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    low, high = yarn_ramp_bounds(scaling, dim, theta)
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 1e-3),
+        0.0,
+        1.0,
+    )
+    return base * (1.0 - ramp) + (base / scaling.factor) * ramp
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    if factor <= 1.0 or not mscale:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
 def rope_cos_sin(
     positions: jnp.ndarray,
     head_dim: int,
     theta: float = 10000.0,
     scaling=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """cos/sin tables for given integer positions.
+    """cos/sin tables for given integer positions. ``head_dim`` is the
+    ROTATED width (``ModelConfig.rope_dim``): all of a GQA head, the
+    rotary part of an MLA head.
 
     positions: [...] int array (any shape, e.g. [B, S]).
     Returns cos, sin of shape [..., head_dim] (half-frequencies duplicated,
     matching the rotate-half convention). ``scaling``: optional
-    :class:`llm_consensus_tpu.models.configs.RopeScaling`.
+    :class:`llm_consensus_tpu.models.configs.RopeScaling` (Llama-3.1)
+    or ``YarnScaling`` (blended frequencies; cos and sin times the
+    ratio of its two ``mscale`` terms).
     """
     half = head_dim // 2
-    freq_exponents = jnp.arange(half, dtype=jnp.float32) / half
-    inv_freq = 1.0 / (theta**freq_exponents)  # [half]
-    if scaling is not None:
-        inv_freq = _llama3_rescale(inv_freq, scaling)
+    amp = 1.0
+    if hasattr(scaling, "beta_fast"):  # YarnScaling
+        inv_freq = yarn_inv_freq(scaling, head_dim, theta)
+        amp = _yarn_mscale(scaling.factor, scaling.mscale) / _yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim
+        )
+    else:
+        freq_exponents = jnp.arange(half, dtype=jnp.float32) / half
+        inv_freq = 1.0 / (theta**freq_exponents)  # [half]
+        if scaling is not None:
+            inv_freq = _llama3_rescale(inv_freq, scaling)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., half]
     angles = jnp.concatenate([angles, angles], axis=-1)  # [..., head_dim]
+    if amp != 1.0:
+        return jnp.cos(angles) * amp, jnp.sin(angles) * amp
     return jnp.cos(angles), jnp.sin(angles)
 
 
@@ -74,3 +126,18 @@ def apply_rope(
     c = cos[..., None, :]  # [B, S, 1, D]
     s = sin[..., None, :]
     return (xf * c + _rotate_half(xf) * s).astype(x.dtype)
+
+
+def apply_rope_tail(
+    x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
+) -> jnp.ndarray:
+    """Rotate the LAST ``cos.shape[-1]`` dims of every head and leave
+    the leading ones as they are (MLA: ``[q_nope | q_pe]``).
+
+    x: [B, S, H, D]; cos/sin: [B, S, R] with R <= D."""
+    r = cos.shape[-1]
+    if r == x.shape[-1]:
+        return apply_rope(x, cos, sin)
+    return jnp.concatenate(
+        [x[..., :-r], apply_rope(x[..., -r:], cos, sin)], axis=-1
+    )

@@ -12,7 +12,10 @@ Routes:
 
 - ``POST /v1/generate`` — one completion from the backend. Body:
   ``{"prompt": ..., "max_new_tokens"?, "temperature"?, "top_k"?,
-  "top_p"?, "seed"?, "stop"?, "stream"?, "priority"?, "deadline_s"?}``.
+  "top_p"?, "seed"?, "stop"?, "stream"?, "priority"?, "deadline_s"?,
+  "logits"?}``. ``"logits": n`` (greedy requests, the continuous
+  backend): ``meta.logits`` carries the float32 logits of the first n
+  generated positions as the serving programs computed them.
   With ``"stream": true`` the response is Server-Sent Events: one
   ``data: {"text": piece}`` event per token chunk, a final
   ``data: {"done": true, ...}`` summary, then ``data: [DONE]``.
@@ -1317,15 +1320,20 @@ class Gateway:
         stop = payload.get("stop") or ()
         if isinstance(stop, str):
             stop = (stop,)
+        temperature = float(payload.get("temperature", d.temperature))
+        logits = int(payload.get("logits", 0))
+        if logits < 0 or (logits and temperature > 0):
+            raise ValueError("'logits' takes n >= 0, on greedy requests only")
         return SamplingParams(
             max_new_tokens=int(
                 payload.get("max_new_tokens", d.max_new_tokens)
             ),
-            temperature=float(payload.get("temperature", d.temperature)),
+            temperature=temperature,
             top_k=int(payload.get("top_k", d.top_k)),
             top_p=float(payload.get("top_p", d.top_p)),
             seed=int(payload.get("seed", d.seed)),
             stop=tuple(stop),
+            logits=logits,
         )
 
     def _cost_kw(

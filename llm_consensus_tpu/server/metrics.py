@@ -639,6 +639,32 @@ PREFILL_TOKENS = REGISTRY.counter(
     "gateway_prefill_tokens_total",
     "Prompt tokens computed by prefill programs (no padding, no cached)",
 )
+#: The dropless expert layer's routing, labeled ``kind`` like the device
+#: programs (``decode`` / ``fused`` / ``prefill``), counted where the
+#: batcher retires a program from numbers the program itself returned
+#: beside its tokens. Over an interval: experts touched / (layer programs
+#: x the layer's experts) is the share of a layer's expert matrices a
+#: step reads; assignments / experts touched is the rows one expert
+#: matrix read serves.
+MOE_ASSIGNMENTS = REGISTRY.counter(
+    "gateway_moe_assignments_total",
+    "(token, expert) pairs the dropless expert layers computed",
+)
+MOE_EXPERTS_TOUCHED = REGISTRY.counter(
+    "gateway_moe_experts_touched_total",
+    "Experts some token reached, summed over expert layers and programs",
+)
+MOE_LAYER_PROGRAMS = REGISTRY.counter(
+    "gateway_moe_layer_programs_total",
+    "Expert layers run: device programs x the model's expert layers",
+)
+#: Cached tokens the attention of a retired program read out of the
+#: pool, a shared run counted once a group: the ``kv_read_tokens`` of
+#: the program's cost model, for every model.
+ATTENTION_TOKENS_READ = REGISTRY.counter(
+    "gateway_attention_tokens_read_total",
+    "Cached tokens attention read (shared runs once a group), by kind",
+)
 #: Device memory as the allocator reports it, labeled
 #: ``kind="in_use"|"peak"|"limit"``: the largest value over the local
 #: devices. Filled when ``/metrics`` is rendered (a render hook the

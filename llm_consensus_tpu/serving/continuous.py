@@ -85,6 +85,7 @@ is the TPU-native throughput-serving counterpart.
 from __future__ import annotations
 
 import contextlib
+import base64
 import functools
 import itertools
 import logging
@@ -150,6 +151,18 @@ from llm_consensus_tpu.ops.kernels import (
     on_tpu,
     resolve_kernels,
     single_device,
+)
+from llm_consensus_tpu.server.metrics import (
+    ATTENTION_TOKENS_READ as _M_ATTN_TOKENS_READ,
+)
+from llm_consensus_tpu.server.metrics import (
+    MOE_ASSIGNMENTS as _M_MOE_ASSIGNMENTS,
+)
+from llm_consensus_tpu.server.metrics import (
+    MOE_EXPERTS_TOUCHED as _M_MOE_EXPERTS,
+)
+from llm_consensus_tpu.server.metrics import (
+    MOE_LAYER_PROGRAMS as _M_MOE_LAYER_PROGRAMS,
 )
 from llm_consensus_tpu.server.metrics import (
     PREFILL_STALL_SECONDS as _M_PREFILL_STALL,
@@ -568,6 +581,11 @@ class ServeResult:
     # and result comparison means "same text/tokens" everywhere
     # (parity tests compare whole ServeResults).
     timing: dict | None = field(default=None, compare=False)
+    # ``submit(logits=n)``: float32 [<= n, V], the logits of the first
+    # generated positions exactly as the step programs handed them to
+    # the sampler (row 0 from the prefill's unembed, the rest from the
+    # decode or fused steps the row rode). None unless asked.
+    logits: np.ndarray | None = field(default=None, compare=False)
 
 
 @dataclass
@@ -606,6 +624,10 @@ class _Request:
     # request's Chrome-export track.
     rid: str = ""
     t_submit: float = 0.0
+    # ``"logits": n`` read-out: the sampler's logits rows of the first
+    # ``logits_n`` generated positions, fetched for this row alone.
+    logits_n: int = 0
+    logit_rows: list = field(default_factory=list)
 
 
 @dataclass
@@ -727,6 +749,11 @@ class _Inflight:
     # against the measured duration.
     flight: object = None
     cost: dict | None = None
+    # What the program returned beside its tokens (``_step_aux``): the
+    # last step's logits [slots, V], left on the device unless a row
+    # asked for them, and a dropless-MoE model's routing counts, which
+    # the fetch brings home with the tokens.
+    aux: tuple | None = None
 
 
 class ContinuousBatcher:
@@ -910,6 +937,7 @@ class ContinuousBatcher:
                 c.decode_rounds,
                 c.steps_per_sync,
             )
+        self._refuse_unsupported(cfg, self._draft_cfg, mesh, host_store)
         self.cache = self._create_pool(cfg)
         # The device as JAX reports it to this process, and which of
         # its devices hold this batcher's pool.
@@ -1376,6 +1404,29 @@ class ContinuousBatcher:
 
         return NamedSharding(self.mesh, P(*spec))
 
+    def _refuse_unsupported(self, cfg, draft_cfg, mesh, host_store) -> None:
+        """A latent-attention (MLA) model serves through the chunked,
+        fused and grouped programs of one device. What has no latent
+        path yet refuses here, by name, rather than fall back or serve
+        wrong pages."""
+        if not (cfg.is_mla or (draft_cfg is not None and draft_cfg.is_mla)):
+            return
+        c = self.config
+        why = None
+        if draft_cfg is not None:
+            why = "a draft model (the draft/verify lane)"
+        elif not single_device(mesh):
+            why = "a mesh (the latent pool has no partitioning yet)"
+        elif c.prefill_chunk <= 0:
+            why = "prefill_chunk=0 (dense prefill has no latent cache)"
+        elif c.host_cache_bytes > 0 or host_store is not None:
+            why = "the host tier / a remote page store"
+        if why:
+            raise ValueError(
+                f"{cfg.name}: latent-attention (MLA) models do not serve "
+                f"with {why} yet"
+            )
+
     def _create_pool(self, cfg: ModelConfig) -> PagedKVCache:
         """An empty pool for ``cfg`` at this batcher's geometry. On a
         mesh it is born in its sharding — every device allocates its
@@ -1494,8 +1545,10 @@ class ContinuousBatcher:
         """``steps_per_sync`` decode+sample steps as ONE device program.
 
         Returns ``([slots, k] tokens, [slots, k] logprobs, cache,
-        [slots] final token)`` — the final-token row is what a pipelined
-        dispatch feeds the NEXT program without a host round trip.
+        [slots] final token, aux)`` — the final-token row is what a
+        pipelined dispatch feeds the NEXT program without a host round
+        trip; ``aux`` is :meth:`_step_aux`'s tuple and stays on the
+        device unless a row asked for its logits.
         Each step folds ``(seed, count+j)`` into the per-slot PRNG —
         the same stream a chunk-of-1 loop would draw, so results are
         chunk-size-invariant (tested).
@@ -1510,10 +1563,10 @@ class ContinuousBatcher:
         body = self._decode_body(
             params, seeds, temps, topks, topps, filters_active, groups
         )
-        (cache, tok_end, _), (toks, logps) = jax.lax.scan(
+        (cache, tok_end, _), (toks, logps, extra) = jax.lax.scan(
             body, (cache, tokens, counts), None, length=k
         )
-        return toks.T, logps.T, cache, tok_end
+        return toks.T, logps.T, cache, tok_end, self._step_aux(extra)
 
     def _decode_body(
         self,
@@ -1550,10 +1603,13 @@ class ContinuousBatcher:
                 alive = None
             else:
                 cache, tok, cnt, alive, emitted = carry
-            logits, cache = decode_step_paged(
+            # A dropless-MoE model's step also returns its routing
+            # counts; ``extra`` = (logits, *counts) rides the scan's ys.
+            logits, cache, *moe = decode_step_paged(
                 self.cfg, params, tok[:, None], cache, groups=groups,
                 write_mask=alive, mesh=self.mesh,
             )
+            extra = (logits, *moe)
             keys = jax.vmap(
                 lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c)
             )(seeds, cnt)
@@ -1566,7 +1622,7 @@ class ContinuousBatcher:
                 filters_active=filters_active,
             )
             if stop is None:
-                return (cache, next_tok, cnt + 1), (next_tok, logp)
+                return (cache, next_tok, cnt + 1), (next_tok, logp, extra)
             budgets, screen = stop
             next_tok = jnp.where(alive, next_tok, tok)
             adv = alive.astype(cnt.dtype)
@@ -1576,9 +1632,22 @@ class ContinuousBatcher:
                 next_tok, self.tokenizer.eos_id, screen, emitted, budgets
             )
             alive = alive & ~hit
-            return (cache, next_tok, cnt, alive, emitted), (next_tok, logp)
+            return (
+                (cache, next_tok, cnt, alive, emitted),
+                (next_tok, logp, extra),
+            )
 
         return body
+
+    @staticmethod
+    def _step_aux(extra):
+        """What a program hands back beside its tokens, from the scan's
+        stacked ``extra``: the LAST step's logits [slots, V] as the
+        sampler got them (``"logits": n`` requests read their row;
+        nothing else fetches it) and, for a dropless-MoE model, the
+        routing counts summed over the steps."""
+        logits, *moe = extra
+        return (logits[-1], *(m.sum(axis=0) for m in moe))
 
     def _rounds_sample(
         self,
@@ -1612,7 +1681,7 @@ class ContinuousBatcher:
         frozen rounds) relies on.
 
         Returns ``(emit [B, R], logps [B, R], cache, next_in [B],
-        counts_out [B], emit_cnt [B])`` — only each row's leading
+        counts_out [B], emit_cnt [B], aux)`` — only each row's leading
         ``emit_cnt`` tokens are real, the spec program's contract.
         """
         alive0 = jnp.ones(tokens.shape, dtype=bool)
@@ -1621,11 +1690,14 @@ class ContinuousBatcher:
             params, seeds, temps, topks, topps, filters_active, groups,
             stop=(budgets, screen),
         )
-        (cache, tok_end, cnt_out, _, emitted), (toks, logps) = jax.lax.scan(
-            body, (cache, tokens, counts, alive0, emitted0), None,
-            length=rounds,
+        (cache, tok_end, cnt_out, _, emitted), (toks, logps, extra) = (
+            jax.lax.scan(
+                body, (cache, tokens, counts, alive0, emitted0), None,
+                length=rounds,
+            )
         )
-        return toks.T, logps.T, cache, tok_end, cnt_out, emitted
+        aux = self._step_aux(extra)
+        return toks.T, logps.T, cache, tok_end, cnt_out, emitted, aux
 
     def _fused_sample(
         self,
@@ -1678,7 +1750,7 @@ class ContinuousBatcher:
         variant first met under load would stall every row for them.
         """
         k = self._sync_chunk
-        logits, hidden, cache = fused_step_paged(
+        logits, hidden, cache, *moe = fused_step_paged(
             self.cfg,
             params,
             tokens[:, None],
@@ -1696,6 +1768,13 @@ class ContinuousBatcher:
         tok1, logp1 = sample_token_per_request(
             logits, keys, temps, topks, topps, filters_active=filters_active
         )
+        def aux_with(extra):
+            """The fused step's aux (its FIRST step's logits, the one
+            step a ``"logits"`` request may ride: ``submit``) with the
+            tail scan's routing counts added."""
+            tail = self._step_aux(extra)[1:]
+            return (logits, *(m + t for m, t in zip(moe, tail)))
+
         c = chunk_tokens.shape[1]
         chunk_logits = jax.lax.cond(
             chunk_done,
@@ -1719,7 +1798,7 @@ class ContinuousBatcher:
                     params, seeds, temps, topks, topps, filters_active,
                     groups, stop=(budgets, screen),
                 )
-                (cache, tok_end, cnt_out, _, emitted), (toks, logps) = (
+                (cache, tok_end, cnt_out, _, emitted), (toks, logps, extra) = (
                     jax.lax.scan(
                         body,
                         (cache, tok1, counts + 1, alive, emitted),
@@ -1731,23 +1810,26 @@ class ContinuousBatcher:
                 logps = jnp.concatenate([logp1[:, None], logps.T], axis=1)
                 return (
                     toks, logps, cache, tok_end, chunk_logits, emitted,
-                    cnt_out,
+                    cnt_out, aux_with(extra),
                 )
             return (
                 tok1[:, None], logp1[:, None], cache, tok1, chunk_logits,
-                emitted, counts + 1,
+                emitted, counts + 1, (logits, *moe),
             )
         if k > 1:
             body = self._decode_body(
                 params, seeds, temps, topks, topps, filters_active, groups
             )
-            (cache, tok_end, _), (toks, logps) = jax.lax.scan(
+            (cache, tok_end, _), (toks, logps, extra) = jax.lax.scan(
                 body, (cache, tok1, counts + 1), None, length=k - 1
             )
             toks = jnp.concatenate([tok1[:, None], toks.T], axis=1)
             logps = jnp.concatenate([logp1[:, None], logps.T], axis=1)
-            return toks, logps, cache, tok_end, chunk_logits
-        return tok1[:, None], logp1[:, None], cache, tok1, chunk_logits
+            return toks, logps, cache, tok_end, chunk_logits, aux_with(extra)
+        return (
+            tok1[:, None], logp1[:, None], cache, tok1, chunk_logits,
+            (logits, *moe),
+        )
 
     def _spec_sample(
         self,
@@ -1816,7 +1898,7 @@ class ContinuousBatcher:
         def dbody(carry, j):
             dc, tok, hist = carry
             din = tok if t2d is None else t2d[tok]
-            lg, dc = decode_step_paged(
+            lg, dc, *_ = decode_step_paged(
                 dcfg, dparams, din[:, None], dc, mesh=self.mesh
             )
             prop = jnp.argmax(lg, axis=-1).astype(jnp.int32)  # [B]
@@ -1851,7 +1933,7 @@ class ContinuousBatcher:
         )  # [B, K] each row's verified proposals == its draft-fed stream
 
         vtok = jnp.concatenate([tokens[:, None], drafts], axis=1)
-        logits, cache = verify_step_paged(
+        logits, cache, *_ = verify_step_paged(
             self._spec_cfg, params, vtok, cache, groups=groups,
             mesh=self.mesh,
         )  # [B, K+1, V] fp32
@@ -2028,7 +2110,7 @@ class ContinuousBatcher:
                 return prefill_chunk_paged(
                     dcfg, params, tokens, table, pos, dcache,
                     mesh=self.mesh,
-                )
+                )[:2]
 
             self._jit_chunk_d[key] = jax.jit(
                 prefill_chunk_draft, donate_argnums=(4,)
@@ -2234,8 +2316,16 @@ class ContinuousBatcher:
         top_p: float | None = None,
         stop: list[str] | tuple[str, ...] | None = None,
         prompt_ids=None,
+        logits: int = 0,
     ) -> Future:
         """Enqueue a request; Future resolves to a :class:`ServeResult`.
+
+        ``logits`` = n > 0 also returns the float32 logits of the first
+        n generated positions (``ServeResult.logits``), as the timed
+        programs computed them: no second forward pass. Greedy requests
+        only, and only where a program is one decode step (no
+        ``steps_per_sync``, ``decode_rounds`` or draft): elsewhere a
+        program keeps only its last step's logits.
 
         ``top_k``/``top_p``: ``None`` inherits the batcher's
         config-level sampler; any EXPLICIT value is authoritative —
@@ -2263,6 +2353,18 @@ class ContinuousBatcher:
             max_new_tokens = c.max_new_tokens
         if max_new_tokens <= 0:
             raise ValueError(f"max_new_tokens must be > 0, got {max_new_tokens}")
+        if logits:
+            if temperature > 0:
+                raise ValueError("'logits' is for greedy requests only")
+            if (
+                self._sync_chunk > 1
+                or c.decode_rounds > 1
+                or self._draft_cfg is not None
+            ):
+                raise ValueError(
+                    "'logits' needs one decode step a program: no "
+                    "steps_per_sync, decode_rounds or draft model"
+                )
         full_ids = (
             prompt_ids
             if prompt_ids is not None
@@ -2314,6 +2416,7 @@ class ContinuousBatcher:
             trace=_tracing.current_trace(),
             rid=f"req-{next(_RID)}",
             t_submit=time.perf_counter(),
+            logits_n=max(0, int(logits)),
         )
         with self._lock:
             self._waiting.append(req)
@@ -3776,6 +3879,7 @@ class ContinuousBatcher:
             self.controller.note_program(kind, cost, dur)
         if cost is None:
             return
+        _M_ATTN_TOKENS_READ.labels(kind=kind).inc(cost["kv_read_tokens"])
         with self._lock:
             m = self._mbu[kind]
             m["hbm_bytes"] += cost["hbm_bytes"]
@@ -3835,7 +3939,9 @@ class ContinuousBatcher:
             chunk_ids = slot.padded_ids[
                 slot.next_pos : slot.next_pos + slot.chunk
             ]
-            hidden, self.cache = self._chunk_fn(slot.chunk, slot.s_bucket)(
+            hidden, self.cache, *moe = self._chunk_fn(
+                slot.chunk, slot.s_bucket
+            )(
                 self.params,
                 jnp.asarray(chunk_ids[None]),
                 jnp.asarray(slot.table),
@@ -3863,6 +3969,8 @@ class ContinuousBatcher:
         with self._phase("retire"):
             dur = time.perf_counter() - t0
             _M_PREFILL_STALL.observe(dur)
+            if moe:
+                self._count_moe("prefill", np.asarray(moe[0]))
             if ev is not None:
                 # Standalone chunk programs are host-blocking: the
                 # device window IS [t0, t0 + dur] — fill the flight
@@ -3934,6 +4042,8 @@ class ContinuousBatcher:
         that program ends (PERF.md, Findings PR 26: ~50 ms a call on
         the chip)."""
         with self._phase("device_wait"):
+            if req.logits_n:
+                req.logit_rows.append(np.asarray(logits))
             key = jax.random.fold_in(jax.random.PRNGKey(req.seed), 0)
             tok, _ = sample_token_per_request(
                 logits[None],
@@ -4237,6 +4347,13 @@ class ContinuousBatcher:
                     text=text,
                     num_tokens=len(slot.generated),
                     timing=summary,
+                    logits=(
+                        np.stack(
+                            slot.request.logit_rows[: slot.request.logits_n]
+                        )
+                        if slot.request.logit_rows
+                        else None
+                    ),
                 )
             )
 
@@ -4572,14 +4689,16 @@ class ContinuousBatcher:
                 # Same prepared device args as the legacy program
                 # (args[9] is groups — _rounds_sample takes it after
                 # the stop data).
-                next_tok, _, self.cache, next_in, cnt_out, emit_cnt = (
-                    self._jit_rounds(
-                        rounds_now, *args[:9], budgets_dev, screen_dev,
-                        args[9],
-                    )
+                (
+                    next_tok, _, self.cache, next_in, cnt_out, emit_cnt,
+                    aux,
+                ) = self._jit_rounds(
+                    rounds_now, *args[:9], budgets_dev, screen_dev, args[9],
                 )
             else:
-                next_tok, _, self.cache, next_in = self._jit_decode(*args)
+                next_tok, _, self.cache, next_in, aux = self._jit_decode(
+                    *args
+                )
             ev = self._count_program(
                 "decode", rows=len(rows_now), rounds=k
             )
@@ -4607,10 +4726,10 @@ class ContinuousBatcher:
             if rounds_now:
                 (
                     next_tok, _, self.cache, next_in, chunk_logits,
-                    emit_cnt, cnt_out,
+                    emit_cnt, cnt_out, aux,
                 ) = out
             else:
-                next_tok, _, self.cache, next_in, chunk_logits = out
+                next_tok, _, self.cache, next_in, chunk_logits, aux = out
             ev = self._count_program(
                 "fused", rows=len(rows_now) + 1, rounds=k
             )
@@ -4671,6 +4790,7 @@ class ContinuousBatcher:
             rows=rows_now, chunk=chunk_rec, rounds=rounds_now,
             rounds_clean=rounds_clean,
             emit_cnt=emit_cnt, counts_out=cnt_out, flight=ev, cost=cost,
+            aux=aux,
         )
         self._dispatch_tail(rec, groups, k)
 
@@ -4716,8 +4836,27 @@ class ContinuousBatcher:
                 if (rec.spec or rec.rounds)
                 else None
             )
+            moe_np = (
+                np.asarray(rec.aux[1])
+                if rec.aux is not None and len(rec.aux) > 1
+                else None
+            )
         with self._phase("retire"):
+            if moe_np is not None:
+                self._count_moe(
+                    "fused" if rec.chunk else "decode", moe_np, rec.k
+                )
             self._credit_fetched(rec, next_np, cnt_np)
+
+    def _count_moe(self, kind: str, moe_np, steps: int = 1) -> None:
+        """A retired program's expert-routing counts (int32 [experts
+        reached, assignments], summed over its expert layers and
+        steps), as the program returned them."""
+        _M_MOE_EXPERTS.labels(kind=kind).inc(int(moe_np[0]))
+        _M_MOE_ASSIGNMENTS.labels(kind=kind).inc(int(moe_np[1]))
+        _M_MOE_LAYER_PROGRAMS.labels(kind=kind).inc(
+            steps * self.cfg.n_moe_layers
+        )
 
     def _credit_fetched(self, rec: "_Inflight", next_np, cnt_np) -> None:
         """The host bookkeeping of one fetched program (the ``retire``
@@ -4849,6 +4988,11 @@ class ContinuousBatcher:
         for i, slot in alive:
             done = False
             n_emit = int(cnt_np[i]) if cnt_np is not None else rec.k
+            want = slot.request.logits_n
+            if want > len(slot.request.logit_rows) and rec.aux is not None:
+                # One step a program (``submit`` saw to it): this row of
+                # the step's logits is the token credited just below.
+                slot.request.logit_rows.append(np.asarray(rec.aux[0][i]))
             for j in range(n_emit):
                 tok = int(next_np[i, j])
                 slot.generated.append(tok)
@@ -5153,6 +5297,24 @@ class ContinuousBatcher:
                 self._work.clear()
 
 
+def _meta_with_logits(timing: dict | None, logits) -> dict | None:
+    """The response's ``meta``: the request's summary, and for a
+    ``"logits": n`` request a COPY of it (the summary ring keeps the
+    original) with the rows as base64 of little-endian float32."""
+    if logits is None:
+        return timing
+    rows = np.ascontiguousarray(logits, dtype="<f4")
+    return {
+        **(timing or {}),
+        "logits": {
+            "positions": int(rows.shape[0]),
+            "vocab": int(rows.shape[1]),
+            "dtype": "float32",
+            "b64": base64.b64encode(rows.tobytes()).decode("ascii"),
+        },
+    }
+
+
 class ContinuousBackend(_backend_base.Backend):
     """Backend seam over a :class:`ContinuousBatcher`.
 
@@ -5188,6 +5350,7 @@ class ContinuousBackend(_backend_base.Backend):
                         top_k=r.params.top_k,
                         top_p=r.params.top_p,
                         stop=r.params.stop,
+                        logits=r.params.logits,
                     )
                 )
         except (RuntimeError, ValueError) as e:
@@ -5201,7 +5364,8 @@ class ContinuousBackend(_backend_base.Backend):
         outs = await asyncio.gather(*(asyncio.wrap_future(f) for f in futs))
         return [
             GenerationResult(
-                text=o.text, num_tokens=o.num_tokens, meta=o.timing
+                text=o.text, num_tokens=o.num_tokens,
+                meta=_meta_with_logits(o.timing, o.logits),
             )
             for o in outs
         ]

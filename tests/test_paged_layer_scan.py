@@ -195,7 +195,8 @@ def test_layer_scan_carries_the_pools_and_stacks_nothing(params, name):
 
 
 def _loop_over_slices(
-    cfg, params, x, cos, sin, cache, pages, offs, attend, mesh=None, mlp=None
+    cfg, params, x, cos, sin, cache, pages, offs, attend, mesh=None, mlp=None,
+    active=None,
 ):
     """``_paged_layers``' contract, the plain way: a Python loop that
     slices layer l's weights and pools out, writes the rows into the
