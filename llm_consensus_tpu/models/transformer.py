@@ -1745,19 +1745,29 @@ def _paged_layers(
     return x, new_k, new_v
 
 
-def _live_rows(cfg: ModelConfig, cache, write_mask=None, repeat=1, extra=0):
-    """[rows * repeat + extra] bool: decode rows that carry a request
-    (an idle slot's table row is all NULL pages, and a mid-prefill one
-    stays so until its last chunk lands), each ``repeat`` times, then
-    ``extra`` live chunk tokens. None unless the model has a dropless
-    expert layer, the one thing that asks (:func:`_moe_dropless`)."""
+def _decoding_rows(cache, write_mask=None):
+    """[rows] bool: decode rows that carry a request and decode a token
+    this step. An idle slot's table row is all NULL pages, and a
+    mid-prefill one stays so until its last chunk lands (its pages go
+    through the host's explicit table): such a row, like one frozen by
+    ``write_mask``, writes into the NULL page, keeps its ``length`` (0
+    since its release) and gives the attention nothing to read — a
+    length that grew with every step would have it fold the NULL page
+    up to a whole table's width, every layer of every step."""
     from llm_consensus_tpu.models.paged_cache import NULL_PAGE
 
+    live = cache.page_table[:, 0] != NULL_PAGE
+    return live if write_mask is None else live & write_mask
+
+
+def _live_rows(cfg: ModelConfig, cache, write_mask=None, repeat=1, extra=0):
+    """[rows * repeat + extra] bool: :func:`_decoding_rows`, each
+    ``repeat`` times, then ``extra`` live chunk tokens. None unless the
+    model has a dropless expert layer, the one thing that asks
+    (:func:`_moe_dropless`)."""
     if not cfg.moe_dropless:
         return None
-    live = cache.page_table[:, 0] != NULL_PAGE
-    if write_mask is not None:
-        live &= write_mask
+    live = _decoding_rows(cache, write_mask)
     if repeat > 1:
         live = jnp.repeat(live, repeat)
     if extra:
@@ -1779,8 +1789,9 @@ def decode_step_paged(
     tokens: [max_seqs, 1]. Each row b writes its new K/V at
     ``page_table[b, length[b] // page]`` offset ``length[b] % page`` and
     attends over its gathered pages. Inactive rows (empty tables) write
-    into the reserved NULL page — harmless garbage, outputs discarded by
-    the serving layer. Returns (logits [max_seqs, V] fp32, new cache) —
+    into the reserved NULL page, keep their length and attend over
+    nothing (:func:`_decoding_rows`) — their outputs are garbage the
+    serving layer discards. Returns (logits [max_seqs, V] fp32, new cache) —
     and, for a ``moe_dropless`` config (all four step programs alike),
     a third value: int32 [experts reached, assignments] summed over the
     expert layers (:func:`_paged_layers`).
@@ -1825,11 +1836,9 @@ def decode_step_paged(
     pg = cache.page_size
     pages_now = cache.page_table[jnp.arange(b), pos // pg]  # [B]
     offset = pos % pg
-    if write_mask is None:
-        adv = 1
-    else:
+    if write_mask is not None:
         pages_now = jnp.where(write_mask, pages_now, NULL_PAGE)
-        adv = write_mask.astype(pos.dtype)
+    adv = _decoding_rows(cache, write_mask).astype(pos.dtype)
     tables = cache.page_table  # [B, P]
 
     def attend(q, k_pools, v_pools, layer):
@@ -1901,9 +1910,12 @@ def verify_step_paged(
     offs = pos % pg
     tables = cache.page_table
 
+    # An idle row verifies nothing and reads nothing.
+    valid = jnp.where(_decoding_rows(cache), pos0 + nq, 0)
+
     def attend(q, k_pools, v_pools, layer):
         return _attn_paged(
-            cfg, q, None, k_pools, v_pools, layer, tables, pos0 + nq,
+            cfg, q, None, k_pools, v_pools, layer, tables, valid,
             groups=groups, mesh=mesh,
         )  # [B, NQ, H, D]
 
@@ -2040,7 +2052,8 @@ def fused_step_paged(
     matches its parity baseline; dense models share one MLP call.
 
     Returns (decode logits [B, V] fp32, chunk hidden [1, C, D], cache).
-    ``cache.length`` advances for the decode rows only.
+    ``cache.length`` advances for the decode rows only, and of those
+    for the ones that carry a request (:func:`_decoding_rows`).
     """
     from llm_consensus_tpu.models.paged_cache import PagedKVCache
 
@@ -2062,12 +2075,13 @@ def fused_step_paged(
     pages_all = jnp.concatenate([pages_dec, chunk_table[chunk_pos // pg]])
     offs_all = jnp.concatenate([pos % pg, chunk_pos % pg])
     tables = cache.page_table
+    adv = _decoding_rows(cache).astype(pos.dtype)
     mlp_split = cfg.is_moe and cfg_chunk is not cfg
 
     def attend(q, k_pools, v_pools, layer):
         attn_dec, attn_ch = _attn_paged(
             cfg, q[0, :b], q[0, b:], k_pools, v_pools, layer, tables,
-            pos + 1, chunk_table=chunk_table, chunk_start=chunk_start,
+            pos + adv, chunk_table=chunk_table, chunk_start=chunk_start,
             groups=groups, mesh=mesh,
         )
         return jnp.concatenate([attn_dec, attn_ch])[None]  # [1, B+C, H, Dh]
@@ -2088,7 +2102,7 @@ def fused_step_paged(
     logits = _unembed(cfg, params, x[0, :b], mesh)
     hidden_chunk = x[:, b:]  # [1, C, D]
     new_cache = PagedKVCache(
-        k=new_k, v=new_v, page_table=cache.page_table, length=pos + 1
+        k=new_k, v=new_v, page_table=cache.page_table, length=pos + adv
     )
     return (logits, hidden_chunk, new_cache, *stats)
 

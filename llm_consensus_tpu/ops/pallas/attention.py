@@ -565,7 +565,8 @@ def _decode_q8_stacked_kernel(
 # decode. The kernel below replaces the family with one program in the
 # style of TPU Ragged Paged Attention (PAPERS.md): every row carries
 # per-row (length, suffix-start, group-id) metadata via scalar
-# prefetch, and row KIND is a grid-position case of the same body:
+# prefetch, and row KIND is a grid-position case of the same body, one
+# grid step a row:
 #
 #   programs [0, B)          decode rows — one query token each, pages
 #                            walked through the row's scalar-prefetched
@@ -584,16 +585,18 @@ def _decode_q8_stacked_kernel(
 # two merge EXACTLY on the host via flash-decoding log-sum-exp
 # (:func:`~llm_consensus_tpu.ops.attention.merge_decode_partials`) —
 # bit-for-bit the arithmetic of the two-phase kernels this replaces.
-# Pages outside a row's live range (before the suffix start, past the
-# fill, or wholly before the sliding window) are sentinel-remapped to
-# page 0 in the index map, so consecutive dead grid steps request the
-# SAME block and their DMAs collapse.
+# The pools stay in HBM and a step walks its row's LIVE pages only
+# (from the suffix start or the sliding window's edge up to the fill; a
+# group's shared run), copying each through a two-slot buffer with the
+# next page in flight: a call's time follows the pages its rows hold,
+# not the table's width (a grid step costs 0.16 us on a v5e even when
+# it folds nothing, and a table has 48 columns a row).
 #
 # Three static layouts share the body (there is one kernel, not three):
 # the serving pool [n_pages, page, Hkv, D]; the dense int8 head-major
 # cache [B, Hkv, S, D] (+ scales), viewed as identity-tabled pages; and
 # the STACKED int8 cache [L, B, Hkv, S, D] with the layer index riding
-# scalar prefetch into the index maps. The dense bf16 cache needs no
+# scalar prefetch into the page copies. The dense bf16 cache needs no
 # layout of its own — [B, S, Hkv, D] reshapes into pool pages for free.
 # The XLA reference (ops.attention.ragged_paged_attention_reference) is
 # the parity oracle and the non-Pallas path.
@@ -686,12 +689,22 @@ def _ragged_kernel(
     gm: int,
     pg: int,
     p_per: int,
+    npp: int,
     window: int,
     quant: bool,
     stacked: bool,
     dv: int = 0,
 ):
-    """One (program-class row, page) step of the ragged kernel.
+    """One program-class row of the ragged kernel: a decode row, the
+    chunk lane or a group, walking ITS OWN live pages.
+
+    The grid is one step a row. The pools stay in HBM; a step computes
+    its row's live page range from the scalar-prefetched lengths (past
+    the suffix start, under the fill, inside the sliding window; a
+    group's shared run) and loops over it with a dynamic trip count,
+    each page copied from ``pool[layer, table[row, j]]`` into a two-slot
+    VMEM buffer, page j + 1 in flight while page j folds. A row with no
+    live page costs its one grid step, whatever the table's width.
 
     ``dv`` (static, default 0 = off): the LATENT pool of an MLA model.
     There is one key a token, [pg, d] with no head axis, shared by all
@@ -709,11 +722,12 @@ def _ragged_kernel(
     ``refs`` is parsed positionally by the same static layout the
     wrapper builds: scalar prefetch ([layer?], tbl, kvlen, sstart,
     [rep, gend]), VMEM inputs ([gid_rows, wlo_rows?], q_dec, [q_chunk?],
-    [q_all?], K(+scales), V(+scales)), outputs (decode partials,
-    [chunk out?], [group partials?]), then scratch. Row scratch is
-    re-initialized at every row's first page; the group accumulator
-    persists across all group programs (they run last) and is written
-    once at the very last program.
+    [q_all?]), the pools in HBM (K(+scales), V(+scales)), outputs
+    (decode partials, [chunk out?], [group partials?]), then scratch:
+    row state, [group state], one two-slot page buffer a plane and
+    their DMA semaphores. Row state is re-initialized at every row; the
+    group accumulator persists across all group programs (they run
+    last) and is written once at the very last program.
 
     Shapes are chosen for Mosaic, not for brevity: every per-head
     quantity keeps the kv head on a LEADING axis (scratch
@@ -724,14 +738,13 @@ def _ragged_kernel(
     """
     i = 0
     if stacked:
-        i += 1  # layer index: consumed by the index maps only
+        layer_ref = refs[0]
+        i += 1
     tbl_ref, kvlen_ref, sstart_ref = refs[i : i + 3]
     i += 3
-    del tbl_ref  # pages are resolved by the index maps
     if gm:
         rep_ref, gend_ref = refs[i : i + 2]
         i += 2
-        del rep_ref
         gid_ref, wlo_ref = refs[i : i + 2]
         i += 2
     q_dec_ref = refs[i]
@@ -742,15 +755,9 @@ def _ragged_kernel(
     if gm:
         q_all_ref = refs[i]
         i += 1
-    if quant:
-        kq_ref, ks_ref, vq_ref, vs_ref = refs[i : i + 4]
-        i += 4
-    elif dv:
-        k_ref = refs[i]
-        i += 1
-    else:
-        k_ref, v_ref = refs[i : i + 2]
-        i += 2
+    n_planes = 4 if quant else 1 if dv else 2
+    pools = refs[i : i + n_planes]
+    i += n_planes
     md_ref, ld_ref, od_ref = refs[i : i + 3]
     i += 3
     if nc:
@@ -763,32 +770,92 @@ def _ragged_kernel(
     i += 3
     if gm:
         m2_s, l2_s, acc2_s = refs[i : i + 3]
+        i += 3
+    bufs = refs[i : i + n_planes]
+    sem = refs[i + n_planes]
 
     s = pl.program_id(0)
-    j = pl.program_id(1)
     R = b + nc
     total = R + gm
 
-    def _kv_head(ref, s_ref, head):
-        """This page's K (or V) slab [pg, D] for one kv head, plus its
-        [1, pg] dequant row (None for the pool layout)."""
-        if quant:
-            if stacked:
-                return ref[0, 0, head], s_ref[0, 0, head : head + 1, :]
-            return ref[0, head], s_ref[0, head : head + 1, :]
-        return _pool_head(ref, head), None
+    def _page_src(plane, page):
+        """Plane ``plane``'s slab of pool page ``page``, in HBM. The
+        int8 caches are identity-tabled virtual pages: page p is slots
+        [(p % npp) * pg, + pg) of cache row p // npp, every kv head."""
+        at = (layer_ref[0],) if stacked else ()
+        if not quant:
+            return pools[plane].at[(*at, page)]
+        at += (jax.lax.div(page, jnp.int32(npp)), slice(None))
+        slots = pl.ds(jax.lax.rem(page, jnp.int32(npp)) * pg, pg)
+        if plane % 2:  # a scale plane [.., Hkv, S]
+            return pools[plane].at[(*at, slots)]
+        return pools[plane].at[(*at, slots, slice(None))]
 
-    def _fold(idx, q, head, mask, mr, lr, ar):
+    def _walk(row, j_lo, j_hi, fold_page):
+        """``fold_page(j, slot)`` for pages j_lo <= j < j_hi of table
+        row ``row``, ascending, each waited for in buffer slot ``slot``
+        with the next one's copy already started."""
+        n = j_hi - j_lo
+
+        def copies(j, slot):
+            page = tbl_ref[row * p_per + j]
+            return [
+                pltpu.make_async_copy(
+                    _page_src(plane, page),
+                    bufs[plane].at[slot],
+                    sem.at[plane, slot],
+                )
+                for plane in range(n_planes)
+            ]
+
+        @pl.when(n > 0)
+        def _start_first():
+            for copy in copies(j_lo, 0):
+                copy.start()
+
+        def step(t, carry):
+            j = j_lo + t
+            slot = jax.lax.rem(t, 2)
+
+            @pl.when(t + 1 < n)
+            def _start_next():
+                for copy in copies(j + 1, 1 - slot):
+                    copy.start()
+
+            for copy in copies(j, slot):
+                copy.wait()
+            fold_page(j, slot)
+            return carry
+
+        jax.lax.fori_loop(0, n, step, 0)
+
+    def _pages(lo, hi):
+        """The table columns j that hold some of slots [lo, hi), as a
+        range: (j + 1) * pg > lo and j * pg < hi."""
+        page = jnp.int32(pg)  # lengths are >= 0: lax.div is the floor
+        return jax.lax.div(lo, page), jnp.minimum(
+            jax.lax.div(hi + (pg - 1), page), p_per
+        )
+
+    def _kv_head(plane, slot, head):
+        """The page in buffer slot ``slot``: one kv head's K (``plane``
+        0) or V slab [pg, D], plus its [1, pg] dequant row (None for
+        the pool layout)."""
         if quant:
-            k, ks = _kv_head(kq_ref, ks_ref, head)
-            v, vs = _kv_head(vq_ref, vs_ref, head)
-        elif dv:
-            k = k_ref[0].astype(jnp.float32)  # [pg, d]: the page, once
+            return (
+                bufs[2 * plane][slot, head],
+                bufs[2 * plane + 1][slot, pl.ds(head, 1), :],
+            )
+        return _pool_head(bufs[plane].at[pl.ds(slot, 1)], head), None
+
+    def _fold(idx, q, head, mask, slot, mr, lr, ar):
+        if dv:
+            k = bufs[0][slot].astype(jnp.float32)  # [pg, d]: the page, once
             v = k[:, :dv]
             ks = vs = None
         else:
-            k, ks = _kv_head(k_ref, None, head)
-            v, vs = _kv_head(v_ref, None, head)
+            k, ks = _kv_head(0, slot, head)
+            v, vs = _kv_head(1, slot, head)
         scores = jax.lax.dot_general(
             q,
             k.astype(jnp.float32),
@@ -798,18 +865,16 @@ def _ragged_kernel(
         scores = jnp.where(mask, scores, _NEG_INF)
         _online_fold(mr, lr, ar, idx, scores, v, v_row_scale=vs)
 
-    # Row scratch: re-initialized per row (its page walk is contiguous
-    # in the grid), shared by decode and chunk programs.
-    @pl.when(j == 0)
     def _init_row():
+        # Row scratch: shared by decode and chunk programs, one a step.
         m_s[...] = jnp.full(m_s.shape, _NEG_INF, jnp.float32)
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    def _causal_mask(n, qbase, lo):
-        """[n * g, pg] ragged-causal mask for n queries at absolute
-        positions qbase + i (rows (query, g)-ordered): query i sees
-        slots <= its own position — chunk_decode_attention's rule;
+    def _causal_mask(n, qbase, lo, j):
+        """[n * g, pg] ragged-causal mask of page j for n queries at
+        absolute positions qbase + i (rows (query, g)-ordered): query i
+        sees slots <= its own position — chunk_decode_attention's rule;
         n == 1 is the classic slot < valid decode mask."""
         row = jax.lax.broadcasted_iota(jnp.int32, (n * g, pg), 0)
         qpos = qbase + (row // g if g > 1 else row)
@@ -819,66 +884,62 @@ def _ragged_kernel(
             mask &= slot > qpos - window
         return mask
 
-    @pl.when(s < b)
-    def _decode_row():
-        valid = kvlen_ref[s]
-        qbase = valid - nq  # first query's absolute position
-        lo = sstart_ref[s]
+    def _row(row, n, q_ref):
+        """A decode/verify row or the chunk lane: ``n`` queries ending
+        at the row's fill, over the row's own pages."""
+        _init_row()
+        valid = kvlen_ref[row]
+        qbase = valid - n  # first query's absolute position
+        lo = sstart_ref[row]
         lo_all = lo
         if window > 0:
             # Sliding window: query i sits at qbase + i and sees slots
-            # (qbase + i - window, qbase + i] — the union of the nq
-            # windows starts at the FIRST query's edge (nq == 1
-            # reduces to ops.attention.decode_attention's rule).
+            # (qbase + i - window, qbase + i] — the union of the n
+            # windows starts at the FIRST query's edge (n == 1 reduces
+            # to ops.attention.decode_attention's rule).
             lo_all = jnp.maximum(lo, qbase + 1 - window)
-        live = ((j + 1) * pg > lo_all) & (j * pg < valid)
 
-        @pl.when(live)
-        def _fold_page():
-            mask = _causal_mask(nq, qbase, lo)
+        def fold_page(j, slot):
+            # The cache so far plus (a chunk, verify row) the row itself.
+            mask = _causal_mask(n, qbase, lo, j)
             for head in range(hkv):  # static unroll over kv heads
                 _fold(
-                    (head, slice(0, nq * g)),
-                    q_dec_ref[0, head],
+                    (head, slice(0, n * g)),
+                    q_ref[0, head],
                     head,
                     mask,
+                    slot,
                     m_s,
                     l_s,
                     acc_s,
                 )
 
+        _walk(row, *_pages(lo_all, valid), fold_page)
+
+    # Slices, never [...]: the row scratch is sized for the WIDER of
+    # the chunk lane (cq) and the decode/verify lane (nq) — each lane's
+    # rows are the leading n * g of every head.
+
+    @pl.when(s < b)
+    def _decode_row():
+        _row(s, nq, q_dec_ref)
+        l = l_s[:, 0 : nq * g]
+        md_ref[0] = m_s[:, 0 : nq * g]
+        ld_ref[0] = l
+        od_ref[0] = acc_s[:, 0 : nq * g] / jnp.maximum(l, 1e-30)
+
     if nc:
 
         @pl.when(s == b)
         def _chunk_row():
-            valid = kvlen_ref[b]  # chunk start + cq
-            qbase = valid - cq
-            lo = sstart_ref[b]
-            lo_all = lo
-            if window > 0:
-                # The union of the cq queries' windows starts at the
-                # FIRST query's window edge.
-                lo_all = jnp.maximum(lo, qbase + 1 - window)
-            live = ((j + 1) * pg > lo_all) & (j * pg < valid)
-
-            @pl.when(live)
-            def _fold_page():
-                # The cache so far plus the chunk itself.
-                mask = _causal_mask(cq, qbase, lo)
-                for head in range(hkv):  # static unroll over kv heads
-                    _fold(
-                        (head, slice(0, cq * g)),
-                        q_chunk_ref[0, head],
-                        head,
-                        mask,
-                        m_s,
-                        l_s,
-                        acc_s,
-                    )
+            _row(b, cq, q_chunk_ref)
+            oc_ref[0] = acc_s[:, 0 : cq * g] / jnp.maximum(
+                l_s[:, 0 : cq * g], 1e-30
+            )
 
     if gm:
         # Group programs run LAST; their accumulator spans all of them.
-        @pl.when((s == R) & (j == 0))
+        @pl.when(s == R)
         def _init_group():
             m2_s[...] = jnp.full((hkv, b * nq * g, 1), _NEG_INF, jnp.float32)
             l2_s[...] = jnp.zeros((hkv, b * nq * g, 1), jnp.float32)
@@ -889,52 +950,31 @@ def _ragged_kernel(
             gi = s - R
             ge = gend_ref[gi]
 
-            @pl.when(j * pg < ge)
-            def _fold_page():
-                slot = j * pg + jax.lax.broadcasted_iota(
+            def fold_page(j, slot):
+                at = j * pg + jax.lax.broadcasted_iota(
                     jnp.int32, (b * nq * g, pg), 1
                 )
                 # Every decode query sits past the shared run's end
                 # (shared pages cover prompt prefixes only), so the
                 # causal limit never binds here — mask is membership +
                 # run extent, for all nq queries alike.
-                mask = (gid_ref[...] == gi) & (slot < ge)
+                mask = (gid_ref[...] == gi) & (at < ge)
                 if window > 0:
                     # Per-member, per-query window edge (the wrapper
                     # precomputes it per stacked row): members of one
                     # group can sit at different fills, and the nq
                     # verify queries of one member at different
                     # positions.
-                    mask &= slot >= wlo_ref[...]
+                    mask &= at >= wlo_ref[...]
                 for head in range(hkv):  # static unroll over kv heads
                     _fold(
-                        head, q_all_ref[head], head, mask, m2_s, l2_s, acc2_s
+                        head, q_all_ref[head], head, mask, slot,
+                        m2_s, l2_s, acc2_s,
                     )
 
-    # -- writes ---------------------------------------------------------
+            _walk(rep_ref[gi], *_pages(jnp.int32(0), ge), fold_page)
 
-    # Slices, never [...]: the row scratch is sized for the WIDER of
-    # the chunk lane (cq) and the decode/verify lane (nq) — each lane's
-    # rows are the leading n * g of every head.
-
-    @pl.when((s < b) & (j == p_per - 1))
-    def _write_dec():
-        l = l_s[:, 0 : nq * g]
-        md_ref[0] = m_s[:, 0 : nq * g]
-        ld_ref[0] = l
-        od_ref[0] = acc_s[:, 0 : nq * g] / jnp.maximum(l, 1e-30)
-
-    if nc:
-
-        @pl.when((s == b) & (j == p_per - 1))
-        def _write_chunk():
-            oc_ref[0] = acc_s[:, 0 : cq * g] / jnp.maximum(
-                l_s[:, 0 : cq * g], 1e-30
-            )
-
-    if gm:
-
-        @pl.when((s == total - 1) & (j == p_per - 1))
+        @pl.when(s == total - 1)
         def _write_group():
             l = l2_s[...]
             mg_ref[...] = m2_s[...]
@@ -974,8 +1014,8 @@ def _ragged_attention(
     cache [B, Hkv, S, D] with [B, Hkv, S] scales, or either of them
     stacked over layers — pools [L, n_pages, pg, Hkv, D], int8 cache
     [L, B, Hkv, S, D] — with ``layer`` a traced index that rides scalar
-    prefetch into the index maps, so the blocks the kernel sees are the
-    unstacked layout's. The dense layouts are addressed as
+    prefetch into the kernel's page copies, so the pages it folds are
+    the unstacked layout's. The dense layouts are addressed as
     identity-tabled virtual pages of width ``pg``. Returns out_dec
     shaped like q_dec (and out_chunk [C, H, D] when ``q_chunk``) in q's
     dtype.
@@ -1027,46 +1067,6 @@ def _ragged_attention(
     pf += [page_table.reshape(-1).astype(jnp.int32), kvlen, sstart]
     if gm:
         pf += [rep.astype(jnp.int32), gend.astype(jnp.int32)]
-    i_tbl = 1 if stacked else 0
-
-    def _page_of(s, j, pf):
-        """Pool page for program (s, j), dead steps sentinel-remapped
-        to page 0 so their DMAs collapse."""
-        tbl, kvl, sst = pf[i_tbl], pf[i_tbl + 1], pf[i_tbl + 2]
-        row = jnp.where(s < R, s, 0)
-        lo = sst[row]
-        if window > 0:
-            nq_row = jnp.where(row < b, nq, cq) if nc else nq
-            lo = jnp.maximum(lo, kvl[row] - (nq_row - 1) - window)
-        live = ((j + 1) * pg > lo) & (j * pg < kvl[row])
-        page = jnp.where(live, tbl[row * p_per + j], 0)
-        if gm:
-            rep_a, gend_a = pf[i_tbl + 3], pf[i_tbl + 4]
-            gi = jnp.clip(s - R, 0, gm - 1)
-            g_page = jnp.where(
-                j * pg < gend_a[gi], tbl[rep_a[gi] * p_per + j], 0
-            )
-            page = jnp.where(s < R, page, g_page)
-        return page
-
-    def _kv_map(s, j, *pf):
-        page = _page_of(s, j, pf)
-        if stacked and quant:
-            return (pf[0][0], page // npp, 0, page % npp, 0)
-        if quant:
-            return (page // npp, 0, page % npp, 0)
-        if latent_dv:
-            return (pf[0][0], page, 0, 0) if stacked else (page, 0, 0)
-        if stacked:
-            return (pf[0][0], page, 0, 0, 0)
-        return (page, 0, 0, 0)
-
-    def _scale_map(s, j, *pf):
-        page = _page_of(s, j, pf)
-        if stacked:
-            return (pf[0][0], page // npp, 0, page % npp)
-        return (page // npp, 0, page % npp)
-
     inputs = []
     in_specs = []
     if gm:
@@ -1079,7 +1079,7 @@ def _ragged_attention(
         for col in (jnp.repeat(gid.astype(jnp.int32), nq * g), wlo):
             inputs.append(col.reshape(rows, 1))
             in_specs.append(
-                pl.BlockSpec((rows, 1), lambda s, j, *pf: (0, 0))
+                pl.BlockSpec((rows, 1), lambda s, *pf: (0, 0))
             )
     # Per-row q block rows are (nq, g)-ordered — the order the fold's
     # mask and the write-out both assume. f32 here: the kernel computes
@@ -1089,7 +1089,7 @@ def _ragged_attention(
     in_specs.append(
         pl.BlockSpec(
             (1, hkv, nq * g, d),
-            lambda s, j, *pf: (jnp.where(s < b, s, 0), 0, 0, 0),
+            lambda s, *pf: (jnp.where(s < b, s, 0), 0, 0, 0),
         )
     )
     if nc:
@@ -1101,7 +1101,7 @@ def _ragged_attention(
         )
         in_specs.append(
             pl.BlockSpec(
-                (1, hkv, cq * g, d), lambda s, j, *pf: (0, 0, 0, 0)
+                (1, hkv, cq * g, d), lambda s, *pf: (0, 0, 0, 0)
             )
         )
     if gm:
@@ -1110,37 +1110,27 @@ def _ragged_attention(
         )
         in_specs.append(
             pl.BlockSpec(
-                (hkv, b * nq * g, d), lambda s, j, *pf: (0, 0, 0)
+                (hkv, b * nq * g, d), lambda s, *pf: (0, 0, 0)
             )
         )
+    # The pools stay where they are, in HBM: the kernel copies the pages
+    # a row holds, one at a time, into its own two-slot buffers (one a
+    # plane; a page of a plane has the same shape stacked or not).
     if quant:
-        if stacked:
-            kv_spec = pl.BlockSpec((1, 1, hkv, pg, d), _kv_map)
-            sc_spec = pl.BlockSpec((1, 1, hkv, pg), _scale_map)
-        else:
-            kv_spec = pl.BlockSpec((1, hkv, pg, d), _kv_map)
-            sc_spec = pl.BlockSpec((1, hkv, pg), _scale_map)
-        inputs += [k_kv, k_scale, v_kv, v_scale]
-        in_specs += [kv_spec, sc_spec, kv_spec, sc_spec]
+        planes = [k_kv, k_scale, v_kv, v_scale]
+        page_shapes = [(hkv, pg, d), (hkv, pg)] * 2
     elif latent_dv:
-        inputs.append(k_kv)
-        in_specs.append(
-            pl.BlockSpec((None, 1, pg, d) if stacked else (1, pg, d), _kv_map)
-        )
+        planes, page_shapes = [k_kv], [(pg, d)]
     else:
-        # The layer dimension of a stacked pool is squeezed out of the
-        # block: the kernel sees one page, [1, pg, Hkv, D], either way.
-        kv_spec = pl.BlockSpec(
-            (None, 1, pg, hkv, d) if stacked else (1, pg, hkv, d), _kv_map
-        )
-        inputs += [k_kv, v_kv]
-        in_specs += [kv_spec, kv_spec]
+        planes, page_shapes = [k_kv, v_kv], [(pg, hkv, d)] * 2
+    inputs += planes
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(planes)
 
     # Outputs. Row partials are blocked per row with one TRASH block
     # (index b / index nc) absorbing the write-backs of programs that
     # own a different class's output — an output block revisited after
     # its owner moved on would otherwise land stale buffer contents.
-    def _dec_out_map(s, j, *pf):
+    def _dec_out_map(s, *pf):
         return (jnp.where(s < b, s, b), 0, 0, 0)
 
     def _out(*shape):
@@ -1163,7 +1153,7 @@ def _ragged_attention(
         out_specs.append(
             pl.BlockSpec(
                 (1, hkv, cq * g, dv),
-                lambda s, j, *pf: (jnp.where(s == b, 0, 1), 0, 0, 0),
+                lambda s, *pf: (jnp.where(s == b, 0, 1), 0, 0, 0),
             )
         )
     if gm:
@@ -1173,9 +1163,9 @@ def _ragged_attention(
             _out(hkv, b * nq * g, dv),
         ]
         out_specs += [
-            pl.BlockSpec((hkv, b * nq * g, 1), lambda s, j, *pf: (0, 0, 0)),
-            pl.BlockSpec((hkv, b * nq * g, 1), lambda s, j, *pf: (0, 0, 0)),
-            pl.BlockSpec((hkv, b * nq * g, dv), lambda s, j, *pf: (0, 0, 0)),
+            pl.BlockSpec((hkv, b * nq * g, 1), lambda s, *pf: (0, 0, 0)),
+            pl.BlockSpec((hkv, b * nq * g, 1), lambda s, *pf: (0, 0, 0)),
+            pl.BlockSpec((hkv, b * nq * g, dv), lambda s, *pf: (0, 0, 0)),
         ]
 
     qs = max(nq, cq if nc else 1)
@@ -1190,6 +1180,11 @@ def _ragged_attention(
             pltpu.VMEM((hkv, b * nq * g, 1), jnp.float32),
             pltpu.VMEM((hkv, b * nq * g, dv), jnp.float32),
         ]
+    scratch += [
+        pltpu.VMEM((2, *shape), plane.dtype)
+        for shape, plane in zip(page_shapes, planes)
+    ]
+    scratch.append(pltpu.SemaphoreType.DMA((len(planes), 2)))
     # A latent chunk lane stacks 16 heads on every query: 64 queries are
     # 1,024 rows of 576 f32 lanes in, 512 out and 512 of accumulator,
     # double-buffered — past Mosaic's default 16 MiB of scoped VMEM.
@@ -1214,6 +1209,7 @@ def _ragged_attention(
             gm=gm,
             pg=pg,
             p_per=p_per,
+            npp=npp,
             window=window,
             quant=quant,
             stacked=stacked,
@@ -1221,7 +1217,7 @@ def _ragged_attention(
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(pf),
-            grid=(total, p_per),
+            grid=(total,),
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=scratch,
@@ -1378,9 +1374,9 @@ def ragged_paged_attention_sharded(
     correctness invariant: every row's table references only pages of
     its own data shard, so per-shard the GLOBAL page ids rebase to
     local pool indices (``id - shard * local_pages``, clamped — NULL
-    and foreign ids appear only in dead/masked steps, where the clamp
-    lands on a harmless masked read, exactly like the kernel's own
-    page-0 sentinel remap). Shared-prefix groups live entirely on one
+    and foreign ids sit only past a row's fill, in columns the kernel
+    does not walk; the chunk lane's on a shard that does not own it
+    clamp to a harmless local read). Shared-prefix groups live entirely on one
     shard for the same reason (one prefix registry per shard), so the
     group phase rides along by rebasing ``group_rep``: a shard that
     holds no members of group g folds an all-masked read (l = 0) that
@@ -1678,7 +1674,7 @@ def flash_decode_attention_shared_prefix_q8_stacked(
     int8 cache — the case that used to FALL BACK to the ungrouped
     stacked kernel. k_q/v_q: [L, B, Hkv, S, D] int8 (the whole stacked
     buffer); k_scale/v_scale: [L, B, Hkv, S]; ``layer`` a traced index
-    riding scalar prefetch into the index maps, exactly like
+    riding scalar prefetch, exactly like
     :func:`flash_decode_attention_q8_stacked`.
     """
     b, _, h, d = q.shape

@@ -78,7 +78,8 @@ def ragged_attention_error(
     :func:`~llm_consensus_tpu.ops.attention.ragged_paged_attention_reference`.
 
     ``valid_len``: tokens readable per decode row (mid-page fills are
-    the interesting ones). ``nq`` > 1: verify rows. ``cq`` > 0: one
+    the interesting ones; a row of 0 holds nothing, and owes only a
+    finite output). ``nq`` > 1: verify rows. ``cq`` > 0: one
     prefill-chunk row of cq queries from ``chunk_start``. ``group_rows``:
     these rows share their first ``shared_pages`` pages and ride the
     kernel's group phase (the reference has no groups — grouped output
@@ -171,12 +172,21 @@ def ragged_attention_error(
         ref = ragged_paged_attention_reference(
             q, kp, vp, jnp.asarray(tbl), vl, **kw
         )
+    live = np.asarray(valid_len) > 0
+
+    def decode_err(got_dec, ref_dec):
+        _finite(got_dec)  # all a dead row owes
+        return max_err(np.asarray(got_dec)[live], np.asarray(ref_dec)[live])
+
     if not cq:
-        return {"decode": max_err(got, ref)}
+        return {"decode": decode_err(got, ref)}
     if null_tables:
-        _finite(got[0])  # all a dead row owes
+        _finite(got[0])
         return {"chunk": max_err(got[1], ref[1])}
-    return {"decode": max_err(got[0], ref[0]), "chunk": max_err(got[1], ref[1])}
+    return {
+        "decode": decode_err(got[0], ref[0]),
+        "chunk": max_err(got[1], ref[1]),
+    }
 
 
 def moe_grouped_matmul_error(

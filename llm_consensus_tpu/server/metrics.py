@@ -665,6 +665,15 @@ ATTENTION_TOKENS_READ = REGISTRY.counter(
     "gateway_attention_tokens_read_total",
     "Cached tokens attention read (shared runs once a group), by kind",
 )
+#: Pool pages the ragged attention kernel of a retired program folded a
+#: layer (each row's own pages, a group's shared run once, the chunk
+#: lane's): ``attn_pages_read`` of the program's cost model. Over
+#: programs x (slots + chunk lane + groups) x ``pages_per_seq`` it is
+#: the share of a whole-table walk that was live.
+ATTENTION_PAGES_READ = REGISTRY.counter(
+    "gateway_attention_pages_read_total",
+    "Pool pages attention folded a layer (shared runs once a group), by kind",
+)
 #: Device memory as the allocator reports it, labeled
 #: ``kind="in_use"|"peak"|"limit"``: the largest value over the local
 #: devices. Filled when ``/metrics`` is rendered (a render hook the

@@ -153,6 +153,7 @@ from llm_consensus_tpu.ops.kernels import (
     single_device,
 )
 from llm_consensus_tpu.server.metrics import (
+    ATTENTION_PAGES_READ as _M_ATTN_PAGES_READ,
     ATTENTION_TOKENS_READ as _M_ATTN_TOKENS_READ,
 )
 from llm_consensus_tpu.server.metrics import (
@@ -1179,6 +1180,7 @@ class ContinuousBatcher:
                 "flops": 0,
                 "kv_read_tokens": 0,
                 "kv_write_tokens": 0,
+                "attn_pages_read": 0,
                 "seconds": 0.0,
                 "programs": 0,
             }
@@ -3117,6 +3119,7 @@ class ContinuousBatcher:
                         "flops",
                         "kv_read_tokens",
                         "kv_write_tokens",
+                        "attn_pages_read",
                         "seconds",
                         "programs",
                     )
@@ -3817,8 +3820,17 @@ class ContinuousBatcher:
         streams' draft KV to hbm_bytes/flops only (the kv_*_tokens
         fields stay target-pool so the spec-on/off write-parity
         invariant is assertable).
+
+        ``attn_pages_read`` (PR 29) is the same reads in the unit the
+        ragged kernel walks: the pages it folds a layer, summed over
+        the program's k kernel calls — each row's own pages under its
+        fill, a group's shared run once (from the same
+        ``saved_tokens_per_step``, which is whole pages), the chunk
+        lane's up to its end. Like ``kv_read_tokens`` it does not
+        deduct what a sliding window skips.
         """
-        kv_read = kv_write = tokens = 0
+        pg = self.config.page_size
+        kv_read = kv_write = tokens = pages = 0
         lengths = []
         for _, s in rows_now:
             L = s.prompt_len + len(s.generated)
@@ -3827,20 +3839,23 @@ class ContinuousBatcher:
                 kv_read += L + k
                 kv_write += k + 1
                 tokens += k + 1
+                pages += -(-(L + k) // pg)
             else:
                 kv_read += k * L + k * (k - 1) // 2
                 kv_write += k
                 tokens += k
+                pages += sum(-(-(L + j) // pg) for j in range(k))
         if self._group_decode and rows_now:
             shared_steps = 1 if kind == "spec" else k
-            kv_read -= min(
-                kv_read, self._groups.saved_tokens_per_step * shared_steps
-            )
+            saved = self._groups.saved_tokens_per_step * shared_steps
+            kv_read -= min(kv_read, saved)
+            pages -= min(pages, saved // pg)
         if chunk_ext is not None:
             read_end, width = chunk_ext
             kv_read += read_end
             kv_write += width
             tokens += width
+            pages += -(-read_end // pg)
         cost = program_hbm_cost(
             self.cfg,
             weight_bytes=self._weight_bytes,
@@ -3865,6 +3880,7 @@ class ContinuousBatcher:
             )
             cost["hbm_bytes"] += d["hbm_bytes"]
             cost["flops"] += d["flops"]
+        cost["attn_pages_read"] = pages
         return cost
 
     def _mbu_account(self, kind: str, cost: dict | None, dur: float) -> None:
@@ -3879,13 +3895,17 @@ class ContinuousBatcher:
             self.controller.note_program(kind, cost, dur)
         if cost is None:
             return
+        # A dense prefill attends in-program: it walks no pool page.
+        pages = cost.get("attn_pages_read", 0)
         _M_ATTN_TOKENS_READ.labels(kind=kind).inc(cost["kv_read_tokens"])
+        _M_ATTN_PAGES_READ.labels(kind=kind).inc(pages)
         with self._lock:
             m = self._mbu[kind]
             m["hbm_bytes"] += cost["hbm_bytes"]
             m["flops"] += cost["flops"]
             m["kv_read_tokens"] += cost["kv_read_tokens"]
             m["kv_write_tokens"] += cost["kv_write_tokens"]
+            m["attn_pages_read"] += pages
             m["seconds"] += dur
             m["programs"] += 1
         peak = self.config.hbm_gbps * 1e9

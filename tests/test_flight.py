@@ -483,6 +483,90 @@ def test_cost_model_spec_on_off_write_parity(params):
         assert t["spec_accepted_per_round"] == pytest.approx(k)
 
 
+def test_attention_pages_read_hand_count(params):
+    """``attn_pages_read`` of the cost model against a count by hand:
+    pages of 16 tokens; rows A (40 tokens), B (33) share their first two
+    pages as a group, C (16) and D (70) walk alone; a chunk lane ending
+    at token 48."""
+    from types import SimpleNamespace
+
+    b = ContinuousBatcher(
+        CFG.with_(use_pallas=True), params, config=ContinuousConfig(**_CCFG)
+    )
+    try:
+        assert b._group_decode
+        b._groups.add(0, (7, 8))
+        b._groups.add(1, (7, 8, 9))
+        b._groups.arrays()
+        rows = [
+            (i, SimpleNamespace(prompt_len=n - 2, generated=[1, 2]))
+            for i, n in enumerate((40, 33, 16, 70))
+        ]
+        decode = b._program_cost("decode", rows, 1)
+        fused = b._program_cost("fused", rows, 1, chunk_ext=(48, 16))
+        rounds = b._program_cost("decode", rows, 2)
+        prefill = b._program_cost("prefill", [], 0, chunk_ext=(48, 16))
+    finally:
+        b.close()
+    # 3 + 3 + 1 + 5 pages under the fills, the shared two read once.
+    assert decode["attn_pages_read"] == 12 - 2
+    assert decode["kv_read_tokens"] == 40 + 33 + 16 + 70 - 32
+    assert fused["attn_pages_read"] == 10 + 3
+    # Two kernel calls: fills 40/41, 33/34, 16/17 (a second page), 70/71.
+    assert rounds["attn_pages_read"] == (3 + 3) + (3 + 3) + (1 + 2) + (
+        5 + 5
+    ) - 2 * 2
+    assert prefill["attn_pages_read"] == 3
+
+
+def test_attention_read_counters_lockstep_on_a_burst(params):
+    """``gateway_attention_pages_read_total`` and
+    ``gateway_attention_tokens_read_total`` move with their ``stats()``
+    mirrors, kind by kind, over a burst with grouped rows (a shared
+    header), an ungrouped one and fused chunks; a page holds at most 16
+    tokens, so pages x 16 bounds the tokens from above."""
+    b = ContinuousBatcher(
+        CFG.with_(use_pallas=True), params, config=ContinuousConfig(**_CCFG)
+    )
+    kinds = ("fused", "decode", "prefill")
+
+    def read():
+        snap = REGISTRY.snapshot()
+        st = b.stats()
+        return {
+            (name, kind): (
+                snap.get(f'gateway_attention_{name}_total{{kind="{kind}"}}', 0),
+                st[f"mbu_{key}_{kind}"],
+            )
+            for name, key in (
+                ("pages_read", "attn_pages_read"),
+                ("tokens_read", "kv_read_tokens"),
+            )
+            for kind in kinds
+        }
+
+    try:
+        r0 = read()
+        _serve(
+            b,
+            [_HEADER + f"tail {i}" for i in range(3)] + ["alone, no header"],
+            max_new_tokens=6,
+        )
+        _quiesce(b)
+        r1 = read()
+        peak_group = b.stats()["decode_group_peak"]
+    finally:
+        b.close()
+    d = {k: (r1[k][0] - r0[k][0], r1[k][1] - r0[k][1]) for k in r1}
+    assert all(metric == mirror for metric, mirror in d.values()), d
+    assert peak_group >= 2  # the header's rows did decode as a group
+    for kind in kinds:
+        pages, tokens = d["pages_read", kind][0], d["tokens_read", kind][0]
+        assert pages * 16 >= tokens, (kind, pages, tokens)
+    assert d["pages_read", "decode"][0] > 0
+    assert d["pages_read", "fused"][0] + d["pages_read", "prefill"][0] > 0
+
+
 def test_mbu_gauge_published_with_peak_configured(params):
     b = ContinuousBatcher(
         CFG,

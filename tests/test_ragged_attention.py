@@ -153,6 +153,94 @@ def test_ragged_all_prefill_and_dead_decode_rows():
     _within_tol(errs)
 
 
+# The walk (PR 29): the kernel's steps follow the pages its rows hold,
+# not the table's width. Each case names what its rows hold.
+_WALK = {
+    "rows of 0, 1 and p_per pages": dict(
+        g=3, valid_len=[0, 5, 48], cq=16, chunk_start=32,
+    ),
+    "48 columns, 3 live": dict(
+        p_per=48, n_pages=160, g=2, valid_len=[21, 24],
+    ),
+    "48 columns, 3 live, stacked": dict(
+        p_per=48, n_pages=160, g=2, valid_len=[21, 24], layer=(1, 2),
+    ),
+    "window's low edge mid-table": dict(
+        p_per=12, n_pages=64, g=3, valid_len=[90, 61, 7], window=20,
+        cq=16, chunk_start=50,
+    ),
+    "shared_start > 0, run shorter than its members": dict(
+        g=3, valid_len=[37, 9, 45, 48], group_rows=(0, 2, 3),
+        shared_pages=2,
+    ),
+    "shared run under a window that has passed it": dict(
+        g=3, valid_len=[37, 9, 45], group_rows=(0, 2), shared_pages=2,
+        window=9,
+    ),
+    "verify row, nq 4": dict(
+        g=3, valid_len=[13, 4, 40, 48], nq=4, cq=16, chunk_start=11,
+    ),
+    "verify rows grouped, nq 4": dict(
+        g=3, valid_len=[29, 4, 40], nq=4, group_rows=(0, 2),
+        shared_pages=3,
+    ),
+    "latent pool, rows of 0, 2 and many pages": dict(
+        hkv=1, g=4, d=128, latent_dv=64, valid_len=[0, 13, 45],
+        group_rows=(1, 2), shared_pages=1, cq=16, chunk_start=16,
+    ),
+}
+
+
+@pytest.mark.parametrize("case", list(_WALK))
+def test_ragged_walk_matches_reference(case):
+    _within_tol(
+        parity.ragged_attention_error(**{**_TOY, "seed": 6, **_WALK[case]})
+    )
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every ``pallas_call`` in a jaxpr, nested ones too."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
+@pytest.mark.parametrize("p_per", [8, 48])
+def test_ragged_grid_is_one_step_a_row_whatever_the_table_width(p_per):
+    """b decode rows + the chunk lane + gm group programs, and no page
+    axis: the pages are walked inside a step, by the lengths."""
+    from llm_consensus_tpu.ops.pallas.attention import ragged_paged_attention
+
+    b, gm, hkv, g, d, pg = 3, 2, 2, 3, 32, 8
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    pool = jnp.zeros((2, 64, pg, hkv, d), jnp.bfloat16)
+    q = jnp.zeros((b, hkv * g, d), jnp.bfloat16)
+
+    def call(chunk, groups):
+        kw = {}
+        if chunk:
+            kw.update(
+                q_chunk=jnp.zeros((16, hkv * g, d), jnp.bfloat16),
+                chunk_table=i32(p_per), chunk_start=jnp.int32(0),
+            )
+        if groups:
+            kw["groups"] = (i32(b), i32(gm), i32(gm), i32(b))
+        return jax.make_jaxpr(
+            lambda layer: ragged_paged_attention(
+                q, pool, pool, i32(b, p_per), i32(b), layer=layer,
+                interpret=True, **kw,
+            )
+        )(jnp.int32(1))
+
+    assert _pallas_grids(call(False, False).jaxpr) == [(b,)]
+    assert _pallas_grids(call(True, False).jaxpr) == [(b + 1,)]
+    assert _pallas_grids(call(True, True).jaxpr) == [(b + 1 + gm,)]
+
+
 def test_ragged_stacked_q8_shared_prefix_matches_reference():
     """The stacked int8 cache case that used to FALL BACK to the
     ungrouped stacked kernel: shared-prefix attention through the
@@ -243,6 +331,31 @@ def _burst_texts(params, ragged, depth=2, chunk=16, cfg=CFG, cfgkw=None,
         return [r.text for r in _serve(b, prompts, **submit_kw)], b.stats()
     finally:
         b.close()
+
+
+@pytest.mark.parametrize("ragged", [True, False], ids=["fused", "split"])
+def test_idle_slots_hold_nothing_while_others_decode(params, ragged):
+    """A slot without a request keeps length 0 through every step
+    program that runs beside it (decode and fused alike), so the
+    attention kernel has no page to walk for it: a length that grew
+    with each step had an idle row fold the NULL page up to a whole
+    table's width, every layer of every step."""
+    b = ContinuousBatcher(
+        CFG, params,
+        config=ContinuousConfig(**_CCFG, ragged_attention=ragged),
+    )
+    try:
+        outs = _serve(
+            b, [_HEADER + "one", "a second, unshared prompt"],
+            max_new_tokens=8,
+        )
+        _quiesce(b)
+        lengths = np.asarray(b.cache.length)
+    finally:
+        b.close()
+    assert all(o.num_tokens == 8 for o in outs)
+    # Two of four slots served; retirement released them too.
+    assert lengths.tolist() == [0, 0, 0, 0]
 
 
 def test_fused_text_parity_across_depths_and_chunks(params):

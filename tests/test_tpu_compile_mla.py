@@ -1,7 +1,8 @@
-"""The two kernels DeepSeek-V2-Lite adds to the serving path, compiled
-for a TPU v5e at the cell's real widths — by the chip's own compiler,
-for a chip that is described and not attached (no chip time, ~2 s a
-case). Interpret mode cannot show what Mosaic refuses (tiling, VMEM) or
+"""The serving path's kernels, compiled for a TPU v5e at real widths:
+the ragged attention walk at the three configurations' sizes and over
+the engine's int8 caches, and the grouped expert matmul
+DeepSeek-V2-Lite adds — by the chip's own compiler, for a chip that is
+described and not attached (no chip time, ~2 s a case). Interpret mode cannot show what Mosaic refuses (tiling, VMEM) or
 what XLA copies around a kernel; a compile that passes is still not a
 chip run.
 
@@ -19,15 +20,19 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -41,26 +46,119 @@ L, PAGES, PG, LANES, DV, H = 19, 1024, 64, 640, 512, 16
 B, P, C, G = 16, 48, 64, 4
 
 
-def test_latent_ragged_attention_compiles_without_copying_the_pool(one_chip):
+# The three configurations' real widths: (layers, pool pages, Hkv, query
+# heads a kv head, key lanes, latent value lanes, slots, group programs).
+# A table of 48 columns, a 64-query chunk lane.
+_RAGGED_WIDTHS = {
+    "mistral-7b": (32, 512, 8, 4, 128, 0, 8, 4),
+    "qwen2-7b": (28, 1024, 4, 7, 128, 0, 16, 8),
+    "deepseek-v2-lite": (L, PAGES, 1, H, LANES, DV, B, 8),
+}
+
+
+@pytest.mark.parametrize("model", list(_RAGGED_WIDTHS))
+def test_ragged_attention_walk_compiles_without_copying_the_pool(
+    one_chip, model
+):
+    """The kernel that walks each row's live pages by its own DMAs, with
+    every lane it serves (decode rows, the chunk lane, group programs)
+    over the stacked pool in HBM."""
     from llm_consensus_tpu.ops.pallas.attention import ragged_paged_attention
 
-    def call(q, pool, tbl, valid, qc, ct, cs, gid, rep, gend, ss, layer):
+    layers, pages, hkv, g, d, dv, b, gm = _RAGGED_WIDTHS[model]
+    h = hkv * g
+    latent = dict(scale=0.1147, latent_dv=dv) if dv else {}
+
+    def call(q, k, v, tbl, valid, qc, ct, cs, gid, rep, gend, ss, layer):
         return ragged_paged_attention(
-            q, pool, None, tbl, valid, q_chunk=qc, chunk_table=ct,
+            q, k, v, tbl, valid, q_chunk=qc, chunk_table=ct,
             chunk_start=cs, groups=(gid, rep, gend, ss), layer=layer,
-            scale=0.1147, latent_dv=DV, interpret=False,
+            interpret=False, **latent,
         )
 
     i32 = lambda *s: _shape(one_chip, s, jnp.int32)  # noqa: E731
+    page = (PG, d) if dv else (PG, hkv, d)
+    pool = _shape(one_chip, (layers, pages, *page), jnp.bfloat16)
     compiled = jax.jit(call).lower(
-        _shape(one_chip, (B, H, LANES), jnp.bfloat16),
-        _shape(one_chip, (L, PAGES, PG, LANES), jnp.bfloat16),
-        i32(B, P), i32(B), _shape(one_chip, (C, H, LANES), jnp.bfloat16),
-        i32(P), i32(), i32(B), i32(G), i32(G), i32(B), i32(),
+        _shape(one_chip, (b, h, d), jnp.bfloat16),
+        pool, None if dv else pool,
+        i32(b, P), i32(b), _shape(one_chip, (C, h, d), jnp.bfloat16),
+        i32(P), i32(), i32(b), i32(gm), i32(gm), i32(b), i32(),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    # The pool is 1.6 GB: any temporary near that is a copy of it (what
-    # a 576-lane pool costs: ModelConfig.latent_pool_dim).
+    # The pools are 1.6-4.3 GB: any temporary near that is a copy of one
+    # (what a 576-lane pool costs: ModelConfig.latent_pool_dim).
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_ragged_attention_walk_compiles_under_shard_map(topo):
+    """The mesh kernel (``--mesh data=2,model=2``, no cell): mistral's
+    pool over four described chips, kv heads over ``model``, rows and
+    pages over ``data``, the chunk lane and the groups riding along."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as Ps
+
+    from llm_consensus_tpu.ops.pallas.attention import (
+        ragged_paged_attention_sharded,
+    )
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    _, pages, hkv, g, d, _, b, gm = _RAGGED_WIDTHS["mistral-7b"]
+    h = hkv * g
+
+    def on(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, Ps(*spec))
+        )
+
+    def call(q, k, v, tbl, valid, qc, ct, cs, gid, rep, gend, ss):
+        return ragged_paged_attention_sharded(
+            mesh, q, k, v, tbl, valid, q_chunk=qc, chunk_table=ct,
+            chunk_start=cs, groups=(gid, rep, gend, ss), window=4096,
+            interpret=False,
+        )
+
+    pool = on((pages, PG, hkv, d), jnp.bfloat16, "data", None, "model", None)
+    compiled = jax.jit(call).lower(
+        on((b, h, d), jnp.bfloat16, "data", "model", None), pool, pool,
+        on((b, P), jnp.int32, "data", None), on((b,), jnp.int32, "data"),
+        on((C, h, d), jnp.bfloat16, None, "model", None),
+        on((P,), jnp.int32, None), on((), jnp.int32),
+        on((b,), jnp.int32, "data"), on((gm,), jnp.int32, None),
+        on((gm,), jnp.int32, None), on((b,), jnp.int32, "data"),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["cache", "stacked"])
+def test_int8_cache_shared_prefix_walk_compiles(one_chip, stacked):
+    """The engine's int8 head-major caches take the same walk over their
+    virtual pages: a page is a strided copy of [Hkv, 128, D] int8 and its
+    [Hkv, 128] scales, at a slot offset that is data."""
+    from llm_consensus_tpu.ops.pallas.attention import (
+        flash_decode_attention_shared_prefix_q8,
+        flash_decode_attention_shared_prefix_q8_stacked,
+    )
+
+    b, hkv, g, s_len, d = 4, 8, 4, 1024, 128
+    lead = (32,) if stacked else ()
+    kv = _shape(one_chip, (*lead, b, hkv, s_len, d), jnp.int8)
+    sc = _shape(one_chip, (*lead, b, hkv, s_len), jnp.float32)
+    i32 = lambda *s: _shape(one_chip, s, jnp.int32)  # noqa: E731
+    fn = (
+        flash_decode_attention_shared_prefix_q8_stacked
+        if stacked
+        else flash_decode_attention_shared_prefix_q8
+    )
+    compiled = jax.jit(
+        lambda *a: fn(*a, window=512, interpret=False)
+    ).lower(
+        _shape(one_chip, (b, 1, hkv * g, d), jnp.bfloat16),
+        kv, sc, kv, sc, i32(b), *([i32()] * (2 if stacked else 1)),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
