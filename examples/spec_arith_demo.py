@@ -1,8 +1,8 @@
 """REAL speculative-decoding acceptance: trained target + trained draft.
 
-``bench.py --draft`` brackets speculation with random weights: ``self``
-gives the acceptance~1 overhead ceiling, a random draft the ~0 floor.
-This script measures the honest middle — a 14M target and a ~2.5M draft
+Random weights only bracket speculation: a self-draft gives the
+acceptance~1 overhead ceiling, a random draft the ~0 floor. This
+script measures the honest middle — a 14M target and a ~2.5M draft
 BOTH trained on the arithmetic SFT corpus (``examples/train_arith_em.py``
 recipe), decoding real eval prompts greedily:
 

@@ -87,7 +87,7 @@ def _printable(text: str) -> str:
 def require_tpu(cpu_pinned: bool) -> None:
     """Device programs run on a TPU or, pinned there on purpose
     (``--cpu``: tests, debugging), on the CPU — never on whatever JAX
-    fell back to. Shared by the CLI's device backends and ``bench.py``."""
+    fell back to."""
     import jax
 
     platform = jax.default_backend()
@@ -438,6 +438,17 @@ def _build_modelset_backend(args):
     return ModelSetBackend(ModelSet(specs, default=args.model_default))
 
 
+def _prefill_chunk_width(text: str) -> int:
+    """``--prefill-chunk``'s value: a width of at least one token."""
+    width = int(text)
+    if width < 1:
+        raise argparse.ArgumentTypeError(
+            f"must be >= 1 (got {width}): chunked prefill is the only "
+            "prefill path"
+        )
+    return width
+
+
 def _add_backend_args(p: argparse.ArgumentParser) -> None:
     """Backend-construction flags — the ONE definition of everything
     `_build_backend` reads, shared by the main parser and `serve` so the
@@ -490,10 +501,10 @@ def _add_backend_args(p: argparse.ArgumentParser) -> None:
     )
     p.add_argument(
         "--prefill-chunk",
-        type=int,
+        type=_prefill_chunk_width,
         default=64,
-        help="continuous backend: prefill-chunk tokens interleaved "
-        "between decode steps (0 = legacy blocking prefill)",
+        help="continuous backend: prefill-chunk tokens (>= 1) "
+        "interleaved between decode steps",
     )
     p.add_argument(
         "--no-share-prefix",
@@ -884,8 +895,7 @@ def build_serve_parser() -> argparse.ArgumentParser:
         action="store_true",
         help="disable request-scoped tracing (trace ids, /debug/traces "
         "span trees, and the span-derived histograms' trace side; "
-        "default ON — bench.py --serve-trace-overhead measures the "
-        "cost at < 2%%)",
+        "default ON)",
     )
     p.add_argument(
         "--trace-max-traces",
@@ -906,9 +916,7 @@ def build_serve_parser() -> argparse.ArgumentParser:
         action="store_true",
         help="disable the serving flight recorder (typed scheduler "
         "events at GET /debug/flight incl. the Perfetto-loadable "
-        "?format=chrome export; default ON — bench.py "
-        "--serve-flight-overhead holds the cost under the PR-5 2%% "
-        "tok/s gate)",
+        "?format=chrome export; default ON)",
     )
     p.add_argument(
         "--flight-events",
@@ -952,8 +960,7 @@ def build_serve_parser() -> argparse.ArgumentParser:
         "X-Trace-Id propagation/adoption across peer forwards, the "
         "per-hop meta['hops'] breakdown on /v1/* responses, and the "
         "/metrics?fleet=1 + /debug/flight?fleet=1 merged views "
-        "(default ON — bench.py --serve-fleet-obs holds the cost "
-        "under the PR-5 2%% tok/s gate)",
+        "(default ON)",
     )
     # Fleet control plane (PR 19).
     p.add_argument(
@@ -1005,7 +1012,7 @@ def build_serve_parser() -> argparse.ArgumentParser:
 
 def _parse_fleet_control(args):
     """``serve --fleet-control`` flags -> :class:`FleetControlConfig`
-    (None when the flag is off). Shared by serve and bench."""
+    (None when the flag is off)."""
     if not getattr(args, "fleet_control", False):
         return None
     from llm_consensus_tpu.serving.fleet_control import FleetControlConfig

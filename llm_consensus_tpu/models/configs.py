@@ -316,9 +316,9 @@ PRESETS: dict[str, ModelConfig] = {
         moe_capacity_factor=1.25,
         max_seq_len=8192,
     ),
-    # ~100M draft model sharing llama-1b's vocab — the speculative-
-    # decoding draft for `bench.py --draft llama-draft-100m` (same
-    # tokenizer/vocab is the only hard requirement for speculation).
+    # ~100M draft model sharing llama-1b's vocab: a speculative-
+    # decoding draft for it (same tokenizer/vocab is the only hard
+    # requirement for speculation).
     "llama-draft-100m": ModelConfig(
         name="llama-draft-100m",
         vocab_size=32000,
@@ -410,8 +410,8 @@ PRESETS: dict[str, ModelConfig] = {
     ),
     # ~2.5M draft for arith-14m: trained on the same corpus it gives a
     # REAL speculative-decoding acceptance rate (examples/
-    # spec_arith_demo.py) — between bench.py's --draft self ceiling and
-    # random-weight floor.
+    # spec_arith_demo.py), between a self-draft's ceiling and a
+    # random-weight draft's floor.
     "arith-3m": ModelConfig(
         name="arith-3m",
         vocab_size=384,
@@ -436,8 +436,7 @@ PRESETS: dict[str, ModelConfig] = {
     ),
     # Draft-sized sibling of test-tiny (same vocab — the one hard
     # requirement for speculation): the continuous batcher's
-    # draft/verify tests and the CPU smoke of `bench.py
-    # --serve-speculative` run this as the cheap proposal model.
+    # draft/verify tests run this as the cheap proposal model.
     "test-tiny-draft": ModelConfig(
         name="test-tiny-draft",
         vocab_size=384,

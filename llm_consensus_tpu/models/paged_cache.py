@@ -546,7 +546,7 @@ class PrefixRegistry:
         """Count a match the caller actually ADMITTED on. Kept separate
         from :meth:`match` so a plan that rolls back (pool too full,
         table overflow) never inflates hits/pages_shared — the numbers
-        stats()/bench report must agree with the Prometheus counters,
+        stats() reports must agree with the Prometheus counters,
         which also count only committed admissions."""
         if match.pages or match.boundary_common:
             self.hits += 1

@@ -498,8 +498,8 @@ def init_params_quantized(
     (~22 GB for a 7B preset at int8) and cannot start on a 16 GB chip.
     Here every quantized leaf is generated layer by layer on the
     default device (:func:`_init_quantized_leaf`), so the peak is the
-    quantized tree plus one layer's float slice. The one random-weight
-    start-up path of the CLI and ``bench.py`` alike.
+    quantized tree plus one layer's float slice. The CLI's one
+    random-weight start-up path.
     """
     return init_params(cfg, key, dtype, quant_bits=bits)
 

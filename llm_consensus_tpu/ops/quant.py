@@ -159,7 +159,7 @@ def maybe_dequantize(leaf, dtype=jnp.bfloat16):
 
 
 # Kernel override: None = observe (kernel on a TPU for weights GSPMD does
-# not partition); True/False forces. For tests and bench.py --no-pallas.
+# not partition); True/False forces. For tests.
 _FORCE_KERNEL: bool | None = None
 
 

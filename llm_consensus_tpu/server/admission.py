@@ -146,8 +146,7 @@ class AdmissionConfig:
     tenant_weights: dict[str, float] | None = None
     # Share-cap slack: a tenant is shed at the door only once its
     # decayed admitted-cost share exceeds fair_weight * slack while
-    # another tenant has queued work (1.1 = the ±10% band the fleet
-    # bench gates on).
+    # another tenant has queued work (1.1 = a ±10% band).
     fair_share_slack: float = 1.1
     # Half-life in seconds of the decayed per-tenant admitted-cost
     # window the share cap is computed over.

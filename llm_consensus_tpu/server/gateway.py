@@ -248,7 +248,7 @@ class GatewayConfig:
         # trace instead of rooting a fresh one), attach the per-hop
         # breakdown to response ``meta["hops"]``, and serve the
         # ``/metrics?fleet=1`` / ``/debug/flight?fleet=1`` federation
-        # views. The bench's ``--serve-fleet-obs`` A/B lever.
+        # views (``serve --no-fleet-obs`` turns it off).
         fleet_obs: bool = True,
     ):
         self.host = host
@@ -1263,8 +1263,8 @@ class Gateway:
 
         A forwarding front prepends ``front_route`` at relay time
         (:meth:`_inject_front_hop`). For a single-generation request
-        the hop sum tracks the client-observed latency (the e2e
-        tolerance the fleet-obs bench gates); a consensus fan-out's
+        the hop sum tracks the client-observed latency
+        (tests/test_fleet_observability.py); a consensus fan-out's
         spans overlap, so there the breakdown is attribution, not a
         wall-clock identity. Each hop lands in the
         ``gateway_hop_seconds{hop=}`` histogram."""

@@ -701,8 +701,8 @@ RAGGED_ROWS = REGISTRY.histogram(
 #: decode-advancing ``gateway_device_programs_total`` is the realized
 #: rounds per program (→ R when multi-round engages), and device
 #: programs per generated token drops ~R× at R for a fixed batch
-#: shape (its absolute value carries the 1/batch-rows factor) — the
-#: cross-check the bench A/B leg gates. Histogram: the per-program
+#: shape (its absolute value carries the 1/batch-rows factor;
+#: tests/test_decode_rounds.py). Histogram: the per-program
 #: round count at dispatch (R, or 1 when a row's stop sequences have
 #: no bounded device screen and the window collapses to the
 #: host-checked cadence).
@@ -761,7 +761,7 @@ SPEC_XMODEL_COVERAGE = REGISTRY.gauge(
 #: fronting N independent engines. Labeled ``model=<member name>`` —
 #: the shared metrics plane's per-model split (requests dispatched to
 #: each member and the tokens it generated), mirrored into
-#: ``ModelSet.stats()`` for the bench.
+#: ``ModelSet.stats()``.
 MODEL_REQUESTS = REGISTRY.counter(
     "gateway_model_requests_total",
     "Requests dispatched to each ModelSet member (label: model)",
@@ -803,7 +803,7 @@ FLIGHT_DROPPED = REGISTRY.counter(
 )
 #: Time between consecutive generated tokens as the HOST observes them
 #: (one observation per generated token past a request's first; tokens
-#: that land in the same program fetch — steps_per_sync > 1 chunks,
+#: that land in the same program fetch — a multi-round window,
 #: accepted speculative runs — observe 0 for all but the first, which
 #: is exactly the bursty arrival a streaming client sees). The
 #: per-request p50/p99 summary rides ``/debug/requests`` and the
@@ -864,8 +864,8 @@ MESH_SHARDS = REGISTRY.gauge(
 #: ``"load"`` — no affinity anywhere, least modeled-cost replica won;
 #: ``"rebalance"`` — the affinity owner was congested, the chain was
 #: exported through the shared store and the request re-homed;
-#: ``"random"`` — the bench's control policy). affinity/total is the
-#: routed prefix-affinity rate the --serve-replicas bench leg gates.
+#: ``"random"`` — the round-robin control policy). affinity/total is
+#: the routed prefix-affinity rate.
 REPLICA_ROUTED = REGISTRY.counter(
     "gateway_replica_routed_total",
     "Requests routed to each fleet replica, by routing reason",

@@ -16,8 +16,6 @@ style, re-founded on XLA's compile-once constraint:
   so running slots keep decoding while new prompts fill. A mid-prefill
   sequence's device table row stays NULL (the decode program never sees
   it); the chunk program writes through an explicit host-side table.
-  ``prefill_chunk=0`` restores the legacy blocking per-admission dense
-  prefill (the parity baseline).
 - **Copy-on-write shared prefixes**: admission hashes the prompt's
   page-aligned prefix into a per-shard
   :class:`~llm_consensus_tpu.models.paged_cache.PrefixRegistry`; full
@@ -54,10 +52,9 @@ style, re-founded on XLA's compile-once constraint:
   admission, host-tier restores) happens while the device is already
   running the next program. Retirement lags by the in-flight depth
   (overshoot tokens are discarded on fetch and pre-budgeted into page
-  reservations); restores, CoW boundary copies, and dense prefill
-  drain the pipeline first (``gateway_pipeline_flushes_total``).
-  Depth 1 is the serialized parity baseline; outputs are
-  byte-identical at every depth (tested).
+  reservations); restores and CoW boundary copies drain the pipeline
+  first (``gateway_pipeline_flushes_total``). Depth 1 is the
+  serialized loop; outputs are byte-identical at every depth (tested).
 
 - **Mesh-native hot path** (PR 13): pass ``mesh=`` and the WHOLE stack
   shards — pool pages and slot blocks over ``data`` (one host
@@ -114,7 +111,6 @@ from llm_consensus_tpu.utils.stops import (
     earliest_stop_cut,
     stop_tail_window,
 )
-from llm_consensus_tpu.models.cache import KVCache
 from llm_consensus_tpu.models.configs import ModelConfig
 from llm_consensus_tpu.models.paged_cache import (
     NULL_PAGE,
@@ -122,13 +118,11 @@ from llm_consensus_tpu.models.paged_cache import (
     PagedKVCache,
     PagePool,
     PrefixRegistry,
-    assign_pages,
     copy_page,
     install_page,
     install_pages,
     install_seq,
     release_seq,
-    write_prefill_kv,
 )
 from llm_consensus_tpu.engine.accept import verify_tokens
 from llm_consensus_tpu.serving import flight as _flight
@@ -138,7 +132,6 @@ from llm_consensus_tpu.models.transformer import (
     fused_step_paged,
     kv_plane_token_bytes,
     model_param_bytes,
-    prefill,
     prefill_chunk_paged,
     program_hbm_cost,
     unembed_one,
@@ -412,154 +405,86 @@ class ContinuousConfig:
     # Over-long prompts: left-truncate to the largest bucket (keeping the
     # question tail) with a warning, or reject when False.
     truncate_prompts: bool = True
-    # Decode steps per device program (one host dispatch+fetch per
-    # chunk). The host-driven loop pays a host<->device round trip per
-    # sync; `bench.py --serve-chunk 16` opts in.
-    # Retirement/admission happen at chunk boundaries, so a finished
-    # row overshoots up to chunk-1 tokens (discarded on host; page
-    # reservations carry the slack — raising this on a config whose
-    # pages_per_seq was sized exactly may need one more page per
-    # sequence) and a waiting request can be admitted up to chunk-1
-    # steps late. Pure throughput/latency knob: outputs are
-    # chunk-size-invariant (per-token PRNG streams are (seed, index) —
-    # tested). Default 1 = per-token retirement/admission.
-    steps_per_sync: int = 1
-    # Prefill-chunk width (tokens). > 0: prompts prefill in chunks of
-    # min(prefill_chunk, prompt's seq bucket) scheduled BETWEEN decode
-    # steps — decode stalls per admission are bounded by one chunk's
-    # compute instead of the whole prompt. 0: legacy blocking dense
-    # prefill at admission (parity baseline; disables prefix sharing).
+    # Prefill-chunk width in tokens, >= 1. A prompt prefills in chunks
+    # of min(prefill_chunk, its seq bucket), one chunk a loop iteration,
+    # riding the decode dispatch when rows are decoding: a decoding row
+    # waits for one chunk's compute, never for a whole prompt. Chunked
+    # prefill is the only prefill path.
     prefill_chunk: int = 64
     # Map page-aligned shared prompt prefixes out of the PrefixRegistry
-    # instead of re-prefilling them. Requires prefill_chunk > 0 (the
-    # chunk program is what can START a prefill mid-prompt).
+    # instead of re-prefilling them, and read a shared run once a group
+    # through the grouped attention kernel where that kernel runs.
+    # Stays an option: a deployment may refuse to share pages across
+    # requests; False is also the ungrouped reference tests compare
+    # against.
     share_prefix: bool = True
-    # Group-aware decode attention (PR 3): sequences whose tables share
-    # a prefix page run read it ONCE per step through the grouped
-    # Pallas kernel instead of once per member. Engages only when
-    # share_prefix is on, the model runs the Pallas paged kernel
-    # (cfg.use_pallas, single device, no sliding window), and a >= 2
-    # member group exists this step — otherwise the plain row kernel
-    # runs, outputs identical. Off = always the plain kernel (the
-    # bench's A/B baseline).
-    prefix_attention: bool = True
-    # Host-RAM offload tier under the prefix registry (PR 4): byte
-    # budget for demoted KV pages. > 0: registry eviction DEMOTES
-    # ready prefix pages to host buffers instead of dropping them, and
-    # admission falls through registry-miss -> host-hit, restoring
-    # pages via device_put interleaved with decode steps. 0 (default):
-    # eviction destroys, exactly the PR 2/3 behavior. Requires
-    # share_prefix + prefill_chunk > 0 (the restore path re-registers
-    # pages under the registry's readiness gates).
+    # Byte budget of the host-RAM tier under the prefix registry. > 0:
+    # registry eviction demotes ready prefix pages to host buffers and
+    # admission restores them between decode steps instead of
+    # re-prefilling. 0: eviction destroys. Requires share_prefix (a
+    # restore re-registers its pages under the registry's readiness
+    # gates).
     host_cache_bytes: int = 0
-    # Decode programs in flight at once (PR 6): the host loop enqueues
-    # program n+1 BEFORE fetching program n's tokens, feeding the next
-    # dispatch from the device-resident token output of the previous
-    # one (the cache already flows through donate_argnums), so the one
-    # true host sync of the loop lands while the next program is
-    # already running — stop scans, retirement, group bookkeeping,
-    # chunked-prefill admission, and host-tier restores all happen in
-    # that overlap window. Purely a host-loop restructuring: it
-    # engages on every backend, meshes included. Retirement lags
-    # dispatch by the in-flight depth (a finished row keeps decoding
-    # through the already-enqueued programs; the extra tokens are
-    # discarded on fetch and pre-budgeted into the page reservation —
-    # up to pipeline_depth * steps_per_sync - 1 overshoot tokens per
-    # sequence). Operations that want a stable cache + settled
-    # bookkeeping (host-tier restores, CoW boundary copies, dense
-    # prefill) DRAIN the pipeline first, counted in
-    # gateway_pipeline_flushes_total. 1 = the serialized
-    # dispatch->sync->bookkeep loop (the parity baseline); outputs are
-    # byte-identical at every depth (tested).
+    # Decode programs in flight at once: program n+1 is enqueued before
+    # program n's tokens are fetched, so stop scans, retirement,
+    # admission and restores run while the device works. A finished row
+    # overshoots by up to pipeline_depth * (tokens a program advances)
+    # - 1 tokens, discarded on fetch and budgeted into its pages.
+    # Restores and CoW boundary copies drain the pipeline first
+    # (gateway_pipeline_flushes_total). Text is byte-identical at every
+    # depth. Stays an option: an adaptive controller steers the live
+    # depth inside [1, pipeline_depth], so depth 1 is a path that runs.
     pipeline_depth: int = 2
-    # Fused scheduler step (PR 8): when a prefill chunk is ready AND
-    # rows are decoding, dispatch ONE device program carrying both —
-    # the chunk rides the decode dispatch as one more row of the
-    # ragged attention kernel, its QKV/MLP matmuls batch with the
-    # decode rows', and its host bookkeeping (readiness flips,
-    # activation, first-token sampling) moves into the pipeline's
-    # fetch path, so chunked prefill stops serializing against decode
-    # and stops forcing a per-chunk device sync. Engages with
-    # prefill_chunk > 0 on BOTH kernel paths (the non-Pallas side runs
-    # the same ragged semantics via the XLA reference) and on every
-    # topology — meshes included since PR 13. False = the
-    # PR 6/7 behavior: one standalone chunk program between decode
-    # steps (the bench's A/B baseline; outputs byte-identical either
-    # way). Read per loop iteration — flipping it between bursts needs
-    # no new batcher.
+    # A ready prefill chunk rides the decode dispatch as one more row
+    # of the ragged attention kernel (one device program an iteration)
+    # instead of running as a program of its own between decode steps.
+    # Read per loop iteration; text is identical either way. Stays
+    # until the mesh parity grids that use False as their second axis
+    # get another (ROADMAP D2).
     ragged_attention: bool = True
-    # Speculative decoding inside the batcher (PR 9): draft tokens
-    # proposed per scheduler round. With spec_k > 0 AND a draft model
-    # passed to the batcher (``ContinuousBatcher(draft=(cfg, params))``,
-    # ``serve --draft-model/--spec-k``), each round dispatches ONE
-    # device program that (a) runs spec_k + 1 greedy draft steps on the
-    # draft's mirror of the page pool — one shared draft stream per
-    # shared-prefix group: a panel mate whose committed text still
-    # agrees with its group donor's reuses the donor's committed
-    # suffix + fresh drafts instead of drafting itself — (b) verifies
-    # all rows' drafts through the target's k+1-token ragged verify
-    # rows (shared embed/QKV/WO/MLP GEMMs over the widened token axis,
-    # speculative K/V scattered into the pool), and (c) applies the
-    # leviathan accept/rollback rule ON DEVICE, emitting the accepted
-    # prefix + correction/bonus token per row. Rollback is pure count
-    # bookkeeping — ``length`` rewinds; rejected K/V sits past every
-    # later read in private pages and is overwritten, exactly like
-    # mid-chunk retirement overshoot. Greedy output is byte-identical
-    # to spec-off for ANY draft; sampled rows use the exact one-hot
-    # residual correction (engine/accept.py). spec_k feeds the
-    # page-overshoot budget of every admission, so it must not be
-    # flipped live — ``spec_decode`` below is the A/B lever. Engages
-    # with steps_per_sync == 1 (the verify round IS the multi-token
-    # step), meshes included since PR 13: the draft pool shards with
-    # the target's (pages over data, heads over model where they
-    # divide) and the draft/verify/accept program runs under GSPMD
-    # like the plain step.
+    # Draft tokens proposed per speculative round. With spec_k > 0 AND
+    # a draft model (``ContinuousBatcher(draft=(cfg, params))``), a
+    # round is one device program: spec_k + 1 greedy draft steps on the
+    # draft's mirror of the page pool (one shared stream per
+    # shared-prefix group), the target's verify rows over all drafts,
+    # and the accept / rollback rule on device. Greedy text is
+    # byte-identical to spec-off for any draft; sampled rows use the
+    # exact residual rule (engine/accept.py). Sizes every admission's
+    # page-overshoot budget, so it must not change on a live batcher.
     spec_k: int = 0
-    # Live on/off lever for speculation, read per loop iteration (the
-    # bench flips THIS between bursts on one batcher; a flip drains the
-    # dispatch pipeline so plain and spec programs never share a
-    # window). No effect without spec_k > 0 + a draft model.
+    # Whether a configured draft speculates, read per loop iteration: a
+    # flip drains the dispatch pipeline and rows that decoded meanwhile
+    # replay through the draft (``_spec_catch_up``). The adaptive
+    # controller's spec gate takes the same path. No effect without
+    # spec_k > 0 and a draft model.
     spec_decode: bool = True
-    # Multi-round on-device decode (PR 12, ``serve --decode-rounds``):
-    # decode rounds per dispatched device program. R > 1 folds up to R
-    # decode rounds into ONE program (lax.scan over the shared decode
-    # body) with stop checking, sampling, and per-row emit-count /
-    # cache-length bookkeeping fully on device: a row that samples EOS,
-    # a screened stop-candidate token, or its max-tokens budget inside
-    # the window FREEZES (K/V writes redirected to the NULL page, PRNG
-    # folds stop, length stops advancing) while its neighbors keep
-    # decoding — the host fetches once per R rounds and retires /
-    # regroups from the lagged mirror, exactly the PR-9 spec-verify
-    # pattern. Text is byte-identical to R = 1: EOS and max-tokens are
-    # exact on device; stop SEQUENCES freeze conservatively via the
-    # derived byte screen (utils.stops.derived_stop_screen) and the
-    # host's byte-level check at fetch stays authoritative (a false
-    # positive resumes next window; a miss is trimmed on fetch) — and
-    # a request whose stops admit no bounded screen collapses the
-    # window to 1 round while it decodes. Engages with
-    # steps_per_sync == 1, meshes included since PR 13 (the legacy
-    # multi-step chunk has no masking);
-    # while speculation is engaged the
-    # verify round IS the multi-token step, so spec windows keep one
-    # verify round per dispatch and multi-round applies to the plain
-    # windows — the two compose by decoupling fetch cadence from the
-    # verify round, and flips drain the pipeline like every mode
-    # change. Sizes the page-overshoot budget of every admission like
-    # spec_k does, so treat live flips as between-bursts events (the
-    # bench's A/B lever). 1 (default) = today's one-round dispatch.
+    # Decode rounds per dispatched program — the one way to put several
+    # decode steps in a program. R > 1 scans the masked decode body R
+    # times with sampling, stop checks and emit counts on device: a row
+    # that samples EOS, a screened stop candidate or its last budgeted
+    # token FREEZES (K/V writes go to the NULL page, its PRNG stops
+    # folding, its length stops advancing) while its neighbours go on,
+    # and the host fetches once a window. Text is byte-identical to
+    # R = 1: EOS and budgets are exact on device, stop strings freeze
+    # through a derived byte screen and the host's check at the fetch
+    # stays authoritative; a request whose stops admit no bounded
+    # screen holds its window to one round. While speculation is
+    # engaged the verify round is the multi-token step and R applies to
+    # the plain windows. Sizes the page-overshoot budget like spec_k.
     decode_rounds: int = 1
-    # Roofline attribution (PR 10): the device's peak HBM bandwidth in
-    # GB/s (1e9 bytes/s — e.g. ~819 for a v5e, ~1640 for a v5p core).
-    # > 0: every fetched device program sets
-    # gateway_program_mbu{kind} = modeled HBM bytes (weights + KV pages
-    # actually touched, per models.transformer.program_hbm_cost) /
-    # measured wall time / peak — ~1.0 means that program kind is at
-    # the weights+KV roofline, and the gap IS the remaining tok/s.
-    # 0 (default): no gauge; the modeled-bytes and measured-seconds
-    # sums still accumulate per kind in stats() (mbu_* keys) so the
-    # ratio can be derived offline against any peak. CPU values are a
-    # plumbing smoke only — MBU is meaningful on the chip.
+    # The device's peak HBM bandwidth in GB/s (~819 for a v5e). > 0:
+    # every fetched program sets gateway_program_mbu{kind} = modelled
+    # HBM bytes (models.transformer.program_hbm_cost) / measured wall
+    # time / peak. 0: no gauge; the modelled bytes and measured seconds
+    # still accumulate per kind in stats() (mbu_* keys).
     hbm_gbps: float = 0.0
+
+    def __post_init__(self):
+        if self.prefill_chunk < 1:
+            raise ValueError(
+                f"prefill_chunk must be >= 1 (got {self.prefill_chunk}): "
+                "chunked prefill is the only prefill path"
+            )
 
 
 class BatcherFailed(_backend_base.BackendError):
@@ -733,7 +658,7 @@ class _Inflight:
     # collapsed the window); its per-row yield is data-dependent like
     # a spec round's (``emit_cnt`` leading tokens real, ``counts_out``
     # device-resident, host count/draft-lag mirrors sync at fetch).
-    # 0: a legacy program whose host mirrors advanced at dispatch.
+    # 0: a one-step program whose host mirrors advanced at dispatch.
     rounds: int = 0
     # Whether this window's length was the rounds controller's CHOICE
     # (PR 15) rather than forced by a near-stop cap or an
@@ -789,17 +714,15 @@ class ContinuousBatcher:
         # window caps, chunk/depth steering from un-overlapped
         # overhead and modeled MBU, restore pacing for the fleet's
         # preempt hook. None (default) = every knob stays its static
-        # config value (the pre-PR-15 behavior, and the bench's
-        # fixed-grid baseline). Bound below once the modeled terms
-        # exist.
+        # config value. Bound below once the modeled terms exist.
         self.controller = controller
         # Speculative draft model (PR 9): the draft decodes against its
         # OWN pool mirroring the target's page geometry — same page
         # ids, same host-side tables/allocator, so prefix sharing, CoW
         # copies, and host-tier restores cover both pools with one set
-        # of bookkeeping. Draft prefill rides every prompt (chunked or
-        # dense) whenever the draft exists, so flipping ``spec_decode``
-        # mid-serve never leaves a prompt without draft context.
+        # of bookkeeping. Draft prefill rides every prompt whenever the
+        # draft exists, so flipping ``spec_decode`` mid-serve never
+        # leaves a prompt without draft context.
         self._draft_cfg: ModelConfig | None = None
         self._draft_params: dict | None = None
         self.draft_cache = None
@@ -842,22 +765,6 @@ class ContinuousBatcher:
                     f"{cfg.vocab_size} — cross-model speculation needs a "
                     "vocab alignment map (serving.vocab_align."
                     "align_vocabs) or one shared tokenizer"
-                )
-            if c.steps_per_sync > 1:
-                # Not an error: spec_decode is a live lever and the
-                # draft pool/prefills are still maintained — but a
-                # config that can never verify pays the full draft
-                # cost (HBM planes + one mirror program per chunk)
-                # for zero speedup, silently. This is the ONE
-                # remaining no-engage condition: since PR 13 the
-                # draft pool shards with the target's and speculation
-                # engages on meshes too.
-                log.warning(
-                    "speculative decoding engages only with "
-                    "steps_per_sync == 1 (got %d): the draft will "
-                    "prefill but no verify round will ever "
-                    "dispatch",
-                    c.steps_per_sync,
                 )
             self._draft_cfg = resolve_kernels(dcfg, mesh, shard_mapped=True)
             self._draft_params = dparams
@@ -921,23 +828,6 @@ class ContinuousBatcher:
             self.kernels = "pallas" if on_tpu() else "pallas-interpret"
             if not single_device(mesh):
                 self.kernels += "/shard_map"
-        if c.decode_rounds > 1 and c.steps_per_sync > 1:
-            # Not an error (the batcher serves correctly either way),
-            # but the config still pays decode_rounds into every
-            # admission's page-overshoot budget (_round_tokens reads
-            # the CONFIG so live flips stay budgeted) while _rounds
-            # never engages — capacity spent for zero benefit needs a
-            # signal, exactly like the spec warning above. (Since
-            # PR 13 meshes engage multi-round decode like single
-            # chips; steps_per_sync > 1 is the one remaining
-            # no-engage condition.)
-            log.warning(
-                "decode_rounds=%d never engages with steps_per_sync=%d"
-                ": no multi-round program will dispatch, but the "
-                "page-overshoot budget still reserves for R rounds",
-                c.decode_rounds,
-                c.steps_per_sync,
-            )
         self._refuse_unsupported(cfg, self._draft_cfg, mesh, host_store)
         self.cache = self._create_pool(cfg)
         # The device as JAX reports it to this process, and which of
@@ -990,8 +880,8 @@ class ContinuousBatcher:
             PrefixRegistry(pool, c.page_size) for pool in self._pools
         ]
         # Host-RAM offload tier (PR 4; mesh-native since PR 13).
-        # Engages only on the chunked shared-prefix path (restores
-        # re-register under the registry's readiness gates). On a mesh
+        # Engages only with prefix sharing (restores re-register
+        # under the registry's readiness gates). On a mesh
         # the demote ``device_get`` assembles the page's sharded plane
         # slices into one host buffer and the restore ``install_page``
         # scatters it back through the pool's NamedSharding — the
@@ -1011,11 +901,7 @@ class ContinuousBatcher:
         # weights fingerprint walks every param leaf, a cost the first
         # debug probe pays once, not construction.
         self._probe_scope: dict | None = None
-        if (
-            c.host_cache_bytes > 0
-            and c.share_prefix
-            and c.prefill_chunk > 0
-        ):
+        if c.host_cache_bytes > 0 and c.share_prefix:
             self._offload = (
                 host_store
                 if host_store is not None
@@ -1065,7 +951,7 @@ class ContinuousBatcher:
         elif host_store is not None:
             raise ValueError(
                 "a shared host_store needs the offload tier engaged: "
-                "host_cache_bytes > 0, share_prefix, prefill_chunk > 0"
+                "host_cache_bytes > 0 and share_prefix"
             )
         # Fleet hooks (PR 14): router-requested preemption (demote
         # reclaimable registry chains to the host tier NOW, freeing
@@ -1120,10 +1006,9 @@ class ContinuousBatcher:
         # shared prefix page runs. The ragged kernel handles groups,
         # sliding windows, and mixed rows in one program, and since
         # PR 13 meshes too (shard_map with groups riding their
-        # members' data shard), so the only remaining engage
-        # conditions are use_pallas plus the feature knobs — the PR 3
-        # sliding-window fallback and the mesh fallback are both gone
-        # (README Serving engage matrix). Grouping is per data shard
+        # members' data shard), so the only engage conditions are the
+        # kernel and prefix sharing (README Serving engage matrix).
+        # Grouping is per data shard
         # by construction: pages share only within one shard's
         # registry, so a group's members always land on one shard. On
         # a mesh the KERNEL must actually be shardable: the XLA
@@ -1131,12 +1016,7 @@ class ContinuousBatcher:
         # only accrue shared-KV "savings" that never happen (and pay
         # the per-iteration tracker work) — telemetry must not claim
         # reads the program still performs.
-        self._group_decode = (
-            c.prefix_attention
-            and c.share_prefix
-            and c.prefill_chunk > 0
-            and attn_kernel
-        )
+        self._group_decode = c.share_prefix and attn_kernel
         self._groups = GroupTracker(c.max_slots, c.page_size)
         # KV bytes one token costs per read across all layers (k + v,
         # pool dtype) — the unit of gateway_shared_kv_bytes_saved_total
@@ -1309,7 +1189,6 @@ class ContinuousBatcher:
         # stops; a cycling adversary re-pays only the capped
         # (max_vocab_scan decodes) derivation on its own thread.
         self._screen_cache: dict[tuple, tuple[int, ...] | None] = {}
-        self._jit_prefill = {}
         self._jit_chunk = {}  # (chunk, s_bucket) -> compiled chunk prefill
         self._jit_fused = {}  # (chunk, s_bucket) -> compiled fused step
         # Buckets whose fused step was built ahead, and the shapes of a
@@ -1348,14 +1227,12 @@ class ContinuousBatcher:
                 donate_argnums=(3, 4),
             )
             self._jit_chunk_d = {}  # (chunk, s_bucket) -> draft chunk
-            self._jit_prefill_d = {}  # s_bucket -> draft dense prefill
             # Draft-pool copy/install ride _jit_copy_page /
             # _jit_install_page: jit caches per input shape, so the
             # draft planes just add a second cached trace.
         # Round-robin pointer over prefilling slots (fairness when
         # several prompts fill concurrently).
         self._prefill_rr = 0
-        self._dense_pending = -1
         _M_REGISTRY.add_render_hook(_fill_device_memory)
         self._thread = threading.Thread(
             target=self._run, name="continuous-batcher", daemon=True
@@ -1419,8 +1296,6 @@ class ContinuousBatcher:
             why = "a draft model (the draft/verify lane)"
         elif not single_device(mesh):
             why = "a mesh (the latent pool has no partitioning yet)"
-        elif c.prefill_chunk <= 0:
-            why = "prefill_chunk=0 (dense prefill has no latent cache)"
         elif c.host_cache_bytes > 0 or host_store is not None:
             why = "the host tier / a remote page store"
         if why:
@@ -1462,19 +1337,11 @@ class ContinuousBatcher:
         )
 
     @property
-    def _sync_chunk(self) -> int:
-        """Decode steps per dispatched device program (>= 1) — THE one
-        definition the decode program, the page-overshoot budget, and
-        the fetch accounting all share (three sites drifting
-        independently is how the overshoot budget breaks)."""
-        return max(1, self.config.steps_per_sync)
-
-    @property
     def _depth(self) -> int:
         """Decode programs allowed in flight (>= 1). Read per loop
         iteration, so a depth change between bursts takes effect
-        without restarting the batcher (the bench's A/B lever). With
-        an adaptive controller the effective depth steers within
+        without restarting the batcher. With an adaptive controller
+        the effective depth steers within
         [1, pipeline_depth] from the un-overlapped overhead signal
         (PR 15) — outputs are depth-invariant by the PR-6 contract,
         so steering can never change text."""
@@ -1486,45 +1353,36 @@ class ContinuousBatcher:
     @property
     def _spec_ok(self) -> bool:
         """Whether decode rounds run the speculative draft/verify
-        program (PR 9). Read per loop iteration — ``spec_decode`` is
-        the bench's A/B lever. Needs steps_per_sync == 1: the verify
-        round IS the multi-token step, and folding further decode
-        steps into the same program would need a second data-dependent
-        scan (not worth the trace)."""
+        program (PR 9). Read per loop iteration: ``spec_decode`` may
+        flip on a live batcher."""
         return (
             self._draft_cfg is not None
             and self.config.spec_k > 0
             and self.config.spec_decode
-            and self._sync_chunk == 1
         )
 
     @property
     def _rounds(self) -> int:
         """Decode rounds folded into one PLAIN (non-spec) dispatch
-        (PR 12) — ``decode_rounds`` when engaged, else 1. Engages with
-        steps_per_sync == 1 (the legacy multi-step chunk is unmasked),
-        meshes included since PR 13: a frozen row's NULL-page write is
-        one more row of the same sharded scatter every live row rides,
-        and the stop screen / budgets / emit counts are per-row data
-        sharded over ``data`` like every other row array. Read per
-        loop iteration (the bench's A/B lever); while > 1 every
-        non-spec dispatch runs the multi-round machinery — even a
-        stop-bound 1-round window — so a pipeline window never mixes
+        (PR 12): ``decode_rounds``, at least 1. Meshes included since
+        PR 13: a frozen row's NULL-page write is one more row of the
+        same sharded scatter every live row rides, and the stop screen
+        / budgets / emit counts are per-row data sharded over ``data``
+        like every other row array. Read per loop iteration; while > 1
+        every non-spec dispatch runs the multi-round machinery — even
+        a stop-bound 1-round window — so a pipeline window never mixes
         host- and device-advanced PRNG counts."""
-        c = self.config
-        if c.decode_rounds <= 1 or self._sync_chunk != 1:
-            return 1
-        return c.decode_rounds
+        return max(1, self.config.decode_rounds)
 
     @property
     def _round_tokens(self) -> int:
         """Worst-case tokens ONE dispatched program advances a row by —
-        the page-overshoot unit. Plain decode: the steps_per_sync
-        chunk, or the decode_rounds window (PR 12) — counted from the
-        CONFIG regardless of live engagement, exactly like spec_k, so
-        in-flight admissions stay budgeted across a flip. With a draft
-        configured: spec_k + 1 verify tokens."""
-        rt = max(self._sync_chunk, self.config.decode_rounds)
+        the page-overshoot unit. Plain decode: the decode_rounds
+        window (PR 12) — counted from the CONFIG regardless of live
+        engagement, exactly like spec_k, so in-flight admissions stay
+        budgeted across a flip. With a draft configured: spec_k + 1
+        verify tokens."""
+        rt = self._rounds
         if self._draft_cfg is not None:
             rt = max(rt, self.config.spec_k + 1)
         return rt
@@ -1544,16 +1402,17 @@ class ContinuousBatcher:
         filters_active,
         groups=None,
     ):
-        """``steps_per_sync`` decode+sample steps as ONE device program.
+        """One decode+sample step for every slot as ONE device program.
 
-        Returns ``([slots, k] tokens, [slots, k] logprobs, cache,
+        Returns ``([slots, 1] tokens, [slots, 1] logprobs, cache,
         [slots] final token, aux)`` — the final-token row is what a
         pipelined dispatch feeds the NEXT program without a host round
-        trip; ``aux`` is :meth:`_step_aux`'s tuple and stays on the
-        device unless a row asked for its logits.
-        Each step folds ``(seed, count+j)`` into the per-slot PRNG —
-        the same stream a chunk-of-1 loop would draw, so results are
-        chunk-size-invariant (tested).
+        trip; ``aux`` is the step's logits [slots, V] as the sampler
+        got them (``"logits": n`` requests read their row; nothing
+        else fetches it) and, for a dropless-MoE model, its routing
+        counts. The step folds ``(seed, count)`` into the per-slot
+        PRNG, so a request's stream is addressed by its output index
+        whatever program carries it (tested).
 
         ``groups`` (DecodeGroupArrays or None): per-step decode-group
         metadata — shared prefix pages read once per group through the
@@ -1561,14 +1420,69 @@ class ContinuousBatcher:
         variants are separate cached traces; membership CHANGES within
         a variant are pure data and never recompile).
         """
-        k = self._sync_chunk
-        body = self._decode_body(
-            params, seeds, temps, topks, topps, filters_active, groups
-        )
+        # One application of the step, under a scan of length 1 ON
+        # PURPOSE. XLA deletes the loop (the compiled program is the
+        # same HLO with and without it), but on the chip's host every
+        # process's warm-up of the chat cell took ~2 s longer without
+        # it, 7 runs of 7 (PERF.md, Findings PR 30): take it out only
+        # with `setup_s` of `mistral-7b.chat` measured beside it.
+        def body(carry, _):
+            cache, tok, cnt = carry
+            next_tok, logp, cache, extra = self._decode_step(
+                params, cache, tok, cnt, seeds, temps, topks, topps,
+                filters_active, groups,
+            )
+            return (cache, next_tok, cnt + 1), (next_tok, logp, extra)
+
         (cache, tok_end, _), (toks, logps, extra) = jax.lax.scan(
-            body, (cache, tokens, counts), None, length=k
+            body, (cache, tokens, counts), None, length=1
         )
         return toks.T, logps.T, cache, tok_end, self._step_aux(extra)
+
+    def _decode_step(
+        self,
+        params,
+        cache,
+        tok,
+        cnt,
+        seeds,
+        temps,
+        topks,
+        topps,
+        filters_active,
+        groups,
+        alive=None,
+    ):
+        """The decode+sample step the one-step program applies once
+        and the multi-round scan body applies per round, so the two
+        cannot drift: the layer pass over every slot's newest token (``alive``
+        rows alone write K/V and advance; None = all), the ``(seed,
+        count)`` PRNG fold, the sampler. Returns ``(next_tok, logp,
+        cache, (logits, *routing counts))``."""
+        logits, cache, *moe = decode_step_paged(
+            self.cfg, params, tok[:, None], cache, groups=groups,
+            write_mask=alive, mesh=self.mesh,
+        )
+        next_tok, logp = self._sample_rows(
+            logits, seeds, cnt, temps, topks, topps, filters_active
+        )
+        return next_tok, logp, cache, (logits, *moe)
+
+    @staticmethod
+    def _sample_rows(logits, seeds, cnt, temps, topks, topps, filters_active):
+        """Every slot's next token from its logits row, drawn with the
+        row's ``(seed, count)`` key — the one sampling site of the
+        decode, multi-round and fused programs. ``filters_active`` is
+        STATIC (two cached programs): the all-defaults workload —
+        every active request with top_k=0, top_p=1.0 — never pays the
+        filters' full-vocab sort."""
+        keys = jax.vmap(
+            lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c)
+        )(seeds, cnt)
+        return sample_token_per_request(
+            logits, keys, temps, topks, topps,
+            filters_active=filters_active,
+        )
 
     def _decode_body(
         self,
@@ -1579,53 +1493,29 @@ class ContinuousBatcher:
         topps,
         filters_active,
         groups,
-        stop=None,
+        budgets,
+        screen,
     ):
-        """One decode+sample step as a scan body — shared by the plain,
-        the fused, AND the multi-round program so the paths cannot
-        drift.
-
-        ``stop`` (PR 12): None = the classic body (every row live,
-        carry ``(cache, tok, cnt)``). A ``(budgets, screen)`` pair =
-        the early-exit-masked body — carry grows to ``(cache, tok,
-        cnt, alive, emitted)``; a live row decodes exactly the classic
-        step (same K/V write, same (seed, count) PRNG fold, same
-        sampler), then :func:`stop_scan_hit` freezes it on EOS, a
-        screened stop candidate, or its emit budget. A frozen row
-        stops writing K/V (decode_step_paged's write_mask), stops
-        folding its PRNG (count invariance vs R = 1), holds its last
-        token (the emit buffer past ``emitted`` is that stale token —
-        the host reads only the real prefix), and stays frozen for the
-        window's remainder (freezing is monotone, so the real tokens
-        are always a prefix)."""
+        """The early-exit-masked decode round as a scan body (PR 12),
+        shared by the multi-round program and the fused step's
+        multi-round tail. Carry ``(cache, tok, cnt, alive, emitted)``:
+        a live row decodes exactly :meth:`_decode_step` (same K/V
+        write, same (seed, count) PRNG fold, same sampler), then
+        :func:`stop_scan_hit` freezes it on EOS, a screened stop
+        candidate, or its emit budget. A frozen row stops writing K/V
+        (decode_step_paged's write_mask), stops folding its PRNG
+        (count invariance vs R = 1), holds its last token (the emit
+        buffer past ``emitted`` is that stale token — the host reads
+        only the real prefix), and stays frozen for the window's
+        remainder (freezing is monotone, so the real tokens are always
+        a prefix)."""
 
         def body(carry, _):
-            if stop is None:
-                cache, tok, cnt = carry
-                alive = None
-            else:
-                cache, tok, cnt, alive, emitted = carry
-            # A dropless-MoE model's step also returns its routing
-            # counts; ``extra`` = (logits, *counts) rides the scan's ys.
-            logits, cache, *moe = decode_step_paged(
-                self.cfg, params, tok[:, None], cache, groups=groups,
-                write_mask=alive, mesh=self.mesh,
+            cache, tok, cnt, alive, emitted = carry
+            next_tok, logp, cache, extra = self._decode_step(
+                params, cache, tok, cnt, seeds, temps, topks, topps,
+                filters_active, groups, alive=alive,
             )
-            extra = (logits, *moe)
-            keys = jax.vmap(
-                lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c)
-            )(seeds, cnt)
-            # filters_active is STATIC (two cached programs): the
-            # all-defaults workload — every active request with
-            # top_k=0, top_p=1.0 — never pays the filters' full-vocab
-            # sort.
-            next_tok, logp = sample_token_per_request(
-                logits, keys, temps, topks, topps,
-                filters_active=filters_active,
-            )
-            if stop is None:
-                return (cache, next_tok, cnt + 1), (next_tok, logp, extra)
-            budgets, screen = stop
             next_tok = jnp.where(alive, next_tok, tok)
             adv = alive.astype(cnt.dtype)
             cnt = cnt + adv
@@ -1643,10 +1533,11 @@ class ContinuousBatcher:
 
     @staticmethod
     def _step_aux(extra):
-        """What a program hands back beside its tokens, from the scan's
-        stacked ``extra``: the LAST step's logits [slots, V] as the
-        sampler got them (``"logits": n`` requests read their row;
-        nothing else fetches it) and, for a dropless-MoE model, the
+        """What a program hands back beside its tokens, from its
+        scan's stacked ``extra``: the LAST step's logits [slots, V] as
+        the sampler got them (``"logits": n`` requests read their row
+        of the one-step program's; ``submit`` refuses them where a
+        program is several rounds) and, for a dropless-MoE model, the
         routing counts summed over the steps."""
         logits, *moe = extra
         return (logits[-1], *(m.sum(axis=0) for m in moe))
@@ -1668,9 +1559,9 @@ class ContinuousBatcher:
         groups=None,
     ):
         """Up to ``rounds`` decode rounds as ONE device program (PR 12)
-        — the multi-round counterpart of :meth:`_decode_sample`, built
-        on the same scan body with the early-exit mask threaded
-        through the carry.
+        — the multi-round counterpart of :meth:`_decode_sample`: a scan
+        of the same step with the early-exit mask threaded through the
+        carry (:meth:`_decode_body`).
 
         counts: [B] device-resident per-row PRNG indices (the yield is
         data-dependent once rows can freeze mid-window, so counts
@@ -1690,7 +1581,7 @@ class ContinuousBatcher:
         emitted0 = jnp.zeros_like(counts)
         body = self._decode_body(
             params, seeds, temps, topks, topps, filters_active, groups,
-            stop=(budgets, screen),
+            budgets, screen,
         )
         (cache, tok_end, cnt_out, _, emitted), (toks, logps, extra) = (
             jax.lax.scan(
@@ -1723,8 +1614,8 @@ class ContinuousBatcher:
         budgets=None,
         screen=None,
     ):
-        """The fused scheduler step: ``steps_per_sync`` decode+sample
-        steps AND one prefill chunk as ONE device program (PR 8).
+        """The fused scheduler step: one decode+sample step AND one
+        prefill chunk as ONE device program (PR 8).
 
         ``stop_rounds`` (STATIC, PR 12): > 0 makes this the MULTI-ROUND
         fused step — the chunk rides round 1 exactly as before (every
@@ -1734,13 +1625,12 @@ class ContinuousBatcher:
         counts_out)`` with only each row's leading ``emit_cnt`` emit
         tokens real — the chunk keeps riding the decode dispatch under
         ``decode_rounds`` without a pipeline flush per admission.
-        0 = the PR-8 behavior and return shape, byte-for-byte.
+        0 = the one-step fused program and its return shape.
 
-        The chunk rides the FIRST decode step's layer pass
+        The chunk rides the decode step's layer pass
         (:func:`~llm_consensus_tpu.models.transformer.fused_step_paged`
         — shared token axis, one K/V scatter, the ragged attention
-        kernel); the remaining k-1 steps run the same scan body as
-        :meth:`_decode_sample`. Returns the plain program's outputs
+        kernel). Returns the plain program's outputs
         plus ``chunk_logits`` [V] — the unembedded hidden state of the
         prompt position ``chunk_last`` (the host samples the request's
         first token from it at fetch, exactly as the standalone path
@@ -1751,7 +1641,6 @@ class ContinuousBatcher:
         seconds to trace and load in every process, and a last-chunk
         variant first met under load would stall every row for them.
         """
-        k = self._sync_chunk
         logits, hidden, cache, *moe = fused_step_paged(
             self.cfg,
             params,
@@ -1764,19 +1653,9 @@ class ContinuousBatcher:
             cfg_chunk=cfg_chunk,
             mesh=self.mesh,
         )
-        keys = jax.vmap(
-            lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c)
-        )(seeds, counts)
-        tok1, logp1 = sample_token_per_request(
-            logits, keys, temps, topks, topps, filters_active=filters_active
+        tok1, logp1 = self._sample_rows(
+            logits, seeds, counts, temps, topks, topps, filters_active
         )
-        def aux_with(extra):
-            """The fused step's aux (its FIRST step's logits, the one
-            step a ``"logits"`` request may ride: ``submit``) with the
-            tail scan's routing counts added."""
-            tail = self._step_aux(extra)[1:]
-            return (logits, *(m + t for m, t in zip(moe, tail)))
-
         c = chunk_tokens.shape[1]
         chunk_logits = jax.lax.cond(
             chunk_done,
@@ -1798,7 +1677,7 @@ class ContinuousBatcher:
             if stop_rounds > 1:
                 body = self._decode_body(
                     params, seeds, temps, topks, topps, filters_active,
-                    groups, stop=(budgets, screen),
+                    groups, budgets, screen,
                 )
                 (cache, tok_end, cnt_out, _, emitted), (toks, logps, extra) = (
                     jax.lax.scan(
@@ -1810,24 +1689,18 @@ class ContinuousBatcher:
                 )
                 toks = jnp.concatenate([tok1[:, None], toks.T], axis=1)
                 logps = jnp.concatenate([logp1[:, None], logps.T], axis=1)
+                # aux: the FIRST round's logits (the fused step's own)
+                # with the tail rounds' routing counts added.
+                tail = self._step_aux(extra)[1:]
+                aux = (logits, *(m + t for m, t in zip(moe, tail)))
                 return (
                     toks, logps, cache, tok_end, chunk_logits, emitted,
-                    cnt_out, aux_with(extra),
+                    cnt_out, aux,
                 )
             return (
                 tok1[:, None], logp1[:, None], cache, tok1, chunk_logits,
                 emitted, counts + 1, (logits, *moe),
             )
-        if k > 1:
-            body = self._decode_body(
-                params, seeds, temps, topks, topps, filters_active, groups
-            )
-            (cache, tok_end, _), (toks, logps, extra) = jax.lax.scan(
-                body, (cache, tok1, counts + 1), None, length=k - 1
-            )
-            toks = jnp.concatenate([tok1[:, None], toks.T], axis=1)
-            logps = jnp.concatenate([logp1[:, None], logps.T], axis=1)
-            return toks, logps, cache, tok_end, chunk_logits, aux_with(extra)
         return (
             tok1[:, None], logp1[:, None], cache, tok1, chunk_logits,
             (logits, *moe),
@@ -2044,40 +1917,16 @@ class ContinuousBatcher:
         streams = len({int(src[i]) for i in decoding})
         return src, fill, off, streams, shared
 
-    def _prefill_fn(self, s_bucket: int):
-        """Jitted per-bucket: prefill one prompt densely, scatter to pages.
-
-        The legacy (``prefill_chunk=0``) admission path — and the parity
-        baseline the chunked path is tested against.
-        """
-        if s_bucket not in self._jit_prefill:
-
-            def prefill_dense(params, cache, tokens, length, seq_id):
-                dense = KVCache.create(self.cfg, 1, s_bucket)
-                logits, dense = prefill(
-                    self.cfg, params, tokens, length[None], dense,
-                    mesh=self.mesh,
-                )
-                cache = write_prefill_kv(
-                    cache, seq_id, dense.k[:, 0], dense.v[:, 0], length
-                )
-                return logits[0], cache
-
-            self._jit_prefill[s_bucket] = jax.jit(
-                prefill_dense, donate_argnums=(1,)
-            )
-        return self._jit_prefill[s_bucket]
-
     def _chunk_fn(self, chunk: int, s_bucket: int):
         """Jitted per (chunk, prompt-bucket): one paged prefill chunk.
 
         Compile-once per chunk bucket: chunk widths come from
         ``min(config.prefill_chunk, s_bucket)``, so the program family
-        is bounded by the seq-bucket list exactly like dense prefill.
-        The bucket also pins the MoE dispatch path to the choice a
-        one-shot [1, s_bucket] prefill would trace — a chunk below the
-        dense-fallback threshold must not diverge from the dense
-        admission path it is parity-tested against.
+        is bounded by the seq-bucket list. The bucket also pins the
+        MoE dispatch path to the choice a one-shot [1, s_bucket]
+        prefill would trace — a chunk below the dense-fallback
+        threshold must not diverge from the engine's whole-prompt
+        prefill it is parity-tested against.
         """
         key = (chunk, s_bucket)
         if key not in self._jit_chunk:
@@ -2118,33 +1967,6 @@ class ContinuousBatcher:
                 prefill_chunk_draft, donate_argnums=(4,)
             )
         return self._jit_chunk_d[key]
-
-    def _prefill_fn_d(self, s_bucket: int):
-        """Jitted per-bucket DRAFT dense prefill (the legacy
-        ``prefill_chunk=0`` admission path's mirror)."""
-        if s_bucket not in self._jit_prefill_d:
-            dcfg = self._draft_cfg
-            t2d = self._t2d
-
-            def prefill_dense_draft(params, cache, tokens, length, seq_id):
-                if t2d is not None:
-                    # Cross-model remap (PR 18): target-id prompt, t2d
-                    # image into the draft (see _chunk_fn_d).
-                    tokens = t2d[tokens]
-                dense = KVCache.create(dcfg, 1, s_bucket)
-                _, dense = prefill(
-                    dcfg, params, tokens, length[None], dense,
-                    mesh=self.mesh,
-                )
-                cache = write_prefill_kv(
-                    cache, seq_id, dense.k[:, 0], dense.v[:, 0], length
-                )
-                return cache
-
-            self._jit_prefill_d[s_bucket] = jax.jit(
-                prefill_dense_draft, donate_argnums=(1,)
-            )
-        return self._jit_prefill_d[s_bucket]
 
     def _draft_prefill_chunk(self, slot: _Slot, chunk_ids, pos: int) -> None:
         """Run the draft's mirror of one prefill chunk (stream-ordered
@@ -2209,16 +2031,7 @@ class ContinuousBatcher:
             # (the round input), so the draft must cover [dlen, tlen).
             tlen = slot.prompt_len + len(slot.generated) - 1
             dlen = tlen - slot.draft_lag
-            if slot.table is not None:
-                table = slot.table
-            else:
-                # Dense-admission rows: the table is the page list in
-                # positional order (mirrors _dense_prefill_pending).
-                table = np.full(
-                    (self.config.pages_per_seq,), NULL_PAGE, np.int32
-                )
-                table[: len(slot.pages)] = slot.pages
-            table_dev = jnp.asarray(table)
+            table_dev = jnp.asarray(slot.table)
             gen = np.asarray(slot.generated, np.int32)
             cur = dlen
             while cur < tlen:
@@ -2245,7 +2058,7 @@ class ContinuousBatcher:
         (:meth:`_fused_sample`). The bucket pins the chunk side's MoE
         dispatch path exactly as :meth:`_chunk_fn` does — the fused
         program must stay output-identical to the split programs it
-        replaces (the A/B contract)."""
+        replaces (tested with ``ragged_attention`` on and off)."""
         key = (chunk, s_bucket)
         if key not in self._jit_fused:
             cfg_chunk = self.cfg.moe_pin_for(s_bucket, chunk)
@@ -2298,12 +2111,9 @@ class ContinuousBatcher:
         owner shard's page range — and the attention read goes through
         the same one kernel seam as the plain step, so ONE device
         program per scheduler iteration holds on every topology. Read
-        per iteration: the bench flips ``config.ragged_attention``
-        between bursts on one batcher."""
-        return (
-            self.config.ragged_attention
-            and self.config.prefill_chunk > 0
-        )
+        per iteration: ``config.ragged_attention`` may flip between
+        bursts on one batcher."""
+        return self.config.ragged_attention
 
     # -- public API -----------------------------------------------------
 
@@ -2326,8 +2136,8 @@ class ContinuousBatcher:
         n generated positions (``ServeResult.logits``), as the timed
         programs computed them: no second forward pass. Greedy requests
         only, and only where a program is one decode step (no
-        ``steps_per_sync``, ``decode_rounds`` or draft): elsewhere a
-        program keeps only its last step's logits.
+        ``decode_rounds`` or draft): elsewhere a program keeps only its
+        last step's logits.
 
         ``top_k``/``top_p``: ``None`` inherits the batcher's
         config-level sampler; any EXPLICIT value is authoritative —
@@ -2358,14 +2168,10 @@ class ContinuousBatcher:
         if logits:
             if temperature > 0:
                 raise ValueError("'logits' is for greedy requests only")
-            if (
-                self._sync_chunk > 1
-                or c.decode_rounds > 1
-                or self._draft_cfg is not None
-            ):
+            if c.decode_rounds > 1 or self._draft_cfg is not None:
                 raise ValueError(
                     "'logits' needs one decode step a program: no "
-                    "steps_per_sync, decode_rounds or draft model"
+                    "decode_rounds or draft model"
                 )
         full_ids = (
             prompt_ids
@@ -3036,8 +2842,8 @@ class ContinuousBatcher:
                 "sched_overhead_seconds_count": self._sched_overhead_count,
                 # Pipelined decode dispatch (PR 6): programs currently
                 # dispatched-not-fetched, and drains forced by
-                # stable-cache operations (restores, CoW copies, dense
-                # prefill) — the same observations behind
+                # stable-cache operations (restores, CoW copies) — the
+                # same observations behind
                 # gateway_dispatch_inflight /
                 # gateway_pipeline_flushes_total (lockstep tested).
                 "dispatch_inflight": len(self._inflight),
@@ -3067,7 +2873,7 @@ class ContinuousBatcher:
                 # device_rounds_total / decode_rounds_count is the
                 # realized rounds per program, and device programs per
                 # generated token drops ~R× at R for a fixed batch
-                # shape — the cross-check the bench leg gates.
+                # shape (tests/test_decode_rounds.py).
                 "device_rounds_total": self._device_rounds,
                 "decode_rounds_sum": self._decode_rounds_sum,
                 "decode_rounds_count": self._decode_rounds_count,
@@ -3186,9 +2992,8 @@ class ContinuousBatcher:
         """Per-request prefill-chunk width: the largest divisor of the
         prompt bucket <= ``config.prefill_chunk`` (power-of-two buckets
         keep it at prefill_chunk). Dividing the bucket makes an
-        UNSHARED chunked prefill cover exactly [0, bucket) — the same
-        page footprint as the legacy dense path, so admission
-        feasibility cannot regress."""
+        UNSHARED chunked prefill cover exactly [0, bucket): the page
+        footprint :meth:`_pages_needed` checks admission against."""
         chunk = min(self.config.prefill_chunk, bucket)
         while bucket % chunk:
             chunk -= 1
@@ -3197,20 +3002,19 @@ class ContinuousBatcher:
     def _pages_needed(self, req: _Request) -> int:
         """Table width in pages for an UNSHARED admission — the
         admit-ever feasibility bound (a request that only fits via
-        sharing must not wait forever on an empty registry; chunked or
-        dense, the unshared footprint is identical)."""
+        sharing must not wait forever on an empty registry)."""
         bucket = self._bucket(len(req.prompt_ids))
         return self._table_pages(bucket, bucket, req)
 
     def _table_pages(self, bucket: int, prefill_end: int, req: _Request) -> int:
-        # + depth * round_tokens - 1: a row finishing mid-chunk keeps
-        # writing K/V until the decode-chunk boundary, and under
+        # + depth * round_tokens - 1: a row finishing mid-window keeps
+        # writing K/V until the window's last round, and under
         # pipelined dispatch its retirement lags up to depth - 1 MORE
         # already-enqueued programs (all those tokens are discarded on
         # host); its pages must absorb the full overshoot. Under
         # speculative decoding a round writes up to spec_k + 1 K/V
         # positions of which a rejected tail is rewound — the same
-        # budget covers it (_round_tokens). depth 1, chunk 1, spec off
+        # budget covers it (_round_tokens). depth 1, one round, spec off
         # reduces this to the classic + 0.
         # prefill_end: last position (+1) the chunked prefill may touch
         # — a shared-prefix start off the chunk grid can overhang the
@@ -3258,26 +3062,17 @@ class ContinuousBatcher:
                         )
                     )
                     continue
-                admitted = (
-                    self._admit_chunked(req)
-                    if c.prefill_chunk > 0
-                    else self._admit_dense(req)
-                )
-                if not admitted:
+                if not self._admit_chunked(req):
                     return  # no slot/pages; retry after retirements
                 self._waiting.popleft()
                 _M_WAITING.set(len(self._waiting))
-            if c.prefill_chunk == 0:
-                # Legacy path: the dense prefill runs OUTSIDE the lock
-                # (device work must not block submit()).
-                self._dense_prefill_pending()
-            elif self._pending_copy is not None:
+            if self._pending_copy is not None:
                 # The admission staged a CoW boundary copy: dispatch it
                 # outside the lock (flush-then-copy; _flush_pipeline's
                 # fetch bookkeeping takes the admission lock).
                 self._boundary_copy_pending()
 
-    # -- admission: chunked + prefix-sharing path ------------------------
+    # -- admission: chunked prefill + prefix sharing ---------------------
 
     def _admit_chunked(self, req: _Request) -> bool:
         """Claim a slot + pages for ``req`` and stage it as a prefilling
@@ -3319,7 +3114,7 @@ class ContinuousBatcher:
             pool = self._pools[shard]
             registry = self._registries[shard]
             # Plan A shares the registered prefix; plan B admits
-            # unshared (exactly the legacy footprint) when the shared
+            # unshared (exactly _pages_needed's footprint) when the shared
             # table would overhang the page budget — a prefix start off
             # the chunk grid pads the final chunk past the bucket, up
             # to chunk-1 positions.
@@ -3529,8 +3324,8 @@ class ContinuousBatcher:
 
         The flush points are the operations that want a stable cache
         and settled host bookkeeping underneath them: host-tier page
-        restores (install_page), CoW boundary copies, and legacy dense
-        prefill. Each drain of a non-empty pipeline counts once in
+        restores (install_page) and CoW boundary copies. Each drain of
+        a non-empty pipeline counts once in
         ``gateway_pipeline_flushes_total`` — the price the pipeline
         pays to keep those paths simple. (Registry demotions read
         pages with ``device_get``, which already blocks on the
@@ -3753,7 +3548,7 @@ class ContinuousBatcher:
         ``rows``: ragged-row occupancy for fused/decode programs
         (decode rows + chunk lanes). ``rounds`` (PR 12): decode rounds
         this program folds — decode/fused pass their window (R under
-        decode_rounds, steps_per_sync on the legacy chunk), spec
+        decode_rounds, else 1), spec
         passes 1 (the verify round IS the multi-token step), prefill/
         draft pass None (they advance no decode row) — feeding
         gateway_device_rounds_total + the per-program histogram and
@@ -3895,8 +3690,7 @@ class ContinuousBatcher:
             self.controller.note_program(kind, cost, dur)
         if cost is None:
             return
-        # A dense prefill attends in-program: it walks no pool page.
-        pages = cost.get("attn_pages_read", 0)
+        pages = cost["attn_pages_read"]
         _M_ATTN_TOKENS_READ.labels(kind=kind).inc(cost["kv_read_tokens"])
         _M_ATTN_PAGES_READ.labels(kind=kind).inc(pages)
         with self._lock:
@@ -4052,8 +3846,9 @@ class ContinuousBatcher:
         )
 
     def _sample_first(self, req: _Request, logits) -> int:
-        """First generated token, sampled from prefill logits — the
-        same (seed, 0) PRNG draw both admission paths share.
+        """First generated token, sampled from the last chunk's
+        logits with the (seed, 0) PRNG draw, whichever program (a
+        standalone chunk or a fused step) carried that chunk.
 
         All of it is a wait on the device (the ``device_wait`` phase),
         not only the ``int()`` at its end: the sampling ops are eager
@@ -4130,133 +3925,6 @@ class ContinuousBatcher:
             or self._hit_stop(slot)
         ):
             self._retire(idx)
-
-    # -- admission: legacy blocking dense-prefill path -------------------
-
-    def _admit_dense(self, req: _Request) -> bool:
-        """Claim a slot + pages (caller holds the lock); the dense
-        prefill itself runs from :meth:`_dense_prefill_pending` outside
-        the lock. Returns False when nothing fits."""
-        c = self.config
-        n_pages = self._pages_needed(req)
-        free_slot = next(
-            (
-                i
-                for i, s in enumerate(self._slots)
-                if s is None
-                and self._pools[self._shard_of_slot[i]].available >= n_pages
-            ),
-            None,
-        )
-        if free_slot is None:
-            # Registry pages are reclaimable capacity even on this path
-            # (a prior chunked-config batcher cannot have populated it —
-            # but evict defensively so the two paths agree on capacity).
-            for i, s in enumerate(self._slots):
-                if s is None:
-                    shard = self._shard_of_slot[i]
-                    self._registries[shard].evict(
-                        n_pages - self._pools[shard].available
-                    )
-                    if self._pools[shard].available >= n_pages:
-                        free_slot = i
-                        break
-            if free_slot is None:
-                return False
-        pool = self._pools[self._shard_of_slot[free_slot]]
-        pages = pool.alloc(n_pages)
-        self._slots[free_slot] = _Slot(
-            request=req,
-            pages=pages,
-            generated=[],
-            prompt_len=len(req.prompt_ids),
-            phase="prefill",  # not decodable until the prefill lands
-        )
-        self._dense_pending = free_slot
-        _flight.flight_recorder().record(
-            "admit",
-            time.perf_counter(),
-            trace_id=_tracing.trace_id_of(req.trace),
-            id=req.rid,
-            slot=free_slot,
-            prompt_tokens=len(req.prompt_ids),
-            dense=1,
-        )
-        return True
-
-    def _dense_prefill_pending(self) -> None:
-        """Blocking dense prefill for the slot staged by _admit_dense.
-        Flushes the decode pipeline first (stable-cache operation: the
-        whole-prompt prefill rewrites a slot's table and pages)."""
-        self._flush_pipeline()
-        c = self.config
-        idx = self._dense_pending
-        slot = self._slots[idx]
-        req = slot.request
-        with self._phase("dispatch", kind="prefill"):
-            t0 = time.perf_counter()
-            ev = self._count_program("prefill")
-            s_bucket = self._bucket(len(req.prompt_ids))
-            slot.s_bucket = s_bucket  # program-family key (draft catch-up)
-            padded = np.full((1, s_bucket), self.tokenizer.pad_id, np.int32)
-            padded[0, : len(req.prompt_ids)] = req.prompt_ids
-            table = np.full((c.pages_per_seq,), NULL_PAGE, np.int32)
-            table[: len(slot.pages)] = slot.pages
-            self.cache = assign_pages(
-                self.cache, jnp.int32(idx), jnp.asarray(table)
-            )
-            logits, self.cache = self._prefill_fn(s_bucket)(
-                self.params,
-                self.cache,
-                jnp.asarray(padded),
-                jnp.int32(len(req.prompt_ids)),
-                jnp.int32(idx),
-            )
-            if self.draft_cache is not None:
-                # Mirror the legacy dense admission into the draft pool:
-                # same table, the draft's own dense prefill + scatter.
-                self._count_program("draft")
-                self.draft_cache = assign_pages(
-                    self.draft_cache, jnp.int32(idx), jnp.asarray(table)
-                )
-                self.draft_cache = self._prefill_fn_d(s_bucket)(
-                    self._draft_params,
-                    self.draft_cache,
-                    jnp.asarray(padded),
-                    jnp.int32(len(req.prompt_ids)),
-                    jnp.int32(idx),
-                )
-            first = self._sample_first(req, logits)
-        with self._phase("device_wait"):
-            jax.block_until_ready(self.cache.length)
-        dur = time.perf_counter() - t0
-        _M_PREFILL_TOKENS.inc(len(req.prompt_ids))
-        # The whole-prompt stall this path pays per admission — the
-        # number the chunked scheduler bounds to one chunk.
-        _M_PREFILL_STALL.observe(dur)
-        if ev is not None:
-            ev.t0 = t0
-            ev.dur = dur
-            ev.meta = {
-                **ev.meta, "slot": idx, "pos": 0,
-                "width": s_bucket, "dense": 1,
-            }
-        # Dense prefill computes attention in-program (no paged KV
-        # reads); its pool traffic is the prompt's K/V scatter.
-        self._mbu_account(
-            "prefill",
-            program_hbm_cost(
-                self.cfg,
-                weight_bytes=self._weight_bytes,
-                weight_params=self._weight_params,
-                kv_token_bytes=self._kv_token_bytes,
-                kv_read_tokens=0,
-                kv_write_tokens=len(req.prompt_ids),
-                tokens=s_bucket,
-            ),
-            dur,
-        )
-        self._activate(idx, slot, first)
 
     def _decoded_text(self, slot: _Slot) -> str:
         ids = [t for t in slot.generated if t != self.tokenizer.eos_id]
@@ -4463,7 +4131,7 @@ class ContinuousBatcher:
         chunks run standalone while speculation is engaged.
 
         ``rounds`` (PR 12): the multi-round engage state from _run's
-        once-per-iteration read (1 = legacy single-round; _run passes
+        once-per-iteration read (1 = the one-step program; _run passes
         1 whenever ``spec`` is set). > 1 dispatches the R-round masked
         program — :meth:`_rounds_sample`, or the fused step's
         multi-round tail when a chunk rides — with the same
@@ -4474,7 +4142,7 @@ class ContinuousBatcher:
         ``rounds_choice`` (PR 15): this dispatch's ``rounds`` was the
         adaptive controller's FREE regime choice (not a near-stop
         force) — such windows are evidence for the two-arm rate
-        arbitration. An adaptive arm-1 window is a PLAIN legacy
+        arbitration. An adaptive arm-1 window is a PLAIN one-step
         dispatch (``rounds == 1``): the masked 1-round program would
         pay the masking machinery + an extra emit-count host fetch
         the plain program doesn't, and the whole point of the arm is
@@ -4483,7 +4151,7 @@ class ContinuousBatcher:
         counts-mode change.
         """
         c = self.config
-        k = self._sync_chunk
+        k = 1  # decode rounds this program holds
         temps = np.zeros((c.max_slots,), np.float32)
         rows_now: list[tuple[int, _Slot]] = []
         for i, slot in enumerate(self._slots):
@@ -4706,7 +4374,7 @@ class ContinuousBatcher:
         chunk_rec = None
         if chunk_idx is None:
             if rounds_now:
-                # Same prepared device args as the legacy program
+                # Same prepared device args as the one-step program
                 # (args[9] is groups — _rounds_sample takes it after
                 # the stop data).
                 (
@@ -4818,8 +4486,8 @@ class ContinuousBatcher:
         """Enqueue the dispatched program and account the window —
         shared by the spec and plain branches so the bookkeeping
         cannot drift. ``k`` is the steps this program reads the shared
-        prefix (spec programs pass 1: _spec_ok pins steps_per_sync to
-        1, and the verify round reads the group's shared pages once)."""
+        prefix (a spec program passes 1: its verify round reads the
+        group's shared pages once)."""
         self._inflight.append(rec)
         _M_DISPATCH_INFLIGHT.set(len(self._inflight))
         _M_GROUP_SIZE.set(
@@ -4973,8 +4641,7 @@ class ContinuousBatcher:
                 if xmodel and accepted > 0:
                     # Cross-model speculation (PR 18): these accepts
                     # crossed a tokenizer boundary through the vocab
-                    # remap. The flight event is the bench's "≥ 1
-                    # cross-model accept" witness.
+                    # remap; the flight event is their witness.
                     _M_SPEC_XMODEL.inc(accepted)
                     _flight.flight_recorder().record(
                         "spec_xmodel_accept",
@@ -5137,14 +4804,13 @@ class ContinuousBatcher:
             # chunk: running slots pay a bounded stall per admission
             # instead of a whole prompt's prefill.
             chunk_idx = None
-            if self.config.prefill_chunk > 0:
-                if self._restores:
-                    with self._phase("restore"):
-                        progress = self._restore_step()
-                if not progress:
-                    chunk_idx = self._pick_prefill_slot()
+            if self._restores:
+                with self._phase("restore"):
+                    progress = self._restore_step()
+            if not progress:
+                chunk_idx = self._pick_prefill_slot()
             # Speculative decoding (PR 9): read the engage state once
-            # per iteration (the bench flips config.spec_decode between
+            # per iteration (config.spec_decode may flip between
             # bursts). While speculation is on, chunks run standalone —
             # the verify program IS the decode dispatch, and a chunk
             # lane on it is future work.
@@ -5235,7 +4901,8 @@ class ContinuousBatcher:
                     # pass would make this the one iteration that runs
                     # two programs. Defer to the next pass (the loop
                     # spins straight back) — one program per iteration
-                    # stays exact, which is the metric the A/B gates.
+                    # stays exact (tests/test_ragged_attention.py,
+                    # tests/test_mesh_serving.py).
                     with self._lock:
                         self._work_iterations += 1
                     continue
@@ -5307,7 +4974,7 @@ class ContinuousBatcher:
                     self._last_step_end = None
             if ran_program:
                 # Denominator of "device programs per scheduler
-                # iteration" — the bench's fusion gate.
+                # iteration".
                 with self._lock:
                     self._work_iterations += 1
             if not progress:

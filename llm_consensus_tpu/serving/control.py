@@ -69,9 +69,9 @@ steering keep working). ``--hbm-gbps auto`` reads the peak from the
 table of published ones and refuses a device that is not in it —
 :func:`resolve_hbm_gbps`.
 
-``bench.py --serve-adaptive`` gates adaptive mode >= every fixed
-(spec_k x R) grid point on a mixed burst with per-pair byte-identical
-greedy text and zero recompiles after warmup.
+Greedy text is byte-identical to every fixed (spec_k x R) grid point,
+and steering compiles nothing after warm-up
+(tests/test_adaptive_control.py).
 """
 
 from __future__ import annotations

@@ -16,7 +16,7 @@ controllers (PR 15). This module adds the ROLE split on top:
   ``decode_rounds=1`` (speculation and R-round windows are decode-
   phase machinery — a replica that hands chains off right after the
   header lands never amortizes them), while chunk width and mesh
-  shape stay per-replica levers (``--serve-prefill-chunk``,
+  shape stay per-replica levers (``--prefill-chunk``,
   ``meshes=`` — an mp-heavy mesh suits the prefill roofline, a
   dp-heavy one suits decode; the PR-15 controller then tunes each
   replica toward ITS role's roofline instead of compromise settings).
@@ -37,7 +37,7 @@ controllers (PR 15). This module adds the ROLE split on top:
 
 Blocking discipline (the fleet's standing rule): the coordinator
 waits for the warm prefill + export ONLY off the asyncio event loop
-(bench/test threads). On the gateway loop the handoff runs on a
+(test threads). On the gateway loop the handoff runs on a
 daemon thread — the triggering request itself goes cache-cold on its
 decode replica (correct, just not accelerated) and the panel mates
 behind it restore once the export lands, exactly the
@@ -236,7 +236,7 @@ class HandoffCoordinator:
         # as they flip ready, so the store (the wire, when it is
         # remote) transfers OVERLAP the prefill instead of serializing
         # after it. The non-streamed path (handoff_stream=False, the
-        # PR-16 shape and the bench A/B's baseline) exports the whole
+        # PR-16 shape) exports the whole
         # chain in one pass after the warm-up completes.
         ev_stream = None
         if streamed:
@@ -273,8 +273,7 @@ class HandoffCoordinator:
             dur = time.perf_counter() - t0
             _M_HANDOFFS.inc()
             # Claim-to-exported latency: the window the decode side
-            # would otherwise re-prefill in. The streamed-vs-sync
-            # bench A/B reads this family's delta.
+            # would otherwise re-prefill in.
             _M_HANDOFF_SECONDS.observe(dur)
             with self._lock:
                 self.handoffs += 1

@@ -126,8 +126,8 @@ class FleetConfig:
     #: Batcher replicas behind the one gateway (``serve --replicas``).
     replicas: int = 2
     #: ``"prefix"`` — affinity routing (the subsystem's point).
-    #: ``"random"`` — round-robin, the bench leg's control: it
-    #: deliberately ignores resident chains so the A/B isolates what
+    #: ``"random"`` — round-robin, the control tests compare with: it
+    #: deliberately ignores resident chains, which isolates what
     #: affinity buys.
     policy: str = "prefix"
     #: Minimum RESIDENT full pages for an affinity claim: below it the
@@ -176,8 +176,7 @@ class FleetConfig:
     #: export as a STREAM alongside the warm-up prefill, so ready
     #: pages cross the (possibly remote) store wire while the tail is
     #: still computing. False restores the PR-16 sequential shape
-    #: (prefill completes, then one whole-chain export) — the bench
-    #: transport A/B's baseline.
+    #: (prefill completes, then one whole-chain export).
     handoff_stream: bool = True
     #: Route-driven restore prefetch (PR 17): after the router picks a
     #: request's destination replica, speculatively stage the chain's
@@ -463,7 +462,7 @@ class ReplicaSet:
     and parameter tree (shared by every replica — jax arrays are
     immutable; a per-replica mesh re-shards without copying the
     original), one :class:`ContinuousConfig` INSTANCE all replicas
-    read live (the bench's knob-flip lever works fleet-wide), and an
+    read live (a knob flipped on it takes effect fleet-wide), and an
     optional draft model passed through to every replica. With
     ``config.host_cache_bytes > 0`` the fleet creates ONE
     :class:`HostPageStore` with that (fleet-wide) budget and hands it
@@ -493,7 +492,7 @@ class ReplicaSet:
         self.cfg = cfg
         if isinstance(config, (list, tuple)):
             # The fleet's whole control surface — live knob flips
-            # (spec_decode, decode_rounds, ragged_attention: the bench
+            # (spec_decode, decode_rounds, ragged_attention: a caller
             # and the adaptive controller flip ONE object between
             # bursts), role_config derivation, the router's shared
             # page-size/bucket view, and FleetBackend.request_cost's
@@ -545,9 +544,7 @@ class ReplicaSet:
         # the whole lifecycle (a retired slot is never reused).
         self.roles = list(resolve_roles(self.fleet_config.role, k))
         self.states: list[str] = ["serving"] * k
-        tier_on = (
-            c.host_cache_bytes > 0 and c.share_prefix and c.prefill_chunk > 0
-        )
+        tier_on = c.host_cache_bytes > 0 and c.share_prefix
         self.store: HostPageStore | None = None
         if host_store is not None:
             # EXTERNAL store (PR 16): typically a RemotePageStore over
@@ -557,8 +554,7 @@ class ReplicaSet:
             if not tier_on:
                 raise ValueError(
                     "a shared host_store needs the offload tier "
-                    "engaged: host_cache_bytes > 0, share_prefix, "
-                    "prefill_chunk > 0"
+                    "engaged: host_cache_bytes > 0 and share_prefix"
                 )
             self.store = host_store
         elif tier_on:

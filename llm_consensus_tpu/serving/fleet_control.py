@@ -195,7 +195,7 @@ class FleetController:
         #: (PR 20): attached by the CLI after the gateway is built, it
         #: feeds the per-class SLO burn rates into elastic decisions.
         self.admission = None
-        # Discoverability: stats/bench surfaces reach the controller
+        # Discoverability: stats surfaces reach the controller
         # through the fleet they already hold.
         replicas.fleet_controller = self
 
@@ -257,7 +257,7 @@ class FleetController:
             "fleet", time.perf_counter(), decision=decision, **meta
         )
 
-    # -- the loop body (public: tests/bench tick synchronously) ---------
+    # -- the loop body (public: tests tick synchronously) ---------------
 
     def tick(self) -> None:
         cfg = self.config

@@ -22,8 +22,7 @@ the answer's substrate:
   with monotonic time and the PR-5 trace id. Evictions are counted and
   mirrored into ``gateway_flight_dropped_total`` so a truncated export
   is detectable. Recording is a bool check when disabled and one
-  lock+append when enabled — the ``bench.py --serve-flight-overhead``
-  A/B leg holds it to the PR-5 < 2% tok/s gate.
+  lock+append when enabled.
 - :class:`RequestLog` — a bounded ring of per-request serving
   summaries (TTFT, inter-token-gap percentiles, spec tokens accepted
   per round, restored-vs-prefilled header pages), fed at retirement,
@@ -96,7 +95,7 @@ class FlightEvent:
         }
 
 
-# Process-wide enable switch (the bench A/B lever). Disabled =>
+# Process-wide enable switch (``serve --no-flight``). Disabled =>
 # record() returns None before touching the lock; instrumentation
 # sites stay branch-free.
 _ENABLED = True

@@ -259,7 +259,7 @@ class ModelSet:
 
     def engage_matrix(self) -> dict[str, dict]:
         """Per-member engage state of every serving feature — the
-        construction audit's data, and the bench/README "engage matrix
+        construction audit's data, and the README's "engage matrix
         row per model". Each value is True (engaged), False (not
         configured), or a string naming WHY a configured feature will
         not engage (the batcher's own warnings fire for the same
@@ -271,25 +271,14 @@ class ModelSet:
             if m.draft_pair is not None:
                 if c.spec_k <= 0:
                     spec_state = "spec_k == 0"
-                elif c.steps_per_sync > 1:
-                    spec_state = "steps_per_sync > 1"
                 elif not c.spec_decode:
                     spec_state = "spec_decode flipped off"
                 else:
                     spec_state = True
-            rounds_state: object = False
-            if c.decode_rounds > 1:
-                rounds_state = (
-                    True
-                    if c.steps_per_sync == 1
-                    else "steps_per_sync > 1"
-                )
+            rounds_state = c.decode_rounds > 1
             tier_state: object = False
             if c.host_cache_bytes > 0:
-                if c.share_prefix and c.prefill_chunk > 0:
-                    tier_state = True
-                else:
-                    tier_state = "needs share_prefix + prefill_chunk > 0"
+                tier_state = True if c.share_prefix else "needs share_prefix"
             out[name] = {
                 "default": name == self.default,
                 "cross_model_spec": spec_state,

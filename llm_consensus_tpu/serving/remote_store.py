@@ -67,7 +67,7 @@ spoken by the server (it sniffs the first two bytes per frame: v2
 frames open with ``b"KV"``, which as a v1 length prefix would mean a
 >1 GiB frame, far past ``_MAX_FRAME``) and by
 ``RemotePageStore(wire="v1")``, which keeps the one-lock synchronous
-client as the measured baseline for the transport A/B bench leg.
+client as the baseline tests/test_kv_transfer.py compares with.
 
 Pickle headers are a FLEET-INTERNAL trust boundary (bind
 localhost/UDS, same deployment): the transport authenticates nothing,
@@ -499,7 +499,7 @@ class PageStoreServer:
 
     def _handle_v1(self, req: tuple) -> tuple:
         """PR-16 ops with pickled plane triples — kept verbatim so a
-        ``wire="v1"`` client (the bench baseline) exercises the exact
+        ``wire="v1"`` client exercises the exact
         old path."""
         op, args = req[0], req[1:]
         store = self.store
@@ -1144,8 +1144,8 @@ def main(argv: list[str] | None = None) -> int:
     """Standalone authoritative store process:
     ``python -m llm_consensus_tpu.serving.remote_store --budget-mb 256``
     prints one JSON line ``{"endpoint": ...}`` then serves until
-    SIGTERM/SIGINT — the cross-process half of the --serve-disagg
-    bench leg and of a real multi-host deployment."""
+    SIGTERM/SIGINT — the cross-process half of a multi-host
+    deployment."""
     import argparse
     import json
     import signal

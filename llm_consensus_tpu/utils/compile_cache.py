@@ -22,7 +22,7 @@ _CHECKOUT_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
 def enable_compilation_cache() -> str:
     """Turn the persistent compile cache on (idempotent); returns the
     directory in use. Every entry point that compiles for the device —
-    the CLI, ``bench.py``, ``chip_smoke.py``'s children — calls this one
+    the CLI, ``chip_smoke.py``'s children — calls this one
     function before its first compile."""
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:

@@ -28,8 +28,7 @@ poll, ``src/main.rs:449-454``; SURVEY.md §5). Two layers here:
 
 Process-wide tracing can be disabled entirely (:func:`set_enabled`,
 ``serve --no-trace``): :meth:`TraceStore.start` then returns ``None``
-and every downstream call site degrades to a no-op — the knob the
-``bench.py --serve-trace-overhead`` A/B leg toggles.
+and every downstream call site degrades to a no-op.
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@ test:
 
 1. Every metric family name REFERENCED by the serving stack
    (continuous batcher, batch scheduler, offload tier, gateway,
-   admission, coordinator, bench) — i.e. every string literal passed to
+   admission, coordinator) — i.e. every string literal passed to
    ``.counter( / .gauge( / .histogram( / .get(`` — must be DECLARED in
    ``llm_consensus_tpu/server/metrics.py`` (module-level family or the
    ``INSTANCE_FAMILIES`` manifest for per-instance-registry families).
@@ -47,7 +47,6 @@ SCANNED = (
     "llm_consensus_tpu/server/gateway.py",
     "llm_consensus_tpu/server/admission.py",
     "llm_consensus_tpu/consensus/coordinator.py",
-    "bench.py",
 )
 
 # A family registration with a literal name — reg.counter("name", ...)

@@ -1,13 +1,14 @@
 #!/bin/bash
 # Tier-1 verify gate — the ONE entry point for local and automated runs.
-# Wraps the ROADMAP.md "Tier-1 verify" command verbatim (CPU, -m 'not
-# slow'); keep the two in sync by editing ROADMAP.md first. Exit code is
-# pytest's; DOTS_PASSED echoes the per-test pass count the growth driver
-# compares against the seed.
+# Runs the suite as the driver does (CPU, -m 'not slow', six xdist
+# workers, one file a worker at a time, 1,470 s cap; ROADMAP.md under
+# "Tier-1 verify" has the driver's own line). Exit code is pytest's;
+# DOTS_PASSED echoes the per-test pass count the growth driver compares
+# against its floor.
 #
-#   --smoke   fast paged-serving slice (~2 min) for iterating on the
+#   --smoke   the paged-serving slice, one process, for iterating on the
 #             continuous batcher / page-table / shared-prefix-attention
-#             stack without the full ~15 min suite.
+#             stack without the whole suite.
 cd "$(dirname "$0")/.." || exit 1
 # Metrics-drift gate (PR 5): every family the serving stack references
 # must be declared in server/metrics.py and documented in the README
@@ -27,8 +28,10 @@ if [ "$1" = "--smoke" ]; then
     -q -p no:cacheprovider -p no:xdist -p no:randomly
 fi
 set -o pipefail
-rm -f /tmp/_t1.log
-timeout -k 10 3900 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log
+tmp=${TMPDIR:-/tmp}
+rm -f "$tmp/_t1.log" "$tmp/_t1.xml"
+timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 --dist loadfile --junitxml="$tmp/_t1.xml" -p no:randomly 2>&1 | tee "$tmp/_t1.log"
 rc=${PIPESTATUS[0]}
-echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)
+echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$tmp/_t1.log" | tr -cd . | wc -c)
+echo WORKERS_DOWN=$(grep -acE '\[gw[0-9]+\] node down' "$tmp/_t1.log")
 exit $rc

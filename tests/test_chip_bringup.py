@@ -171,8 +171,8 @@ def test_kernel_choice_follows_platform_and_mesh(monkeypatch):
         kernels.resolve_kernels(CFG, _mesh(2), shard_mapped=True).use_pallas
         is True
     )
-    # An explicit choice (tests forcing interpret mode, bench
-    # --no-pallas) passes through.
+    # An explicit choice (tests forcing interpret mode) passes
+    # through.
     forced = CFG.with_(use_pallas=False)
     assert kernels.resolve_kernels(forced).use_pallas is False
 

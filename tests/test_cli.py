@@ -276,6 +276,21 @@ def test_serve_parser_defaults_and_dispatch(monkeypatch):
     assert seen["argv"] == ["--port", "0"]
 
 
+def test_serve_refuses_prefill_chunk_zero(capsys):
+    """`serve --prefill-chunk 0` is an argparse error that says why:
+    there is no prefill path but the chunked one."""
+    from llm_consensus_tpu import cli
+
+    with pytest.raises(SystemExit) as exc:
+        cli.build_serve_parser().parse_args(["--prefill-chunk", "0"])
+    assert exc.value.code == 2
+    err = capsys.readouterr().err
+    assert "--prefill-chunk" in err
+    assert "chunked prefill is the only prefill path" in err
+    args = cli.build_serve_parser().parse_args(["--prefill-chunk", "16"])
+    assert args.prefill_chunk == 16
+
+
 def test_serve_subcommand_boots_and_drains_on_sigterm(tmp_path):
     """End-to-end `serve` process: ephemeral port, fake backend, one
     consensus request over HTTP, then SIGTERM -> graceful exit 0."""

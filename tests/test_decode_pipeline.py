@@ -5,10 +5,10 @@ is enqueued before program n's tokens are fetched, fed from the
 device-resident token output of the previous dispatch. These tests pin
 the acceptance contract — ``pipeline_depth=2`` (the default) serves
 byte-identical text to the serialized ``pipeline_depth=1`` baseline
-across the hard shapes (multi-token string stops mid-chunk, staggered
+across the hard shapes (multi-token string stops mid-window, staggered
 retirement shrinking a decode group, eviction + host-tier restore with
 programs in flight, concurrent same-prefix bursts), the PRNG stream is
-chunk- and depth-invariant, the flush/inflight metrics stay in lockstep
+window- and depth-invariant, the flush/inflight metrics stay in lockstep
 with ``stats()``, and a wedged in-flight fetch still goes stale on the
 liveness heartbeat.
 """
@@ -70,12 +70,13 @@ def _run_depth(params, depth, prompts, cfgkw=None, submit_kw=None, cfg=CFG):
 # ---------------------------------------------------------------------------
 
 
-def test_string_stop_mid_chunk_parity(params):
-    """Multi-token string stop landing mid-chunk: retirement lags one
-    pipeline stage AND up to steps_per_sync-1 tokens — the post-stop
+@pytest.mark.parametrize("rounds", [1, 2, 4])
+def test_string_stop_mid_chunk_parity(params, rounds):
+    """Multi-token string stop landing mid-window: retirement lags one
+    pipeline stage AND up to decode_rounds-1 rounds — the post-stop
     tokens decoded in flight must be discarded with the exact depth-1
     stop-trim semantics (text cut at the stop, honest num_tokens)."""
-    cfgkw = dict(_CCFG, steps_per_sync=4, max_new_tokens=16)
+    cfgkw = dict(_CCFG, decode_rounds=rounds, max_new_tokens=16)
     prompts = [_HEADER + "stop probe"]
     # Derive a stop the tiny random model actually emits: a 2-char
     # substring from the middle of the baseline's output (random
@@ -185,21 +186,22 @@ def test_eviction_and_host_restore_during_flight_parity(params):
 
 
 # ---------------------------------------------------------------------------
-# PRNG stream: chunk-size x depth invariance (greedy AND sampled)
+# PRNG stream: window x depth invariance (greedy AND sampled)
 # ---------------------------------------------------------------------------
 
 
-def test_prng_stream_chunk_and_depth_invariant(params):
+@pytest.mark.parametrize("rounds, depths", [(1, (2, 3)), (4, (1, 2))])
+def test_prng_stream_chunk_and_depth_invariant(params, rounds, depths):
     """The per-token PRNG stream is (seed, index) — independent of how
-    many steps ride one program (steps_per_sync) AND how many programs
+    many rounds ride one program (decode_rounds) AND how many programs
     ride in flight (pipeline_depth)."""
 
-    def run(sync, depth):
+    def run(rounds, depth):
         b = ContinuousBatcher(
             CFG,
             params,
             config=ContinuousConfig(
-                **dict(_CCFG, steps_per_sync=sync),
+                **dict(_CCFG, decode_rounds=rounds),
                 pipeline_depth=depth,
             ),
         )
@@ -214,31 +216,30 @@ def test_prng_stream_chunk_and_depth_invariant(params):
             b.close()
 
     want = run(1, 1)
-    assert all(
-        run(sync, depth) == want
-        for sync, depth in ((1, 2), (4, 1), (4, 2), (1, 3))
-    )
+    for depth in depths:
+        assert run(rounds, depth) == want, depth
 
 
 # ---------------------------------------------------------------------------
-# Page-overshoot budget: exact-fit tables absorb depth*chunk-1 tokens
+# Page-overshoot budget: exact-fit tables absorb depth*rounds-1 tokens
 # ---------------------------------------------------------------------------
 
 
-def test_overshoot_budget_tight_pages(params):
+@pytest.mark.parametrize("rounds", [1, 4])
+def test_overshoot_budget_tight_pages(params, rounds):
     """A config whose pages_per_seq is sized EXACTLY for the deepest
-    overshoot (bucket + max_new + depth*chunk - 1): rows that finish at
-    the first token of a chunk keep writing through the in-flight
-    programs without escaping their reservation — completion, parity,
-    and a clean pool prove the budget holds."""
+    overshoot (bucket + max_new + depth*rounds - 1): rows that finish
+    inside a window keep decoding through the in-flight programs
+    without escaping their reservation — completion, parity, and a
+    clean pool prove the budget holds."""
     kw = dict(
         max_slots=2,
         page_size=16,
         n_pages=16,
-        pages_per_seq=2,  # ceil((16 + 8 + 2*4 - 1) / 16) = 2
+        pages_per_seq=2,  # ceil((16 + 8 + 2*4 - 1) / 16) = 2 at R = 4
         max_new_tokens=8,
         seq_buckets=(16,),
-        steps_per_sync=4,
+        decode_rounds=rounds,
         prefill_chunk=16,
         share_prefix=False,
     )
@@ -268,9 +269,9 @@ def test_overshoot_budget_tight_pages(params):
 
 def test_pipeline_metrics_exported_and_lockstep(params):
     """gateway_dispatch_inflight and gateway_pipeline_flushes_total are
-    declared on the process registry and mirrored in stats(); the dense
-    (prefill_chunk=0) path flushes per admission that lands while
-    programs are in flight."""
+    declared on the process registry and mirrored in stats(); a CoW
+    boundary copy flushes when its admission lands while programs are
+    in flight."""
     from llm_consensus_tpu.server.metrics import (
         DISPATCH_INFLIGHT,
         PIPELINE_FLUSHES,
@@ -282,21 +283,21 @@ def test_pipeline_metrics_exported_and_lockstep(params):
         CFG,
         params,
         config=ContinuousConfig(
-            **dict(
-                _CCFG, prefill_chunk=0, share_prefix=False,
-                max_new_tokens=128, pages_per_seq=12,
-            ),
+            **dict(_CCFG, max_new_tokens=128, pages_per_seq=13),
             pipeline_depth=2,
         ),
     )
+    # Common run = BOS + 40 bytes = 41 ids: 2 full pages + a 9-token
+    # run into page 3, which the second admission COPIES.
+    common = "Forty common characters of shared text."
     try:
-        first = b.submit("a long-running request", max_new_tokens=128)
+        first = b.submit(common + " runs long", max_new_tokens=128)
         # Wait until the first request is decoding with a program in
-        # flight, then admit a second: its dense prefill MUST flush.
+        # flight, then admit a second: its boundary copy MUST flush.
         deadline = time.time() + 60
         while b.stats()["decode_steps"] < 2 and time.time() < deadline:
             time.sleep(0.01)
-        second = b.submit("late arrival", max_new_tokens=4)
+        second = b.submit(common + " arrives late", max_new_tokens=4)
         second.result(timeout=120)
         first.result(timeout=120)
         # Futures resolve DURING fetch bookkeeping; the loop drains the
@@ -307,6 +308,7 @@ def test_pipeline_metrics_exported_and_lockstep(params):
         st = b.stats()
     finally:
         b.close()
+    assert st["prefix_pages_copied"] == 1
     assert st["pipeline_flushes"] >= 1
     assert PIPELINE_FLUSHES.value - before == st["pipeline_flushes"]
     assert st["dispatch_inflight"] == 0  # drained at rest
@@ -355,7 +357,7 @@ def test_wedged_inflight_fetch_flips_heartbeat(params):
         params,
         config=ContinuousConfig(
             **dict(
-                _CCFG, prefill_chunk=0, share_prefix=False,
+                _CCFG, share_prefix=False,
                 max_new_tokens=256, pages_per_seq=20,
             ),
             pipeline_depth=2,

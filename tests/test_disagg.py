@@ -14,8 +14,9 @@ pages re-prefilled on the decode side, a roled fleet over a DEAD store
 still completes every request with a fresh heartbeat, the controller's
 restore-batch knob follows the overhead EWMA, the gateway's
 ``/debug/chains`` probe and cross-host peer forwarding route by
-residency, and the ``bench.py --serve-disagg`` CPU leg gates the whole
-stack in a subprocess.
+residency, and the store served by a process of its own
+(``python -m llm_consensus_tpu.serving.remote_store``) hands pages
+across the process boundary and, killed, turns into misses.
 """
 
 import json
@@ -584,32 +585,58 @@ def test_peer_forwarding_unreachable_peer_502s():
 
 
 # ---------------------------------------------------------------------------
-# The bench disaggregation leg (subprocess, CPU smoke sizes)
+# The store as a process of its own
 # ---------------------------------------------------------------------------
 
 
-def test_bench_serve_disagg_cpu_leg(tmp_path: Path):
-    """Acceptance: prefill+decode roles over a remote store in a REAL
-    separate process, byte-identical text vs the mixed-role control,
-    >= 1 cross-process handoff with zero re-prefilled header pages,
-    then the store process is killed and the degrade burst completes
-    with no 429s and /readyz still ready."""
-    out = tmp_path / "disagg.json"
-    r = subprocess.run(
+def test_store_process_serves_pages_and_killed_degrades_to_misses():
+    """``python -m llm_consensus_tpu.serving.remote_store``: its first
+    line names the endpoint, a page put by one client is read back bit
+    for bit by another (the handoff's path between replicas in
+    different processes), and once the process is KILLED a read is a
+    miss with the error counted — never a hang, never an exception."""
+    import ml_dtypes
+
+    proc = subprocess.Popen(
         [
-            sys.executable, "bench.py", "--tiny", "--cpu",
-            "--serve-disagg", "--serve-requests", "8",
-            "--serve-slots", "2", "--new-tokens", "6",
-            "--prompt-len", "64", "--serve-chunk", "1",
-            "--serve-prefill-chunk", "64", "--out", str(out),
+            sys.executable, "-m", "llm_consensus_tpu.serving.remote_store",
+            "--budget-mb", "8", "--port", "0",
         ],
         cwd=Path(__file__).resolve().parent.parent,
-        capture_output=True,
+        stdout=subprocess.PIPE,
         text=True,
-        timeout=900,
     )
-    assert r.returncode == 0, r.stderr[-2000:]
-    payload = json.loads(out.read_text())
-    assert payload["status"] == "ok"
-    assert payload["unit"] == "tokens/sec"
-    assert payload["value"] > 0
+    writer = reader = None
+    try:
+        endpoint = json.loads(proc.stdout.readline())["endpoint"]
+        writer = RemotePageStore(endpoint, timeout_s=10.0)
+        reader = RemotePageStore(endpoint, timeout_s=2.0, retry_s=0.05)
+        key = ("chain", 0, 7, 42)
+        planes = (
+            np.arange(64, dtype=np.float32)
+            .astype(ml_dtypes.bfloat16)
+            .reshape(8, 8),
+            np.arange(512, dtype=np.int8).reshape(4, 128),
+        )
+        resident, demoted, dropped = writer.put_counted(key, planes)
+        assert (resident, demoted, dropped) == (True, 1, 0)
+        got = reader.get(key)
+        assert got is not None and len(got) == len(planes)
+        for a, b in zip(planes, got):
+            assert b.dtype == a.dtype and a.tobytes() == b.tobytes()
+        assert reader.errors == 0
+
+        proc.kill()
+        proc.wait(timeout=30)
+        e0 = _errors_total()
+        assert reader.get(key) is None
+        assert reader.errors >= 1
+        assert _errors_total() > e0
+    finally:
+        for client in (writer, reader):
+            if client is not None:
+                client.close()
+        if proc.poll() is None:
+            proc.kill()
+        proc.wait(timeout=30)
+        proc.stdout.close()

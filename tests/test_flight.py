@@ -21,13 +21,11 @@ Contract layers:
 - GATEWAY: ``/debug/flight`` (+ ``?format=chrome``), ``/debug/requests``
   (+ ``?id=`` by request OR trace id), response ``meta``, and the shed
   event on a 429.
-- CI: the ``bench.py --serve-flight-overhead`` dual tok/s gate.
+- THE SWITCH: with the recorder off (``serve --no-flight``) a burst
+  serves the same text and records nothing.
 """
 
 import json
-import re
-import subprocess
-import sys
 import threading
 import time
 from pathlib import Path
@@ -363,14 +361,12 @@ def test_cost_model_fused_vs_split_kv_parity(params):
     (chunks ride the decode dispatch) and split (standalone chunk
     programs) must model identical KV token totals — while the weight
     term moves with the program count, which is exactly what fusion
-    saves. depth/sync pinned to 1 so retirement overshoot can't smear
+    saves. Depth pinned to 1 so retirement overshoot can't smear
     row-steps across the legs."""
     b = ContinuousBatcher(
         CFG,
         params,
-        config=ContinuousConfig(
-            **_CCFG, pipeline_depth=1, steps_per_sync=1
-        ),
+        config=ContinuousConfig(**_CCFG, pipeline_depth=1),
     )
     prompts = [_HEADER + f"tail {i}" for i in range(4)]
 
@@ -443,7 +439,6 @@ def test_cost_model_spec_on_off_write_parity(params):
         config=ContinuousConfig(
             **dict(_CCFG, max_new_tokens=new_tokens),
             pipeline_depth=1,
-            steps_per_sync=1,
             spec_k=k,
         ),
         draft=(CFG, params),  # self-draft: the acceptance-1.0 ceiling
@@ -725,39 +720,34 @@ def test_gateway_shed_records_flight_event(params):
 
 
 # ---------------------------------------------------------------------------
-# CI: the bench A/B leg and the bench-history no-data rule
+# The switch: a burst with the recorder off
 # ---------------------------------------------------------------------------
 
 
-def test_bench_serve_flight_overhead_cpu_ab_leg(tmp_path):
-    """PR-10 acceptance: --serve-flight-overhead passes its tok/s gate
-    with the recorder on (PR-5 dual gate, loadavg-aware escalation),
-    emits the machine-readable status field, and lands atomically."""
-    out = tmp_path / "reports" / "flight_ab.json"
-    r = subprocess.run(
-        [
-            sys.executable, "bench.py", "--tiny", "--cpu",
-            "--serve-flight-overhead", "--serve-requests", "6",
-            "--serve-slots", "2", "--new-tokens", "8",
-            "--prompt-len", "64", "--serve-chunk", "1",
-            "--serve-prefill-chunk", "64", "--out", str(out),
-        ],
-        cwd=ROOT,
-        capture_output=True,
-        text=True,
-        timeout=570,
-    )
-    assert r.returncode == 0, (r.stdout[-500:], r.stderr[-2000:])
-    payload = json.loads(out.read_text())
-    assert payload == json.loads(r.stdout.strip().splitlines()[-1])
-    assert payload["value"] > 0
-    assert payload["status"] == "ok"  # the machine-readable satellite
-    m = payload["metric"]
-    assert "flight recorder ON" in m
-    assert int(re.search(r"(\d+) events", m).group(1)) > 0
-    # rc 0 means the DUAL gate held (best-vs-best OR paired median —
-    # under box noise the best ratio alone can dip while the paired
-    # median clears, so no second hard floor here); vs_baseline stays
-    # a sanity check that both legs measured something.
-    assert payload["vs_baseline"] > 0
-    assert list(out.parent.glob("*.tmp.*")) == []
+def test_burst_with_the_recorder_off_serves_the_same_text(params):
+    """One batcher, the recorder flipped between two identical bursts:
+    on, the burst leaves program, admit and token events in the ring;
+    off (``serve --no-flight``), every instrumentation site gets None
+    back and the burst serves the same text and counts, recording
+    nothing."""
+    prompts = [_HEADER + f"switch {i}" for i in range(3)]
+    rec = flight.flight_recorder()
+    b = ContinuousBatcher(CFG, params, config=ContinuousConfig(**_CCFG))
+    try:
+        rec.clear()
+        on = [(r.text, r.num_tokens) for r in _serve(b, prompts)]
+        _quiesce(b)
+        kinds_on = {e.kind for e in rec.events()}
+        rec.clear()
+        flight.set_enabled(False)
+        try:
+            off = [(r.text, r.num_tokens) for r in _serve(b, prompts)]
+            _quiesce(b)
+            recorded_off = len(rec)
+        finally:
+            flight.set_enabled(True)
+    finally:
+        b.close()
+    assert {"program", "admit"} <= kinds_on, kinds_on
+    assert recorded_off == 0
+    assert off == on

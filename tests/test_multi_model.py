@@ -462,32 +462,101 @@ def test_modelset_low_coverage_pairing_warns_not_raises(params, caplog):
         ms.close()
 
 
-def test_bench_serve_multi_model_cpu_ab_leg():
-    """The CPU-run A/B leg (acceptance): debate-shaped traffic through
-    a 2-member ModelSet, identical consensus decisions spec on/off,
-    cross-model accepts witnessed, tok/s gate passes, rc 0."""
-    import subprocess
-    import sys
-    from pathlib import Path
-
-    r = subprocess.run(
+def test_modelset_pair_decides_alike_spec_on_and_off(params):
+    """A judge that drafts from a vocab-permuted twin of itself under
+    another tokenizer, paired by the ModelSet: the judge's greedy
+    answers are the same bytes with its speculation on and off, and
+    with it on the pair's accepted draft tokens crossed the vocabulary
+    map (the member's own counter)."""
+    vmap = _xmodel_map()
+    ms = ModelSet(
         [
-            sys.executable, "bench.py", "--tiny", "--cpu",
-            "--serve-multi-model", "--serve-requests", "4",
-            "--serve-slots", "3", "--new-tokens", "8",
-            "--prompt-len", "96", "--serve-prefill-chunk", "64",
-            "--k-spec", "3", "--mm-ab-rounds", "1",
+            ModelSpec(
+                name="large", cfg=CFG, params=params,
+                tokenizer=ByteTokenizer(),
+                config=ContinuousConfig(**_CCFG, spec_k=3),
+                draft_from="small",
+                # The twin is DEFINED by this map, padded tail included.
+                vocab_map=vmap,
+            ),
+            ModelSpec(
+                name="small", cfg=CFG,
+                params=_twin_params(params, vmap.d2t),
+                tokenizer=ShiftedByteTokenizer(),
+                config=ContinuousConfig(**_CCFG),
+            ),
         ],
-        cwd=Path(__file__).resolve().parent.parent,
-        capture_output=True,
-        text=True,
-        timeout=900,
+        default="large",
     )
-    assert r.returncode == 0, f"stdout={r.stdout}\nstderr={r.stderr}"
-    assert "decisions unchanged=True" in r.stdout
-    assert "cross-model accepted draft tokens" in r.stdout
-    assert '"unit": "tokens/sec"' in r.stdout
-    assert '"status": "ok"' in r.stdout
+    be = ModelSetBackend(ms)
+    judge = ms.members["large"].engine
+    sp = SamplingParams(max_new_tokens=8, temperature=0.0)
+    header = "Panel shared header, forty characters xx: "
+
+    def answers(tag):
+        outs = asyncio.run(
+            be.generate_batch(
+                [
+                    GenerationRequest(header + f"{tag} {i}", sp, model="large")
+                    for i in range(3)
+                ]
+            )
+        )
+        return [o.text for o in outs]
+
+    def accepted():
+        return ms.stats()["per_model"]["large"]["engine"][
+            "spec_cross_model_accepted_tokens"
+        ]
+
+    try:
+        assert ms.engage_matrix()["large"]["cross_model_spec"] is True
+        on = answers("case")
+        crossed = accepted()
+        judge.config.spec_decode = False
+        assert (
+            ms.engage_matrix()["large"]["cross_model_spec"]
+            == "spec_decode flipped off"
+        )
+        off = answers("case")
+        assert accepted() == crossed  # nothing drafts while it is off
+    finally:
+        asyncio.run(be.close())
+    assert on == off
+    assert crossed > 0
+
+
+def test_engage_matrix_says_what_is_engaged_and_why_not(params):
+    """One row a member: a configured feature reads True, one that is
+    not configured False, and one that cannot engage names the reason
+    (the host tier without prefix sharing)."""
+    ms = ModelSet(
+        [
+            ModelSpec(
+                name="windows", cfg=CFG, params=params,
+                config=ContinuousConfig(
+                    **_CCFG, decode_rounds=4, host_cache_bytes=1 << 20
+                ),
+            ),
+            ModelSpec(
+                name="plain", cfg=CFG, params=params,
+                config=ContinuousConfig(
+                    **{**_CCFG, "share_prefix": False},
+                    host_cache_bytes=1 << 20,
+                ),
+            ),
+        ],
+        default="windows",
+    )
+    try:
+        eng = ms.engage_matrix()
+    finally:
+        ms.close()
+    assert eng["windows"]["decode_rounds"] is True
+    assert eng["windows"]["host_tier"] is True
+    assert eng["windows"]["cross_model_spec"] is False
+    assert eng["plain"]["decode_rounds"] is False
+    assert eng["plain"]["host_tier"] == "needs share_prefix"
 
 
 def test_modelset_duplicate_and_unknown_member_validation(params):

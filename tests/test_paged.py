@@ -707,15 +707,16 @@ def test_mesh_batcher_rejects_indivisible_shapes():
         )
 
 
-def test_continuous_chunk_size_invariance():
-    """steps_per_sync AND pipeline_depth are pure throughput knobs:
-    chunk 1/4 x depth 1/2 all serve identical text for the same greedy
+@pytest.mark.parametrize("rounds", [1, 4])
+def test_continuous_chunk_size_invariance(rounds):
+    """decode_rounds AND pipeline_depth are pure throughput knobs:
+    rounds 1/4 x depth 1/2 all serve identical text for the same greedy
     AND sampled requests (the per-token PRNG stream is (seed, index),
-    independent of how many steps ride one program or how many
+    independent of how many rounds ride one program or how many
     programs ride in flight)."""
     params = _params()
 
-    def run(chunk, depth):
+    def run(rounds, depth):
         b = ContinuousBatcher(
             CFG,
             params,
@@ -726,7 +727,7 @@ def test_continuous_chunk_size_invariance():
                 pages_per_seq=8,
                 max_new_tokens=8,
                 seq_buckets=(16, 32, 64),
-                steps_per_sync=chunk,
+                decode_rounds=rounds,
                 pipeline_depth=depth,
             ),
         )
@@ -741,6 +742,6 @@ def test_continuous_chunk_size_invariance():
             b.close()
 
     want = run(1, 1)
-    assert run(4, 1) == want
-    assert run(1, 2) == want
-    assert run(4, 2) == want
+    if rounds > 1:
+        assert run(rounds, 1) == want
+    assert run(rounds, 2) == want

@@ -4,7 +4,7 @@ Covers the host-side allocator (PagePool), the prefix radix tree
 (PrefixRegistry), the copy_page device op, and the ContinuousBatcher's
 shared-prefix admission path: share/CoW/release lifecycle, boundary-page
 copy, pool exhaustion under sharing, and decode-output parity against
-the legacy blocking dense-prefill path (the acceptance criterion: page
+the engine's whole-prompt dense prefill (the acceptance criterion: page
 sharing + chunked prefill must be output-identical to per-request dense
 prefill, CPU, seeded).
 """
@@ -197,23 +197,41 @@ def _serve(batcher, prompts, **kw):
     return [f.result(timeout=120) for f in futs]
 
 
+def _dense_reference(params, prompts):
+    """The engine's one-shot generation: each prompt prefilled whole
+    into a dense KVCache, nothing paged, chunked or shared."""
+    eng = InferenceEngine(
+        CFG, params,
+        engine_config=EngineConfig(
+            max_new_tokens=_CCFG["max_new_tokens"],
+            seq_buckets=_CCFG["seq_buckets"],
+        ),
+    )
+    return [
+        r.text
+        for r in eng.generate_texts(
+            prompts, max_new_tokens=_CCFG["max_new_tokens"]
+        )
+    ]
+
+
+@pytest.mark.parametrize("width", [0, -16])
+def test_prefill_chunk_under_one_is_refused(width):
+    """Chunked prefill is the only prefill path: a width under one
+    token is refused where the config is built."""
+    with pytest.raises(ValueError, match="only prefill path"):
+        ContinuousConfig(prefill_chunk=width)
+
+
 def test_shared_prefix_parity_and_single_prefill():
     """The acceptance criterion: N same-prefix requests served with page
-    sharing + chunked prefill produce IDENTICAL text to the legacy
-    blocking dense per-request prefill path, and the shared prefix's
-    full pages prefill once — every later admission maps them
-    (prefix_pages_shared counts 2 pages x (N-1) admissions)."""
+    sharing + chunked prefill produce IDENTICAL text to per-request
+    dense prefill (the engine's), and the shared prefix's full pages
+    prefill once — every later admission maps them
+    (prefix_pages_shared counts 3 pages x (N-1) admissions)."""
     params = _params()
     prompts = [_HEADER + f"Q{i}: what is {i}+{i}?" for i in range(6)]
-
-    legacy = ContinuousBatcher(
-        CFG, params,
-        config=ContinuousConfig(**_CCFG, prefill_chunk=0, share_prefix=False),
-    )
-    try:
-        want = [r.text for r in _serve(legacy, prompts)]
-    finally:
-        legacy.close()
+    want = _dense_reference(params, prompts)
 
     shared = ContinuousBatcher(
         CFG, params,
@@ -249,15 +267,7 @@ def test_boundary_page_copy_on_write():
     # 9-token boundary run into page 3 (>= min_boundary pg//4 = 4).
     common = "Forty common characters of shared text."  # 40 chars
     prompts = [common + " tail one", common + " tail two"]
-
-    legacy = ContinuousBatcher(
-        CFG, params,
-        config=ContinuousConfig(**_CCFG, prefill_chunk=0, share_prefix=False),
-    )
-    try:
-        want = [r.text for r in _serve(legacy, prompts)]
-    finally:
-        legacy.close()
+    want = _dense_reference(params, prompts)
 
     shared = ContinuousBatcher(
         CFG, params,

@@ -6,7 +6,8 @@ the weight stacks and both KV pools resident. Two contracts, on the CPU
 with ``test-tiny``:
 
 - STRUCTURE: in each program's jaxpr (and in the batcher's multi-round
-  program, which nests the decode step in an outer scan) the layer scan
+  program, which nests the decode step in an outer scan of the rounds;
+  its one-step program keeps a scan of length 1 there) the layer scan
   carries both pools, scans nothing but the layer ids and stacks no
   output; compiled, the program's temp stays under one pool's size. A
   pool that enters as ``xs`` is copied out layer by layer for the
@@ -112,23 +113,33 @@ def _program(name: str, cfg, params, cache):
 # ---------------------------------------------------------------------------
 
 
-def _scans(jaxpr, found: list) -> list:
-    """Every ``scan`` equation of ``jaxpr``, nested ones included."""
+def _scans(jaxpr, depth: int = 0) -> list:
+    """``(equation, number of scans around it)`` for every ``scan`` of
+    ``jaxpr``, nested ones included."""
+    found = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            found.append(eqn)
+        is_scan = eqn.primitive.name == "scan"
+        if is_scan:
+            found.append((eqn, depth))
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else (value,):
                 inner = getattr(sub, "jaxpr", sub)
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    _scans(inner, found)
+                    found += _scans(inner, depth + is_scan)
     return found
 
 
-def _rounds_trace(params, cache):
-    """The batcher's multi-round program, traced with arguments shaped
-    as ``_dispatch`` builds them. The batcher serves nothing here."""
+def _scans_around(jaxpr, length: int) -> list:
+    """For every scan of ``length`` in ``jaxpr``: how many scans
+    enclose it."""
+    return [d for e, d in _scans(jaxpr) if e.params["length"] == length]
+
+
+def _batcher_trace(params, cache, rounds: int):
+    """The batcher's multi-round program (``rounds`` > 1) or its
+    one-step decode program, traced with arguments shaped as
+    ``_dispatch`` builds them. The batcher serves nothing here."""
     b = ContinuousBatcher(
         CFG, params,
         config=ContinuousConfig(
@@ -139,16 +150,38 @@ def _rounds_trace(params, cache):
     )
     try:
         i32 = partial(jnp.zeros, dtype=jnp.int32)
-        return b._jit_rounds.trace(
-            ROUNDS, params, cache, i32((SLOTS,)),
+        rows = (
+            params, cache, i32((SLOTS,)),
             jnp.zeros((SLOTS,), jnp.uint32), i32((SLOTS,)),
             jnp.ones((SLOTS,), jnp.float32), i32((SLOTS,)),
             jnp.ones((SLOTS,), jnp.float32), False,
-            jnp.full((SLOTS,), ROUNDS, jnp.int32),
+        )
+        if rounds == 1:
+            return b._jit_decode.trace(*rows, None)
+        return b._jit_rounds.trace(
+            rounds, *rows,
+            jnp.full((SLOTS,), rounds, jnp.int32),
             jnp.full((SLOTS, _SCREEN_W), -1, jnp.int32), None,
         )
     finally:
         b.close()
+
+
+def test_decode_step_program_is_one_step_under_a_scan_of_length_one(params):
+    """``jit_decode_step`` applies the decode step once, and does so
+    under a ``lax.scan`` of length 1: a measured decision, not a
+    leftover (without it the chat cell's set-up took ~2 s longer on the
+    chip's host though XLA compiles the same program: PERF.md, Findings
+    PR 30). Whoever removes it measures ``setup_s`` beside it. The
+    multi-round program's enclosing scan is the rounds'."""
+    cache = _cache(64, jnp.float32)
+    step = _batcher_trace(params, cache, 1)
+    assert step.lower().as_text().startswith("module @jit_decode_step")
+    assert _scans_around(step.jaxpr.jaxpr, 1) == [0]
+    assert _scans_around(step.jaxpr.jaxpr, CFG.n_layers) == [1]
+    rounds = _batcher_trace(params, cache, ROUNDS)
+    assert _scans_around(rounds.jaxpr.jaxpr, CFG.n_layers) == [1]
+    assert _scans_around(rounds.jaxpr.jaxpr, ROUNDS) == [0]
 
 
 @pytest.mark.parametrize(
@@ -160,13 +193,13 @@ def test_layer_scan_carries_the_pools_and_stacks_nothing(params, name):
     # the backend's own making that the TPU compiler does not have.
     cache = _cache(1024, jnp.float32)
     if name == "rounds_step":
-        traced = _rounds_trace(params, cache)
+        traced = _batcher_trace(params, cache, ROUNDS)
     else:
         traced = jax.jit(
             _program(name, CFG, params, cache), donate_argnums=(1,)
         ).trace(params, cache)
     layer_scans = [
-        e for e in _scans(traced.jaxpr.jaxpr, [])
+        e for e, _ in _scans(traced.jaxpr.jaxpr)
         if e.params["length"] == CFG.n_layers
     ]
     assert len(layer_scans) == 1, [e.params["length"] for e in layer_scans]

@@ -341,7 +341,7 @@ def test_batcher_grouped_attention_parity_and_metrics():
 
     base = ContinuousBatcher(
         CFG, params,
-        config=ContinuousConfig(**_CCFG, prefix_attention=False),
+        config=ContinuousConfig(**{**_CCFG, "share_prefix": False}),
     )
     try:
         want = [r.text for r in _serve(base, prompts)]
@@ -354,7 +354,7 @@ def test_batcher_grouped_attention_parity_and_metrics():
     before = SHARED_KV_BYTES_SAVED.value
     grouped = ContinuousBatcher(
         CFG.with_(use_pallas=True), params,
-        config=ContinuousConfig(**_CCFG, prefix_attention=True),
+        config=ContinuousConfig(**_CCFG),
     )
     try:
         got = [r.text for r in _serve(grouped, prompts)]
@@ -382,10 +382,13 @@ def test_batcher_grouped_boundary_page_and_shrinking_group():
                common + " tail three"]
     caps = [6, 2, 4]  # retire at different decode steps
 
-    def run(cfg, prefix_attention):
+    def run(cfg, share_prefix):
+        # share_prefix=False: no page is mapped, so no group can form.
         b = ContinuousBatcher(
             cfg, params,
-            config=ContinuousConfig(**_CCFG, prefix_attention=prefix_attention),
+            config=ContinuousConfig(
+                **{**_CCFG, "share_prefix": share_prefix}
+            ),
         )
         try:
             # Serialize the first admission so the boundary content is
@@ -445,15 +448,14 @@ def test_grouped_attention_survives_host_round_trip():
         max_new_tokens=4,
         seq_buckets=(16, 32, 64),
         prefill_chunk=16,
-        share_prefix=True,
     )
 
-    def run(cfg, prefix_attention, host_cache_bytes):
+    def run(cfg, share_prefix, host_cache_bytes):
         b = ContinuousBatcher(
             cfg, params,
             config=ContinuousConfig(
                 **kw,
-                prefix_attention=prefix_attention,
+                share_prefix=share_prefix,
                 host_cache_bytes=host_cache_bytes,
             ),
         )
@@ -467,7 +469,7 @@ def test_grouped_attention_survives_host_round_trip():
                 r.text for r in _serve(b, fillers2, max_new_tokens=4)
             ]
             mid = b.stats()
-            if prefix_attention:
+            if share_prefix:
                 # Scope the lifetime peak to the re-vote round: the
                 # worker is idle here (all futures resolved, queue
                 # empty), and a fresh peak proves the group RE-FORMED

@@ -83,8 +83,7 @@ def _spy_on(batcher, seen: set) -> None:
     for attr in ("_jit_decode", "_jit_rounds", "_jit_spec"):
         if hasattr(batcher, attr):
             setattr(batcher, attr, _Spy(getattr(batcher, attr), seen))
-    for getter in ("_chunk_fn", "_fused_fn", "_prefill_fn", "_chunk_fn_d",
-                   "_prefill_fn_d"):
+    for getter in ("_chunk_fn", "_fused_fn", "_chunk_fn_d"):
         real, spies = getattr(batcher, getter), {}
 
         def spied(*key, _real=real, _spies=spies):
@@ -116,13 +115,10 @@ def _modules_of(params, prompts=PROMPTS, draft=None, **cfgkw) -> set:
         ({}, False,
          {"jit_decode_step", "jit_fused_step", "jit_prefill_chunk"}),
         ({"decode_rounds": 2}, False, {"jit_rounds_step"}),
-        ({"prefill_chunk": 0}, False, {"jit_prefill_dense"}),
         ({"spec_k": 2}, True,
          {"jit_verify_step", "jit_prefill_chunk_draft"}),
-        ({"spec_k": 2, "prefill_chunk": 0}, True,
-         {"jit_prefill_dense_draft"}),
     ],
-    ids=["chunked", "rounds", "dense", "draft", "draft-dense"],
+    ids=["chunked", "rounds", "draft"],
 )
 def test_step_programs_lower_to_named_modules(
     params, cfgkw, with_draft, expected
