@@ -383,7 +383,9 @@ def kernels_child(dry_run: bool) -> int:
     def ragged(name, **kw):
         cases.append((
             f"ragged_attention[{name}]", parity.ATTENTION_TOL,
-            lambda: max(parity.ragged_attention_error(**base, **kw).values()),
+            lambda: max(
+                parity.ragged_attention_error(**{**base, **kw}).values()
+            ),
         ))
 
     grouped = [max(f, 2 * pg + 5) for f in fills]  # members past the run
@@ -414,6 +416,13 @@ def kernels_child(dry_run: bool) -> int:
     ragged("verify nq=5, layer 2 of 3 stacked",
            valid_len=[max(f, 5) for f in fills], nq=5, window=win_small,
            layer=(2, 3))
+    # Three chunk lanes (PR 31), each over a table of its own at its own
+    # fill, one of them dead, beside grouped rows on the stacked pools.
+    lanes = dict(cq=cq, chunk_start=[pg + 11, -cq, 2 * pg],
+                 n_pages=(b + 3) * p_per + 1)
+    ragged("grouped+3 lanes, layer 2 of 3 stacked", valid_len=grouped,
+           group_rows=(0, 2, 3, 5), shared_pages=2, window=window,
+           layer=(2, 3), **lanes)
     # A latent (MLA) pool: one 640-lane key a token for 16 heads, the
     # value its first 512 lanes (DeepSeek-V2-Lite's, padded; toy sizes
     # in the dry run), on the stacked pool as the layer scan calls it.
@@ -424,11 +433,14 @@ def kernels_child(dry_run: bool) -> int:
         ("grouped+chunk, layer 1 of 2 stacked", dict(
             valid_len=grouped, cq=cq, chunk_start=pg + 11,
             group_rows=(0, 2, 3, 5), shared_pages=2, layer=(1, 2))),
+        ("grouped+3 lanes, layer 1 of 2 stacked", dict(
+            valid_len=grouped, group_rows=(0, 2, 3, 5), shared_pages=2,
+            layer=(1, 2), **lanes)),
     ):
         cases.append((
             f"ragged_attention[latent {name}]", parity.ATTENTION_TOL,
             lambda kw=kw: max(parity.ragged_attention_error(
-                **{**base, "hkv": 1, **lat}, **kw).values()),
+                **{**base, "hkv": 1, **lat, **kw}).values()),
         ))
     # The grouped expert matmul at DeepSeek-V2-Lite's two shapes: 8
     # experts of which two hold no row and one holds three tiles.

@@ -1580,8 +1580,11 @@ def _attn_paged(
     don't divide (``ragged_mesh_shardable``) the XLA reference runs
     instead — GSPMD shards it — so every serving feature still engages.
 
-    q_dec: [B, H, D]; q_chunk: [C, H, D] or None; returns out_dec
-    [B, H, D] (and out_chunk [C, H, D] when q_chunk is given).
+    q_dec: [B, H, D]; q_chunk: [L, C, H, D] (L chunk lanes, with
+    ``chunk_table`` [L, P] and ``chunk_start`` [L]; -C marks a dead
+    lane) or None; returns out_dec [B, H, D] (and out_chunk [L, C, H, D]
+    when q_chunk is given). The mesh kernel resolves ONE lane on its
+    owner shard, so a mesh program carries one.
     """
     window = cfg.sliding_window
     # An MLA pool is one latent plane [L, n_pages, page, lanes]: q is in
@@ -1619,11 +1622,20 @@ def _attn_paged(
             ragged_paged_attention_sharded,
         )
 
-        return ragged_paged_attention_sharded(
+        if q_chunk is None:
+            lane = {}
+        elif q_chunk.shape[0] == 1:
+            lane = dict(
+                q_chunk=q_chunk[0], chunk_table=chunk_table[0],
+                chunk_start=chunk_start[0],
+            )
+        else:
+            raise ValueError("the mesh kernel takes one chunk lane")
+        out = ragged_paged_attention_sharded(
             mesh, q_dec, k_pool, v_pool, tables, valid,
-            q_chunk=q_chunk, chunk_table=chunk_table,
-            chunk_start=chunk_start, groups=gtuple, window=window,
+            groups=gtuple, window=window, **lane,
         )
+        return (out[0], out[1][None]) if lane else out
     from llm_consensus_tpu.ops.attention import (
         ragged_paged_attention_reference,
     )
@@ -1760,19 +1772,44 @@ def _decoding_rows(cache, write_mask=None):
     return live if write_mask is None else live & write_mask
 
 
-def _live_rows(cfg: ModelConfig, cache, write_mask=None, repeat=1, extra=0):
-    """[rows * repeat + extra] bool: :func:`_decoding_rows`, each
-    ``repeat`` times, then ``extra`` live chunk tokens. None unless the
-    model has a dropless expert layer, the one thing that asks
-    (:func:`_moe_dropless`)."""
+def _live_rows(cfg: ModelConfig, cache, write_mask=None, repeat=1, lanes=None):
+    """[rows * repeat (+ L * C)] bool: :func:`_decoding_rows`, each
+    ``repeat`` times, then the chunk tokens ``lanes`` ([L * C] bool: a
+    live lane's are all live). None unless the model has a dropless
+    expert layer, the one thing that asks (:func:`_moe_dropless`)."""
     if not cfg.moe_dropless:
         return None
     live = _decoding_rows(cache, write_mask)
     if repeat > 1:
         live = jnp.repeat(live, repeat)
-    if extra:
-        live = jnp.concatenate([live, jnp.ones((extra,), bool)])
+    if lanes is not None:
+        live = jnp.concatenate([live, lanes])
     return live
+
+
+def _chunk_lanes(cache, tokens, table, start):
+    """The chunk lanes of a step program, normalised: ``tokens`` [L, C]
+    with ``table`` [L, P] and ``start`` [L] (one lane may come as [P]
+    and a scalar). Returns (table, start, pos [L, C] absolute
+    positions, pages [L, C] and offs [L, C] of each token's K/V,
+    live [L] bool, attn_start [L]).
+
+    A lane with nothing to carry is a dead row, as an idle slot is: its
+    table is all NULL pages, so its tokens write into the NULL page, and
+    ``attn_start`` is -C for it, which is length 0 to the attention — it
+    reads nothing (``ragged_paged_attention``)."""
+    from llm_consensus_tpu.models.paged_cache import NULL_PAGE
+
+    lanes, c = tokens.shape
+    table = jnp.atleast_2d(table)
+    start = jnp.asarray(start, jnp.int32).reshape(lanes)
+    pos = start[:, None] + jnp.arange(c)[None]
+    pages = jnp.take_along_axis(table, pos // cache.page_size, axis=1)
+    live = table[:, 0] != NULL_PAGE
+    return (
+        table, start, pos, pages, pos % cache.page_size, live,
+        jnp.where(live, start, -c),
+    )
 
 
 def decode_step_paged(
@@ -1939,16 +1976,21 @@ def prefill_chunk_paged(
     cache,
     mesh=None,
 ) -> tuple[jnp.ndarray, object]:
-    """One prompt chunk for ONE sequence, scattered into paged K/V.
+    """One prompt chunk for each of L sequences (lanes), scattered into
+    paged K/V.
 
-    tokens: [1, C] — chunk token ids at absolute positions
-    ``start + i``; table: [pages_per_seq] int32 page ids (position p
-    lives in ``table[p // page_size]`` at offset ``p % page_size``);
-    start: scalar int32. Writes each chunk token's K/V through
-    ``table`` and attends over the table's content so far plus the
-    chunk itself — the same ragged-causal rule as
-    :func:`decode_chunk`, so a sequence of chunk calls writes the
-    identical cache a dense :func:`prefill` + scatter would.
+    tokens: [L, C] — lane l's chunk token ids at absolute positions
+    ``start[l] + i``; table: [L, pages_per_seq] int32 page ids (position
+    p lives in ``table[l, p // page_size]`` at offset ``p % page_size``);
+    start: [L] int32 (one lane may come as a [pages_per_seq] table and
+    a scalar). Writes each chunk token's K/V through its lane's table
+    and attends over that table's content so far plus the chunk itself
+    — the same ragged-causal rule as :func:`decode_chunk`, so a
+    sequence of chunk calls writes the identical cache a dense
+    :func:`prefill` + scatter would. Lanes are different sequences:
+    they write disjoint pages and a row of a matmul does not depend on
+    its neighbours, so L lanes in one call leave what L calls would.
+    A lane whose table is all NULL pages is dead (:func:`_chunk_lanes`).
 
     The table rides as an ARGUMENT, not through ``cache.page_table``:
     a mid-prefill sequence must stay invisible to the concurrently
@@ -1959,7 +2001,7 @@ def prefill_chunk_paged(
     head, and this program only ever writes positions >= ``start``, so
     refcount-shared pages are read, never written.
 
-    Returns ([1, C, D] hidden states, cache). ``cache.page_table`` and
+    Returns ([L, C, D] hidden states, cache). ``cache.page_table`` and
     ``cache.length`` are untouched. The serving layer gathers the
     last-valid position's hidden state from the FINAL chunk and
     unembeds that single row (see :func:`unembed_one`) — never a
@@ -1967,15 +2009,13 @@ def prefill_chunk_paged(
     """
     from llm_consensus_tpu.models.paged_cache import PagedKVCache
 
-    c = tokens.shape[1]
-    pos = start + jnp.arange(c)  # [C] absolute positions
-    x = params["embed"][tokens]  # [1, C, D]
-    cos, sin = rope_cos_sin(
-        pos[None], cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
+    table, start, pos, pages, offs, live, attn_start = _chunk_lanes(
+        cache, tokens, table, start
     )
-    pg = cache.page_size
-    pages = table[pos // pg]  # [C] destination page per chunk token
-    offs = pos % pg
+    x = params["embed"][tokens]  # [L, C, D]
+    cos, sin = rope_cos_sin(
+        pos, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
+    )
     nb = 1 if mesh is None else int(mesh.shape.get("data", 1))
 
     def attend(q, k_pools, v_pools, layer):
@@ -1994,20 +2034,22 @@ def prefill_chunk_paged(
         return _attn_paged(
             cfg,
             jnp.zeros((nb, cfg.n_heads, q.shape[-1]), q.dtype),
-            q[0],
+            q,
             k_pools,
             v_pools,
             layer,
-            jnp.zeros((nb, table.shape[0]), jnp.int32),
+            jnp.zeros((nb, table.shape[1]), jnp.int32),
             jnp.zeros((nb,), jnp.int32),
             chunk_table=table,
-            chunk_start=start,
+            chunk_start=attn_start,
             mesh=mesh,
-        )[1][None]  # out_chunk [C, H, D] -> [1, C, H, D]
+        )[1]  # out_chunk [L, C, H, D]
 
     x, new_k, new_v, *stats = _paged_layers(
-        cfg, params, x, cos, sin, cache, pages[None], offs[None], attend,
-        mesh=mesh,
+        cfg, params, x, cos, sin, cache, pages, offs, attend, mesh=mesh,
+        active=(
+            jnp.repeat(live, tokens.shape[1]) if cfg.moe_dropless else None
+        ),
     )
     new_cache = PagedKVCache(
         k=new_k, v=new_v, page_table=cache.page_table, length=cache.length
@@ -2027,31 +2069,36 @@ def fused_step_paged(
     cfg_chunk: ModelConfig | None = None,
     mesh=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, object]:
-    """One decode step for every cache sequence PLUS one prefill chunk
-    — a single device program (the fused scheduler step).
+    """One decode step for every cache sequence PLUS the next prefill
+    chunk of up to L other sequences — a single device program (the
+    fused scheduler step).
 
-    tokens: [B, 1] decode inputs; chunk_tokens: [1, C] one sequence's
-    prompt chunk at absolute positions ``chunk_start + i``, written
-    through the explicit host-side ``chunk_table`` [P] exactly as
-    :func:`prefill_chunk_paged` (the mid-prefill row stays invisible to
+    tokens: [B, 1] decode inputs; chunk_tokens: [L, C] — lane l is one
+    sequence's prompt chunk at absolute positions ``chunk_start[l] + i``,
+    written through the explicit host-side ``chunk_table[l]`` ([L, P];
+    one lane may come as [P] and a scalar start) exactly as
+    :func:`prefill_chunk_paged` (a mid-prefill row stays invisible to
     the decode rows — its device table row is still NULL). The decode
-    rows and the chunk share ONE token axis: embedding, RoPE, the
-    QKV/WO/MLP matmuls, and the K/V pool scatter all run over the
-    [B + C] concatenation (bigger GEMMs, one scatter), and attention is
-    the ragged kernel with the chunk riding as one more row — chunked
-    prefill stops being a separate device program serializing against
-    decode.
+    rows and the lanes share ONE token axis: embedding, RoPE, the
+    QKV/WO/MLP matmuls, the K/V pool scatter and a dropless expert
+    layer's router and grouped matmul all run over the [B + L * C]
+    concatenation (bigger GEMMs, one scatter: a weight is read once for
+    all of them), and attention is the ragged kernel with each lane
+    riding as one more row — chunked prefill stops being a separate
+    device program serializing against decode. A lane whose table is all
+    NULL pages is dead (:func:`_chunk_lanes`).
 
-    The two workloads are independent by construction: decode rows
-    write only their own private pages, the chunk writes only positions
-    >= ``chunk_start`` of its own table (shared prefix pages are read,
-    never written), so each side's outputs equal the split programs'.
+    The workloads are independent by construction: decode rows write
+    only their own private pages, a lane writes only positions
+    >= ``chunk_start[l]`` of its own table (shared prefix pages are
+    read, never written; lanes are different sequences), so each side's
+    outputs equal the split programs'.
     ``cfg_chunk`` (default ``cfg``): the MoE-pinned config the
     standalone chunk program would have used — when it differs (MoE
     configs), the MLP runs split per side so each side's dispatch path
     matches its parity baseline; dense models share one MLP call.
 
-    Returns (decode logits [B, V] fp32, chunk hidden [1, C, D], cache).
+    Returns (decode logits [B, V] fp32, chunk hidden [L, C, D], cache).
     ``cache.length`` advances for the decode rows only, and of those
     for the ones that carry a request (:func:`_decoding_rows`).
     """
@@ -2060,31 +2107,36 @@ def fused_step_paged(
     if cfg_chunk is None:
         cfg_chunk = cfg
     b = tokens.shape[0]
-    c = chunk_tokens.shape[1]
+    lanes, c = chunk_tokens.shape
     pos = cache.length  # [B] decode write positions
-    chunk_pos = chunk_start + jnp.arange(c)  # [C] absolute positions
-    all_pos = jnp.concatenate([pos, chunk_pos])
+    chunk_table, chunk_start, chunk_pos, chunk_pages, chunk_offs, live, (
+        attn_start
+    ) = _chunk_lanes(cache, chunk_tokens, chunk_table, chunk_start)
+    all_pos = jnp.concatenate([pos, chunk_pos.reshape(-1)])
     x = params["embed"][
-        jnp.concatenate([tokens[:, 0], chunk_tokens[0]])
-    ][None]  # [1, B+C, D]
+        jnp.concatenate([tokens[:, 0], chunk_tokens.reshape(-1)])
+    ][None]  # [1, B + L*C, D]
     cos, sin = rope_cos_sin(
         all_pos[None], cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
     )
     pg = cache.page_size
     pages_dec = cache.page_table[jnp.arange(b), pos // pg]  # [B]
-    pages_all = jnp.concatenate([pages_dec, chunk_table[chunk_pos // pg]])
-    offs_all = jnp.concatenate([pos % pg, chunk_pos % pg])
+    pages_all = jnp.concatenate([pages_dec, chunk_pages.reshape(-1)])
+    offs_all = jnp.concatenate([pos % pg, chunk_offs.reshape(-1)])
     tables = cache.page_table
     adv = _decoding_rows(cache).astype(pos.dtype)
     mlp_split = cfg.is_moe and cfg_chunk is not cfg
 
     def attend(q, k_pools, v_pools, layer):
         attn_dec, attn_ch = _attn_paged(
-            cfg, q[0, :b], q[0, b:], k_pools, v_pools, layer, tables,
-            pos + adv, chunk_table=chunk_table, chunk_start=chunk_start,
+            cfg, q[0, :b], q[0, b:].reshape(lanes, c, *q.shape[2:]),
+            k_pools, v_pools, layer, tables, pos + adv,
+            chunk_table=chunk_table, chunk_start=attn_start,
             groups=groups, mesh=mesh,
         )
-        return jnp.concatenate([attn_dec, attn_ch])[None]  # [1, B+C, H, Dh]
+        return jnp.concatenate(
+            [attn_dec, attn_ch.reshape(lanes * c, *attn_ch.shape[2:])]
+        )[None]  # [1, B + L*C, H, Dh]
 
     def mlp_by_side(p, h2):
         return jnp.concatenate(
@@ -2092,15 +2144,15 @@ def fused_step_paged(
         )
 
     # One scatter over DISJOINT real pages: decode rows write their
-    # private pages, the chunk writes positions >= chunk_start of its
-    # own table.
+    # private pages, each lane positions >= its chunk_start of its own
+    # table.
     x, new_k, new_v, *stats = _paged_layers(
         cfg, params, x, cos, sin, cache, pages_all[None], offs_all[None],
         attend, mesh=mesh, mlp=mlp_by_side if mlp_split else None,
-        active=_live_rows(cfg, cache, extra=c),
+        active=_live_rows(cfg, cache, lanes=jnp.repeat(live, c)),
     )
     logits = _unembed(cfg, params, x[0, :b], mesh)
-    hidden_chunk = x[:, b:]  # [1, C, D]
+    hidden_chunk = x[0, b:].reshape(lanes, c, -1)  # [L, C, D]
     new_cache = PagedKVCache(
         k=new_k, v=new_v, page_table=cache.page_table, length=pos + adv
     )
@@ -2112,7 +2164,15 @@ def unembed_one(
 ) -> jnp.ndarray:
     """Logits [V] fp32 for ONE hidden state [D] — the final-chunk
     unembed of the chunked-prefill path (a D x V matvec, not C x V)."""
-    return _unembed(cfg, params, h[None], mesh)[0]
+    return unembed_rows(cfg, params, h[None], mesh)[0]
+
+
+def unembed_rows(
+    cfg: ModelConfig, params: dict, h: jnp.ndarray, mesh=None
+) -> jnp.ndarray:
+    """Logits [N, V] fp32 for N hidden states [N, D]: one a chunk lane
+    of the fused step."""
+    return _unembed(cfg, params, h, mesh)
 
 
 def decode_chunk(

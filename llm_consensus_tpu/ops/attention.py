@@ -298,9 +298,12 @@ def ragged_paged_attention_reference(
     composed from the gather-then-attend references: decode rows
     materialize their tables out of the pool and apply
     :func:`decode_attention`'s one-token rule; the optional
-    prefill-chunk row (``q_chunk`` [C, H, D], queries at absolute
-    positions ``chunk_start + i`` through ``chunk_table`` [P]) applies
-    :func:`chunk_decode_attention`'s ragged-causal rule. Shared-prefix
+    prefill-chunk lanes (``q_chunk`` [L, C, H, D], lane l's queries at
+    absolute positions ``chunk_start[l] + i`` through ``chunk_table[l]``
+    of [L, P]; one lane may come as [C, H, D], [P] and a scalar) apply
+    :func:`chunk_decode_attention`'s ragged-causal rule, each over its
+    own table; a dead lane (``chunk_start`` = -C) sees nothing and its
+    output is garbage nobody reads. Shared-prefix
     groups are a pure bandwidth optimization in the kernel and do not
     exist here — the kernel's grouped output must match this ungrouped
     math (the PR 3 contract, extended to mixed rows).
@@ -313,7 +316,7 @@ def ragged_paged_attention_reference(
     verify row is exactly a chunk row over the row's own table.
     k_pool/v_pool: [n_pages, page, Hkv, D]; page_table: [B, P];
     valid_len: [B]. Returns out_dec shaped like ``q`` (and out_chunk
-    [C, H, D] when ``q_chunk`` is given).
+    shaped like ``q_chunk`` when it is given).
 
     ``latent_dv`` > 0: the latent (MLA) pool. ``k_pool`` is
     [n_pages, page, D] — one key a token, shared by all H query heads
@@ -343,13 +346,17 @@ def ragged_paged_attention_reference(
         )
     if q_chunk is None:
         return out
-    kc = k_pool[chunk_table].reshape(1, -1, hkv, d)
-    vc = v_pool[chunk_table].reshape(1, -1, hkv, dv)
-    start = jnp.asarray(chunk_start, jnp.int32).reshape(1)
+    one_lane = q_chunk.ndim == 3
+    if one_lane:
+        q_chunk, chunk_table = q_chunk[None], chunk_table[None]
+    lanes = q_chunk.shape[0]
+    kc = k_pool[chunk_table].reshape(lanes, -1, hkv, d)
+    vc = v_pool[chunk_table].reshape(lanes, -1, hkv, dv)
+    start = jnp.asarray(chunk_start, jnp.int32).reshape(lanes)
     out_chunk = chunk_decode_attention(
-        q_chunk[None], kc, vc, start, window=window, scale=scale
-    )[0]
-    return out, out_chunk
+        q_chunk, kc, vc, start, window=window, scale=scale
+    )
+    return out, out_chunk[0] if one_lane else out_chunk
 
 
 def chunk_decode_attention(

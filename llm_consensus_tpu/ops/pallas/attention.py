@@ -695,7 +695,7 @@ def _ragged_kernel(
     stacked: bool,
     dv: int = 0,
 ):
-    """One program-class row of the ragged kernel: a decode row, the
+    """One program-class row of the ragged kernel: a decode row, a
     chunk lane or a group, walking ITS OWN live pages.
 
     The grid is one step a row. The pools stay in HBM; a step computes
@@ -930,9 +930,9 @@ def _ragged_kernel(
 
     if nc:
 
-        @pl.when(s == b)
+        @pl.when((s >= b) & (s < R))
         def _chunk_row():
-            _row(b, cq, q_chunk_ref)
+            _row(s, cq, q_chunk_ref)
             oc_ref[0] = acc_s[:, 0 : cq * g] / jnp.maximum(
                 l_s[:, 0 : cq * g], 1e-30
             )
@@ -1008,8 +1008,8 @@ def _ragged_attention(
     q_dec: [B, H, D] (one query per decode row) or [B, NQ, H, D]
     (NQ-query verify rows, PR 9 — queries at kv_len - NQ + i, the
     chunk lane's ragged-causal rule per row); page_table: [B + nc, P]
-    (row B is the chunk's table when ``q_chunk`` [C, H, D] rides
-    along); kv_len/suffix_start: [B + nc]. K/V layout is static: the
+    (rows B.. are the chunk lanes' tables when ``q_chunk`` [nc, C, H, D]
+    rides along); kv_len/suffix_start: [B + nc]. K/V layout is static: the
     pool [n_pages, pg, Hkv, D] (``k_scale`` None), the int8 head-major
     cache [B, Hkv, S, D] with [B, Hkv, S] scales, or either of them
     stacked over layers — pools [L, n_pages, pg, Hkv, D], int8 cache
@@ -1017,8 +1017,8 @@ def _ragged_attention(
     prefetch into the kernel's page copies, so the pages it folds are
     the unstacked layout's. The dense layouts are addressed as
     identity-tabled virtual pages of width ``pg``. Returns out_dec
-    shaped like q_dec (and out_chunk [C, H, D] when ``q_chunk``) in q's
-    dtype.
+    shaped like q_dec (and out_chunk [nc, C, H, D] when ``q_chunk``) in
+    q's dtype.
 
     ``latent_dv`` > 0: ``k_kv`` is the latent pool [n_pages, pg, D]
     (stacked: [L, n_pages, pg, D]) of an MLA model and ``v_kv`` is
@@ -1048,8 +1048,8 @@ def _ragged_attention(
         npp = 0  # unused
     dv = latent_dv or d
     g = h // hkv
-    nc = 0 if q_chunk is None else 1
-    cq = q_chunk.shape[0] if nc else 1
+    nc = 0 if q_chunk is None else q_chunk.shape[0]
+    cq = q_chunk.shape[1] if nc else 1
     gm = 0 if gid is None else int(rep.shape[0])
     p_per = page_table.shape[1]
     R = b + nc
@@ -1093,15 +1093,18 @@ def _ragged_attention(
         )
     )
     if nc:
+        # One lane's block at a time: the row's own while a chunk row
+        # runs, an end lane's (already resident) otherwise.
         inputs.append(
             q_chunk.astype(jnp.float32)
-            .reshape(cq, hkv, g, d)
-            .transpose(1, 0, 2, 3)
-            .reshape(1, hkv, cq * g, d)
+            .reshape(nc, cq, hkv, g, d)
+            .transpose(0, 2, 1, 3, 4)
+            .reshape(nc, hkv, cq * g, d)
         )
         in_specs.append(
             pl.BlockSpec(
-                (1, hkv, cq * g, d), lambda s, *pf: (0, 0, 0, 0)
+                (1, hkv, cq * g, d),
+                lambda s, *pf: (jnp.clip(s - b, 0, nc - 1), 0, 0, 0),
             )
         )
     if gm:
@@ -1147,13 +1150,15 @@ def _ragged_attention(
         pl.BlockSpec((1, hkv, nq * g, dv), _dec_out_map),
     ]
     if nc:
-        # The chunk lane never meets a group partial, so only its
+        # A chunk lane never meets a group partial, so only its
         # normalized output leaves the kernel.
-        out_shapes.append(_out(2, hkv, cq * g, dv))
+        out_shapes.append(_out(nc + 1, hkv, cq * g, dv))
         out_specs.append(
             pl.BlockSpec(
                 (1, hkv, cq * g, dv),
-                lambda s, *pf: (jnp.where(s == b, 0, 1), 0, 0, 0),
+                lambda s, *pf: (
+                    jnp.where((s >= b) & (s < R), s - b, nc), 0, 0, 0
+                ),
             )
         )
     if gm:
@@ -1251,11 +1256,11 @@ def _ragged_attention(
         out_dec = out_dec[:, 0]
     if not nc:
         return out_dec
-    oc = outs[3][0]  # [Hkv, cq*G, D]
+    oc = outs[3][:nc]  # [nc, Hkv, cq*G, D]
     out_chunk = (
-        oc.reshape(hkv, cq, g, dv)
-        .transpose(1, 0, 2, 3)
-        .reshape(cq, h, dv)
+        oc.reshape(nc, hkv, cq, g, dv)
+        .transpose(0, 2, 1, 3, 4)
+        .reshape(nc, cq, h, dv)
         .astype(q_dec.dtype)
     )
     return out_dec, out_chunk
@@ -1291,18 +1296,24 @@ def ragged_paged_attention(
     in place — what the step programs' layer scan passes, since a layer
     sliced out for a Pallas call is a copy of it.
 
-    ``q_chunk`` [C, H, D] adds ONE prefill-chunk row: C queries at
-    absolute positions ``chunk_start + i``, walking ``chunk_table``
-    [P] (the chunk's K/V must already be scattered through it), with
-    the ragged-causal rule of
+    ``q_chunk`` [L, C, H, D] adds L prefill-chunk rows (lanes), each a
+    different sequence's chunk: lane l's C queries sit at absolute
+    positions ``chunk_start[l] + i`` and walk ``chunk_table[l]`` ([L, P];
+    the chunk's K/V must already be scattered through it), with the
+    ragged-causal rule of
     :func:`~llm_consensus_tpu.ops.attention.chunk_decode_attention`.
+    A lane with ``chunk_start[l] == -C`` is DEAD: it reads nothing and
+    returns zeros. One lane may come without the lane axis (``q_chunk``
+    [C, H, D], ``chunk_table`` [P], scalar ``chunk_start``) and
+    ``out_chunk`` then has none either.
     ``groups`` = (group_id [B] (-1 ungrouped), group_rep [Gm],
     group_end [Gm] tokens, shared_start [B]) — decode rows sharing a
     prefix page run read it ONCE per group (all member queries
     stacked), each row's own walk starting at ``shared_start``; the
     partials merge exactly via flash-decoding LSE. ``window`` > 0
     applies sliding-window masking to every row kind. Returns
-    out_dec [B, H, D] (and out_chunk [C, H, D] when ``q_chunk``).
+    out_dec [B, H, D] (and out_chunk, shaped like ``q_chunk`` but
+    ``latent_dv`` wide on a latent pool, when ``q_chunk``).
 
     ``latent_dv`` > 0: the latent pool of an MLA model, [n_pages, page,
     D] (stacked [L, n_pages, page, D]); ``v_pool`` is ignored, each page
@@ -1320,16 +1331,19 @@ def ragged_paged_attention(
         gid = rep = gend = None
         sstart = jnp.zeros((b,), jnp.int32)
     tbl = page_table
+    one_lane = q_chunk is not None and q_chunk.ndim == 3
+    if one_lane:
+        q_chunk, chunk_table = q_chunk[None], chunk_table[None]
     if q_chunk is not None:
-        cq = q_chunk.shape[0]
+        nc, cq = q_chunk.shape[:2]
         tbl = jnp.concatenate(
-            [page_table.astype(jnp.int32), chunk_table[None].astype(jnp.int32)]
+            [page_table.astype(jnp.int32), chunk_table.astype(jnp.int32)]
         )
         kvlen = jnp.concatenate(
-            [kvlen, jnp.asarray(chunk_start, jnp.int32).reshape(1) + cq]
+            [kvlen, jnp.asarray(chunk_start, jnp.int32).reshape(nc) + cq]
         )
-        sstart = jnp.concatenate([sstart, jnp.zeros((1,), jnp.int32)])
-    return _ragged_attention(
+        sstart = jnp.concatenate([sstart, jnp.zeros((nc,), jnp.int32)])
+    out = _ragged_attention(
         q,
         k_pool,
         v_pool,
@@ -1347,6 +1361,7 @@ def ragged_paged_attention(
         latent_dv=latent_dv,
         interpret=interpret,
     )
+    return (out[0], out[1][0]) if one_lane else out
 
 
 def ragged_paged_attention_sharded(

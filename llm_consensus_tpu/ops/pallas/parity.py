@@ -65,7 +65,7 @@ def ragged_attention_error(
     valid_len: list[int],
     nq: int = 1,
     cq: int = 0,
-    chunk_start: int = 0,
+    chunk_start: int | list[int] = 0,
     group_rows: tuple[int, ...] = (),
     shared_pages: int = 1,
     window: int = 0,
@@ -80,7 +80,10 @@ def ragged_attention_error(
     ``valid_len``: tokens readable per decode row (mid-page fills are
     the interesting ones; a row of 0 holds nothing, and owes only a
     finite output). ``nq`` > 1: verify rows. ``cq`` > 0: one
-    prefill-chunk row of cq queries from ``chunk_start``. ``group_rows``:
+    prefill-chunk row of cq queries from ``chunk_start`` — or, where
+    ``chunk_start`` is a list, one chunk lane a start, each over a table
+    of its own; a start of ``-cq`` is a dead lane (an all-NULL table),
+    which owes only a finite output. ``group_rows``:
     these rows share their first ``shared_pages`` pages and ride the
     kernel's group phase (the reference has no groups — grouped output
     must equal ungrouped math). ``null_tables``: all-NULL decode tables
@@ -134,7 +137,23 @@ def ragged_attention_error(
     kw: dict = {"window": window}
     if latent_dv:
         kw.update(latent_dv=latent_dv, scale=1.3 * d**-0.5)
-    if cq:
+    lane_live = None
+    if cq and isinstance(chunk_start, list):
+        lanes = len(chunk_start)
+        lane_live = np.asarray(chunk_start) >= 0
+        ctbl = np.asarray(
+            perm[b * p_per : (b + lanes) * p_per].reshape(lanes, p_per),
+            np.int32,
+        )
+        ctbl[~lane_live] = 0
+        kw.update(
+            q_chunk=jnp.asarray(
+                rng.standard_normal((lanes, cq, h, d)), jnp.bfloat16
+            ),
+            chunk_table=jnp.asarray(ctbl),
+            chunk_start=jnp.asarray(chunk_start, jnp.int32),
+        )
+    elif cq:
         kw.update(
             q_chunk=jnp.asarray(
                 rng.standard_normal((cq, h, d)), jnp.bfloat16
@@ -180,12 +199,17 @@ def ragged_attention_error(
 
     if not cq:
         return {"decode": decode_err(got, ref)}
+    got_chunk, ref_chunk = got[1], ref[1]
+    if lane_live is not None:
+        _finite(got_chunk)  # all a dead lane owes
+        got_chunk = np.asarray(got_chunk)[lane_live]
+        ref_chunk = np.asarray(ref_chunk)[lane_live]
     if null_tables:
         _finite(got[0])
-        return {"chunk": max_err(got[1], ref[1])}
+        return {"chunk": max_err(got_chunk, ref_chunk)}
     return {
         "decode": decode_err(got[0], ref[0]),
-        "chunk": max_err(got[1], ref[1]),
+        "chunk": max_err(got_chunk, ref_chunk),
     }
 
 

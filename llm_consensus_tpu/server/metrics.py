@@ -57,6 +57,7 @@ __all__ = [
     "BATCHER_PHASE_SECONDS",
     "GENERATED_TOKENS",
     "PREFILL_TOKENS",
+    "CHUNK_LANES",
     "DEVICE_MEMORY_BYTES",
     "RAGGED_ROWS",
     "SPEC_DRAFT_TOKENS",
@@ -638,6 +639,15 @@ GENERATED_TOKENS = REGISTRY.counter(
 PREFILL_TOKENS = REGISTRY.counter(
     "gateway_prefill_tokens_total",
     "Prompt tokens computed by prefill programs (no padding, no cached)",
+)
+#: Chunk programs by how many of their lanes carried a chunk, labeled
+#: ``kind="prefill"|"fused"`` and ``lanes="n"``: a step program carries
+#: the ready chunks of up to L prefilling slots (PR 31), and "one slot
+#: was ready" reads differently from "three rode". Over a kind the
+#: lanes sum to ``gateway_device_programs_total`` of that kind.
+CHUNK_LANES = REGISTRY.counter(
+    "gateway_chunk_lanes_total",
+    "Chunk programs by kind and by the lanes that carried a chunk",
 )
 #: The dropless expert layer's routing, labeled ``kind`` like the device
 #: programs (``decode`` / ``fused`` / ``prefill``), counted where the

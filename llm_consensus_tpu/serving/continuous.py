@@ -11,9 +11,11 @@ style, re-founded on XLA's compile-once constraint:
   page tables and lengths — data, not shapes.
 - **Chunked prefill interleaved with decode** (PR 2): prompts prefill in
   fixed-size chunks scheduled as work units BETWEEN decode steps
-  (compile-once per (chunk, prompt-bucket) pair, paged K/V scatter per
+  (compile-once per (chunk width, lanes) pair, paged K/V scatter per
   chunk — :func:`llm_consensus_tpu.models.transformer.prefill_chunk_paged`),
-  so running slots keep decoding while new prompts fill. A mid-prefill
+  so running slots keep decoding while new prompts fill; the ready
+  chunks of several prefilling sequences share one program, a lane each
+  (PR 31: a weight read serves up to L chunks). A mid-prefill
   sequence's device table row stays NULL (the decode program never sees
   it); the chunk program writes through an explicit host-side table.
 - **Copy-on-write shared prefixes**: admission hashes the prompt's
@@ -38,7 +40,7 @@ style, re-founded on XLA's compile-once constraint:
   dedups against an in-flight restore like an in-flight prefill — and
   a restored prefix is byte-identical to a re-prefilled one (tested).
 - A host thread drives: admit waiting requests into free slots, run at
-  most one restore or prefill chunk, run one decode step for all
+  most one restore or chunk program, run one decode step for all
   slots, sample,
   retire EOS/length-capped slots, resolve futures. Inactive slots decode
   into the reserved NULL page and their outputs are discarded (the cost
@@ -114,6 +116,7 @@ from llm_consensus_tpu.utils.stops import (
 from llm_consensus_tpu.models.configs import ModelConfig
 from llm_consensus_tpu.models.paged_cache import (
     NULL_PAGE,
+    DecodeGroupArrays,
     GroupTracker,
     PagedKVCache,
     PagePool,
@@ -125,6 +128,7 @@ from llm_consensus_tpu.models.paged_cache import (
     release_seq,
 )
 from llm_consensus_tpu.engine.accept import verify_tokens
+from llm_consensus_tpu.ops.pallas.quant_matmul import _MAX_M as _QMM_MAX_ROWS
 from llm_consensus_tpu.serving import flight as _flight
 from llm_consensus_tpu.serving.offload import HostPageStore
 from llm_consensus_tpu.models.transformer import (
@@ -135,6 +139,7 @@ from llm_consensus_tpu.models.transformer import (
     prefill_chunk_paged,
     program_hbm_cost,
     unembed_one,
+    unembed_rows,
     verify_step_paged,
 )
 from llm_consensus_tpu.models.transformer import (
@@ -217,6 +222,9 @@ from llm_consensus_tpu.server.metrics import (
 )
 from llm_consensus_tpu.server.metrics import (
     PREFILL_TOKENS as _M_PREFILL_TOKENS,
+)
+from llm_consensus_tpu.server.metrics import (
+    CHUNK_LANES as _M_CHUNK_LANES,
 )
 from llm_consensus_tpu.server.metrics import (
     DEVICE_MEMORY_BYTES as _M_DEVICE_MEMORY,
@@ -406,10 +414,13 @@ class ContinuousConfig:
     # question tail) with a warning, or reject when False.
     truncate_prompts: bool = True
     # Prefill-chunk width in tokens, >= 1. A prompt prefills in chunks
-    # of min(prefill_chunk, its seq bucket), one chunk a loop iteration,
-    # riding the decode dispatch when rows are decoding: a decoding row
-    # waits for one chunk's compute, never for a whole prompt. Chunked
-    # prefill is the only prefill path.
+    # of min(prefill_chunk, its seq bucket), one chunk of a sequence a
+    # loop iteration; the ready chunks of up to L sequences share that
+    # iteration's program (L from the slots, this width and the int8
+    # matmul kernel's row cap: ContinuousBatcher._lanes_for), riding the
+    # decode dispatch when rows are decoding: a decoding row waits for
+    # one program of up to L chunks (at most 256 rows), never for a
+    # whole prompt. Chunked prefill is the only prefill path.
     prefill_chunk: int = 64
     # Map page-aligned shared prompt prefixes out of the PrefixRegistry
     # instead of re-prefilling them, and read a shared run once a group
@@ -640,7 +651,8 @@ class _Inflight:
     t0: float  # host dispatch stamp (perf_counter)
     k: int  # decode steps folded into this program
     rows: list  # [(slot_idx, _Slot)] decoding at dispatch
-    chunk: _InflightChunk | None = None  # fused prefill chunk (PR 8)
+    # Fused prefill chunks (PR 8), one a live lane (PR 31).
+    chunks: list = field(default_factory=list)
     # -- speculative round (PR 9) --------------------------------------
     # ``tokens`` is then the [slots, spec_k + 1] emit buffer; only
     # ``emit_cnt`` leading tokens per row are real. ``counts_out`` is
@@ -1102,6 +1114,9 @@ class ContinuousBatcher:
         self._ragged_rows_sum = 0
         self._ragged_rows_count = 0
         self._work_iterations = 0
+        # Chunk programs by (kind, lanes filled): the observations
+        # behind gateway_chunk_lanes_total (PR 31).
+        self._chunk_lanes_n: dict[tuple[str, int], int] = {}
         # Multi-round decode (PR 12): total decode rounds dispatched
         # and the per-program round-count observations — the same
         # numbers behind gateway_device_rounds_total /
@@ -1189,12 +1204,14 @@ class ContinuousBatcher:
         # stops; a cycling adversary re-pays only the capped
         # (max_vocab_scan decodes) derivation on its own thread.
         self._screen_cache: dict[tuple, tuple[int, ...] | None] = {}
-        self._jit_chunk = {}  # (chunk, s_bucket) -> compiled chunk prefill
-        self._jit_fused = {}  # (chunk, s_bucket) -> compiled fused step
-        # Buckets whose fused step was built ahead, and the shapes of a
-        # plain dispatch's arguments to build one from
+        # The chunk programs, keyed by what changes their HLO
+        # (:meth:`_chunk_key`): (chunk width, lanes, MoE pin).
+        self._jit_chunk = {}  # key -> compiled chunk prefill
+        self._jit_fused = {}  # key -> compiled fused step
+        # Keys whose programs were built ahead, and the shapes of a
+        # plain dispatch's arguments to build them from
         # (:meth:`_build_fused_ahead`).
-        self._fused_ahead: set[tuple[int, int]] = set()
+        self._fused_ahead: set[tuple] = set()
         self._plain_shapes: tuple | None = None
         self._jit_copy_page = jax.jit(copy_page, donate_argnums=(0,))
         self._jit_install_page = jax.jit(install_page, donate_argnums=(0,))
@@ -1614,8 +1631,11 @@ class ContinuousBatcher:
         budgets=None,
         screen=None,
     ):
-        """The fused scheduler step: one decode+sample step AND one
-        prefill chunk as ONE device program (PR 8).
+        """The fused scheduler step: one decode+sample step AND the
+        next prefill chunk of up to L sequences as ONE device program
+        (PR 8; L lanes since PR 31). ``chunk_tokens`` [L, C],
+        ``chunk_table`` [L, P], ``chunk_start`` / ``chunk_last`` /
+        ``chunk_done`` [L]; a lane with an all-NULL table is dead.
 
         ``stop_rounds`` (STATIC, PR 12): > 0 makes this the MULTI-ROUND
         fused step — the chunk rides round 1 exactly as before (every
@@ -1631,11 +1651,13 @@ class ContinuousBatcher:
         (:func:`~llm_consensus_tpu.models.transformer.fused_step_paged`
         — shared token axis, one K/V scatter, the ragged attention
         kernel). Returns the plain program's outputs
-        plus ``chunk_logits`` [V] — the unembedded hidden state of the
-        prompt position ``chunk_last`` (the host samples the request's
-        first token from it at fetch, exactly as the standalone path
-        does after its final chunk). ``chunk_done`` is a traced bool
-        under a ``lax.cond``: a non-final chunk skips the full-vocab
+        plus ``chunk_logits``, a tuple of L [V] rows — lane l's is the
+        unembedded hidden state of its prompt position ``chunk_last[l]``
+        (the host samples the request's first token from it at fetch,
+        exactly as the standalone path does after its final chunk; a
+        row a lane, so the fetch slices nothing). ``chunk_done`` is
+        traced, under a ``lax.cond`` taken when any lane ends its
+        prompt: a program of non-final chunks skips the full-vocab
         unembed at run time (its ``chunk_logits`` are zeros nobody
         reads) without being a program of its own — a program costs
         seconds to trace and load in every process, and a last-chunk
@@ -1656,13 +1678,16 @@ class ContinuousBatcher:
         tok1, logp1 = self._sample_rows(
             logits, seeds, counts, temps, topks, topps, filters_active
         )
-        c = chunk_tokens.shape[1]
+        lanes, c = chunk_tokens.shape
         chunk_logits = jax.lax.cond(
-            chunk_done,
-            lambda h: unembed_one(self.cfg, params, h, mesh=self.mesh),
-            lambda h: jnp.zeros((self.cfg.vocab_size,), jnp.float32),
-            hidden[0, jnp.clip(chunk_last - chunk_start, 0, c - 1)],
+            jnp.any(chunk_done),
+            lambda h: unembed_rows(self.cfg, params, h, mesh=self.mesh),
+            lambda h: jnp.zeros((lanes, self.cfg.vocab_size), jnp.float32),
+            hidden[
+                jnp.arange(lanes), jnp.clip(chunk_last - chunk_start, 0, c - 1)
+            ],
         )
+        chunk_logits = tuple(chunk_logits[lane] for lane in range(lanes))
         if stop_rounds:
             # Multi-round tail (PR 12): round 1 was the fused step
             # above (all rows alive by the dispatch invariant); apply
@@ -1917,20 +1942,52 @@ class ContinuousBatcher:
         streams = len({int(src[i]) for i in decoding})
         return src, fill, off, streams, shared
 
-    def _chunk_fn(self, chunk: int, s_bucket: int):
-        """Jitted per (chunk, prompt-bucket): one paged prefill chunk.
+    def _lanes_for(self, chunk: int) -> int:
+        """Chunk lanes of the WIDE chunk programs at this chunk width:
+        what fits, beside every slot's decode row, on the token axis
+        the int8 matmul kernel takes (``quant_matmul._MAX_M`` rows: past
+        it every matrix leaves the kernel, and a decoding row's wait
+        stops buying throughput well before) — and never more lanes
+        than slots. A program is static in its lanes, so there are two
+        widths: this one, and 1 for the iteration that finds one slot
+        ready (a lone prompt pays for no dead lane).
 
-        Compile-once per chunk bucket: chunk widths come from
-        ``min(config.prefill_chunk, s_bucket)``, so the program family
-        is bounded by the seq-bucket list. The bucket also pins the
-        MoE dispatch path to the choice a one-shot [1, s_bucket]
-        prefill would trace — a chunk below the dense-fallback
-        threshold must not diverge from the engine's whole-prompt
-        prefill it is parity-tested against.
+        One lane where a path takes one: the mesh kernel resolves the
+        lane on its owner shard, a draft mirrors a chunk through its
+        own one-lane program, and a capacity-dispatch MoE pins its path
+        (and counts its capacity) by one sequence's chunk."""
+        c = self.config
+        if (
+            self.mesh is not None
+            or self._draft_cfg is not None
+            or (self.cfg.is_moe and self.cfg.moe_capacity_factor > 0)
+        ):
+            return 1
+        return max(1, min(c.max_slots, (_QMM_MAX_ROWS - c.max_slots) // chunk))
+
+    def _chunk_key(self, chunk: int, lanes: int, s_bucket: int):
+        """(program key, pinned config) of a chunk program. The bucket
+        only pins the chunk side's MoE dispatch path to the choice a
+        one-shot [1, s_bucket] prefill would trace — a chunk below the
+        dense-fallback threshold must not diverge from the engine's
+        whole-prompt prefill it is parity-tested against — and the pin
+        is ``self.cfg`` itself for every dense and every dropless
+        configuration: their buckets share one program, and lanes of
+        different buckets ride it together."""
+        cfg = self.cfg.moe_pin_for(s_bucket, chunk)
+        return (chunk, lanes, cfg.moe_dense_decode_tokens), cfg
+
+    def _chunk_fn(self, chunk: int, lanes: int, s_bucket: int):
+        """Jitted per :meth:`_chunk_key`: one paged prefill chunk for
+        each of ``lanes`` sequences.
+
+        Compile-once: chunk widths come from
+        ``min(config.prefill_chunk, s_bucket)`` and lanes are 1 or
+        :meth:`_lanes_for`, so the program family is bounded by the
+        seq-bucket list.
         """
-        key = (chunk, s_bucket)
+        key, cfg = self._chunk_key(chunk, lanes, s_bucket)
         if key not in self._jit_chunk:
-            cfg = self.cfg.moe_pin_for(s_bucket, chunk)
             self._jit_chunk[key] = jax.jit(
                 _step_program(
                     "prefill_chunk",
@@ -2053,15 +2110,15 @@ class ContinuousBatcher:
             )
             slot.draft_lag = 0
 
-    def _fused_fn(self, chunk: int, s_bucket: int):
-        """Jitted per (chunk, prompt-bucket): the fused scheduler step
-        (:meth:`_fused_sample`). The bucket pins the chunk side's MoE
-        dispatch path exactly as :meth:`_chunk_fn` does — the fused
-        program must stay output-identical to the split programs it
-        replaces (tested with ``ragged_attention`` on and off)."""
-        key = (chunk, s_bucket)
+    def _fused_fn(self, chunk: int, lanes: int, s_bucket: int):
+        """Jitted per :meth:`_chunk_key`: the fused scheduler step
+        (:meth:`_fused_sample`) with ``lanes`` chunk lanes. The pin is
+        the chunk side's MoE dispatch path exactly as :meth:`_chunk_fn`
+        has it — the fused program must stay output-identical to the
+        split programs it replaces (tested with ``ragged_attention`` on
+        and off)."""
+        key, cfg_chunk = self._chunk_key(chunk, lanes, s_bucket)
         if key not in self._jit_fused:
-            cfg_chunk = self.cfg.moe_pin_for(s_bucket, chunk)
             self._jit_fused[key] = jax.jit(
                 _step_program(
                     "fused_step", partial(self._fused_sample, cfg_chunk)
@@ -2072,33 +2129,50 @@ class ContinuousBatcher:
         return self._jit_fused[key]
 
     def _build_fused_ahead(self, chunk: int, s_bucket: int) -> None:
-        """Trace and compile the bucket's ungrouped fused step, once.
+        """Trace and compile the chunk programs a decoding row could
+        meet, once: the fused step at both widths (one lane and
+        :meth:`_lanes_for`), with ungrouped rows and — where rows group
+        — with grouped ones, and the wide standalone chunk.
 
-        Called before a chunk of the bucket runs alone because nothing
-        decodes, so no decoding row waits for the build: the prompt
-        being prefilled does, seconds, once a bucket and process (and
-        not the process's first, which comes before the first plain
-        dispatch, whose arguments give the shapes). Without it the
-        fused step is first built when a chunk of the bucket first
-        rides a dispatch, with every decoding row stalled behind it; a
-        bucket first met under load is still built there, as is a
-        bucket's grouped step, since rows group only under load.
-        Lowering the jitted function from shapes fills the caches its
-        call reads, and runs nothing."""
-        key = (chunk, s_bucket)
-        if key in self._fused_ahead or self._plain_shapes is None:
+        Called before a chunk runs alone because nothing decodes, so no
+        decoding row waits for the builds: the prompt being prefilled
+        does, seconds, once a process (and not the process's first,
+        which comes before the first plain dispatch, whose arguments
+        give the shapes). Without it a fused step is first built when a
+        chunk first rides a dispatch, or when two slots are first ready
+        at once, or rows first group, with every decoding row stalled
+        behind it; a chunk width or MoE pin first met under load is
+        still built there. Lowering the jitted function from shapes
+        fills the caches its call reads, and runs nothing."""
+        ahead, _ = self._chunk_key(chunk, 0, s_bucket)
+        if ahead in self._fused_ahead or self._plain_shapes is None:
             return
-        self._fused_ahead.add(key)
+        self._fused_ahead.add(ahead)
         t0 = time.perf_counter()
+        c = self.config
         i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
-        self._fused_fn(chunk, s_bucket).lower(
-            *self._plain_shapes, None, i32((1, chunk)),
-            i32((self.config.pages_per_seq,)), i32(()), i32(()),
-            jax.ShapeDtypeStruct((), jnp.bool_),
-        ).compile()
+        grouped = [None]
+        if self._group_decode:
+            rows, gm = i32((c.max_slots,)), i32((self._groups.max_groups,))
+            grouped.append(DecodeGroupArrays(rows, gm, gm, rows))
+        widths = sorted({1, self._lanes_for(chunk)})
+        for lanes in widths:
+            lane = (
+                i32((lanes, chunk)), i32((lanes, c.pages_per_seq)),
+                i32((lanes,)),
+            )
+            for groups in grouped:
+                self._fused_fn(chunk, lanes, s_bucket).lower(
+                    *self._plain_shapes, groups, *lane, i32((lanes,)),
+                    jax.ShapeDtypeStruct((lanes,), jnp.bool_),
+                ).compile()
+            if lanes > 1:
+                self._chunk_fn(chunk, lanes, s_bucket).lower(
+                    self._plain_shapes[0], *lane, self._plain_shapes[1]
+                ).compile()
         log.info(
-            "fused step program for a chunk of %d, bucket %d: built in "
-            "%.1f s", chunk, s_bucket, time.perf_counter() - t0,
+            "fused step programs for chunks of %d, lanes %s, grouped and "
+            "not: built in %.1f s", chunk, widths, time.perf_counter() - t0,
         )
 
     @property
@@ -2862,6 +2936,13 @@ class ContinuousBatcher:
                 "device_programs_draft": self._programs["draft"],
                 "ragged_rows_sum": self._ragged_rows_sum,
                 "ragged_rows_count": self._ragged_rows_count,
+                # Chunk programs by how many lanes carried a chunk
+                # (gateway_chunk_lanes_total{kind,lanes}): over a kind
+                # they sum to its chunk programs.
+                **{
+                    f"chunk_lanes_{kind}_{n}": v
+                    for (kind, n), v in self._chunk_lanes_n.items()
+                },
                 "work_iterations": self._work_iterations,
                 # Multi-round on-device decode (PR 12) — the same
                 # observations behind gateway_device_rounds_total /
@@ -3593,7 +3674,7 @@ class ContinuousBatcher:
         kind: str,
         rows_now: list,
         k: int,
-        chunk_ext: tuple[int, int] | None = None,
+        chunk_ext: list[tuple[int, int]] | tuple = (),
         streams: int = 0,
     ) -> dict:
         """Static HBM/FLOPs model for ONE dispatched program (PR 10).
@@ -3606,8 +3687,9 @@ class ContinuousBatcher:
         the reason a spec program's KV read equals a plain decode
         program's over the same rows) and writes k+1 positions of
         which a rejected tail is rewound (written traffic either way);
-        a chunk lane (``chunk_ext = (read_end, width)``) reads the
-        pages covering [0, read_end) and writes its width. Group-
+        a chunk lane (one ``(read_end, width)`` of ``chunk_ext`` a live
+        lane) reads the pages covering [0, read_end) and writes its
+        width. Group-
         shared prefix reads are deducted exactly as
         :meth:`_dispatch_tail` counts them saved — the two accountings
         cannot drift apart without a test noticing. The draft side of
@@ -3645,8 +3727,7 @@ class ContinuousBatcher:
             saved = self._groups.saved_tokens_per_step * shared_steps
             kv_read -= min(kv_read, saved)
             pages -= min(pages, saved // pg)
-        if chunk_ext is not None:
-            read_end, width = chunk_ext
+        for read_end, width in chunk_ext:
             kv_read += read_end
             kv_write += width
             tokens += width
@@ -3708,13 +3789,21 @@ class ContinuousBatcher:
                 cost["hbm_bytes"] / dur / peak
             )
 
-    def _pick_prefill_slot(self) -> int | None:
-        """Next ready prefilling slot — deps satisfied and chunks still
-        to run (a slot whose FINAL chunk is already in flight under the
-        fused path waits for its fetch-side activation). Round-robin
-        for fairness; advances the pointer, so callers must run the
-        returned slot's next chunk. None when nothing is ready."""
+    def _pick_prefill_slots(self) -> list[int]:
+        """The ready prefilling slots whose next chunks share this
+        iteration's program, up to :meth:`_lanes_for` of them — deps
+        satisfied and chunks still to run (a slot whose FINAL chunk is
+        already in flight under the fused path waits for its fetch-side
+        activation), all of the first one's chunk width. Round-robin
+        for fairness; advances the pointer past the last, so callers
+        must run every returned slot's next chunk. Empty when nothing
+        is ready.
+
+        Readiness is read for all of them before any is dispatched: a
+        slot whose deps another lane of the same program would only
+        then write is not ready yet, and rides a later one."""
         n = self.config.max_slots
+        picked: list[int] = []
         for off in range(n):
             i = (self._prefill_rr + off) % n
             s = self._slots[i]
@@ -3723,21 +3812,78 @@ class ContinuousBatcher:
                 and s.phase == "prefill"
                 and s.next_pos < s.prompt_len
                 and all(node.ready for node in s.deps)
+                and (not picked or s.chunk == self._slots[picked[0]].chunk)
             ):
-                self._prefill_rr = (i + 1) % n
-                return i
-        return None
+                picked.append(i)
+                if len(picked) == self._lanes_for(s.chunk):
+                    break
+        if picked:
+            self._prefill_rr = (picked[-1] + 1) % n
+        return picked
 
-    def _prefill_step(self, idx: int) -> bool:
-        """Run ONE prefill chunk for slot ``idx`` as a STANDALONE
-        device program (the pre-fusion path, and still the path when no
-        decode batch exists to ride or ``ragged_attention`` is off).
+    def _lane_args(self, idxs: list[int]):
+        """The lane arguments of one chunk program carrying the next
+        chunk of each slot of ``idxs``: (program lanes, chunk ids
+        [L, C], tables [L, P], starts [L], last prompt positions [L],
+        done [L] bool, cost extents of the live lanes). One ready slot
+        takes the one-lane program; more take the wide one, the lanes
+        past ``idxs`` dead — an all-NULL table, as an idle slot's row."""
+        c = self.config
+        first = self._slots[idxs[0]]
+        lanes = 1 if len(idxs) == 1 else self._lanes_for(first.chunk)
+        ids = np.zeros((lanes, first.chunk), np.int32)
+        tables = np.full((lanes, c.pages_per_seq), NULL_PAGE, np.int32)
+        starts = np.zeros((lanes,), np.int32)
+        lasts = np.zeros((lanes,), np.int32)
+        done = np.zeros((lanes,), bool)
+        ext = []
+        for lane, idx in enumerate(idxs):
+            slot = self._slots[idx]
+            end = slot.next_pos + slot.chunk
+            ids[lane] = slot.padded_ids[slot.next_pos : end]
+            tables[lane] = slot.table
+            starts[lane] = slot.next_pos
+            lasts[lane] = slot.prompt_len - 1
+            done[lane] = end >= slot.prompt_len
+            ext.append((end, slot.chunk))
+        return lanes, ids, tables, starts, lasts, done, ext
+
+    def _count_lanes(self, kind: str, live: int) -> None:
+        """One chunk program of ``kind`` with ``live`` lanes filled."""
+        _M_CHUNK_LANES.labels(kind=kind, lanes=str(live)).inc()
+        with self._lock:
+            key = (kind, live)
+            self._chunk_lanes_n[key] = self._chunk_lanes_n.get(key, 0) + 1
+
+    def _chunk_dispatched(self, slot: _Slot) -> None:
+        """A chunk of ``slot`` is on the device stream: count its real
+        tokens, flip the registry nodes it completes and move the slot
+        on. The pages it covers are written by an ALREADY-DISPATCHED
+        program, and every consumer is either a later program on the
+        same stream (dependent chunks, decode reads) or a host
+        operation that flushes the pipeline first (restore installs,
+        CoW copies, demotion device_gets block on the stream)."""
+        written_end = slot.next_pos + slot.chunk
+        written_real = min(written_end, slot.prompt_len)
+        _M_PREFILL_TOKENS.inc(written_real - slot.next_pos)
+        for node, end_pos in slot.reg_nodes:
+            if not node.ready and end_pos <= written_real:
+                node.ready = True
+        slot.next_pos = written_end
+
+    def _prefill_step(self, idxs: list[int]) -> bool:
+        """Run the next prefill chunk of each slot of ``idxs`` as ONE
+        STANDALONE device program (the pre-fusion path, and still the
+        path when no decode batch exists to ride or ``ragged_attention``
+        is off).
 
         The unit of decode stall under chunked prefill: between any two
         decode steps at most one of these runs, so admission latency
-        costs running requests one bounded chunk, never a whole prompt.
+        costs running requests one bounded program, never a whole
+        prompt.
         """
-        slot = self._slots[idx]
+        slots = [self._slots[i] for i in idxs]
+        head = slots[0]
         if self._inflight:
             # Let in-flight decode work clear the device queue so the
             # stall histogram times ONLY this chunk. A device-order
@@ -3747,32 +3893,31 @@ class ContinuousBatcher:
                 jax.block_until_ready(self.cache.length)
         with self._phase("dispatch", kind="prefill"):
             if self._fused_ok and not self._decoding():
-                self._build_fused_ahead(slot.chunk, slot.s_bucket)
+                self._build_fused_ahead(head.chunk, head.s_bucket)
             t0 = time.perf_counter()
             ev = self._count_program("prefill")
-            chunk_ids = slot.padded_ids[
-                slot.next_pos : slot.next_pos + slot.chunk
-            ]
+            self._count_lanes("prefill", len(idxs))
+            lanes, ids, tables, starts, _, done, ext = self._lane_args(idxs)
             hidden, self.cache, *moe = self._chunk_fn(
-                slot.chunk, slot.s_bucket
+                head.chunk, lanes, head.s_bucket
             )(
                 self.params,
-                jnp.asarray(chunk_ids[None]),
-                jnp.asarray(slot.table),
-                jnp.int32(slot.next_pos),
+                jnp.asarray(ids),
+                jnp.asarray(tables),
+                jnp.asarray(starts),
                 self.cache,
             )
-            if self.draft_cache is not None:
-                self._draft_prefill_chunk(slot, chunk_ids, slot.next_pos)
-            written_end = slot.next_pos + slot.chunk
-            done = written_end >= slot.prompt_len
-            if done:
-                # Sample the first token from the last REAL position's
-                # hidden state (a [D] gather + D x V unembed — never a
-                # [C, V] logits buffer per chunk).
-                h = hidden[0, slot.prompt_len - 1 - slot.next_pos]
-                logits = self._jit_unembed(self.params, h)
-                first = self._sample_first(slot.request, logits)
+            firsts = {}
+            for lane, slot in enumerate(slots):
+                if self.draft_cache is not None:
+                    self._draft_prefill_chunk(slot, ids[lane], slot.next_pos)
+                if done[lane]:
+                    # Sample the first token from the last REAL
+                    # position's hidden state (a [D] gather + D x V
+                    # unembed — never a [C, V] logits buffer per chunk).
+                    h = hidden[lane, slot.prompt_len - 1 - slot.next_pos]
+                    logits = self._jit_unembed(self.params, h)
+                    firsts[lane] = self._sample_first(slot.request, logits)
         # The device work above must COMPLETE before (a) the stall
         # histogram records it and (b) successors read the pages this
         # chunk wrote.
@@ -3782,7 +3927,6 @@ class ContinuousBatcher:
         # last one activate the row.
         with self._phase("retire"):
             dur = time.perf_counter() - t0
-            _M_PREFILL_STALL.observe(dur)
             if moe:
                 self._count_moe("prefill", np.asarray(moe[0]))
             if ev is not None:
@@ -3793,43 +3937,40 @@ class ContinuousBatcher:
                 ev.t0 = t0
                 ev.dur = dur
                 ev.meta = {
-                    **ev.meta, "slot": idx, "pos": slot.next_pos,
-                    "width": slot.chunk,
+                    **ev.meta, "slot": idxs[0], "pos": head.next_pos,
+                    "width": head.chunk, "lanes": len(idxs),
                 }
             self._mbu_account(
-                "prefill",
-                self._program_cost(
-                    "prefill", [], 0, chunk_ext=(written_end, slot.chunk)
-                ),
+                "prefill", self._program_cost("prefill", [], 0, chunk_ext=ext),
                 dur,
             )
-            trace = slot.request.trace
-            if trace is not None:
-                trace.add_span(
-                    "prefill_chunk", t0, dur,
-                    pos=slot.next_pos, chunk=slot.chunk,
+            for lane, (idx, slot) in enumerate(zip(idxs, slots)):
+                # The program stalled the decode loop once: the lanes
+                # past the first rode along (0, as a fused chunk's) —
+                # count-lockstep with prefill_chunks.
+                _M_PREFILL_STALL.observe(dur if lane == 0 else 0.0)
+                trace = slot.request.trace
+                if trace is not None:
+                    trace.add_span(
+                        "prefill_chunk", t0, dur,
+                        pos=slot.next_pos, chunk=slot.chunk,
+                    )
+                self._chunk_dispatched(slot)
+                with self._lock:
+                    self._prefill_chunks += 1
+                if not done[lane]:
+                    continue
+                # Final chunk landed: make the row visible to the decode
+                # program (table + true length in one pass) and flip to
+                # decoding.
+                self.cache = install_seq(
+                    self.cache,
+                    jnp.int32(idx),
+                    jnp.asarray(slot.table),
+                    jnp.int32(slot.prompt_len),
                 )
-            written_real = min(written_end, slot.prompt_len)
-            _M_PREFILL_TOKENS.inc(written_real - slot.next_pos)
-            for node, end_pos in slot.reg_nodes:
-                if not node.ready and end_pos <= written_real:
-                    node.ready = True
-            slot.next_pos = written_end
-            with self._lock:
-                self._prefill_chunks += 1
-            if not done:
-                return True
-            # Final chunk landed: make the row visible to the decode
-            # program (table + true length in one pass) and flip to
-            # decoding.
-            self.cache = install_seq(
-                self.cache,
-                jnp.int32(idx),
-                jnp.asarray(slot.table),
-                jnp.int32(slot.prompt_len),
-            )
-            self._install_draft_seq(idx, slot)
-            self._activate(idx, slot, first)
+                self._install_draft_seq(idx, slot)
+                self._activate(idx, slot, firsts[lane])
             return True
 
     def _install_draft_seq(self, idx: int, slot: _Slot) -> None:
@@ -4096,7 +4237,7 @@ class ContinuousBatcher:
 
     def _dispatch(
         self,
-        chunk_idx: int | None = None,
+        chunk_idxs: list[int] | None = None,
         spec: bool = False,
         rounds: int = 1,
         rounds_choice: bool = False,
@@ -4112,14 +4253,15 @@ class ContinuousBatcher:
         execution. Rows (re)activated since the previous dispatch are
         patched in from the host mirror (``_tok_dirty``).
 
-        ``chunk_idx`` (PR 8): a ready prefilling slot whose next chunk
-        rides THIS program (the fused scheduler step) instead of
-        running standalone. The chunk's device work is ordered on the
-        stream at dispatch — its registry nodes flip ready HERE, since
-        every consumer is a later program on the same stream or a
-        flush-first host operation — while its host bookkeeping
-        (activation, first-token sampling off the returned logits)
-        happens at the fetch, inside the pipeline's overlap window.
+        ``chunk_idxs`` (PR 8; a list since PR 31): ready prefilling
+        slots whose next chunks ride THIS program (the fused scheduler
+        step), a lane each, instead of running standalone. A chunk's
+        device work is ordered on the stream at dispatch — its registry
+        nodes flip ready HERE, since every consumer is a later program
+        on the same stream or a flush-first host operation — while its
+        host bookkeeping (activation, first-token sampling off the
+        returned logits) happens at the fetch, inside the pipeline's
+        overlap window.
 
         ``spec`` (PR 9): dispatch the speculative draft/verify program
         instead — one device program whose per-row token yield is
@@ -4127,7 +4269,7 @@ class ContinuousBatcher:
         pipeline: the emit buffer is the fetch target, the last
         emitted token the next dispatch's input, and the PRNG counts
         thread device-resident program-to-program (the host mirror
-        syncs at fetch). Mutually exclusive with ``chunk_idx`` —
+        syncs at fetch). Mutually exclusive with ``chunk_idxs`` —
         chunks run standalone while speculation is engaged.
 
         ``rounds`` (PR 12): the multi-round engage state from _run's
@@ -4371,8 +4513,8 @@ class ContinuousBatcher:
         )
         if self._plain_shapes is None and self._fused_ok and not rounds_now:
             self._plain_shapes = jax.tree.map(_abstract, args[:9])
-        chunk_rec = None
-        if chunk_idx is None:
+        chunk_recs: list[_InflightChunk] = []
+        if not chunk_idxs:
             if rounds_now:
                 # Same prepared device args as the one-step program
                 # (args[9] is groups — _rounds_sample takes it after
@@ -4392,19 +4534,17 @@ class ContinuousBatcher:
             )
             cost = self._program_cost("decode", rows_now, k)
         else:
-            slot = self._slots[chunk_idx]
-            chunk_ids = slot.padded_ids[
-                slot.next_pos : slot.next_pos + slot.chunk
-            ]
-            written_end = slot.next_pos + slot.chunk
-            chunk_done = written_end >= slot.prompt_len
-            out = self._fused_fn(slot.chunk, slot.s_bucket)(
+            head = self._slots[chunk_idxs[0]]
+            lanes, ids, tables, starts, lasts, done, ext = self._lane_args(
+                chunk_idxs
+            )
+            out = self._fused_fn(head.chunk, lanes, head.s_bucket)(
                 *args,
-                jnp.asarray(chunk_ids[None]),
-                jnp.asarray(slot.table),
-                jnp.int32(slot.next_pos),
-                jnp.int32(slot.prompt_len - 1),
-                np.bool_(chunk_done),
+                jnp.asarray(ids),
+                jnp.asarray(tables),
+                jnp.asarray(starts),
+                jnp.asarray(lasts),
+                jnp.asarray(done),
                 *(
                     (rounds_now, budgets_dev, screen_dev)
                     if rounds_now
@@ -4419,46 +4559,39 @@ class ContinuousBatcher:
             else:
                 next_tok, _, self.cache, next_in, chunk_logits, aux = out
             ev = self._count_program(
-                "fused", rows=len(rows_now) + 1, rounds=k
+                "fused", rows=len(rows_now) + len(chunk_idxs), rounds=k
             )
-            cost = self._program_cost(
-                "fused", rows_now, k, chunk_ext=(written_end, slot.chunk)
-            )
-            _flight.flight_recorder().record(
-                "chunk",
-                t0,
-                trace_id=_tracing.trace_id_of(slot.request.trace),
-                slot=chunk_idx,
-                pos=slot.next_pos,
-                width=slot.chunk,
-                fused=1,
-            )
-            if self.draft_cache is not None:
-                # The draft's mirror of the riding chunk — its own
-                # small program right behind the fused dispatch (the
-                # two touch disjoint pools; stream order is irrelevant
-                # between them, only their fetch/flush consumers care).
-                self._draft_prefill_chunk(slot, chunk_ids, slot.next_pos)
-            written_real = min(written_end, slot.prompt_len)
-            _M_PREFILL_TOKENS.inc(written_real - slot.next_pos)
-            # Device-stream readiness: the pages this chunk covers are
-            # written by an ALREADY-DISPATCHED program, and every
-            # consumer is either a later program on the same stream
-            # (dependent chunks, decode reads) or a host operation
-            # that flushes the pipeline first (restore installs, CoW
-            # copies, demotion device_gets block on the stream).
-            for node, end_pos in slot.reg_nodes:
-                if not node.ready and end_pos <= written_real:
-                    node.ready = True
-            chunk_rec = _InflightChunk(
-                idx=chunk_idx,
-                slot=slot,
-                done=chunk_done,
-                logits=chunk_logits,
-                pos=slot.next_pos,
-                width=slot.chunk,
-            )
-            slot.next_pos = written_end
+            self._count_lanes("fused", len(chunk_idxs))
+            cost = self._program_cost("fused", rows_now, k, chunk_ext=ext)
+            for lane, idx in enumerate(chunk_idxs):
+                slot = self._slots[idx]
+                _flight.flight_recorder().record(
+                    "chunk",
+                    t0,
+                    trace_id=_tracing.trace_id_of(slot.request.trace),
+                    slot=idx,
+                    pos=slot.next_pos,
+                    width=slot.chunk,
+                    fused=1,
+                )
+                if self.draft_cache is not None:
+                    # The draft's mirror of the riding chunk — its own
+                    # small program right behind the fused dispatch (the
+                    # two touch disjoint pools; stream order is
+                    # irrelevant between them, only their fetch/flush
+                    # consumers care).
+                    self._draft_prefill_chunk(slot, ids[lane], slot.next_pos)
+                chunk_recs.append(
+                    _InflightChunk(
+                        idx=idx,
+                        slot=slot,
+                        done=bool(done[lane]),
+                        logits=chunk_logits[lane],
+                        pos=slot.next_pos,
+                        width=slot.chunk,
+                    )
+                )
+                self._chunk_dispatched(slot)
         # Host counters track the DEVICE stream at dispatch: the
         # program advances every participating row by k regardless of
         # what the fetch later keeps, so a surviving row's next
@@ -4475,7 +4608,7 @@ class ContinuousBatcher:
                     s.draft_lag += k
         rec = _Inflight(
             tokens=next_tok, next_input=next_in, t0=t0, k=k,
-            rows=rows_now, chunk=chunk_rec, rounds=rounds_now,
+            rows=rows_now, chunks=chunk_recs, rounds=rounds_now,
             rounds_clean=rounds_clean,
             emit_cnt=emit_cnt, counts_out=cnt_out, flight=ev, cost=cost,
             aux=aux,
@@ -4532,7 +4665,7 @@ class ContinuousBatcher:
         with self._phase("retire"):
             if moe_np is not None:
                 self._count_moe(
-                    "fused" if rec.chunk else "decode", moe_np, rec.k
+                    "fused" if rec.chunks else "decode", moe_np, rec.k
                 )
             self._credit_fetched(rec, next_np, cnt_np)
 
@@ -4574,7 +4707,7 @@ class ContinuousBatcher:
             rec.flight.t0 = start
             rec.flight.dur = dur
         self._mbu_account(
-            "spec" if rec.spec else ("fused" if rec.chunk else "decode"),
+            "spec" if rec.spec else ("fused" if rec.chunks else "decode"),
             rec.cost,
             dur,
         )
@@ -4734,8 +4867,9 @@ class ContinuousBatcher:
             # Replace, never mutate: a concurrent export may hold the
             # old meta dict.
             rec.flight.meta = {**rec.flight.meta, "tokens": emitted_total}
-        ch = rec.chunk
-        if ch is not None and self._slots[ch.idx] is ch.slot:
+        for ch in rec.chunks:
+            if self._slots[ch.idx] is not ch.slot:
+                continue
             # Fused prefill chunk (PR 8): host bookkeeping deferred to
             # the fetch — its device work completed with the program
             # whose tokens we just pulled. The chunk did not stall the
@@ -4800,15 +4934,16 @@ class ContinuousBatcher:
             progress = False
             ran_program = False
             # At most ONE prefill work unit per iteration — a host-tier
-            # page restore (which unblocks gated prefills) or a prefill
-            # chunk: running slots pay a bounded stall per admission
+            # page restore (which unblocks gated prefills) or one chunk
+            # program (the next chunk of each ready slot it has a lane
+            # for): running slots pay a bounded stall per admission
             # instead of a whole prompt's prefill.
-            chunk_idx = None
+            chunk_idxs: list[int] = []
             if self._restores:
                 with self._phase("restore"):
                     progress = self._restore_step()
             if not progress:
-                chunk_idx = self._pick_prefill_slot()
+                chunk_idxs = self._pick_prefill_slots()
             # Speculative decoding (PR 9): read the engage state once
             # per iteration (config.spec_decode may flip between
             # bursts). While speculation is on, chunks run standalone —
@@ -4879,19 +5014,19 @@ class ContinuousBatcher:
                         "spec_flip", time.perf_counter(), on=spec_now
                     )
                 self._spec_flip_prev = spec_now
-            # The fused scheduler step (PR 8): a ready chunk rides the
-            # decode dispatch as one more ragged-kernel row — ONE
-            # device program per iteration instead of chunk-then-
-            # decode. With no decode batch to ride (or fusion off) the
-            # chunk runs standalone, still one program this iteration.
+            # The fused scheduler step (PR 8): the ready chunks ride the
+            # decode dispatch as more ragged-kernel rows — ONE device
+            # program per iteration instead of chunk-then-decode. With
+            # no decode batch to ride (or fusion off) they run
+            # standalone, still one program this iteration.
             fused = (
-                chunk_idx is not None
+                bool(chunk_idxs)
                 and self._fused_ok
                 and self._decoding()
                 and not spec_now
             )
-            if chunk_idx is not None and not fused:
-                self._prefill_step(chunk_idx)
+            if chunk_idxs and not fused:
+                self._prefill_step(chunk_idxs)
                 progress = True
                 ran_program = True
                 if self._fused_ok:
@@ -4952,7 +5087,7 @@ class ContinuousBatcher:
                         # the steady state (every lag-free iteration).
                         self._spec_catch_up()
                     self._dispatch(
-                        chunk_idx if fused else None,
+                        chunk_idxs if fused else None,
                         spec=spec_now,
                         rounds=rounds_now,
                         rounds_choice=rounds_choice,

@@ -368,13 +368,24 @@ def test_cost_model_fused_vs_split_kv_parity(params):
         params,
         config=ContinuousConfig(**_CCFG, pipeline_depth=1),
     )
-    prompts = [_HEADER + f"tail {i}" for i in range(4)]
+    # A first prompt is decoding when the others arrive, so their chunks
+    # have a dispatch to ride (with every slot mid-prompt at once their
+    # chunks share standalone programs, PR 31, and nothing is fused).
+    prompts = ["first: it is decoding when the rest arrive"] + [
+        _HEADER + f"tail {i}" for i in range(3)
+    ]
 
     def leg(ragged: bool):
         b.config.ragged_attention = ragged
         _quiesce(b)
         s0 = b.stats()
-        texts = [r.text for r in _serve(b, prompts, max_new_tokens=8)]
+        first = b.submit(prompts[0], max_new_tokens=24)
+        deadline = time.monotonic() + 60
+        while not b.stats()["active_slots"]:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        texts = [r.text for r in _serve(b, prompts[1:], max_new_tokens=8)]
+        texts.append(first.result(timeout=120).text)
         _quiesce(b)
         s1 = b.stats()
         d = {
@@ -385,7 +396,8 @@ def test_cost_model_fused_vs_split_kv_parity(params):
         return texts, d
 
     try:
-        _serve(b, [_HEADER + "warm fused"], max_new_tokens=4)  # compile
+        # Compile, and leave both legs the same registry to map from.
+        _serve(b, [prompts[0], _HEADER + "warm fused"], max_new_tokens=4)
         b.config.ragged_attention = False
         _serve(b, [_HEADER + "warm split"], max_new_tokens=4)
         texts_on, d_on = leg(True)
@@ -498,9 +510,9 @@ def test_attention_pages_read_hand_count(params):
             for i, n in enumerate((40, 33, 16, 70))
         ]
         decode = b._program_cost("decode", rows, 1)
-        fused = b._program_cost("fused", rows, 1, chunk_ext=(48, 16))
+        fused = b._program_cost("fused", rows, 1, chunk_ext=[(48, 16)])
         rounds = b._program_cost("decode", rows, 2)
-        prefill = b._program_cost("prefill", [], 0, chunk_ext=(48, 16))
+        prefill = b._program_cost("prefill", [], 0, chunk_ext=[(48, 16)])
     finally:
         b.close()
     # 3 + 3 + 1 + 5 pages under the fills, the shared two read once.

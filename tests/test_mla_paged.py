@@ -163,6 +163,94 @@ def test_fused_and_grouped_programs_match_reference(use_pallas):
     np.testing.assert_allclose(got_c, want_c, atol=TOL, rtol=0)
 
 
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["xla", "pallas"])
+def test_fused_step_with_three_lanes_matches_reference(use_pallas):
+    """Three prompts of 30, 45 and 16 tokens prefill together, a lane
+    each of one fused program a step, beside two grouped decoding rows:
+    lanes at different fills over tables of their own, and a lane that
+    has run out of prompt riding on dead (an all-NULL table)."""
+    cfg, params = _model(use_pallas)
+    shared = _tokens(2 * PAGE, 3)
+    a = np.concatenate([shared, _tokens(5, 4)])
+    b = np.concatenate([shared, _tokens(9, 5)])
+    ta, tb = _table([1, 2, 3]), _table([1, 2, 4, 5])
+    cache = _fresh_cache(cfg)
+    la, cache = _prefill(cfg, params, cache, a, ta)
+    lb, cache = _prefill(cfg, params, cache, b, tb, start=2 * PAGE)
+    cache = install_seq(cache, jnp.int32(0), ta, jnp.int32(len(a)))
+    cache = install_seq(cache, jnp.int32(2), tb, jnp.int32(len(b)))
+    groups = DecodeGroupArrays(
+        group_id=jnp.asarray([0, -1, 0, -1], jnp.int32),
+        group_rep=jnp.asarray([0, 0], jnp.int32),
+        group_pages=jnp.asarray([2, 0], jnp.int32),
+        shared_start=jnp.asarray([2 * PAGE, 0, 2 * PAGE, 0], jnp.int32),
+    )
+    prompts = [_tokens(30, 6), _tokens(45, 7), _tokens(16, 8)]
+    tables = [
+        _table([9, 10, 11, 12]), _table([13, 14, 15, 16, 17, 18]),
+        _table([19, 20]),
+    ]
+    dead = _table([])
+    fa, fb = _tokens(4, 9), _tokens(4, 10)
+    got_a, got_b, got_c = [np.asarray(la)], [np.asarray(lb)], {}
+    for step in range(3):
+        c0 = step * CHUNK
+        live = [c0 < len(ids) for ids in prompts]
+        chunk = np.zeros((3, CHUNK), np.int32)
+        for lane, ids in enumerate(prompts):
+            part = ids[c0 : c0 + CHUNK]
+            chunk[lane, : len(part)] = part
+        toks = (
+            jnp.zeros((SLOTS, 1), jnp.int32)
+            .at[0, 0].set(int(fa[step]))
+            .at[2, 0].set(int(fb[step]))
+        )
+        logits, hidden, cache, stats = fused_step_paged(
+            cfg, params, toks, cache, jnp.asarray(chunk),
+            jnp.stack([t if on else dead for t, on in zip(tables, live)]),
+            jnp.asarray([c0 if on else 0 for on in live], jnp.int32),
+            groups=groups,
+        )
+        got_a.append(np.asarray(logits[0]))
+        got_b.append(np.asarray(logits[2]))
+        # 2 live rows + the live lanes' tokens, 3 experts each, 2 expert
+        # layers: a dead lane's tokens take no expert.
+        assert int(stats[1]) == 2 * 3 * (2 + CHUNK * sum(live))
+        for lane, ids in enumerate(prompts):
+            if c0 < len(ids) <= c0 + CHUNK:
+                got_c[lane] = np.asarray(
+                    unembed_one(cfg, params, hidden[lane, len(ids) - 1 - c0])
+                )
+    for ids, follow, got in ((a, fa, got_a), (b, fb, got_b)):
+        full = np.concatenate([ids, follow[:3]])
+        want = _ref_logits(
+            cfg, params, full, np.arange(len(ids) - 1, len(full))
+        )
+        np.testing.assert_allclose(np.stack(got), want, atol=TOL, rtol=0)
+    for lane, ids in enumerate(prompts):
+        want = _ref_logits(cfg, params, ids, [len(ids) - 1])[0]
+        np.testing.assert_allclose(got_c[lane], want, atol=TOL, rtol=0)
+
+
+@pytest.mark.parametrize(
+    "starts", [[11, 24], [11, -16, 29], [-16, 0, 5]],
+    ids=["two", "three-one-dead", "three-two-dead"],
+)
+def test_latent_kernel_chunk_lanes_match_reference(starts):
+    """The kernel on a latent pool with ``nc`` in {2, 3}: lanes at
+    different fills and tables, dead ones, a group present, the stacked
+    pool indexed in place."""
+    from llm_consensus_tpu.ops.pallas import parity
+
+    errs = parity.ragged_attention_error(
+        seed=5, pg=8, hkv=1, g=4, d=64, latent_dv=32, p_per=6, n_pages=64,
+        valid_len=[13, 9, 40, 23], cq=16, chunk_start=starts,
+        group_rows=(0, 2, 3), layer=(1, 2), interpret=True,
+    )
+    for lane, err in errs.items():
+        parity.check(lane, err, parity.ATTENTION_TOL)
+
+
 def test_tolerance_rejects_bf16():
     """The reference with its residual stream rounded to bfloat16 after
     every layer misses the float32 reference by far more than TOL."""

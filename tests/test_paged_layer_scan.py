@@ -292,6 +292,87 @@ def test_step_programs_match_a_loop_over_layer_slices(
 
 
 # ---------------------------------------------------------------------------
+# Chunk lanes (PR 31): L sequences' chunks on one token axis
+# ---------------------------------------------------------------------------
+
+
+def _lane_args(cfg, lanes: int, dead: tuple = ()):
+    """``lanes`` chunks at different starts over tables of their own
+    (past the decode rows' pages); the ``dead`` ones an all-NULL table."""
+    rng = np.random.default_rng(3)
+    tokens = rng.integers(1, cfg.vocab_size, (lanes, CHUNK)).astype(np.int32)
+    tables = np.full((lanes, PPS), NULL_PAGE, np.int32)
+    starts = np.zeros((lanes,), np.int32)
+    for lane in range(lanes):
+        if lane not in dead:
+            first = 1 + (SLOTS + lane) * PPS
+            tables[lane] = np.arange(first, first + PPS)
+            starts[lane] = (19, 0, 35, 50)[lane]
+    return jnp.asarray(tokens), jnp.asarray(tables), jnp.asarray(starts)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("model", ["test-tiny", "test-tiny-mla"])
+@pytest.mark.parametrize("dead", [(), (1,)], ids=["all-live", "one-dead"])
+def test_fused_step_with_lanes_equals_single_lane_calls(
+    params, model, use_pallas, dead
+):
+    """One ``fused_step_paged`` with three lanes leaves what three
+    single-lane calls in sequence leave — the fused step with the first
+    lane, then a standalone chunk for each other: the decode logits,
+    each lane's hidden states and the pools. A row of a matmul does not
+    depend on its neighbours, lanes write disjoint pages, and a dead
+    lane writes the NULL page alone. Dense and latent/expert."""
+    if model == "test-tiny":
+        cfg = CFG.with_(use_pallas=use_pallas)
+    else:
+        cfg = get_config(model).with_(use_pallas=use_pallas)
+        params = T.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    base = _cache(96, jnp.float32)
+    if cfg.is_mla:
+        rng = np.random.default_rng(5)
+        shape = (cfg.n_layers, 96, PAGE, cfg.latent_pool_dim)
+        base = PagedKVCache(
+            k=jnp.asarray(rng.standard_normal(shape), jnp.float32),
+            v=jnp.zeros((*shape[:-1], 0), jnp.float32),
+            page_table=base.page_table, length=base.length,
+        )
+    tokens, tables, starts = _lane_args(cfg, 3, dead)
+    logits, hidden, cache, *stats = jax.jit(
+        partial(T.fused_step_paged, cfg)
+    )(params, _tokens(1), base, tokens, tables, starts)
+
+    logits1, hidden0, cache1, *stats1 = jax.jit(
+        partial(T.fused_step_paged, cfg)
+    )(params, _tokens(1), base, tokens[:1], tables[:1], starts[:1])
+    hiddens = [hidden0[0]]
+    chunk = jax.jit(partial(T.prefill_chunk_paged, cfg))
+    for lane in (1, 2):
+        h, cache1, *st = chunk(
+            params, tokens[lane : lane + 1], tables[lane], starts[lane], cache1
+        )
+        hiddens.append(h[0])
+        stats1 = [a + b for a, b in zip(stats1, st)]
+
+    tol = dict(rtol=0, atol=2e-5)
+    np.testing.assert_allclose(logits, logits1, **tol)
+    for lane in range(3):
+        if lane not in dead:
+            np.testing.assert_allclose(hidden[lane], hiddens[lane], **tol)
+    live_pages = np.ones((96,), bool)
+    live_pages[NULL_PAGE] = False  # dead rows and lanes write garbage there
+    for a, b in ((cache.k, cache1.k), (cache.v, cache1.v)):
+        np.testing.assert_allclose(
+            np.asarray(a)[:, live_pages], np.asarray(b)[:, live_pages], **tol
+        )
+    np.testing.assert_array_equal(cache.length, cache1.length)
+    if stats:
+        # [experts reached, assignments]: the assignments add up, lane
+        # by lane; a dead lane's tokens take no expert.
+        assert int(stats[0][1]) == int(stats1[0][1])
+
+
+# ---------------------------------------------------------------------------
 # The batcher's fused programs: built ahead, one a bucket and grouping
 # ---------------------------------------------------------------------------
 
@@ -340,12 +421,13 @@ def _until(what: str, holds, timeout=120.0):
 
 
 def test_fused_program_is_built_before_its_first_use(params):
-    """A bucket's fused program is traced and compiled when a chunk of
-    the bucket runs alone on a batcher that has dispatched before:
-    nothing decodes then, and a chunk that later rides a dispatch, on
-    its last step or not, traces nothing (PR 27: a program first met
-    under load stalled every row, and "last chunk" was a program of its
-    own)."""
+    """The fused programs — one lane and the wide one (PR 31) — are
+    traced and compiled when a chunk runs alone on a batcher that has
+    dispatched before: nothing decodes then, and chunks that later ride
+    a dispatch, one or several, of this bucket or another, on their last
+    step or not, trace nothing (PR 27: a program first met under load
+    stalled every row, and "last chunk" was a program of its own; until
+    PR 31 every bucket built the same program again)."""
     b = ContinuousBatcher(
         CFG, params, config=ContinuousConfig(**_CCFG)
     )
@@ -355,14 +437,24 @@ def test_fused_program_is_built_before_its_first_use(params):
         _serve(b, ["the process's first prompt"], max_new_tokens=2)
         assert traces == []
         _serve(b, ["a prompt served alone, 2 chunks"], max_new_tokens=2)
-        assert traces == [True]
+        assert traces == [True, True]  # one lane, and SLOTS of them
+        assert len(b._jit_fused) == 2 and len(b._jit_chunk) == 2
         assert _quiesce(b)["device_programs_fused"] == 0
         # The second prompt's chunks ride the first one's decode steps.
         texts = [r.text for r in _serve(
             b, ["a companion that keeps decoding", "the one that rides"]
         )]
         assert _quiesce(b)["device_programs_fused"] >= 1
-        assert traces == [True]
+        # Two more, of another bucket, ride together beside a third.
+        _serve(b, [
+            "a companion that keeps decoding",
+            "the first of two that ride together, four chunks of them",
+            "the second of two that ride together, four chunks too...",
+        ])
+        st = _quiesce(b)
+        assert st.get("chunk_lanes_fused_2", 0) >= 1, st
+        assert traces == [True, True]
+        assert len(b._jit_fused) == 2 and len(b._jit_chunk) == 2
     finally:
         b.close()
     # The first token of the one that rode comes off the fused

@@ -153,6 +153,63 @@ def test_ragged_all_prefill_and_dead_decode_rows():
     _within_tol(errs)
 
 
+# Chunk lanes (PR 31): rows b .. b + nc - 1 are prefill chunks of
+# different sequences, each over its own table at its own fill.
+_LANES = {
+    "two lanes": dict(chunk_start=[11, 24]),
+    "three lanes, one at position 0": dict(chunk_start=[11, 0, 29]),
+    "three lanes, the middle one dead": dict(chunk_start=[11, -16, 24]),
+    "three lanes, only the last live": dict(chunk_start=[-16, -16, 5]),
+}
+
+
+@pytest.mark.parametrize("grouped", [False, True], ids=["plain", "grouped"])
+@pytest.mark.parametrize("case", list(_LANES))
+def test_ragged_chunk_lanes_match_reference(case, grouped):
+    """Several chunk lanes in one program, with and without a group
+    present and under a window, sliced and stacked pools: each lane
+    equals the reference's own fold over its table; a dead lane owes a
+    finite output and moves nothing else."""
+    groups = dict(group_rows=(0, 2, 3)) if grouped else {}
+    for layer, window in ((None, 0), ((2, 3), 9)):
+        errs = parity.ragged_attention_error(
+            **{**_TOY, "n_pages": 64}, seed=7, g=3,
+            valid_len=[13, 9, 40, 23], cq=16, window=window, layer=layer,
+            **groups, **_LANES[case],
+        )
+        assert set(errs) == {"decode", "chunk"}
+        _within_tol(errs)
+
+
+def test_ragged_lanes_equal_single_lane_calls():
+    """Lane l of a three-lane call returns the bytes a one-lane call
+    on that lane alone returns: a lane does not see its neighbours."""
+    from llm_consensus_tpu.ops.pallas.attention import ragged_paged_attention
+
+    rng = np.random.default_rng(11)
+    pg, hkv, g, d, p_per, n_pages, cq = 8, 2, 3, 32, 6, 64, 16
+    pool = lambda: jnp.asarray(  # noqa: E731
+        rng.standard_normal((n_pages, pg, hkv, d)), jnp.bfloat16
+    )
+    kp, vp = pool(), pool()
+    q = jnp.asarray(rng.standard_normal((2, hkv * g, d)), jnp.bfloat16)
+    perm = rng.permutation(np.arange(1, n_pages)).astype(np.int32)
+    tbl = jnp.asarray(perm[: 2 * p_per].reshape(2, p_per))
+    ctbl = jnp.asarray(perm[2 * p_per : 5 * p_per].reshape(3, p_per))
+    qc = jnp.asarray(rng.standard_normal((3, cq, hkv * g, d)), jnp.bfloat16)
+    starts = jnp.asarray([11, 0, 29], jnp.int32)
+    vl = jnp.asarray([13, 30], jnp.int32)
+    call = lambda qc, ct, cs: ragged_paged_attention(  # noqa: E731
+        q, kp, vp, tbl, vl, q_chunk=qc, chunk_table=ct, chunk_start=cs,
+        interpret=True,
+    )
+    dec, chunks = call(qc, ctbl, starts)
+    for lane in range(3):
+        dec1, one = call(qc[lane], ctbl[lane], starts[lane])
+        np.testing.assert_array_equal(np.asarray(one), np.asarray(chunks[lane]))
+        np.testing.assert_array_equal(np.asarray(dec1), np.asarray(dec))
+
+
 # The walk (PR 29): the kernel's steps follow the pages its rows hold,
 # not the table's width. Each case names what its rows hold.
 _WALK = {
@@ -506,3 +563,130 @@ def test_prefill_chunks_and_stall_lockstep_under_fusion(params):
     _, st = _burst_texts(params, ragged=True)
     assert st["prefill_chunks"] >= 2
     assert PREFILL_STALL_SECONDS.count - before == st["prefill_chunks"]
+
+
+# ---------------------------------------------------------------------------
+# Batcher: chunk lanes (PR 31)
+# ---------------------------------------------------------------------------
+
+# The cells' own rule: 8 slots and 64-token chunks leave the 256-row
+# token axis room for three lanes.
+_LCFG = dict(
+    max_slots=8, page_size=16, n_pages=256, pages_per_seq=20,
+    max_new_tokens=6, seq_buckets=(64, 128, 256), prefill_chunk=64,
+    share_prefix=True,
+)
+# 127 bytes behind the BOS token: eight full pages. The tails part at
+# their first byte, so no partly shared page is there to copy whether a
+# donor has finished or not.
+_LONG_HEADER = (("Panel header shared by every evaluation, one page a line"
+                 + "." * 8) * 2)[:127]
+_LANE_PROMPTS = [
+    _LONG_HEADER + f"{i} evaluates the answer of a persona " + "x" * (5 + 7 * i)
+    for i in range(4)
+] + [f"{i + 5} is the number of an unshared prompt: " + "yz" * (60 + 9 * i)
+     for i in range(2)]
+
+
+def _lane_counters():
+    from llm_consensus_tpu.server.metrics import (
+        CHUNK_LANES, DEVICE_PROGRAMS, PREFILL_TOKENS,
+    )
+
+    lanes = {
+        (kind, n): CHUNK_LANES.labels(kind=kind, lanes=str(n)).value
+        for kind in ("prefill", "fused") for n in range(1, 9)
+    }
+    return {
+        "tokens": PREFILL_TOKENS.value,
+        "programs": {
+            k: DEVICE_PROGRAMS.labels(kind=k).value
+            for k in ("prefill", "fused")
+        },
+        "lanes": lanes,
+    }
+
+
+def _lane_run(params, waves, cfg=CFG):
+    """Serve ``waves`` (lists of prompts sent together) one after the
+    other on one batcher; results, stats and counter deltas."""
+    before = _lane_counters()
+    b = ContinuousBatcher(cfg, params, config=ContinuousConfig(**_LCFG))
+    try:
+        assert b._lanes_for(64) == 3
+        outs = []
+        for wave in waves:
+            outs += _serve(b, wave)
+            _quiesce(b)
+        st = _quiesce(b)
+    finally:
+        b.close()
+    after = _lane_counters()
+    delta = {
+        "tokens": after["tokens"] - before["tokens"],
+        "programs": {k: after["programs"][k] - before["programs"][k]
+                     for k in after["programs"]},
+        "lanes": {k: after["lanes"][k] - before["lanes"][k]
+                  for k in after["lanes"] if after["lanes"][k] != before["lanes"][k]},
+    }
+    return outs, st, delta
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["xla", "kernel"])
+def test_chunk_lanes_burst_equals_one_lane_serving(params, use_pallas):
+    """Four prompts that share a page-aligned header and two that share
+    nothing, sent together (their ready chunks ride three to a program)
+    against the same prompts sent one after another (one lane, as
+    before): byte-identical greedy text and token counts, the same
+    prefix hits and prompt tokens computed — in a third of the chunk
+    programs."""
+    cfg = CFG.with_(use_pallas=use_pallas)
+    burst, st_b, d_b = _lane_run(params, [_LANE_PROMPTS], cfg)
+    alone, st_a, d_a = _lane_run(params, [[p] for p in _LANE_PROMPTS], cfg)
+    assert [(o.text, o.num_tokens) for o in burst] == [
+        (o.text, o.num_tokens) for o in alone
+    ]
+    for key in ("prefix_hits", "prefix_pages_shared", "prefix_pages_copied",
+                "prefill_chunks"):
+        assert st_b[key] == st_a[key], key
+    assert st_b["prefix_pages_shared"] == 3 * 8  # three mappers, 8 pages
+    assert d_b["tokens"] == d_a["tokens"] == sum(
+        o.timing["prompt_tokens"] for o in burst
+    ) - 3 * 8 * 16
+
+    chunks = st_b["prefill_chunks"]
+    assert chunks == sum(
+        -(-(o.timing["prompt_tokens"] - 16 * o.timing["header_pages_shared"])
+          // 64)
+        for o in burst
+    ) == 3 + 3 * 1 + 2 * 3  # donor, mappers, the unshared
+    # One after another: a program a chunk, every one the narrow one.
+    programs_a = d_a["programs"]["prefill"] + d_a["programs"]["fused"]
+    assert programs_a == chunks
+    assert set(n for _, n in d_a["lanes"]) == {1}
+    # Together: three lanes a program, but for the donor's head start
+    # (its two header chunks, which the mappers wait for).
+    programs_b = d_b["programs"]["prefill"] + d_b["programs"]["fused"]
+    assert programs_b <= -(-chunks // 3) + 2, d_b
+    assert max(n for _, n in d_b["lanes"]) == 3
+    # The lanes counter: by kind it sums to the chunk programs, and
+    # weighted by its lanes to the chunks.
+    for d in (d_a, d_b):
+        for kind in ("prefill", "fused"):
+            assert sum(
+                v for (k, _), v in d["lanes"].items() if k == kind
+            ) == d["programs"][kind]
+        assert sum(n * v for (_, n), v in d["lanes"].items()) == chunks
+    assert sum(
+        v for k, v in st_b.items() if k.startswith("chunk_lanes_")
+    ) == programs_b
+
+
+def test_lone_prompt_takes_the_narrow_program(params):
+    """One ready slot: the one-lane programs, as many as it has chunks,
+    and no wide program is ever called."""
+    prompt = "a lone prompt of three chunks: " + "ab" * 70
+    outs, st, d = _lane_run(params, [[prompt]])
+    assert st["prefill_chunks"] == 3
+    assert d["programs"]["prefill"] + d["programs"]["fused"] == 3
+    assert d["lanes"] == {("prefill", 1): 3}

@@ -197,3 +197,73 @@ def test_moe_grouped_matmul_compiles_on_the_resident_stack(
     # 3.3 GB of expert matrices: merging the stack's leading axes and
     # indexing it must not materialise any of it.
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+# The cells' fused step (PR 31): (layers served, slots, pool pages, group
+# programs) and three lanes of 64 tokens — 8 + 192 and 16 + 192 rows.
+_FUSED_CELLS = {
+    "mistral-7b": (32, 8, 512, 4),
+    "qwen2-7b": (28, 16, 1024, 8),
+    "deepseek-v2-lite": (L, B, PAGES, 8),
+}
+
+
+@pytest.mark.parametrize("grouped", [False, True], ids=["plain", "grouped"])
+@pytest.mark.parametrize("model", list(_FUSED_CELLS))
+def test_fused_step_with_three_lanes_compiles_at_the_cells_shapes(
+    one_chip, monkeypatch, model, grouped
+):
+    """``jit_fused_step`` with L = 3 at each configuration's real widths
+    and depth, int8 weights as the cells serve them: ISSUE 25's probe saw
+    16 rows beside ONE 256-wide chunk refused for 16.08 of 16.00 MiB of
+    scoped VMEM; three 64-wide lanes are a block a lane, and pass."""
+    from functools import partial
+
+    from llm_consensus_tpu.models import transformer as T
+    from llm_consensus_tpu.models.configs import PRESETS
+    from llm_consensus_tpu.models.paged_cache import (
+        DecodeGroupArrays,
+        PagedKVCache,
+    )
+
+    # The kernels ask the backend whether to compile or interpret.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layers, slots, pages, gm = _FUSED_CELLS[model]
+    cfg = PRESETS[model]
+    if layers != cfg.n_layers:
+        cfg = cfg.with_layers(layers)
+    cfg = cfg.with_(use_pallas=True)
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: _shape(one_chip, a.shape, a.dtype), tree
+        )
+
+    params = described(jax.eval_shape(
+        lambda: T.init_params_quantized(cfg, jax.random.PRNGKey(0))
+    ))
+    cache = described(jax.eval_shape(
+        lambda: PagedKVCache.create(cfg, pages, PG, slots, P)
+    ))
+    i32 = lambda *s: _shape(one_chip, s, jnp.int32)  # noqa: E731
+    groups = (
+        DecodeGroupArrays(i32(slots), i32(gm), i32(gm), i32(slots))
+        if grouped else None
+    )
+    lanes = 3
+    # The serving process's matmul precision, not the test process's
+    # ``highest`` (Mosaic refuses a bf16 dot under it).
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            partial(T.fused_step_paged, cfg), donate_argnums=(2,)
+        ).lower(
+            params, i32(slots, 1), cache, i32(lanes, C), i32(lanes, P),
+            i32(lanes), groups,
+        ).compile()
+    text = compiled.as_text()
+    # The ragged attention, the int8 matrices and (deepseek) the grouped
+    # expert matmul are all still kernels at 200 / 208 rows.
+    assert text.count("tpu_custom_call") >= 10
+    assert "quant_matmul_stacked" in text
+    # Both pools alias the donated cache; nothing pool-sized is copied.
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20

@@ -75,6 +75,9 @@ class _Spy:
             self.seen.add(re.match(r"module @(\w+)", text).group(1))
         return self.jitted(*args)
 
+    def lower(self, *args):  # the batcher's build-ahead
+        return self.jitted.lower(*args)
+
 
 def _spy_on(batcher, seen: set) -> None:
     """Put a spy before every step program of ``batcher``: the three
@@ -129,6 +132,42 @@ def test_step_programs_lower_to_named_modules(
     # No step program is left to jax's default for a partial or a
     # bound method.
     assert not {m for m in seen if "unknown" in m or "sample" in m}, seen
+
+
+def test_one_named_program_a_width_whatever_the_bucket(params):
+    """Prompts of three buckets (one, two and four 16-token chunks), sent
+    one at a time and then together: the chunk programs are keyed by what
+    changes their HLO — chunk width, lanes, MoE pin — so a dense model has
+    ONE fused and ONE chunk program object a width (one lane, and the wide
+    one), shared by every bucket, and each lowers to the module name a
+    trace reduction finds it by (``fused_dev_ms.panel`` reads
+    ``jit_fused_step`` whatever L)."""
+    by_bucket = ["b16: tiny", "b32: " + "m" * 20, "b64: " + "l" * 50]
+    seen: set = set()
+    keys: dict[str, set] = {"_chunk_fn": set(), "_fused_fn": set()}
+    b = ContinuousBatcher(CFG, params, config=ContinuousConfig(**_CCFG))
+    try:
+        _spy_on(b, seen)
+        for getter, called in keys.items():
+            spied = getattr(b, getter)
+            setattr(b, getter, lambda *k, _s=spied, _c=called: (
+                _c.add(k), _s(*k))[1])
+        for p in by_bucket:
+            b.submit(p).result(timeout=120)
+        for f in [b.submit("again " + p) for p in by_bucket]:
+            f.result(timeout=120)
+        wide = b._lanes_for(16)
+        fused, chunk = dict(b._jit_fused), dict(b._jit_chunk)
+    finally:
+        b.close()
+    assert seen == {"jit_decode_step", "jit_fused_step", "jit_prefill_chunk"}
+    assert wide == 4
+    # Called for three buckets and two widths ...
+    assert {k[2] for k in keys["_fused_fn"]} >= {32, 64}
+    assert {k[1] for k in keys["_fused_fn"] | keys["_chunk_fn"]} == {1, wide}
+    # ... and served by one program object a width.
+    pin = CFG.moe_dense_decode_tokens  # the same for every bucket
+    assert sorted(fused) == sorted(chunk) == [(16, 1, pin), (16, wide, pin)]
 
 
 def _counter(name: str, **labels) -> float:
