@@ -452,6 +452,17 @@ def kernels_child(dry_run: bool) -> int:
                 seed=4, rows=[5, 0, 40, 1, 16, 0, 17, 2], k=k_, n=n_,
                 interpret=interpret),
         ))
+    # The state-carrying scan (a recurrent model's layers): decode rows
+    # of one token padded to a sublane tile, chunk lanes of a whole chunk.
+    for rows, tokens in ((b, 8), (3, cq)):
+        ssm = (dict(heads=4, head_dim=16, state=16, groups=2) if interpret
+               else dict(heads=64, head_dim=64, state=128, groups=8))
+        cases.append((
+            f"ssm_scan[{rows} rows x {tokens} tokens]", parity.SSM_SCAN_TOL,
+            lambda rows=rows, tokens=tokens, ssm=ssm: parity.ssm_scan_error(
+                seed=5, rows=rows, tokens=tokens, slots=2 * rows + 2,
+                interpret=interpret, **ssm),
+        ))
     for rows in (1, b, b + cq):
         cases.append((
             f"fused_rms_norm[{rows}x{d_model} bf16]", parity.NORM_BF16_TOL,

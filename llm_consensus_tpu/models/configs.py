@@ -10,7 +10,10 @@ Qwen2, MoE for Mixtral), so one functional implementation serves all.
 DeepSeek-V2-Lite (PR 28) is the first that is not: latent attention (MLA), a
 leading dense layer before the expert layers, shared experts, a
 softmax-then-top-k router and YaRN — each a field below, read by the same
-functions.
+functions. Nemotron-3-Nano (PR 32) is the first whose layers are not
+"attention then MLP": a per-layer plan of ONE-mixer layers (Mamba-2
+state-space, GQA attention without positions, ungated relu² experts
+behind a sigmoid router), read by the same functions again.
 """
 
 from __future__ import annotations
@@ -46,6 +49,10 @@ class YarnScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+
+
+# ``ModelConfig.layer_plan``'s characters and the kinds they name.
+LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "moe", "-": "mlp"}
 
 
 @dataclass(frozen=True)
@@ -93,7 +100,10 @@ class ModelConfig:
     # softmaxes those; "softmax_topk" (DeepSeek-V2) softmaxes all
     # experts in float32, takes the top k probabilities as they are
     # (``moe_renormalize`` divides them by their sum) and multiplies
-    # them by ``moe_routed_scale``.
+    # them by ``moe_routed_scale``. "sigmoid_topk" (DeepSeek-V3's form):
+    # sigmoid scores in float32; the top k of score + the layer's
+    # ``router_bias`` are chosen, the weights are the chosen SCORES
+    # (no bias), renormalised and scaled the same way.
     moe_router: str = "topk_softmax"
     moe_renormalize: bool = False
     moe_routed_scale: float = 1.0
@@ -136,6 +146,42 @@ class ModelConfig:
     # (parallel/ring.py) when a mesh with seq > 1 is passed to
     # forward/prefill — sequence-parallel long-context support.
     use_ring: bool = False
+    # The per-layer plan (nemotron_h's ``hybrid_override_pattern``), one
+    # character a layer, each layer ONE mixer ``x + mixer(norm(x))``:
+    # ``M`` Mamba-2 state-space, ``*`` attention, ``E`` routed experts,
+    # ``-`` dense MLP. Empty: every layer is attention then MLP (every
+    # older preset), and ``n_layers`` counts those.
+    layer_plan: str = ""
+    # A GQA head's width where it is not ``d_model // n_heads``.
+    attn_head_dim: int = 0
+    # "rope", or "none": attention sees no position signal at all (the
+    # state-space layers carry order).
+    positions: str = "rope"
+    # Feed-forward form, dense and expert alike: "swiglu" (gate, up,
+    # down) or "relu2", ungated: ``W_down · relu(W_up x)²``.
+    mlp_form: str = "swiglu"
+    # Width of the shared expert where it is not ``n_shared_experts *
+    # expert width``.
+    moe_shared_d_ff: int = 0
+    # Mamba-2: ``ssm_heads`` heads of ``ssm_head_dim`` (their product is
+    # the inner width, not ``expand * d_model``), a state of
+    # ``ssm_state`` values a head channel, B and C shared by the heads
+    # of one of ``ssm_groups`` groups, a causal depthwise convolution of
+    # ``ssm_conv`` taps over [x | B | C].
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+
+    def __post_init__(self):
+        if self.layer_plan:
+            bad = set(self.layer_plan) - set(LAYER_KINDS)
+            if bad or len(self.layer_plan) != self.n_layers:
+                raise ValueError(
+                    f"{self.name}: layer_plan {self.layer_plan!r} must be "
+                    f"{self.n_layers} characters of {''.join(LAYER_KINDS)}"
+                )
 
     @property
     def is_mla(self) -> bool:
@@ -147,7 +193,52 @@ class ModelConfig:
         contracts over, and the rotary width of a GQA model."""
         if self.is_mla:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
+
+    def plan_kinds(self) -> tuple[str, ...]:
+        """A planned model's layer kinds in order (``LAYER_KINDS``
+        values); empty for the attention-then-MLP models."""
+        return tuple(LAYER_KINDS[c] for c in self.layer_plan)
+
+    def n_of(self, kind: str) -> int:
+        return self.plan_kinds().count(kind)
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that keep K/V pages: the page pool's layer axis."""
+        return self.n_of("attn") if self.layer_plan else self.n_layers
+
+    @property
+    def n_ssm_layers(self) -> int:
+        """Layers that keep recurrent state: the state pool's."""
+        return self.n_of("ssm")
+
+    @property
+    def is_recurrent(self) -> bool:
+        return self.n_ssm_layers > 0
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the convolution runs over: x, then B and C of
+        every group."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def expert_d_ff_stored(self) -> int:
+        """Expert width as a one-mixer expert layer stores it: rounded
+        up to the grouped expert matmul's rule, whole 128-lane tiles
+        (``moe_grouped_matmul_supported``: 1856 -> 1920), with zero
+        columns of ``w_up`` and zero rows of ``w_down`` — exact for
+        relu² and for SwiGLU (both map 0 to 0)."""
+        return -(-self.expert_d_ff // 128) * 128
+
+    @property
+    def shared_d_ff(self) -> int:
+        return self.moe_shared_d_ff or self.n_shared_experts * self.expert_d_ff
 
     @property
     def rope_dim(self) -> int:
@@ -193,6 +284,8 @@ class ModelConfig:
 
     @property
     def n_moe_layers(self) -> int:
+        if self.layer_plan:
+            return self.n_of("moe")
         return self.n_layers - self.n_dense_layers if self.is_moe else 0
 
     def with_layers(self, n: int) -> "ModelConfig":
@@ -207,6 +300,8 @@ class ModelConfig:
                 f"--layers {n}: {self.name} has {self.n_dense_layers} leading "
                 "dense layer(s); keep at least one expert layer"
             )
+        if self.layer_plan:
+            return self.with_(n_layers=n, layer_plan=self.layer_plan[:n])
         return self.with_(n_layers=n)
 
     def with_(self, **kw) -> "ModelConfig":
@@ -514,6 +609,73 @@ PRESETS: dict[str, ModelConfig] = {
         n_shared_experts=2,
         moe_router="softmax_topk",
         moe_dropless=True,
+    ),
+    # NVIDIA-Nemotron-3-Nano-30B-A3B at its published sizes
+    # (huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16
+    # config.json, model_type nemotron_h): 52 one-mixer layers — 23
+    # Mamba-2, 23 expert (128 ungated relu² experts of 1856, top-6 by a
+    # sigmoid router with a choice-only bias, one shared expert of
+    # 3712), 6 GQA attention layers of 32 / 2 heads of 128 with no
+    # position signal. max_seq_len is the repo's cap, not 262144.
+    "nemotron-3-nano-30b-a3b": ModelConfig(
+        name="nemotron-3-nano-30b-a3b",
+        vocab_size=131072,
+        d_model=2688,
+        n_layers=52,
+        n_heads=32,
+        n_kv_heads=2,
+        d_ff=1856,
+        rms_norm_eps=1e-5,
+        max_seq_len=8192,
+        layer_plan="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+        attn_head_dim=128,
+        positions="none",
+        mlp_form="relu2",
+        n_experts=128,
+        n_experts_per_token=6,
+        moe_d_ff=1856,
+        n_shared_experts=1,
+        moe_shared_d_ff=3712,
+        moe_router="sigmoid_topk",
+        moe_renormalize=True,
+        moe_routed_scale=2.5,
+        moe_dropless=True,
+        ssm_heads=64,
+        ssm_head_dim=64,
+        ssm_state=128,
+        ssm_groups=8,
+        ssm_conv=4,
+    ),
+    # The same shapes at CPU-test size: the plan MEM*EME, 4 experts
+    # top-2 of a width (40) that is no multiple of any tile, 2 groups.
+    "test-tiny-nemotron": ModelConfig(
+        name="test-tiny-nemotron",
+        vocab_size=384,
+        d_model=64,
+        n_layers=7,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=40,
+        rms_norm_eps=1e-5,
+        max_seq_len=256,
+        layer_plan="MEM*EME",
+        attn_head_dim=32,
+        positions="none",
+        mlp_form="relu2",
+        n_experts=4,
+        n_experts_per_token=2,
+        moe_d_ff=40,
+        n_shared_experts=1,
+        moe_shared_d_ff=80,
+        moe_router="sigmoid_topk",
+        moe_renormalize=True,
+        moe_routed_scale=2.5,
+        moe_dropless=True,
+        ssm_heads=4,
+        ssm_head_dim=16,
+        ssm_state=16,
+        ssm_groups=2,
+        ssm_conv=4,
     ),
     "test-tiny-moe": ModelConfig(
         name="test-tiny-moe",

@@ -244,7 +244,107 @@ def _load_mla_params(cfg: ModelConfig, ckpt: _ShardedCheckpoint, dtype) -> dict:
     return jax.tree_util.tree_map(jnp.asarray, params)
 
 
+# nemotron_h (NemotronHForCausalLM): every layer is ``backbone.layers.N``
+# with a ``norm`` and ONE ``mixer``, whose kind the config's
+# ``hybrid_override_pattern`` gives. Ours <- theirs, ``{p}`` the layer's
+# prefix; linear weights are [out, in] there and [in, out] here.
+_NEMOTRON_MAPS = {
+    "attn": {
+        "wq": "{p}.mixer.q_proj.weight", "wk": "{p}.mixer.k_proj.weight",
+        "wv": "{p}.mixer.v_proj.weight", "wo": "{p}.mixer.o_proj.weight",
+    },
+    "mlp": {
+        "w_up": "{p}.mixer.up_proj.weight",
+        "w_down": "{p}.mixer.down_proj.weight",
+    },
+    "moe": {
+        "router": "{p}.mixer.gate.weight",
+        "ws_up": "{p}.mixer.shared_experts.up_proj.weight",
+        "ws_down": "{p}.mixer.shared_experts.down_proj.weight",
+    },
+    "ssm": {"w_out": "{p}.mixer.out_proj.weight"},
+}
+
+
+def _load_plan_params(cfg: ModelConfig, ckpt: _ShardedCheckpoint, dtype) -> dict:
+    """NemotronHForCausalLM -> the stack-a-kind tree of
+    ``transformer.init_params`` for a planned model: ``in_proj`` cut
+    into its z | xBC | dt column ranges, the convolution's [C, 1, K]
+    weight as [K, C], an expert's width zero-padded to the stored one,
+    ``A_log`` / ``D`` / ``dt_bias`` / the router's bias kept float32."""
+    from llm_consensus_tpu.models.transformer import PLAN_STACKS, _plan_layers
+
+    np_dtype = jnp.dtype(dtype)
+
+    def get(name: str) -> np.ndarray:
+        if name not in ckpt:
+            raise KeyError(f"checkpoint missing {name!r}")
+        return ckpt.get(name)
+
+    def linear(name: str) -> np.ndarray:
+        return get(name).astype(np_dtype).T
+
+    def padded(w: np.ndarray, axis: int) -> np.ndarray:
+        pad = [(0, 0)] * w.ndim
+        pad[axis] = (0, cfg.expert_d_ff_stored - w.shape[axis])
+        return np.pad(w, pad)
+
+    def layer(kind: str, p: str) -> dict:
+        out = {"norm": get(f"{p}.norm.weight").astype(np_dtype)}
+        for ours, theirs in _NEMOTRON_MAPS[kind].items():
+            out[ours] = linear(theirs.format(p=p))
+        if kind == "moe":
+            out["router_bias"] = get(
+                f"{p}.mixer.gate.e_score_correction_bias"
+            ).astype(np.float32)
+            experts = [f"{p}.mixer.experts.{e}" for e in range(cfg.n_experts)]
+            out["w_up"] = np.stack(
+                [padded(linear(f"{e}.up_proj.weight"), 1) for e in experts]
+            )
+            out["w_down"] = np.stack(
+                [padded(linear(f"{e}.down_proj.weight"), 0) for e in experts]
+            )
+        elif kind == "ssm":
+            inner, cx = cfg.ssm_inner, cfg.ssm_conv_dim
+            w_in = linear(f"{p}.mixer.in_proj.weight")  # [D, z | xBC | dt]
+            if w_in.shape[1] != inner + cx + cfg.ssm_heads:
+                raise ValueError(
+                    f"{p}.mixer.in_proj.weight has {w_in.shape[1]} columns, "
+                    f"the config gives {inner} + {cx} + {cfg.ssm_heads}"
+                )
+            out["w_in_z"] = w_in[:, :inner]
+            out["w_in_xbc"] = w_in[:, inner : inner + cx]
+            out["w_in_dt"] = w_in[:, inner + cx :]
+            out["conv_w"] = (
+                get(f"{p}.mixer.conv1d.weight")[:, 0, :].T.astype(np_dtype)
+            )
+            out["conv_b"] = get(f"{p}.mixer.conv1d.bias").astype(np_dtype)
+            out["gate_norm"] = get(f"{p}.mixer.norm.weight").astype(np_dtype)
+            for ours, theirs in (
+                ("dt_bias", "dt_bias"), ("a_log", "A_log"), ("d_skip", "D"),
+            ):
+                out[ours] = get(f"{p}.mixer.{theirs}").astype(np.float32)
+        return out
+
+    stacks: dict = {}
+    for n, (kind, _) in enumerate(_plan_layers(cfg)):
+        stacks.setdefault(kind, []).append(layer(kind, f"backbone.layers.{n}"))
+    params = {
+        PLAN_STACKS[kind]: {
+            name: np.stack([one[name] for one in layers])
+            for name in layers[0]
+        }
+        for kind, layers in stacks.items()
+    }
+    params["embed"] = get("backbone.embeddings.weight").astype(np_dtype)
+    params["norm_f"] = get("backbone.norm_f.weight").astype(np_dtype)
+    params["lm_head"] = linear("lm_head.weight")
+    return jax.tree_util.tree_map(jnp.asarray, params)
+
+
 def _load_hf_params(cfg: ModelConfig, ckpt: _ShardedCheckpoint, dtype) -> dict:
+    if cfg.layer_plan:
+        return _load_plan_params(cfg, ckpt, dtype)
     if cfg.is_mla:
         return _load_mla_params(cfg, ckpt, dtype)
     np_dtype = jnp.dtype(dtype)
@@ -341,6 +441,8 @@ def config_from_hf(path: str | Path, name: str = "hf") -> ModelConfig:
     arch = (hf.get("architectures") or [""])[0]
     if "DeepseekV2" in arch or hf.get("model_type") == "deepseek_v2":
         return _deepseek_v2_config(hf, name)
+    if "NemotronH" in arch or hf.get("model_type") == "nemotron_h":
+        return _nemotron_h_config(hf, name)
     is_moe = "Mixtral" in arch or "num_local_experts" in hf
 
     rope_scaling = None
@@ -384,6 +486,62 @@ def config_from_hf(path: str | Path, name: str = "hf") -> ModelConfig:
         tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
         n_experts=int(hf.get("num_local_experts", 0)) if is_moe else 0,
         n_experts_per_token=int(hf.get("num_experts_per_tok", 2)),
+    )
+
+
+def _nemotron_h_config(hf: dict, name: str) -> ModelConfig:
+    """NemotronHForCausalLM's ``config.json`` -> ModelConfig. What the
+    layer equations here do not cover raises: grouped routing, biases
+    other than the convolution's, an activation other than relu²."""
+    unsupported = {
+        "n_group/topk_group": hf.get("n_group", 1) != 1
+        or hf.get("topk_group", 1) != 1,
+        "mlp_hidden_act": hf.get("mlp_hidden_act", "relu2") != "relu2",
+        "bias": any(
+            hf.get(k, False)
+            for k in ("attention_bias", "mlp_bias", "mamba_proj_bias", "use_bias")
+        ),
+        "use_conv_bias": not hf.get("use_conv_bias", True),
+        "sliding_window": bool(hf.get("sliding_window")),
+    }
+    bad = [k for k, v in unsupported.items() if v]
+    if bad:
+        raise ValueError(
+            f"nemotron_h config uses {bad}, which the layers here do not "
+            "implement (NVIDIA-Nemotron-3-Nano-30B-A3B's values are supported)"
+        )
+    n_experts = int(hf.get("n_routed_experts") or 0)
+    return ModelConfig(
+        name=name,
+        vocab_size=hf["vocab_size"],
+        d_model=hf["hidden_size"],
+        n_layers=hf["num_hidden_layers"],
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        d_ff=hf["intermediate_size"],
+        rms_norm_eps=float(hf.get("layer_norm_epsilon", 1e-5)),
+        max_seq_len=min(int(hf.get("max_position_embeddings", 8192)), 8192),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        layer_plan=hf["hybrid_override_pattern"][: hf["num_hidden_layers"]],
+        attn_head_dim=int(hf["head_dim"]),
+        # nemotron_h's attention applies no rotary embedding: the
+        # config's rope_theta / partial_rotary_factor are unread there.
+        positions="none",
+        mlp_form="relu2",
+        n_experts=n_experts,
+        n_experts_per_token=int(hf.get("num_experts_per_tok", 2)),
+        moe_d_ff=int(hf.get("moe_intermediate_size") or 0),
+        n_shared_experts=int(hf.get("n_shared_experts") or 0),
+        moe_shared_d_ff=int(hf.get("moe_shared_expert_intermediate_size") or 0),
+        moe_router="sigmoid_topk",
+        moe_renormalize=bool(hf.get("norm_topk_prob", True)),
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_dropless=n_experts > 0,
+        ssm_heads=int(hf["mamba_num_heads"]),
+        ssm_head_dim=int(hf["mamba_head_dim"]),
+        ssm_state=int(hf["ssm_state_size"]),
+        ssm_groups=int(hf["n_groups"]),
+        ssm_conv=int(hf["conv_kernel"]),
     )
 
 

@@ -28,6 +28,15 @@ Layout:
 
 Page 0 is reserved as the "null" page so freshly-reset tables are valid.
 
+A model with recurrent (state-space) layers keeps a SECOND kind of state
+in the same cache (:class:`SsmState`): a pool of state slots ``[state
+layer, slot, ...]`` — a Mamba-2 layer's ``S`` [H, P, N] float32 and the
+last K - 1 rows of its convolution's input — one slot a live sequence
+and one a registry snapshot, handed out by a :class:`StatePool` as
+pages are by a :class:`PagePool`. Slot 0 is the empty state, as page 0
+is the null page. The K/V pool then has a plane for each ATTENTION
+layer only, indexed by a layer's index among its kind.
+
 Two host-side structures complete the picture (PR 2):
 
 - :class:`PagePool` — refcounted page allocator. A page mapped into N
@@ -47,7 +56,7 @@ Two host-side structures complete the picture (PR 2):
 from __future__ import annotations
 
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Iterable, Sequence
 
 import jax
@@ -83,6 +92,26 @@ def prefix_chain_key(
     )
 
 
+NULL_SLOT = 0
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class SsmState:
+    """Recurrent state beside the pages: ``s`` and ``conv`` are pools
+    over (state layer, slot); ``slot`` is each decode row's slot, kept
+    in step with the row's page-table row (0 while it is idle or still
+    prefilling: the chunk programs take a lane's slots as arguments)."""
+
+    s: jnp.ndarray  # [Ls, n_slots, H, P, N] float32
+    conv: jnp.ndarray  # [Ls, n_slots, K - 1, conv_dim]
+    slot: jnp.ndarray  # [max_seqs] int32
+
+    @property
+    def n_slots(self) -> int:
+        return self.s.shape[1]
+
+
 @jax.tree_util.register_dataclass
 @dataclass
 class PagedKVCache:
@@ -90,6 +119,9 @@ class PagedKVCache:
     v: jnp.ndarray  # as k; MLA: [L, n_pages, page_size, 0] (empty)
     page_table: jnp.ndarray  # [max_seqs, pages_per_seq] int32
     length: jnp.ndarray  # [max_seqs] int32
+    # None for a model without recurrent layers: no leaf, and the step
+    # programs of such a model are what they were.
+    state: SsmState | None = None
 
     @staticmethod
     def create(
@@ -99,19 +131,41 @@ class PagedKVCache:
         max_seqs: int,
         pages_per_seq: int,
         dtype=jnp.bfloat16,
+        state_slots: int = 0,
     ) -> "PagedKVCache":
         if cfg.is_mla:
             lead = (cfg.n_layers, n_pages, page_size)
             k_shape, v_shape = lead + (cfg.latent_pool_dim,), lead + (0,)
         else:
             k_shape = v_shape = (
-                cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim
+                cfg.n_attn_layers, n_pages, page_size, cfg.n_kv_heads,
+                cfg.head_dim,
+            )
+        state = None
+        if cfg.is_recurrent:
+            if state_slots < 2:
+                raise ValueError(
+                    f"{cfg.name} keeps recurrent state: the cache needs "
+                    f"state_slots >= 2 (slot 0 is the empty state), got "
+                    f"{state_slots}"
+                )
+            lead = (cfg.n_ssm_layers, state_slots)
+            state = SsmState(
+                s=jnp.zeros(
+                    lead + (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                    jnp.float32,
+                ),
+                conv=jnp.zeros(
+                    lead + (cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype
+                ),
+                slot=jnp.full((max_seqs,), NULL_SLOT, jnp.int32),
             )
         return PagedKVCache(
             k=jnp.zeros(k_shape, dtype),
             v=jnp.zeros(v_shape, dtype),
             page_table=jnp.full((max_seqs, pages_per_seq), NULL_PAGE, jnp.int32),
             length=jnp.zeros((max_seqs,), jnp.int32),
+            state=state,
         )
 
     @property
@@ -158,7 +212,7 @@ def write_decode_kv(
     k = cache.k.at[:, pages, offset].set(k_new.astype(cache.k.dtype))
     v = cache.v.at[:, pages, offset].set(v_new.astype(cache.v.dtype))
     length = cache.length.at[seq_ids].add(1)
-    return PagedKVCache(k=k, v=v, page_table=cache.page_table, length=length)
+    return replace(cache, k=k, v=v, length=length)
 
 
 def write_prefill_kv(
@@ -187,7 +241,7 @@ def write_prefill_kv(
     k = cache.k.at[:, pages].set(k_pages)
     v = cache.v.at[:, pages].set(v_pages)
     new_len = cache.length.at[seq_id].set(length.astype(jnp.int32))
-    return PagedKVCache(k=k, v=v, page_table=cache.page_table, length=new_len)
+    return replace(cache, k=k, v=v, length=new_len)
 
 
 def assign_pages(
@@ -195,18 +249,17 @@ def assign_pages(
 ) -> PagedKVCache:
     """Install a page list (padded with NULL_PAGE) for one sequence."""
     table = cache.page_table.at[seq_id].set(pages.astype(jnp.int32))
-    return PagedKVCache(
-        k=cache.k, v=cache.v, page_table=table, length=cache.length
-    )
+    return replace(cache, page_table=table)
 
 
 def release_seq(cache: PagedKVCache, seq_id: jnp.ndarray) -> PagedKVCache:
     """Clear a sequence's table/length (page recycling is host-side)."""
     table = cache.page_table.at[seq_id].set(NULL_PAGE)
     length = cache.length.at[seq_id].set(0)
-    return PagedKVCache(
-        k=cache.k, v=cache.v, page_table=table, length=length
-    )
+    state = cache.state
+    if state is not None:
+        state = replace(state, slot=state.slot.at[seq_id].set(NULL_SLOT))
+    return replace(cache, page_table=table, length=length, state=state)
 
 
 def install_seq(
@@ -214,16 +267,21 @@ def install_seq(
     seq_id: jnp.ndarray,
     pages: jnp.ndarray,
     length: jnp.ndarray,
+    slot: jnp.ndarray | None = None,
 ) -> PagedKVCache:
     """Install table AND length for one sequence in one pass — the
     moment a chunk-prefilled sequence (whose pages were written through
     an explicit host-side table, invisible to the decode program)
-    becomes a live decode row."""
+    becomes a live decode row. ``slot``: its state slot, where the
+    model keeps recurrent state."""
     table = cache.page_table.at[seq_id].set(pages.astype(jnp.int32))
     new_len = cache.length.at[seq_id].set(length.astype(jnp.int32))
-    return PagedKVCache(
-        k=cache.k, v=cache.v, page_table=table, length=new_len
-    )
+    state = cache.state
+    if state is not None:
+        state = replace(
+            state, slot=state.slot.at[seq_id].set(slot.astype(jnp.int32))
+        )
+    return replace(cache, page_table=table, length=new_len, state=state)
 
 
 def copy_page(
@@ -239,9 +297,7 @@ def copy_page(
     """
     k = cache.k.at[:, dst].set(cache.k[:, src])
     v = cache.v.at[:, dst].set(cache.v[:, src])
-    return PagedKVCache(
-        k=k, v=v, page_table=cache.page_table, length=cache.length
-    )
+    return replace(cache, k=k, v=v)
 
 
 def install_page(
@@ -260,9 +316,7 @@ def install_page(
     """
     k = cache.k.at[:, page].set(k_page.astype(cache.k.dtype))
     v = cache.v.at[:, page].set(v_page.astype(cache.v.dtype))
-    return PagedKVCache(
-        k=k, v=v, page_table=cache.page_table, length=cache.length
-    )
+    return replace(cache, k=k, v=v)
 
 
 def install_pages(
@@ -279,9 +333,7 @@ def install_pages(
     a different chain prefix)."""
     k = cache.k.at[:, pages].set(k_pages.astype(cache.k.dtype))
     v = cache.v.at[:, pages].set(v_pages.astype(cache.v.dtype))
-    return PagedKVCache(
-        k=k, v=v, page_table=cache.page_table, length=cache.length
-    )
+    return replace(cache, k=k, v=v)
 
 
 # ---------------------------------------------------------------------------
@@ -343,6 +395,18 @@ class PagePool:
             self._rc[page] = rc - 1
 
 
+class StatePool(PagePool):
+    """Refcounted allocator of recurrent-state slots, exactly a
+    :class:`PagePool` over slot ids: a live sequence holds its slot from
+    admission to retirement, a registry snapshot holds one, and an
+    admission that will start from a snapshot shares it until its first
+    chunk is on the stream. Slot 0 (the empty state) is never handed
+    out."""
+
+    def __init__(self, n_slots: int):
+        super().__init__(range(1, n_slots))
+
+
 @dataclass
 class _PrefixNode:
     """One page-sized token run in the prefix radix tree."""
@@ -360,6 +424,18 @@ class _PrefixNode:
     ready: bool = False
     # LRU tick for eviction (registry-maintained).
     last_used: int = 0
+    # Recurrent models: a snapshot of the state after this page's last
+    # token (a slot of the state pool), and whether it has been written
+    # (a promised snapshot has its slot before its content). A page
+    # without one cannot be continued from: the state inside it exists
+    # nowhere. ``want_state``: some admission's match ended here, or a
+    # prompt's last full page does — whoever next finishes this page on
+    # a chunk end saves one. ``evicted``: dropped from the tree while a
+    # prefilling sequence still held the node.
+    state: int | None = None
+    state_ready: bool = False
+    want_state: bool = False
+    evicted: bool = False
 
 
 @dataclass
@@ -392,9 +468,15 @@ class PrefixRegistry:
     ``_PrefixNode.ready`` gates content readers.
     """
 
-    def __init__(self, pool: PagePool, page_size: int):
+    def __init__(
+        self, pool: PagePool, page_size: int, states: StatePool | None = None
+    ):
         self.pool = pool
         self.page_size = page_size
+        # The state slots snapshots live in; None for a model whose
+        # every layer keeps pages.
+        self.states = states
+        self.snapshots_evicted = 0
         self._root = _PrefixNode(tokens=(), page=NULL_PAGE, parent=None)
         self._nodes = 0
         self._tick = 0
@@ -450,8 +532,15 @@ class PrefixRegistry:
             stack.extend(node.children.values())
             yield node
 
-    def match(self, ids: Sequence[int], min_boundary: int = 1) -> PrefixMatch:
+    def match(
+        self, ids: Sequence[int], min_boundary: int = 1,
+        depth: int | None = None,
+    ) -> PrefixMatch:
         """Longest registered page-aligned prefix of ``ids``.
+
+        ``depth`` (recurrent models): map at most that many pages — as
+        deep as a state snapshot lets the caller continue from — and
+        offer no boundary page (no state exists inside a page).
 
         Sharing is capped at ``len(ids) - 1`` tokens: at least the last
         prompt token must be (re)computed so the admission has a hidden
@@ -474,6 +563,8 @@ class PrefixRegistry:
         nodes: list[_PrefixNode] = []
         # Only prefixes strictly shorter than the prompt are usable.
         usable_full = (len(ids) - 1) // pg
+        if depth is not None:
+            usable_full = min(usable_full, depth)
         k = 0
         while k < usable_full:
             key = tuple(int(t) for t in ids[k * pg : (k + 1) * pg])
@@ -495,7 +586,7 @@ class PrefixRegistry:
         # but diverge (or run past our prompt) before the page ends.
         rem = tuple(int(t) for t in ids[k * pg :])
         cap = len(rem) - 1  # leave >= 1 token to prefill
-        if cap > 0:
+        if cap > 0 and depth is None:
             best, best_child = 0, None
             for key, child in node.children.items():
                 if not child.ready:
@@ -513,7 +604,9 @@ class PrefixRegistry:
                 match.boundary_common = min(best, cap)
         return match
 
-    def probe(self, ids: Sequence[int]) -> tuple[list[_PrefixNode], int]:
+    def probe(
+        self, ids: Sequence[int], whole: bool = False
+    ) -> tuple[list[_PrefixNode], int]:
         """Read-only longest-prefix walk: which registered nodes cover
         this prompt's page-aligned prefix, and how many tokens they
         span. NO side effects — no refcount bumps, no LRU ticks, no
@@ -526,11 +619,15 @@ class PrefixRegistry:
         admission (PR 2), so a concurrent same-prefix burst probes the
         donor's replica as a match while the donor's prefill is still
         in flight — exactly the affinity the router needs.
+
+        ``whole``: walk every FULL page of ``ids``, the last one too
+        (the chain a prefilling sequence writes through, as
+        :meth:`register` walks it).
         """
         pg = self.page_size
         node = self._root
         nodes: list[_PrefixNode] = []
-        usable_full = (len(ids) - 1) // pg
+        usable_full = (len(ids) - (0 if whole else 1)) // pg
         k = 0
         while k < usable_full:
             key = tuple(int(t) for t in ids[k * pg : (k + 1) * pg])
@@ -590,6 +687,47 @@ class PrefixRegistry:
     def mark_ready(node: _PrefixNode) -> None:
         node.ready = True
 
+    # -- state snapshots (recurrent models) -------------------------------
+
+    def snapshot_nodes(self) -> list[_PrefixNode]:
+        return [n for n in self._walk() if n.state is not None]
+
+    def alloc_state(self) -> int | None:
+        """One free state slot, dropping the least recently used
+        snapshot nobody is waiting on if the pool is empty; None when
+        every slot is a live sequence's or a snapshot in use."""
+        if not self.states.available and not self.evict_states(1):
+            return None
+        return self.states.alloc(1)[0]
+
+    def promise_state(self, node: _PrefixNode) -> int | None:
+        """Give ``node`` a snapshot slot whose content a prefill in
+        flight will write (``state_ready`` stays False until then)."""
+        slot = self.alloc_state()
+        if slot is not None:
+            node.state, node.state_ready = slot, False
+        return slot
+
+    def drop_state(self, node: _PrefixNode) -> None:
+        if node.state is not None:
+            self.states.release(node.state)
+            node.state, node.state_ready = None, False
+            self.snapshots_evicted += 1
+
+    def evict_states(self, n: int) -> int:
+        """Release up to ``n`` written snapshots that only the registry
+        holds, least recently used first. Returns how many."""
+        idle = sorted(
+            (
+                node for node in self.snapshot_nodes()
+                if node.state_ready and self.states.refcount(node.state) == 1
+            ),
+            key=lambda node: node.last_used,
+        )
+        for node in idle[:n]:
+            self.drop_state(node)
+        return min(n, len(idle))
+
     @staticmethod
     def chain_tokens(node: _PrefixNode) -> tuple[int, ...]:
         """Every token from the prefix root through ``node``'s page —
@@ -639,6 +777,12 @@ class PrefixRegistry:
             if self.on_evict is not None and victim.ready:
                 demote.append(victim)
             del parent.children[victim.tokens]
+            victim.evicted = True
+            if victim.state is not None:
+                # A snapshot goes with its page (an unready one is
+                # shared by the admission waiting on it, whose matched
+                # pages also keep this node off the heap).
+                self.drop_state(victim)
             self.pool.release(victim.page)
             self._nodes -= 1
             self.evictions += 1

@@ -20,6 +20,10 @@ BASELINE.json's north star. Design choices are XLA-first:
 - A latent-attention (MLA) model keeps one compressed latent a token in
   the page pool and attends in the absorbed form (``_paged_layers``);
   layers of two shapes are two stacks (``layer_stacks``).
+- A PLANNED model (``ModelConfig.layer_plan``: Nemotron-3-Nano) is
+  one-mixer layers of several kinds, a stack a kind; its state-space
+  layers keep a recurrent state a sequence in the paged cache's state
+  pool (``_ssm_mix``), beside the attention layers' pages.
 
 Three entry points:
 - :func:`forward` — full causal forward, logits for every position
@@ -32,13 +36,16 @@ Three entry points:
 from __future__ import annotations
 
 import math
+from dataclasses import dataclass, replace
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from llm_consensus_tpu.models.cache import KVCache, QuantKVCache, quantize_kv
 from llm_consensus_tpu.models.configs import ModelConfig
+from llm_consensus_tpu.ops import ssm as _ssm
 from llm_consensus_tpu.ops.activations import swiglu
 from llm_consensus_tpu.ops.attention import (
     causal_attention,
@@ -237,14 +244,23 @@ def init_params(
     quantized, generated a layer at a time."""
     from llm_consensus_tpu.ops.quant import quant_axis
 
-    keys = iter(jax.random.split(key, 32 if _two_stacks(cfg) else 16))
+    keys = iter(
+        jax.random.split(
+            key, 32 if _two_stacks(cfg) or cfg.layer_plan else 16
+        )
+    )
 
-    def normal(name, shape, scale=0.02):
+    def normal(name, shape, scale=0.02, pad=None):
+        """``pad`` = (axis, width): the leaf is drawn at ``shape`` and
+        stored zero-padded along ``axis`` to ``width``."""
         k = next(keys)
         axis = quant_axis(name, len(shape)) if quant_bits else None
         if axis is not None:
-            return _init_quantized_leaf(k, shape, scale, axis, quant_bits, dtype)
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+            return _init_quantized_leaf(
+                k, shape, scale, axis, quant_bits, dtype, pad
+            )
+        w = (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+        return w if pad is None else _zero_pad(w, *pad)
 
     L, D, H, Hkv, F, V = (
         cfg.n_layers,
@@ -256,6 +272,22 @@ def init_params(
     )
     Dh = cfg.head_dim
     resid_scale = 0.02 / math.sqrt(2 * L)
+
+    if cfg.layer_plan:
+        # One stack a layer kind, the largest leaves drawn first (while
+        # the device is emptiest: an expert layer's float slice is GBs).
+        params = {}
+        for kind in ("moe", "ssm", "attn", "mlp"):
+            if cfg.n_of(kind):
+                params[PLAN_STACKS[kind]] = _init_plan_stack(
+                    cfg, kind, cfg.n_of(kind), normal, next(keys),
+                    resid_scale, dtype,
+                )
+        params["embed"] = normal("embed", (V, D))
+        params["norm_f"] = jnp.ones((D,), dtype)
+        if not cfg.tie_embeddings:
+            params["lm_head"] = normal("lm_head", (D, V))
+        return params
 
     if _two_stacks(cfg):
         Ld = cfg.n_dense_layers if cfg.is_moe else L
@@ -353,7 +385,9 @@ def _init_stack(cfg: ModelConfig, n: int, normal, resid_scale, dtype, moe):
     blocks["router"] = normal("router", (n, D, E))
     blocks["w_gate"] = normal("w_gate", (n, E, D, F))
     blocks["w_up"] = normal("w_up", (n, E, D, F))
-    blocks["w_down"] = normal("w_down", (n, E, F, D), resid_scale)
+    blocks["w_down"] = normal(
+        "w_down", (n, E, F, D), resid_scale * _routed_out_scale(cfg)
+    )
     if cfg.n_shared_experts:
         Fs = cfg.n_shared_experts * F
         blocks["ws_gate"] = normal("ws_gate", (n, D, Fs))
@@ -362,8 +396,110 @@ def _init_stack(cfg: ModelConfig, n: int, normal, resid_scale, dtype, moe):
     return blocks
 
 
-@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
-def _init_quantized_leaf(key, shape, scale, axis, bits, dtype):
+# Layer kinds of a planned model (``ModelConfig.layer_plan``) and the
+# parameter stack each lives in.
+PLAN_STACKS = {
+    "ssm": "ssm_blocks", "attn": "attn_blocks", "moe": "moe_blocks",
+    "mlp": "mlp_blocks",
+}
+
+
+def _zero_pad(w, axis: int, width: int):
+    pad = [(0, 0)] * w.ndim
+    pad[axis] = (0, width - w.shape[axis])
+    return jnp.pad(w, pad)
+
+
+def _routed_out_scale(cfg: ModelConfig) -> float:
+    """Width of the routed experts' random out-projection relative to
+    the other out-projections.
+
+    Keyed on the ROUTER's form, which is what makes the difference: a
+    "sigmoid_topk" router's weights are renormalised and scaled, so
+    the k chosen experts enter with weights that sum to
+    ``moe_routed_scale`` (2.5), where "softmax_topk" leaves them a
+    softmax's shares (top-6 of 64: ~0.15 in all). A random router is
+    also indecisive — its k-th and (k+1)-th scores tie within bf16
+    rounding for ~2% of tokens a layer — and at equal widths ONE
+    swapped expert then moves the residual stream more than every
+    rounding in the model, each expert layer multiplying the error it
+    is given (a float32 and a bf16 pass of the same weights end 30-50%
+    apart, measured on the chip). 1/16 makes a routed update a few per
+    cent of the stream, as a trained model's is and as the softmax
+    router's weights make it by themselves; bytes, operations and
+    routing statistics are what they were."""
+    return 1 / 16 if cfg.moe_router == "sigmoid_topk" else 1.0
+
+
+def _init_plan_stack(cfg, kind, n, normal, key, resid_scale, dtype):
+    """``n`` stacked ONE-mixer layers of ``kind``: the pre-norm and the
+    mixer's weights. An ungated ("relu2") feed-forward has no gate
+    matrix; an expert's width is stored padded to whole tiles
+    (``ModelConfig.expert_d_ff_stored``: zero columns of ``w_up``, zero
+    rows of ``w_down``)."""
+    D = cfg.d_model
+    gated = cfg.mlp_form == "swiglu"
+    blocks = {"norm": jnp.ones((n, D), dtype)}
+    if kind == "attn":
+        H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        blocks["wq"] = normal("wq", (n, D, H * Dh))
+        blocks["wk"] = normal("wk", (n, D, Hkv * Dh))
+        blocks["wv"] = normal("wv", (n, D, Hkv * Dh))
+        blocks["wo"] = normal("wo", (n, H * Dh, D), resid_scale)
+    elif kind == "mlp":
+        F = cfg.d_ff
+        if gated:
+            blocks["w_gate"] = normal("w_gate", (n, D, F))
+        blocks["w_up"] = normal("w_up", (n, D, F))
+        blocks["w_down"] = normal("w_down", (n, F, D), resid_scale)
+    elif kind == "moe":
+        E, F, Fp = cfg.n_experts, cfg.expert_d_ff, cfg.expert_d_ff_stored
+        if gated:
+            blocks["w_gate"] = normal("w_gate", (n, E, D, F), pad=(3, Fp))
+        blocks["w_up"] = normal("w_up", (n, E, D, F), pad=(3, Fp))
+        blocks["w_down"] = normal(
+            "w_down", (n, E, F, D), resid_scale * _routed_out_scale(cfg),
+            pad=(2, Fp),
+        )
+        blocks["router"] = normal("router", (n, D, E))
+        if cfg.moe_router == "sigmoid_topk":
+            blocks["router_bias"] = 0.02 * jax.random.normal(
+                jax.random.fold_in(key, 0), (n, E), jnp.float32
+            )
+        if cfg.n_shared_experts:
+            Fs = cfg.shared_d_ff
+            if gated:
+                blocks["ws_gate"] = normal("ws_gate", (n, D, Fs))
+            blocks["ws_up"] = normal("ws_up", (n, D, Fs))
+            blocks["ws_down"] = normal("ws_down", (n, Fs, D), resid_scale)
+    else:  # ssm
+        Hs, inner, cx = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
+        blocks["w_in_z"] = normal("w_in_z", (n, D, inner))
+        blocks["w_in_xbc"] = normal("w_in_xbc", (n, D, cx))
+        blocks["w_in_dt"] = normal("w_in_dt", (n, D, Hs))
+        blocks["w_out"] = normal("w_out", (n, inner, D), resid_scale)
+        blocks["conv_w"] = normal("conv_w", (n, cfg.ssm_conv, cx), 0.3)
+        blocks["conv_b"] = normal("conv_b", (n, cx))
+        blocks["gate_norm"] = jnp.ones((n, inner), dtype)
+        # Float32, as published: the step's bias so that softplus of it
+        # is log-uniform in [1e-3, 1e-1] (``time_step_min`` / ``_max``),
+        # A = -exp(a_log) in [-16, -1], the skip D = 1.
+        dt0 = jnp.exp(
+            jax.random.uniform(
+                jax.random.fold_in(key, 1), (n, Hs), jnp.float32,
+                math.log(1e-3), math.log(1e-1),
+            )
+        )
+        blocks["dt_bias"] = dt0 + jnp.log(-jnp.expm1(-dt0))
+        blocks["a_log"] = jnp.log(
+            jnp.broadcast_to(1.0 + jnp.arange(Hs, dtype=jnp.float32) % 16, (n, Hs))
+        )
+        blocks["d_skip"] = jnp.ones((n, Hs), jnp.float32)
+    return blocks
+
+
+@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
+def _init_quantized_leaf(key, shape, scale, axis, bits, dtype, pad=None):
     """One quantized weight leaf whose float form never exists whole.
 
     A stacked leaf ([L, ...], every quantized block weight) is drawn and
@@ -377,12 +513,25 @@ def _init_quantized_leaf(key, shape, scale, axis, bits, dtype):
 
     qfn = quantizer(bits)
 
-    def draw(k, shp, ax):
+    def draw(k, shp, ax, lead=1):
         w = (jax.random.normal(k, shp, jnp.float32) * scale).astype(dtype)
+        if pad is not None:
+            w = _zero_pad(w, pad[0] - lead, pad[1])
         return qfn(w, ax)
 
     if len(shape) == 2:  # lm_head: no layer axis
-        return draw(key, shape, axis)
+        return draw(key, shape, axis, 0)
+    if len(shape) == 4 and math.prod(shape[1:]) > 2**28:
+        # A layer of experts too large to hold in float (128 x 2688 x
+        # 1856 is 2.6 GB in float32 beside 11 GB of weights): one
+        # expert at a time, over the merged [layer, expert] axis.
+        flat = jax.lax.map(
+            lambda k: draw(k, shape[2:], axis - 2, 2),
+            jax.random.split(key, shape[0] * shape[1]),
+        )
+        return jax.tree.map(
+            lambda a: a.reshape(shape[0], shape[1], *a.shape[1:]), flat
+        )
     return jax.lax.map(
         lambda k: draw(k, shape[1:], axis - 1),
         jax.random.split(key, shape[0]),
@@ -422,7 +571,7 @@ def kv_plane_token_bytes(cfg: ModelConfig, kv_dtype) -> int:
             cfg.n_layers * cfg.latent_pool_dim * jnp.dtype(kv_dtype).itemsize
         )
     return (
-        cfg.n_layers
+        cfg.n_attn_layers
         * cfg.n_kv_heads
         * cfg.head_dim
         * 2
@@ -569,7 +718,7 @@ def _mlp(
     ``cfg.moe_dropless`` takes :func:`_moe_dropless` (``active``: see
     there), every older MoE preset the two paths below."""
     if not cfg.is_moe or "router" not in p:
-        y = swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
+        y = _ffn(cfg, h, p.get("w_gate"), p["w_up"], p["w_down"])
         return (y, _zero_aux()) if collect_aux else y
     if cfg.moe_dropless:
         y, logits, top_idx, _ = _moe_dropless(cfg, p, h, active=active)
@@ -599,7 +748,16 @@ def _mlp(
     return y
 
 
-def moe_route(cfg: ModelConfig, router, x: jnp.ndarray):
+def _ffn(cfg: ModelConfig, h, w_gate, w_up, w_down):
+    """A feed-forward in the configuration's form: SwiGLU, or the
+    ungated ``W_down · relu(W_up h)²`` (which has no gate matrix)."""
+    if cfg.mlp_form == "relu2":
+        up = jax.nn.relu(_qmm(h, w_up))
+        return _qmm(up * up, w_down)
+    return swiglu(h, w_gate, w_up, w_down)
+
+
+def moe_route(cfg: ModelConfig, router, x: jnp.ndarray, bias=None):
     """Router of the dropless layer: x [T, D] -> (logits [T, E] f32,
     weights [T, k] f32, experts [T, k]).
 
@@ -609,8 +767,24 @@ def moe_route(cfg: ModelConfig, router, x: jnp.ndarray):
     their inputs agree), the k largest probabilities as they are —
     divided by their sum only under ``moe_renormalize`` — times
     ``moe_routed_scale``. ``topk_softmax`` (Mixtral): the k largest
-    logits, softmaxed among themselves."""
+    logits, softmaxed among themselves. ``sigmoid_topk`` (DeepSeek-V3,
+    Nemotron-3): sigmoid scores in float32; the k largest of score +
+    ``bias`` [E] are CHOSEN, the weights are the chosen scores
+    themselves, renormalised and scaled as above."""
     k = cfg.n_experts_per_token
+    if cfg.moe_router == "sigmoid_topk":
+        logits = jnp.einsum(
+            "td,de->te",
+            x.astype(jnp.float32),
+            router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        scores = jax.nn.sigmoid(logits)
+        _, top_idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+        if cfg.moe_renormalize:
+            top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+        return logits, top_w * cfg.moe_routed_scale, top_idx
     if cfg.moe_router == "softmax_topk":
         logits = jnp.einsum(
             "td,de->te",
@@ -703,7 +877,9 @@ def _moe_dropless(cfg: ModelConfig, p: dict, h: jnp.ndarray, active=None):
     e, k = cfg.n_experts, cfg.n_experts_per_token
     tm = MOE_TILE
     x = h.reshape(t, d)
-    logits, top_w, top_idx = moe_route(cfg, _w(p["router"]), x)
+    logits, top_w, top_idx = moe_route(
+        cfg, _w(p["router"]), x, p.get("router_bias")
+    )
 
     a = t * k
     n_tiles = n_tiles_for(a, e, tm)
@@ -736,11 +912,16 @@ def _moe_dropless(cfg: ModelConfig, p: dict, h: jnp.ndarray, active=None):
         .set(order // k, mode="drop")
     )
     x_sorted = x[src_tok]
-    gate = _grouped_matmul(x_sorted, p["w_gate"], tile_expert, n_live)
-    up = _grouped_matmul(x_sorted, p["w_up"], tile_expert, n_live)
-    y_sorted = _grouped_matmul(
-        jax.nn.silu(gate) * up, p["w_down"], tile_expert, n_live
-    )
+    if cfg.mlp_form == "relu2":  # ungated: two matrices an expert
+        up = jax.nn.relu(
+            _grouped_matmul(x_sorted, p["w_up"], tile_expert, n_live)
+        )
+        act = up * up
+    else:
+        gate = _grouped_matmul(x_sorted, p["w_gate"], tile_expert, n_live)
+        up = _grouped_matmul(x_sorted, p["w_up"], tile_expert, n_live)
+        act = jax.nn.silu(gate) * up
+    y_sorted = _grouped_matmul(act, p["w_down"], tile_expert, n_live)
     dest = jnp.zeros((a,), jnp.int32).at[order].set(dest_sorted)
     live = dest < n_tiles * tm
     y_tok = jnp.where(
@@ -750,7 +931,7 @@ def _moe_dropless(cfg: ModelConfig, p: dict, h: jnp.ndarray, active=None):
     ).reshape(t, k, d)
     y = jnp.einsum("tkd,tk->td", y_tok, top_w).astype(h.dtype).reshape(b, s, d)
     if cfg.n_shared_experts:
-        y = y + swiglu(h, p["ws_gate"], p["ws_up"], p["ws_down"])
+        y = y + _ffn(cfg, h, p.get("ws_gate"), p["ws_up"], p["ws_down"])
     stats = jnp.stack([jnp.sum(counts > 0), jnp.sum(counts)]).astype(jnp.int32)
     return y, logits, top_idx, stats
 
@@ -1142,6 +1323,16 @@ def first_layers(cfg: ModelConfig, params: dict, n: int):
     head kept (``serve --layers`` on a loaded checkpoint)."""
     cut = cfg.with_layers(n)
     out = dict(params)
+    if cfg.layer_plan:
+        for kind, name in PLAN_STACKS.items():
+            if name not in params:
+                continue
+            keep = cut.n_of(kind)
+            if keep:
+                out[name] = jax.tree.map(lambda a: a[:keep], params[name])
+            else:
+                del out[name]
+        return cut, out
     for name in ("dense_blocks", "blocks"):
         if name not in params:
             continue
@@ -1206,6 +1397,18 @@ def _run_layers(
     router aux losses averaged over layers ({"load_balance", "z_loss"}).
     ``shared_prefix_len`` (decode mode): see :func:`_block`.
     """
+    if cfg.layer_plan:
+        if mode != "full":
+            raise NotImplementedError(
+                f"{cfg.name}: a model with recurrent (state-space) layers "
+                "runs through forward() and the paged step programs (serve "
+                f"--backend continuous); the contiguous-cache {mode!r} path "
+                "keeps no recurrent state"
+            )
+        return _run_plan_full(
+            cfg, params, x, cos, sin, positions, remat=remat, mesh=mesh,
+            collect_aux=collect_aux,
+        )
     blocks = params["blocks"]
 
     if isinstance(blocks, (list, tuple)):
@@ -1329,6 +1532,60 @@ def _run_layers(
     if isinstance(cache, QuantKVCache):
         return x, QuantKVCache(*new_leaves, length=cache.length)
     return x, KVCache(k=new_leaves[0], v=new_leaves[1], length=cache.length)
+
+
+def _plan_layers(cfg: ModelConfig):
+    """(kind, index within its kind's stack) of each planned layer."""
+    seen: dict[str, int] = {}
+    out = []
+    for kind in cfg.plan_kinds():
+        out.append((kind, seen.get(kind, 0)))
+        seen[kind] = seen.get(kind, 0) + 1
+    return out
+
+
+def _run_plan_full(
+    cfg, params, x, cos, sin, positions, remat=False, mesh=None,
+    collect_aux=False,
+):
+    """Full causal pass of a planned model, no cache: a Python loop over
+    the plan (tests, scoring, training parity — the serving path is
+    :func:`_paged_layers`). Returns (x, None[, aux])."""
+
+    def layer(x, p, kind):
+        h = _rms(cfg, x, p["norm"], mesh)
+        aux = _zero_aux()
+        if kind == "ssm":
+            y = _ssm_mix_full(cfg, p, h)
+        elif kind == "attn":
+            q, k, v = _project_qkv(cfg, p, h)
+            if cfg.positions == "rope":
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            attn = _attn_causal(cfg, q, k, v, positions, mesh=mesh)
+            y = _qmm(attn.reshape(*x.shape[:-1], -1), p["wo"])
+        elif kind == "moe":
+            y, logits, top_idx, _ = _moe_dropless(cfg, p, h)
+            if collect_aux:
+                aux = moe_router_aux(cfg, logits, top_idx)
+        else:
+            y = _ffn(cfg, h, p.get("w_gate"), p["w_up"], p["w_down"])
+        return x + y.astype(x.dtype), aux
+
+    if remat:
+        layer = jax.checkpoint(layer, static_argnums=(2,))
+    auxes = []
+    for kind, i in _plan_layers(cfg):
+        p = jax.tree.map(lambda a: a[i], params[PLAN_STACKS[kind]])
+        x, aux = layer(x, p, kind)
+        if kind == "moe":
+            auxes.append(aux)
+    if collect_aux:
+        aux = (
+            jax.tree.map(lambda *xs: jnp.mean(jnp.stack(xs)), *auxes)
+            if auxes else _zero_aux()
+        )
+        return x, None, aux
+    return x, None
 
 
 def _layer_view(blocks: dict, layer_idx) -> dict:
@@ -1670,6 +1927,305 @@ def _mla_attend_paged(
     return mla_expand_o(cfg, p["w_kvb"], o_lat), k_pools
 
 
+def _ssm_project(cfg: ModelConfig, p: dict, hf: jnp.ndarray):
+    """A state-space layer's in-projection of hf [T, D]: the gate z
+    [T, inner], the convolution's input xBC [T, conv_dim] (both through
+    the int8 kernel where the leaves are int8), and the step Δ [T, H] =
+    softplus(dt + dt_bias) in float32 (its 64 columns a float matmul)."""
+    z = _qmm(hf, p["w_in_z"])
+    xbc = _qmm(hf, p["w_in_xbc"])
+    dt = jnp.einsum(
+        "td,dh->th", hf, p["w_in_dt"], preferred_element_type=jnp.float32
+    )
+    return z, xbc, jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
+
+
+def _ssm_split(cfg: ModelConfig, xc: jnp.ndarray):
+    """The convolved, activated [.., conv_dim] into x [.., H, P] and the
+    groups' B and C [.., G, N]."""
+    inner, gn = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
+    lead = xc.shape[:-1]
+    x = xc[..., :inner].reshape(*lead, cfg.ssm_heads, cfg.ssm_head_dim)
+    b = xc[..., inner : inner + gn].reshape(*lead, cfg.ssm_groups, -1)
+    c = xc[..., inner + gn :].reshape(*lead, cfg.ssm_groups, -1)
+    return x, b, c
+
+
+def _ssm_out(cfg: ModelConfig, p: dict, y, x, z, dtype):
+    """From the scan's y [T, H, P]: the skip ``D·x``, the gated group
+    norm against z [T, inner], and the out-projection -> [T, D]."""
+    y = y + p["d_skip"].astype(jnp.float32)[:, None] * x
+    g = _ssm.gated_group_norm(
+        y.reshape(y.shape[0], -1), z, p["gate_norm"], cfg.ssm_groups,
+        cfg.rms_norm_eps,
+    )
+    return _qmm(g.astype(dtype), p["w_out"])
+
+
+def _ssm_mix_full(cfg: ModelConfig, p: dict, h: jnp.ndarray):
+    """A state-space mixer over whole sequences h [b, s, D] from an
+    empty state: :func:`forward`'s path (blocks of 64 tokens)."""
+    b, s, d = h.shape
+    z, xbc, dt = _ssm_project(cfg, p, h.reshape(b * s, d))
+    k = cfg.ssm_conv
+    xbc = xbc.reshape(b, s, -1)
+    win = jnp.pad(xbc, [(0, 0), (k - 1, 0), (0, 0)])
+    xc = jax.nn.silu(_ssm.causal_conv(win, p["conv_w"], p["conv_b"]))
+    x, bm, cm = _ssm_split(cfg, xc)
+    a = -jnp.exp(p["a_log"].astype(jnp.float32))
+    s0 = jnp.zeros(
+        (b, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32
+    )
+    y, _ = _ssm.ssd_scan(x, bm, cm, dt.reshape(b, s, -1), a, s0)
+    out = _ssm_out(
+        cfg, p, y.reshape(b * s, *y.shape[2:]),
+        x.reshape(b * s, *x.shape[2:]), z, h.dtype,
+    )
+    return out.reshape(b, s, d)
+
+
+def _ssm_scan_rows(cfg, terms, s_pool, layer, slot_in, slot_out):
+    """The rows' blocks against the state pool: the kernel where the
+    configuration runs kernels, else gather, :func:`ops.ssm.ssd_apply`,
+    scatter — the same sums."""
+    if cfg.use_pallas:
+        from llm_consensus_tpu.ops.pallas.ssm_scan import ssm_scan
+
+        return ssm_scan(terms, s_pool, layer, slot_in, slot_out)
+    y, s1 = _ssm.ssd_apply(terms, s_pool[layer, slot_in])
+    return y, s_pool.at[layer, slot_out].set(s1)
+
+
+def _ssm_rows(cfg, p, z, xbc, dt, state, layer, slot_in, slot_out, n_real):
+    """One group of rows of a state-space layer through the state pool.
+
+    z / xbc / dt: [R, T, ..] — R rows of T tokens; row r starts from
+    slot ``slot_in[r]`` (slot 0: an empty state) and leaves the state
+    after its first ``n_real[r]`` tokens in ``slot_out[r]``: tokens past
+    them get Δ = 0 and stay out of the convolution's rows, so padding
+    changes nothing, and a row that carries no request (``n_real`` 0,
+    ``slot_out`` 0) writes slot 0 what it read there. Returns (y
+    [R, T, H, P] float32, x [R, T, H, P], (s_pool, conv_pool))."""
+    s_pool, conv_pool = state
+    r, t = dt.shape[:2]
+    k = cfg.ssm_conv
+    fresh = slot_in == 0
+    conv0 = jnp.where(
+        fresh[:, None, None], 0, conv_pool[layer, slot_in]
+    )  # [R, K - 1, C]; slot 0's rows are whatever idle rows left there
+    win = jnp.concatenate([conv0.astype(xbc.dtype), xbc], axis=1)
+    xc = jax.nn.silu(_ssm.causal_conv(win, p["conv_w"], p["conv_b"]))
+    conv_pool = conv_pool.at[layer, slot_out].set(
+        _ssm.conv_rows_after(win, n_real, k).astype(conv_pool.dtype)
+    )
+    x, bm, cm = _ssm_split(cfg, xc)
+    dt = jnp.where((jnp.arange(t)[None] < n_real[:, None])[..., None], dt, 0.0)
+    pad = -t % 8  # the kernel's blocks are whole sublane tiles
+    terms = _ssm.ssd_terms(
+        *(
+            jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))
+            for v in (x, bm, cm, dt)
+        ),
+        -jnp.exp(p["a_log"].astype(jnp.float32)),
+    )
+    keep = (~fresh).astype(jnp.float32)[:, None, None, None]
+    terms["ce"] = terms["ce"] * keep
+    terms["f"] = terms["f"] * keep
+    y, s_pool = _ssm_scan_rows(cfg, terms, s_pool, layer, slot_in, slot_out)
+    return y.transpose(0, 2, 1, 3)[:, :t], x, (s_pool, conv_pool)
+
+
+def _ssm_mix(cfg: ModelConfig, p: dict, h: jnp.ndarray, state, layer, rows):
+    """A state-space mixer on a step program's token axis.
+
+    h: [.., D] with ``rows.n_dec`` decode rows of one token first, then
+    ``lanes`` chunk lanes of ``c`` tokens (either part may be empty);
+    ``state`` = (S pool [Ls, slots, H, P, N] float32, conv pool [Ls,
+    slots, K - 1, conv_dim]); ``layer`` this layer's index among the
+    state layers; ``rows`` a :class:`SsmRows`. A decode row advances
+    its slot's state by its token; a lane runs its chunk from
+    ``lane_in`` (its own slot, a snapshot's, or 0 = empty), leaves the
+    state after its last real token in ``lane_out`` and copies it to
+    ``lane_snap`` (a registry snapshot; 0 = none). Returns (mixer
+    output shaped as h, state)."""
+    shape = h.shape
+    hf = h.reshape(-1, shape[-1])
+    z, xbc, dt = _ssm_project(cfg, p, hf)
+    b, lanes = rows.n_dec, rows.lanes
+    ys, xs = [], []
+    if b:
+        y, x, state = _ssm_rows(
+            cfg, p, z[:b, None], xbc[:b, None], dt[:b, None], state, layer,
+            rows.dec_slot, rows.dec_slot,
+            (rows.dec_slot != 0).astype(jnp.int32),
+        )
+        ys.append(y[:, 0])
+        xs.append(x[:, 0])
+    if lanes:
+        c = (hf.shape[0] - b) // lanes
+
+        def grid(v):
+            return v[b:].reshape(lanes, c, *v.shape[1:])
+
+        y, x, state = _ssm_rows(
+            cfg, p, grid(z), grid(xbc), grid(dt), state, layer,
+            rows.lane_in, rows.lane_out, rows.lane_n,
+        )
+        ys.append(y.reshape(lanes * c, *y.shape[2:]))
+        xs.append(x.reshape(lanes * c, *x.shape[2:]))
+        # A snapshot is a copy of the lane's state as this program
+        # leaves it; a lane that saves none copies slot 0 onto itself.
+        src = jnp.where(rows.lane_snap != 0, rows.lane_out, 0)
+        state = tuple(
+            pool.at[layer, rows.lane_snap].set(pool[layer, src])
+            for pool in state
+        )
+    out = _ssm_out(
+        cfg, p, jnp.concatenate(ys), jnp.concatenate(xs), z, h.dtype
+    )
+    return out.reshape(shape), state
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class SsmRows:
+    """How a step program's token axis falls into rows of recurrent
+    state (:func:`_ssm_mix`): slots are indices into the state pool, 0
+    the empty slot that rows carrying no request read and write."""
+
+    dec_slot: jnp.ndarray  # [n_dec] slot of each decode row (0: idle)
+    lane_in: jnp.ndarray  # [lanes] slot a lane's chunk starts from
+    lane_out: jnp.ndarray  # [lanes] the sequence's own slot (0: dead)
+    lane_snap: jnp.ndarray  # [lanes] snapshot slot to copy into, or 0
+    lane_n: jnp.ndarray  # [lanes] real tokens of the chunk
+
+    @property
+    def n_dec(self) -> int:
+        return self.dec_slot.shape[0]
+
+    @property
+    def lanes(self) -> int:
+        return self.lane_in.shape[0]
+
+
+def _ssm_rows_of(cfg, cache, live_dec=None, chunk_state=None, lane_live=None):
+    """The :class:`SsmRows` of a step program, or None for a model
+    without recurrent layers. ``live_dec`` [B] bool marks the decode
+    rows that advance (None: the program has no decode rows);
+    ``chunk_state`` [L, 4] int32 is the host's (slot in, slot out,
+    snapshot slot, real tokens) a lane, ``lane_live`` [L] bool."""
+    if not cfg.is_recurrent:
+        return None
+    none = jnp.zeros((0,), jnp.int32)
+    dec = none
+    if live_dec is not None:
+        dec = jnp.where(live_dec, cache.state.slot, 0)
+    if chunk_state is None:
+        if lane_live is not None:
+            raise ValueError(
+                f"{cfg.name}: a chunk program of a model with recurrent "
+                "layers needs its lanes' state slots (chunk_state)"
+            )
+        return SsmRows(dec, none, none, none, none)
+    cs = jnp.where(lane_live[:, None], chunk_state, 0)
+    return SsmRows(dec, cs[:, 0], cs[:, 1], cs[:, 2], cs[:, 3])
+
+
+def _plan_segments(kinds: tuple[str, ...]) -> list[tuple[tuple[str, ...], int]]:
+    """A layer plan as (unit, repetitions) runs, greedily: at each
+    position the unit whose immediate repetition covers most layers
+    (``MEMEM*E`` x 2, then ``ME`` x 2, for the 18-layer cut), a single
+    layer where nothing repeats. A run is one ``lax.scan`` over its
+    unit."""
+    out, i, n = [], 0, len(kinds)
+    while i < n:
+        best = (1, 1)
+        for u in range(1, (n - i) // 2 + 1):
+            r = 1
+            while kinds[i + r * u : i + (r + 1) * u] == kinds[i : i + u]:
+                r += 1
+            if r > 1 and u * r > best[0] * best[1]:
+                best = (u, r)
+        u, r = best
+        out.append((kinds[i : i + u], r))
+        i += u * r
+    return out
+
+
+class _Mixer(NamedTuple):
+    """One mixer of a run's unit (:func:`_plan_runs`): repetition ``j``
+    reads layer ``first + j * step`` of ``stack`` (pre-norm ``norm``)
+    and, where it keeps pages or state, layer ``pool_first`` + that of
+    the pool."""
+
+    kind: str  # "ssm" | "attn" | anything else: a feed-forward
+    stack: dict
+    norm: str
+    first: int
+    step: int
+    pool_first: int
+
+
+def _plan_runs(cfg: ModelConfig, params: dict) -> list[tuple[tuple, int]]:
+    """The layer loop as runs of (unit of :class:`_Mixer`, repetitions).
+
+    A model without a plan is the unit "attention, then MLP" — both off
+    the same layer of a stack, the K/V pool indexed by ABSOLUTE layer —
+    repeated over each of its stacks. A planned model's runs are
+    :func:`_plan_segments`' with every mixer off its kind's stack,
+    whose index is also its layer of the K/V or the state pool."""
+    if not cfg.layer_plan:
+        return [
+            (
+                (
+                    _Mixer("attn", blocks, "attn_norm", 0, 1, first),
+                    _Mixer("ffn", blocks, "mlp_norm", 0, 1, 0),
+                ),
+                len(jax.tree_util.tree_leaves(blocks)[0]),
+            )
+            for blocks, first in layer_stacks(params)
+        ]
+    runs, done = [], {}
+    for unit, reps in _plan_segments(cfg.plan_kinds()):
+        seen: dict[str, int] = {}
+        mixers = []
+        for kind in unit:
+            mixers.append(_Mixer(
+                kind, params[PLAN_STACKS[kind]], "norm",
+                done.get(kind, 0) + seen.get(kind, 0), unit.count(kind), 0,
+            ))
+            seen[kind] = seen.get(kind, 0) + 1
+        runs.append((tuple(mixers), reps))
+        for kind, n in seen.items():
+            done[kind] = done.get(kind, 0) + n * reps
+    return runs
+
+
+def _paged_attn(
+    cfg, p, h, cos, sin, k_pools, v_pools, layer, pages, offs, attend
+):
+    """One layer's attention in a paged step program, from its normed
+    input to the out-projection: (delta [b, s, D], k_pools, v_pools)."""
+    if cfg.is_mla:
+        attn, k_pools = _mla_attend_paged(
+            cfg, p, h, cos, sin, k_pools, v_pools, layer, pages, offs,
+            attend,
+        )
+    else:
+        q, k, v = _project_qkv(cfg, p, h)
+        if cfg.positions == "rope":
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        k_pools = k_pools.at[layer, pages, offs].set(
+            k.astype(k_pools.dtype)
+        )
+        v_pools = v_pools.at[layer, pages, offs].set(
+            v.astype(v_pools.dtype)
+        )
+        attn = attend(q, k_pools, v_pools, layer)
+    return _qmm(attn.reshape(*h.shape[:-1], -1), p["wo"]), k_pools, v_pools
+
+
 def _paged_layers(
     cfg: ModelConfig,
     params: dict,
@@ -1683,6 +2239,7 @@ def _paged_layers(
     mesh=None,
     mlp=None,
     active=None,
+    ssm=None,
 ):
     """The layer loop of the four paged step programs.
 
@@ -1700,61 +2257,83 @@ def _paged_layers(
     in-page offset of each token's K/V; ``attend(q, k_pools, v_pools,
     layer)`` -> [b, s, H, Dh] is the program's own call of
     :func:`_attn_paged` on q [b, s, H, Dh]; ``mlp(p, h)`` replaces the
-    plain :func:`_mlp` where the program splits it. Returns (x, k, v).
+    plain :func:`_mlp` where the program splits it. Returns (x, k, v[,
+    a recurrent model's S pool and conv pool][, stats]).
 
-    A model whose layers have two shapes (leading dense layers, then
-    expert layers) is one scan a stack over ONE pool, indexed by
-    absolute layer. An MLA model writes one latent a token into ``k``
-    (``v`` is its empty plane and rides along untouched) and attends in
-    the absorbed form on every lane: ``attend`` then takes queries in
-    latent space and returns latent outputs. A ``moe_dropless`` model
-    also returns int32 [experts reached, assignments] summed over its
-    expert layers, for the batcher's counters; ``active`` ([b * s] bool
-    or None) marks the rows that carry a request (:func:`_moe_dropless`).
+    ONE loop follows the model's plan (:func:`_plan_runs`): a run is a
+    unit of mixers repeated, scanned over its repetitions (a single
+    repetition too: the scan's carry is what keeps the pools in
+    place), each mixer ``y + mixer(norm(y))`` off its stack. A model
+    without a plan is the unit "attention, then MLP" repeated over
+    each of its stacks — leading dense layers and expert layers are
+    two runs over ONE pool, indexed by absolute layer. A planned model
+    indexes the K/V pool by a layer's index among the attention layers
+    and the state pool (``cache.state``, carried and written in place
+    like the pages) among the state-space layers; ``ssm`` is the
+    program's :class:`SsmRows`. An MLA model writes one latent a token
+    into ``k`` (``v`` is its empty plane and rides along untouched) and
+    attends in the absorbed form on every lane. A ``moe_dropless``
+    model also returns int32 [experts reached, assignments] summed over
+    its expert layers, for the batcher's counters; ``active`` ([b * s]
+    bool or None) marks the rows that carry a request
+    (:func:`_moe_dropless`).
     """
     stats0 = (jnp.zeros((2,), jnp.int32),) if cfg.moe_dropless else ()
+    state0 = (
+        (cache.state.s, cache.state.conv) if cfg.is_recurrent else ()
+    )
+    n_state = len(state0)
 
-    carry = (x, cache.k, cache.v, *stats0)
-    for blocks, first in layer_stacks(params):
+    def ffn(p, h2, stats):
+        if stats and "router" in p:
+            out, _, _, st = _moe_dropless(cfg, p, h2, active=active)
+            return out, [stats[0] + st]
+        return (_mlp(cfg, p, h2) if mlp is None else mlp(p, h2)), stats
 
-        def body(carry, i, blocks=blocks, first=first):
-            y, k_pools, v_pools, *stats = carry
-            # The pool is indexed by ABSOLUTE layer, the stack by its own.
-            layer = i + first if first else i
-            p = _layer_view(blocks, i)
-            h = _rms(cfg, y, p["attn_norm"], mesh)
-            if cfg.is_mla:
-                attn, k_pools = _mla_attend_paged(
-                    cfg, p, h, cos, sin, k_pools, v_pools, layer, pages, offs,
-                    attend,
-                )
-            else:
-                q, k, v = _project_qkv(cfg, p, h)
-                q = apply_rope(q, cos, sin)
-                k = apply_rope(k, cos, sin)
-                k_pools = k_pools.at[layer, pages, offs].set(
-                    k.astype(k_pools.dtype)
-                )
-                v_pools = v_pools.at[layer, pages, offs].set(
-                    v.astype(v_pools.dtype)
-                )
-                attn = attend(q, k_pools, v_pools, layer)
-            y = y + _qmm(attn.reshape(*y.shape[:-1], -1), p["wo"])
-            h2 = _rms(cfg, y, p["mlp_norm"], mesh)
-            if stats and "router" in p:
-                out, _, _, st = _moe_dropless(cfg, p, h2, active=active)
-                y = y + out
-                stats = [stats[0] + st]
-            else:
-                y = y + (_mlp(cfg, p, h2) if mlp is None else mlp(p, h2))
-            return (y, k_pools, v_pools, *stats), None
+    carry = (x, cache.k, cache.v, *state0, *stats0)
+    for unit, reps in _plan_runs(cfg, params):
 
-        n = len(jax.tree_util.tree_leaves(blocks)[0])
-        carry, _ = jax.lax.scan(body, carry, jnp.arange(n))
-    x, new_k, new_v, *stats = carry
-    if cfg.moe_dropless:
-        return x, new_k, new_v, stats[0]
-    return x, new_k, new_v
+        def body(carry, j, unit=unit):
+            y, k_pools, v_pools, *rest = carry
+            state, stats = tuple(rest[:n_state]), rest[n_state:]
+            for m in unit:
+                # No multiply by 1 and no add of 0: the older models'
+                # programs stay text-equal to the ones they had.
+                i = j if m.step == 1 else j * m.step
+                i = i + m.first if m.first else i
+                p = _layer_view(m.stack, i)
+                h = _rms(cfg, y, p[m.norm], mesh)
+                if m.kind == "ssm":
+                    delta, state = _ssm_mix(cfg, p, h, state, i, ssm)
+                elif m.kind == "attn":
+                    layer = i + m.pool_first if m.pool_first else i
+                    delta, k_pools, v_pools = _paged_attn(
+                        cfg, p, h, cos, sin, k_pools, v_pools, layer, pages,
+                        offs, attend,
+                    )
+                else:
+                    delta, stats = ffn(p, h, stats)
+                y = y + delta.astype(y.dtype)
+            return (y, k_pools, v_pools, *state, *stats), None
+
+        carry, _ = jax.lax.scan(body, carry, jnp.arange(reps))
+    return carry
+
+
+def _run_paged(cfg, params, x, cos, sin, cache, pages, offs, attend, ssm=None,
+               **kw):
+    """:func:`_paged_layers` for a step program: (x, the cache with its
+    pools — and a recurrent model's state pools — replaced, *stats)."""
+    if ssm is not None:
+        kw["ssm"] = ssm
+    x, new_k, new_v, *rest = _paged_layers(
+        cfg, params, x, cos, sin, cache, pages, offs, attend, **kw
+    )
+    new = {"k": new_k, "v": new_v}
+    if cfg.is_recurrent:
+        new["state"] = replace(cache.state, s=rest[0], conv=rest[1])
+        rest = rest[2:]
+    return (x, replace(cache, **new), *rest)
 
 
 def _decoding_rows(cache, write_mask=None):
@@ -1862,7 +2441,7 @@ def decode_step_paged(
     scatter — is plain jnp that GSPMD shards from the operands'
     NamedShardings; only the pallas_call needs the explicit seam.
     """
-    from llm_consensus_tpu.models.paged_cache import NULL_PAGE, PagedKVCache
+    from llm_consensus_tpu.models.paged_cache import NULL_PAGE
 
     b = tokens.shape[0]
     pos = cache.length  # [B] current write position
@@ -1884,16 +2463,14 @@ def decode_step_paged(
             groups=groups, mesh=mesh,
         )[:, None]  # [B, H, D] -> [B, 1, H, D] (seq axis restored)
 
-    x, new_k, new_v, *stats = _paged_layers(
+    x, new_cache, *stats = _run_paged(
         cfg, params, x, cos, sin, cache, pages_now[:, None],
         offset[:, None], attend, mesh=mesh,
         active=_live_rows(cfg, cache, write_mask),
+        ssm=_ssm_rows_of(cfg, cache, _decoding_rows(cache, write_mask)),
     )
     logits = _unembed(cfg, params, x[:, 0], mesh)
-    new_cache = PagedKVCache(
-        k=new_k, v=new_v, page_table=cache.page_table, length=pos + adv
-    )
-    return (logits, new_cache, *stats)
+    return (logits, replace(new_cache, length=pos + adv), *stats)
 
 
 def verify_step_paged(
@@ -1931,8 +2508,11 @@ def verify_step_paged(
     :func:`decode_step_paged` (every verify query of a member stacks
     against one read of the shared run).
     """
-    from llm_consensus_tpu.models.paged_cache import PagedKVCache
-
+    if cfg.is_recurrent:
+        raise NotImplementedError(
+            f"{cfg.name}: the verify lane writes positions it may rewind, "
+            "and a recurrent state cannot be rewound"
+        )
     b, nq = tokens.shape
     pos0 = cache.length  # [B] first write position per row
     pos = pos0[:, None] + jnp.arange(nq)[None]  # [B, NQ]
@@ -1956,14 +2536,11 @@ def verify_step_paged(
             groups=groups, mesh=mesh,
         )  # [B, NQ, H, D]
 
-    x, new_k, new_v, *stats = _paged_layers(
+    x, new_cache, *stats = _run_paged(
         cfg, params, x, cos, sin, cache, pages, offs, attend, mesh=mesh,
         active=_live_rows(cfg, cache, repeat=nq),
     )
     logits = _unembed(cfg, params, x, mesh)  # [B, NQ, V]
-    new_cache = PagedKVCache(
-        k=new_k, v=new_v, page_table=cache.page_table, length=cache.length
-    )
     return (logits, new_cache, *stats)
 
 
@@ -1975,9 +2552,16 @@ def prefill_chunk_paged(
     start: jnp.ndarray,
     cache,
     mesh=None,
+    chunk_state=None,
 ) -> tuple[jnp.ndarray, object]:
     """One prompt chunk for each of L sequences (lanes), scattered into
     paged K/V.
+
+    ``chunk_state`` ([L, 4] int32; a model with recurrent layers): a
+    lane's (state slot it starts from — its own, a registry snapshot's,
+    or 0 for an empty state —, its own slot, the snapshot slot to copy
+    its end state into or 0, its chunk's REAL tokens); see
+    :func:`_ssm_mix`.
 
     tokens: [L, C] — lane l's chunk token ids at absolute positions
     ``start[l] + i``; table: [L, pages_per_seq] int32 page ids (position
@@ -2007,8 +2591,6 @@ def prefill_chunk_paged(
     unembeds that single row (see :func:`unembed_one`) — never a
     [C, V] logits buffer per chunk.
     """
-    from llm_consensus_tpu.models.paged_cache import PagedKVCache
-
     table, start, pos, pages, offs, live, attn_start = _chunk_lanes(
         cache, tokens, table, start
     )
@@ -2045,14 +2627,14 @@ def prefill_chunk_paged(
             mesh=mesh,
         )[1]  # out_chunk [L, C, H, D]
 
-    x, new_k, new_v, *stats = _paged_layers(
+    x, new_cache, *stats = _run_paged(
         cfg, params, x, cos, sin, cache, pages, offs, attend, mesh=mesh,
         active=(
             jnp.repeat(live, tokens.shape[1]) if cfg.moe_dropless else None
         ),
-    )
-    new_cache = PagedKVCache(
-        k=new_k, v=new_v, page_table=cache.page_table, length=cache.length
+        ssm=_ssm_rows_of(
+            cfg, cache, chunk_state=chunk_state, lane_live=live
+        ),
     )
     return (x, new_cache, *stats)
 
@@ -2068,6 +2650,7 @@ def fused_step_paged(
     groups=None,
     cfg_chunk: ModelConfig | None = None,
     mesh=None,
+    chunk_state=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, object]:
     """One decode step for every cache sequence PLUS the next prefill
     chunk of up to L other sequences — a single device program (the
@@ -2101,9 +2684,8 @@ def fused_step_paged(
     Returns (decode logits [B, V] fp32, chunk hidden [L, C, D], cache).
     ``cache.length`` advances for the decode rows only, and of those
     for the ones that carry a request (:func:`_decoding_rows`).
+    ``chunk_state``: as :func:`prefill_chunk_paged`.
     """
-    from llm_consensus_tpu.models.paged_cache import PagedKVCache
-
     if cfg_chunk is None:
         cfg_chunk = cfg
     b = tokens.shape[0]
@@ -2146,17 +2728,19 @@ def fused_step_paged(
     # One scatter over DISJOINT real pages: decode rows write their
     # private pages, each lane positions >= its chunk_start of its own
     # table.
-    x, new_k, new_v, *stats = _paged_layers(
+    x, new_cache, *stats = _run_paged(
         cfg, params, x, cos, sin, cache, pages_all[None], offs_all[None],
         attend, mesh=mesh, mlp=mlp_by_side if mlp_split else None,
         active=_live_rows(cfg, cache, lanes=jnp.repeat(live, c)),
+        ssm=_ssm_rows_of(
+            cfg, cache, _decoding_rows(cache), chunk_state, live
+        ),
     )
     logits = _unembed(cfg, params, x[0, :b], mesh)
     hidden_chunk = x[0, b:].reshape(lanes, c, -1)  # [L, C, D]
-    new_cache = PagedKVCache(
-        k=new_k, v=new_v, page_table=cache.page_table, length=pos + adv
+    return (
+        logits, hidden_chunk, replace(new_cache, length=pos + adv), *stats
     )
-    return (logits, hidden_chunk, new_cache, *stats)
 
 
 def unembed_one(

@@ -264,6 +264,50 @@ def moe_grouped_matmul_error(
     return err
 
 
+#: The state-space scan is float32 matrix products at "highest" in
+#: kernel and reference alike: summation order alone, over T <= 64
+#: tokens and N = 128 state values with O(1) operands.
+SSM_SCAN_TOL = 2e-4
+
+
+def ssm_scan_error(
+    *, seed: int, rows: int, tokens: int, heads: int, head_dim: int,
+    state: int, groups: int, slots: int = 8, layers: int = 2,
+    interpret: bool | None = None,
+) -> float:
+    """The state-carrying scan kernel (``ssm_scan``) vs gather,
+    ``ops.ssm.ssd_apply``, scatter: ``rows`` rows of ``tokens`` tokens,
+    each from its own slot of a [layers, slots, H, P, N] pool into
+    another (row 0 back into its own), the pool aliased through the
+    call. The error covers the rows' outputs AND the whole pool after:
+    a slot nobody wrote must come back as it went in."""
+    from llm_consensus_tpu.ops import ssm
+    from llm_consensus_tpu.ops.pallas.ssm_scan import ssm_scan
+
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(k[0], (rows, tokens, heads, head_dim))
+    b = jax.random.normal(k[1], (rows, tokens, groups, state))
+    c = jax.random.normal(k[2], (rows, tokens, groups, state))
+    dt = jax.nn.softplus(jax.random.normal(k[3], (rows, tokens, heads)) - 2)
+    a = -jnp.exp(jax.random.normal(k[4], (heads,)))
+    pool = jax.random.normal(
+        k[5], (layers, slots, heads, head_dim, state), jnp.float32
+    )
+    slot_in = jnp.arange(1, rows + 1, dtype=jnp.int32) % slots
+    slot_out = slot_in.at[1:].set((slot_in[1:] + rows) % slots)
+    layer = jnp.int32(layers - 1)
+    with jax.default_matmul_precision("highest"):
+        terms = ssm.ssd_terms(x, b, c, dt, a)
+        want_y, s1 = ssm.ssd_apply(terms, pool[layer, slot_in])
+        want_pool = pool.at[layer, slot_out].set(s1)
+        got_y, got_pool = jax.jit(
+            lambda t, p: ssm_scan(
+                t, p, layer, slot_in, slot_out, interpret=interpret
+            )
+        )(terms, pool)
+    return max(max_err(got_y, want_y), max_err(got_pool, want_pool))
+
+
 def rms_norm_error(
     *, seed: int, shape: tuple[int, ...], dtype=jnp.float32, eps: float = 1e-5,
     blk: int = 256, interpret: bool | None = None,

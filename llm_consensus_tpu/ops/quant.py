@@ -42,6 +42,11 @@ _QUANT_AXES_DENSE = {
     "ws_gate": 1,
     "ws_up": 1,
     "ws_down": 1,
+    # Mamba-2 projections (in_proj split into its z and xBC parts; the
+    # 64 dt columns stay a float matmul).
+    "w_in_z": 1,
+    "w_in_xbc": 1,
+    "w_out": 1,
 }
 _QUANT_AXES_MOE = {"w_gate": 2, "w_up": 2, "w_down": 2}
 
@@ -336,7 +341,10 @@ def quantize_params(
     qfn = quantizer(bits)
     qtypes = (QuantizedTensor, Quantized4Tensor)
     out = dict(params)
-    for stack in ("dense_blocks", "blocks"):
+    for stack in (
+        "dense_blocks", "blocks",
+        "ssm_blocks", "attn_blocks", "moe_blocks", "mlp_blocks",
+    ):
         if stack not in params:
             continue
         blocks = dict(params[stack])

@@ -58,6 +58,10 @@ __all__ = [
     "GENERATED_TOKENS",
     "PREFILL_TOKENS",
     "CHUNK_LANES",
+    "STATE_SLOTS",
+    "STATE_SNAPSHOTS",
+    "PREFIX_TOKENS_RECOMPUTED",
+    "SSM_TOKENS",
     "DEVICE_MEMORY_BYTES",
     "RAGGED_ROWS",
     "SPEC_DRAFT_TOKENS",
@@ -648,6 +652,29 @@ PREFILL_TOKENS = REGISTRY.counter(
 CHUNK_LANES = REGISTRY.counter(
     "gateway_chunk_lanes_total",
     "Chunk programs by kind and by the lanes that carried a chunk",
+)
+#: Recurrent state beside the pages (PR 32; a model with state-space
+#: layers): the state pool's slots by holder — ``live`` sequences,
+#: registry ``snapshot``s, ``free`` — and what became of snapshots: a
+#: prefill ``saved`` one at a page end, an admission ``restored`` its
+#: state from one, ``missed`` (its page match ran deeper than any
+#: snapshot, so ``gateway_prefix_tokens_recomputed_total`` tokens that
+#: pages covered were prefilled again), or one was ``evicted``.
+STATE_SLOTS = REGISTRY.gauge(
+    "gateway_state_slots",
+    "Recurrent-state slots by holder: live, snapshot, free",
+)
+STATE_SNAPSHOTS = REGISTRY.counter(
+    "gateway_state_snapshots_total",
+    "Recurrent-state snapshots by event: saved, restored, missed, evicted",
+)
+PREFIX_TOKENS_RECOMPUTED = REGISTRY.counter(
+    "gateway_prefix_tokens_recomputed_total",
+    "Prompt tokens whose pages matched and whose state no snapshot held",
+)
+SSM_TOKENS = REGISTRY.counter(
+    "gateway_ssm_tokens_total",
+    "Tokens through the state-space layers, by device-program kind",
 )
 #: The dropless expert layer's routing, labeled ``kind`` like the device
 #: programs (``decode`` / ``fused`` / ``prefill``), counted where the

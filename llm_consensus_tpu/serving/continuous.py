@@ -121,6 +121,7 @@ from llm_consensus_tpu.models.paged_cache import (
     PagedKVCache,
     PagePool,
     PrefixRegistry,
+    StatePool,
     copy_page,
     install_page,
     install_pages,
@@ -285,6 +286,18 @@ from llm_consensus_tpu.server.metrics import (
 )
 from llm_consensus_tpu.server.metrics import (
     MESH_SHARDS as _M_MESH_SHARDS,
+)
+from llm_consensus_tpu.server.metrics import (
+    PREFIX_TOKENS_RECOMPUTED as _M_PREFIX_RECOMPUTED,
+)
+from llm_consensus_tpu.server.metrics import (
+    SSM_TOKENS as _M_SSM_TOKENS,
+)
+from llm_consensus_tpu.server.metrics import (
+    STATE_SLOTS as _M_STATE_SLOTS,
+)
+from llm_consensus_tpu.server.metrics import (
+    STATE_SNAPSHOTS as _M_STATE_SNAPSHOTS,
 )
 from llm_consensus_tpu.server.metrics import (
     KV_PREFETCH as _M_PREFETCH,
@@ -489,6 +502,11 @@ class ContinuousConfig:
     # time / peak. 0: no gauge; the modelled bytes and measured seconds
     # still accumulate per kind in stats() (mbu_* keys).
     hbm_gbps: float = 0.0
+    # Slots of the recurrent-state pool, for a model with state-space
+    # layers (a size, as n_pages is): one a live sequence, the rest
+    # registry snapshots, slot 0 the empty state. 0: 4 a decode slot.
+    # A model without such layers has no state pool.
+    state_slots: int = 0
 
     def __post_init__(self):
         if self.prefill_chunk < 1:
@@ -614,6 +632,19 @@ class _Slot:
     # prompt tokens this request never re-prefilled).
     pages_shared_n: int = 0
     pages_restored_n: int = 0
+    # -- recurrent state (a model with state-space layers) --------------
+    # The sequence's own state slot, held from admission to retirement;
+    # the slot its NEXT chunk starts from (0: an empty state; a registry
+    # snapshot's for the first chunk after a shared prefix; its own from
+    # then on); the snapshot's node while its content is still to be
+    # written; the registry nodes of the prompt's full pages, by page;
+    # and how many pages the registry matched (``pages_shared_n`` is
+    # how many of them a snapshot made usable).
+    state_slot: int = 0
+    state_src: int = 0
+    state_dep: object = None
+    chain: list = field(default_factory=list)
+    pages_matched_n: int = 0
 
 
 @dataclass
@@ -790,6 +821,7 @@ class ContinuousBatcher:
         self._dp = 1
         self._mp = 1
         self._row_sharding = None
+        self._refuse_unsupported(cfg, self._draft_cfg, mesh, host_store)
         # The ragged attention kernel runs compiled (or interpreted) iff
         # the config asks for it and, on a mesh, the mesh can shard it.
         attn_kernel = bool(cfg.use_pallas) and (
@@ -840,7 +872,7 @@ class ContinuousBatcher:
             self.kernels = "pallas" if on_tpu() else "pallas-interpret"
             if not single_device(mesh):
                 self.kernels += "/shard_map"
-        self._refuse_unsupported(cfg, self._draft_cfg, mesh, host_store)
+        self._state_slots = c.state_slots or 4 * c.max_slots + 1
         self.cache = self._create_pool(cfg)
         # The device as JAX reports it to this process, and which of
         # its devices hold this batcher's pool.
@@ -888,9 +920,24 @@ class ContinuousBatcher:
             )
             for j in range(self._dp)
         ]
+        # Recurrent state (PR 32): the second kind of state in the one
+        # cache manager. One StatePool (a recurrent model serves on one
+        # device); the registry's nodes hold snapshots out of it.
+        self._states = (
+            StatePool(self._state_slots) if cfg.is_recurrent else None
+        )
         self._registries = [
-            PrefixRegistry(pool, c.page_size) for pool in self._pools
+            PrefixRegistry(pool, c.page_size, states=self._states)
+            for pool in self._pools
         ]
+        self._state_events = dict.fromkeys(
+            ("saved", "restored", "missed", "evicted"), 0
+        )
+        self._prefix_recomputed = 0
+        self._miss_depths: list[int] = []  # _remember_miss_depth
+        self._ssm_tokens = dict.fromkeys(
+            ("fused", "decode", "prefill", "spec"), 0
+        )
         # Host-RAM offload tier (PR 4; mesh-native since PR 13).
         # Engages only with prefix sharing (restores re-register
         # under the registry's readiness gates). On a mesh
@@ -1301,25 +1348,54 @@ class ContinuousBatcher:
         return NamedSharding(self.mesh, P(*spec))
 
     def _refuse_unsupported(self, cfg, draft_cfg, mesh, host_store) -> None:
-        """A latent-attention (MLA) model serves through the chunked,
-        fused and grouped programs of one device. What has no latent
-        path yet refuses here, by name, rather than fall back or serve
-        wrong pages."""
-        if not (cfg.is_mla or (draft_cfg is not None and draft_cfg.is_mla)):
+        """A latent-attention (MLA) model, and a model with recurrent
+        (state-space) layers, serve through the chunked, fused and
+        grouped programs of one device. What has no latent path or
+        moves pages only (a state is no page) refuses here, by name,
+        rather than fall back or serve wrong state."""
+        models = [m for m in (cfg, draft_cfg) if m is not None]
+        recurrent = any(m.is_recurrent for m in models)
+        if not (recurrent or any(m.is_mla for m in models)):
             return
         c = self.config
         why = None
         if draft_cfg is not None:
-            why = "a draft model (the draft/verify lane)"
-        elif not single_device(mesh):
-            why = "a mesh (the latent pool has no partitioning yet)"
-        elif c.host_cache_bytes > 0 or host_store is not None:
-            why = "the host tier / a remote page store"
-        if why:
-            raise ValueError(
-                f"{cfg.name}: latent-attention (MLA) models do not serve "
-                f"with {why} yet"
+            why = "a draft model (the draft/verify lane" + (
+                " rewinds positions, and a recurrent state cannot be "
+                "rolled back)" if recurrent else ")"
             )
+        elif not single_device(mesh):
+            why = (
+                "a mesh (the latent pool and the state pool have no "
+                "partitioning yet)"
+            )
+        elif c.host_cache_bytes > 0 or host_store is not None:
+            why = "the host tier / a remote page store" + (
+                " / handoff export (they move pages only, and a page "
+                "without its state cannot be continued from)"
+                if recurrent else ""
+            )
+        elif recurrent and c.decode_rounds > 1:
+            why = (
+                "decode_rounds > 1 (jit_rounds_step freezes and rewinds "
+                "rows inside a window; a state has no rollback)"
+            )
+        elif recurrent and any(
+            self._chunk_width(b) % c.page_size and b > c.page_size
+            for b in c.seq_buckets
+        ):
+            why = (
+                f"prefill_chunk {c.prefill_chunk} / seq_buckets "
+                f"{c.seq_buckets} whose chunks are no multiple of the page "
+                f"size {c.page_size} (a state snapshot is saved where a "
+                "chunk ends on a page boundary)"
+            )
+        if why:
+            kind = (
+                "models with recurrent (state-space) layers" if recurrent
+                else "latent-attention (MLA) models"
+            )
+            raise ValueError(f"{cfg.name}: {kind} do not serve with {why} yet")
 
     def _create_pool(self, cfg: ModelConfig) -> PagedKVCache:
         """An empty pool for ``cfg`` at this batcher's geometry. On a
@@ -1330,6 +1406,7 @@ class ContinuousBatcher:
         create = partial(
             PagedKVCache.create,
             cfg, c.n_pages, c.page_size, c.max_slots, c.pages_per_seq,
+            state_slots=self._state_slots if cfg.is_recurrent else 0,
         )
         if self.mesh is None:
             return create()
@@ -1630,12 +1707,16 @@ class ContinuousBatcher:
         stop_rounds=0,
         budgets=None,
         screen=None,
+        chunk_state=None,
     ):
         """The fused scheduler step: one decode+sample step AND the
         next prefill chunk of up to L sequences as ONE device program
         (PR 8; L lanes since PR 31). ``chunk_tokens`` [L, C],
         ``chunk_table`` [L, P], ``chunk_start`` / ``chunk_last`` /
         ``chunk_done`` [L]; a lane with an all-NULL table is dead.
+        ``chunk_state`` [L, 4] (a model with recurrent layers): each
+        lane's state slots and real tokens, as ``prefill_chunk_paged``
+        takes them.
 
         ``stop_rounds`` (STATIC, PR 12): > 0 makes this the MULTI-ROUND
         fused step — the chunk rides round 1 exactly as before (every
@@ -1674,6 +1755,7 @@ class ContinuousBatcher:
             groups=groups,
             cfg_chunk=cfg_chunk,
             mesh=self.mesh,
+            chunk_state=chunk_state,
         )
         tok1, logp1 = self._sample_rows(
             logits, seeds, counts, temps, topks, topps, filters_active
@@ -2161,14 +2243,19 @@ class ContinuousBatcher:
                 i32((lanes, chunk)), i32((lanes, c.pages_per_seq)),
                 i32((lanes,)),
             )
+            state = (
+                {"chunk_state": i32((lanes, 4))}
+                if self._states is not None else {}
+            )
             for groups in grouped:
                 self._fused_fn(chunk, lanes, s_bucket).lower(
                     *self._plain_shapes, groups, *lane, i32((lanes,)),
-                    jax.ShapeDtypeStruct((lanes,), jnp.bool_),
+                    jax.ShapeDtypeStruct((lanes,), jnp.bool_), **state,
                 ).compile()
             if lanes > 1:
                 self._chunk_fn(chunk, lanes, s_bucket).lower(
-                    self._plain_shapes[0], *lane, self._plain_shapes[1]
+                    self._plain_shapes[0], *lane, self._plain_shapes[1],
+                    **state,
                 ).compile()
         log.info(
             "fused step programs for chunks of %d, lanes %s, grouped and "
@@ -2944,6 +3031,28 @@ class ContinuousBatcher:
                     for (kind, n), v in self._chunk_lanes_n.items()
                 },
                 "work_iterations": self._work_iterations,
+                # Recurrent state (PR 32): the mirrors of
+                # gateway_state_snapshots_total{event},
+                # gateway_prefix_tokens_recomputed_total,
+                # gateway_ssm_tokens_total{kind} and gateway_state_slots
+                # — absent for a model without state-space layers.
+                **(
+                    {
+                        **{
+                            f"state_snapshots_{e}": n
+                            for e, n in self._state_events.items()
+                        },
+                        "prefix_tokens_recomputed": self._prefix_recomputed,
+                        **{
+                            f"ssm_tokens_{k}": n
+                            for k, n in self._ssm_tokens.items()
+                        },
+                        "state_slots_free": self._states.available,
+                        "state_slots_held": self._states.held,
+                    }
+                    if self._states is not None
+                    else {}
+                ),
                 # Multi-round on-device decode (PR 12) — the same
                 # observations behind gateway_device_rounds_total /
                 # gateway_decode_rounds_per_program (lockstep tested):
@@ -3199,18 +3308,31 @@ class ContinuousBatcher:
             # table would overhang the page budget — a prefix start off
             # the chunk grid pads the final chunk past the bucket, up
             # to chunk-1 positions.
+            # Pages the registry matched for a recurrent model, kept
+            # across the plans: plan B of a match no snapshot covers is
+            # that admission's miss.
+            matched_n = 0
             for use_share in (True, False) if c.share_prefix else (False,):
                 match = None
                 shared_pages: list[int] = []
                 start0 = 0
                 boundary = 0
                 restore_plan: list = []
+                state_node = None
                 if use_share:
+                    depth = None
+                    if self._states is not None:
+                        # A recurrent model continues from a page only
+                        # where a snapshot holds the state after it.
+                        depth, state_node, matched_n = self._state_depth(
+                            registry, ids
+                        )
                     # Boundary copies must beat recompute: a whole-page
                     # device copy for a trivial overlap (every prompt
                     # shares BOS) is pure overhead.
                     match = registry.match(
-                        ids, min_boundary=max(2, c.page_size // 4)
+                        ids, min_boundary=max(2, c.page_size // 4),
+                        depth=depth,
                     )
                     _M_PREFIX_LOOKUPS.inc()
                     shared_pages = match.pages
@@ -3290,6 +3412,23 @@ class ContinuousBatcher:
                     for p in shared_pages:
                         pool.release(p)
                     continue
+                own_state = 0
+                if self._states is not None:
+                    # The snapshot this admission starts from is shared
+                    # BEFORE its own slot is found: that search may
+                    # drop the least recently used idle snapshot.
+                    if state_node is not None:
+                        self._states.share(state_node.state)
+                    own_state = registry.alloc_state()
+                    if own_state is None:
+                        if state_node is not None:
+                            self._states.release(state_node.state)
+                        for p in shared_pages:
+                            pool.release(p)
+                        continue
+                    self._count_state_admission(
+                        state_node, matched_n, len(shared_pages)
+                    )
                 if use_share:
                     registry.record_commit(match, copied=bool(boundary))
                     if shared_pages or boundary:
@@ -3347,6 +3486,15 @@ class ContinuousBatcher:
                     for n in (match.nodes if match else [])
                     if not n.ready
                 ]
+                chain: list = []
+                if self._states is not None and c.share_prefix:
+                    # Snapshots are wanted where a later prompt can
+                    # continue from: the last full page (a later turn)
+                    # and the last one an exact copy may map.
+                    chain = registry.probe(ids, whole=True)[0]
+                    for k in {(L - 1) // pg, L // pg}:
+                        if 0 < k <= len(chain):
+                            chain[k - 1].want_state = True
                 self._slots[i] = _Slot(
                     request=req,
                     pages=pages,
@@ -3362,6 +3510,11 @@ class ContinuousBatcher:
                     reg_nodes=reg_nodes,
                     pages_shared_n=len(shared_pages),
                     pages_restored_n=len(restore_plan),
+                    state_slot=own_state,
+                    state_src=state_node.state if state_node else 0,
+                    state_dep=state_node,
+                    chain=chain,
+                    pages_matched_n=matched_n,
                 )
                 _flight.flight_recorder().record(
                     "admit",
@@ -3376,6 +3529,119 @@ class ContinuousBatcher:
                 )
                 return True
         return False
+
+    # -- recurrent state beside the pages (PR 32) -------------------------
+
+    def _state_depth(self, registry, ids):
+        """How deep a recurrent model's admission may map ``ids``'
+        registered pages: (pages, the node whose snapshot it starts
+        from or None, pages the registry matched). The deepest matched
+        node now WANTS a snapshot; it gets one promised at once if the
+        prefill that registered its page has yet to finish it on a
+        chunk end (the panel's mappers arrive while the donor is at
+        page 0). Otherwise the deepest node that has one decides; none
+        at all is a prefill from token 0 — slower, never different."""
+        nodes, _ = registry.probe(ids)
+        if nodes:
+            last = nodes[-1]
+            last.want_state = True
+            if last.state is None and self._writer_reaches(last, len(nodes)):
+                registry.promise_state(last)
+        for k in range(len(nodes), 0, -1):
+            if nodes[k - 1].state is not None:
+                return k, nodes[k - 1], len(nodes)
+        return 0, None, len(nodes)
+
+    def _writer_reaches(self, node, k: int) -> bool:
+        """Whether a prefilling sequence will end a chunk exactly at
+        the end of page ``k`` (1-based), which ``node`` is and which it
+        registered: only such a chunk's program can save the state."""
+        end = k * self.config.page_size
+        return any(
+            s is not None and s.phase == "prefill" and s.next_pos < end
+            and (end - s.next_pos) % s.chunk == 0
+            and any(n is node for n, _ in s.reg_nodes)
+            for s in self._slots
+        )
+
+    def _count_state_admission(self, state_node, matched: int, used: int):
+        """One committed admission of a recurrent model: it restores a
+        snapshot or not, and its page match ran deeper than the
+        snapshot or not."""
+        events = []
+        if state_node is not None:
+            events.append("restored")
+        if matched > used:
+            events.append("missed")
+            self._remember_miss_depth(matched)
+            lost = (matched - used) * self.config.page_size
+            _M_PREFIX_RECOMPUTED.inc(lost)
+            self._prefix_recomputed += lost
+        self._state_event(*events)
+
+    # Distinct page depths of recent snapshot misses that are kept.
+    _MISS_DEPTHS_KEPT = 4
+
+    def _remember_miss_depth(self, depth: int) -> None:
+        """A match ended ``depth`` pages in on a node without a snapshot.
+
+        Templated traffic branches at the same DEPTH on chain after
+        chain, though each chain comes once: a panel's refine prompt
+        leaves its question's evaluate chain 4-5 pages in, after that
+        chain's prefill is over, so the rule "a node wants a snapshot
+        once a match ended at it" always learns too late. Every prefill
+        therefore also saves a snapshot as it passes a depth where a
+        match lately missed (:meth:`_snapshot_slot`). The newest
+        ``_MISS_DEPTHS_KEPT`` distinct depths are kept, so one that
+        stops recurring is forgotten; the snapshots compete for slots
+        LRU like any other. Worth 5% of a panel question and most of
+        its run-to-run spread (PERF.md, Findings PR 32)."""
+        kept = [d for d in self._miss_depths if d != depth]
+        self._miss_depths = [*kept[-(self._MISS_DEPTHS_KEPT - 1):], depth]
+
+    def _state_event(self, *events: str) -> None:
+        """Count snapshot events, fold in what the registry evicted
+        since, and refresh the slots gauge (caller holds the lock or is
+        the worker thread; plain ints)."""
+        evicted = sum(r.snapshots_evicted for r in self._registries)
+        new = evicted - self._state_events["evicted"]
+        for event in (*events, *(["evicted"] * new)):
+            self._state_events[event] += 1
+            _M_STATE_SNAPSHOTS.labels(event=event).inc()
+        live = sum(s is not None for s in self._slots)
+        for state, n in (
+            ("live", live),
+            ("snapshot", self._states.held - live),
+            ("free", self._states.available),
+        ):
+            _M_STATE_SLOTS.labels(state=state).set(n)
+
+    def _snapshot_slot(self, slot: _Slot, end: int) -> int:
+        """The state slot the chunk of ``slot`` that ends at ``end``
+        must copy its state into, or 0: a chunk that ends a full page
+        of real tokens whose registry node wants a snapshot (or was
+        promised one, or lies at a depth where matches lately missed:
+        :meth:`_remember_miss_depth`) and has none written yet."""
+        node = self._node_ending_at(slot, end)
+        if node is None or node.state_ready:
+            return 0
+        depth = end // self.config.page_size
+        if node.state is None and (
+            node.want_state or depth in self._miss_depths
+        ):
+            self._registries[0].promise_state(node)
+        return node.state or 0
+
+    def _node_ending_at(self, slot: _Slot, end: int):
+        """The registry node of the full page of REAL prompt tokens that
+        ends at position ``end`` of ``slot``'s prompt, if ``end`` is
+        such a page's end and the node is still in the tree."""
+        pg = self.config.page_size
+        k = end // pg
+        if end > slot.prompt_len or end % pg or not 0 < k <= len(slot.chain):
+            return None
+        node = slot.chain[k - 1]
+        return None if node.evicted else node
 
     def _boundary_copy_pending(self) -> None:
         """Dispatch the CoW boundary copy staged by :meth:`_admit_chunked`
@@ -3772,6 +4038,9 @@ class ContinuousBatcher:
         if cost is None:
             return
         pages = cost["attn_pages_read"]
+        if self._states is not None:
+            _M_SSM_TOKENS.labels(kind=kind).inc(cost["tokens"])
+            self._ssm_tokens[kind] += cost["tokens"]
         _M_ATTN_TOKENS_READ.labels(kind=kind).inc(cost["kv_read_tokens"])
         _M_ATTN_PAGES_READ.labels(kind=kind).inc(pages)
         with self._lock:
@@ -3812,6 +4081,7 @@ class ContinuousBatcher:
                 and s.phase == "prefill"
                 and s.next_pos < s.prompt_len
                 and all(node.ready for node in s.deps)
+                and (s.state_dep is None or s.state_dep.state_ready)
                 and (not picked or s.chunk == self._slots[picked[0]].chunk)
             ):
                 picked.append(i)
@@ -3825,7 +4095,9 @@ class ContinuousBatcher:
         """The lane arguments of one chunk program carrying the next
         chunk of each slot of ``idxs``: (program lanes, chunk ids
         [L, C], tables [L, P], starts [L], last prompt positions [L],
-        done [L] bool, cost extents of the live lanes). One ready slot
+        done [L] bool, cost extents of the live lanes, and — for a model
+        with recurrent layers — the lanes' [L, 4] state slots and real
+        tokens as the program's ``chunk_state`` keyword). One ready slot
         takes the one-lane program; more take the wide one, the lanes
         past ``idxs`` dead — an all-NULL table, as an idle slot's row."""
         c = self.config
@@ -3836,6 +4108,7 @@ class ContinuousBatcher:
         starts = np.zeros((lanes,), np.int32)
         lasts = np.zeros((lanes,), np.int32)
         done = np.zeros((lanes,), bool)
+        state = np.zeros((lanes, 4), np.int32)
         ext = []
         for lane, idx in enumerate(idxs):
             slot = self._slots[idx]
@@ -3846,7 +4119,17 @@ class ContinuousBatcher:
             lasts[lane] = slot.prompt_len - 1
             done[lane] = end >= slot.prompt_len
             ext.append((end, slot.chunk))
-        return lanes, ids, tables, starts, lasts, done, ext
+            if self._states is not None:
+                state[lane] = (
+                    slot.state_src, slot.state_slot,
+                    self._snapshot_slot(slot, end),
+                    min(end, slot.prompt_len) - slot.next_pos,
+                )
+        kw = (
+            {"chunk_state": jnp.asarray(state)}
+            if self._states is not None else {}
+        )
+        return lanes, ids, tables, starts, lasts, done, ext, kw
 
     def _count_lanes(self, kind: str, live: int) -> None:
         """One chunk program of ``kind`` with ``live`` lanes filled."""
@@ -3869,7 +4152,26 @@ class ContinuousBatcher:
         for node, end_pos in slot.reg_nodes:
             if not node.ready and end_pos <= written_real:
                 node.ready = True
+        if self._states is not None:
+            self._state_chunk_dispatched(slot, written_end)
         slot.next_pos = written_end
+
+    def _state_chunk_dispatched(self, slot: _Slot, end: int) -> None:
+        """The recurrent half of :meth:`_chunk_dispatched`: the chunk's
+        program has read the slot it started from, so a snapshot's hold
+        goes back and the next chunk starts from the sequence's own
+        slot; and the snapshot that program saved (:meth:`_snapshot_slot`
+        named it) is written as far as any later program can tell."""
+        if slot.state_src != slot.state_slot:
+            if slot.state_src:
+                self._states.release(slot.state_src)
+            slot.state_src = slot.state_slot
+            slot.state_dep = None
+        node = self._node_ending_at(slot, end)
+        if node is not None and node.state is not None and not node.state_ready:
+            node.state_ready = True
+            with self._lock:
+                self._state_event("saved")
 
     def _prefill_step(self, idxs: list[int]) -> bool:
         """Run the next prefill chunk of each slot of ``idxs`` as ONE
@@ -3897,7 +4199,9 @@ class ContinuousBatcher:
             t0 = time.perf_counter()
             ev = self._count_program("prefill")
             self._count_lanes("prefill", len(idxs))
-            lanes, ids, tables, starts, _, done, ext = self._lane_args(idxs)
+            lanes, ids, tables, starts, _, done, ext, state_kw = (
+                self._lane_args(idxs)
+            )
             hidden, self.cache, *moe = self._chunk_fn(
                 head.chunk, lanes, head.s_bucket
             )(
@@ -3906,6 +4210,7 @@ class ContinuousBatcher:
                 jnp.asarray(tables),
                 jnp.asarray(starts),
                 self.cache,
+                **state_kw,
             )
             firsts = {}
             for lane, slot in enumerate(slots):
@@ -3968,6 +4273,7 @@ class ContinuousBatcher:
                     jnp.int32(idx),
                     jnp.asarray(slot.table),
                     jnp.int32(slot.prompt_len),
+                    jnp.int32(slot.state_slot),
                 )
                 self._install_draft_seq(idx, slot)
                 self._activate(idx, slot, firsts[lane])
@@ -4122,6 +4428,11 @@ class ContinuousBatcher:
                 else 0.0
             ),
             "header_pages_shared": slot.pages_shared_n,
+            # What the registry matched; a recurrent model could use
+            # ``header_pages_shared`` of them (a snapshot's depth).
+            "header_pages_matched": max(
+                slot.pages_matched_n, slot.pages_shared_n
+            ),
             "header_pages_restored": slot.pages_restored_n,
             "finished_at": time.time(),
         }
@@ -4146,6 +4457,9 @@ class ContinuousBatcher:
             for p in slot.pages:
                 pool.release(p)
             self._slots[idx] = None
+            if self._states is not None:
+                self._states.release(slot.state_slot)
+                self._state_event()
             self._completed += 1
             self._generated_tokens += len(slot.generated)
             _M_ACTIVE.set(self._decoding())
@@ -4535,8 +4849,8 @@ class ContinuousBatcher:
             cost = self._program_cost("decode", rows_now, k)
         else:
             head = self._slots[chunk_idxs[0]]
-            lanes, ids, tables, starts, lasts, done, ext = self._lane_args(
-                chunk_idxs
+            lanes, ids, tables, starts, lasts, done, ext, state_kw = (
+                self._lane_args(chunk_idxs)
             )
             out = self._fused_fn(head.chunk, lanes, head.s_bucket)(
                 *args,
@@ -4550,6 +4864,7 @@ class ContinuousBatcher:
                     if rounds_now
                     else ()
                 ),
+                **state_kw,
             )
             if rounds_now:
                 (
@@ -4897,6 +5212,7 @@ class ContinuousBatcher:
                     jnp.int32(ch.idx),
                     jnp.asarray(slot.table),
                     jnp.int32(slot.prompt_len),
+                    jnp.int32(slot.state_slot),
                 )
                 self._install_draft_seq(ch.idx, slot)
                 self._activate(ch.idx, slot, first)

@@ -164,8 +164,10 @@ def test_int8_cache_shared_prefix_walk_compiles(one_chip, stacked):
 
 @pytest.mark.parametrize(
     "k,n,assignments",
-    [(2048, 1408, 480), (1408, 2048, 480), (2048, 1408, 6)],
-    ids=["gate-fused-step", "down-fused-step", "gate-one-row"],
+    [(2048, 1408, 480), (1408, 2048, 480), (2048, 1408, 6),
+     (2688, 1920, 1248), (1920, 2688, 1248)],
+    ids=["gate-fused-step", "down-fused-step", "gate-one-row",
+         "relu2-up-208-rows", "relu2-down-208-rows"],
 )
 def test_moe_grouped_matmul_compiles_on_the_resident_stack(
     one_chip, k, n, assignments
@@ -267,3 +269,93 @@ def test_fused_step_with_three_lanes_compiles_at_the_cells_shapes(
     assert "quant_matmul_stacked" in text
     # Both pools alias the donated cache; nothing pool-sized is copied.
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+# -- a recurrent model's programs (PR 32) -----------------------------------
+# nemotron-3-nano-30b-a3b's cell: the plan's first 18 layers, 16 slots,
+# 1,024 pages, 96 state slots of 8 layers x [64, 64, 128] float32.
+
+
+@pytest.mark.parametrize("rows,tokens", [(16, 8), (3, 64)],
+                         ids=["decode-rows", "chunk-lanes"])
+def test_ssm_scan_compiles_on_the_resident_state_pool(one_chip, rows, tokens):
+    """The scan kernel with its float32 products at "highest" and the
+    state pool aliased through it: Mosaic takes it, and nothing
+    pool-sized (1.6 GB) is a temporary."""
+    from llm_consensus_tpu.ops.pallas.ssm_scan import ssm_scan
+
+    h, p, n = 64, 64, 128
+    f32 = lambda *s: _shape(one_chip, s, jnp.float32)  # noqa: E731
+    i32 = lambda *s: _shape(one_chip, s, jnp.int32)  # noqa: E731
+
+    def call(x, m, ce, bw, f, pool, layer, sin, sout):
+        return ssm_scan(
+            dict(x=x, m=m, ce=ce, bw=bw, f=f), pool, layer, sin, sout,
+            interpret=False,
+        )
+
+    compiled = jax.jit(call, donate_argnums=(5,)).lower(
+        f32(rows, h, tokens, p), f32(rows, h, tokens, tokens),
+        f32(rows, h, tokens, n), f32(rows, h, tokens, n), f32(rows, h, 1, n),
+        f32(8, 96, h, p, n), i32(), i32(rows), i32(rows),
+    ).compile()
+    assert "ssm_scan" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("program", ["fused", "decode", "chunk"])
+def test_recurrent_models_step_programs_compile_without_copying_a_pool(
+    one_chip, monkeypatch, program
+):
+    """``jit_fused_step`` (3 lanes, grouped), ``jit_decode_step`` and the
+    wide ``jit_prefill_chunk`` of the 18-layer cut at the cell's sizes:
+    the K/V pool, the state pool and every weight stack alias or stay
+    where they are — a copy of any would be a temporary of 0.13-5 GB."""
+    from functools import partial
+
+    from llm_consensus_tpu.models import transformer as T
+    from llm_consensus_tpu.models.configs import PRESETS
+    from llm_consensus_tpu.models.paged_cache import (
+        DecodeGroupArrays,
+        PagedKVCache,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = PRESETS["nemotron-3-nano-30b-a3b"].with_layers(18).with_(
+        use_pallas=True
+    )
+    slots, lanes, gm = 16, 3, 8
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: _shape(one_chip, a.shape, a.dtype), tree
+        )
+
+    params = described(jax.eval_shape(
+        lambda: T.init_params_quantized(cfg, jax.random.PRNGKey(0))
+    ))
+    cache = described(jax.eval_shape(
+        lambda: PagedKVCache.create(cfg, PAGES, PG, slots, P, state_slots=96)
+    ))
+    i32 = lambda *s: _shape(one_chip, s, jnp.int32)  # noqa: E731
+    groups = DecodeGroupArrays(i32(slots), i32(gm), i32(gm), i32(slots))
+    lane = (i32(lanes, C), i32(lanes, P), i32(lanes))
+    with jax.default_matmul_precision("default"):
+        if program == "fused":
+            lowered = jax.jit(
+                partial(T.fused_step_paged, cfg), donate_argnums=(2,)
+            ).lower(params, i32(slots, 1), cache, *lane, groups,
+                    chunk_state=i32(lanes, 4))
+        elif program == "decode":
+            lowered = jax.jit(
+                partial(T.decode_step_paged, cfg), donate_argnums=(2,)
+            ).lower(params, i32(slots, 1), cache, groups)
+        else:
+            lowered = jax.jit(
+                partial(T.prefill_chunk_paged, cfg), donate_argnums=(4,)
+            ).lower(params, *lane, cache, chunk_state=i32(lanes, 4))
+        compiled = lowered.compile()
+    text = compiled.as_text()
+    for kernel in ("ssm_scan", "moe_grouped_matmul", "quant_matmul_stacked"):
+        assert kernel in text, kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < 96 * 2**20
