@@ -381,11 +381,18 @@ def kernels_child(dry_run: bool) -> int:
     cases: list[tuple[str, float, object]] = []
 
     def ragged(name, **kw):
+        """Two lines a variant: its outputs against the XLA reference,
+        and its float32 outputs (before the cast to the queries' dtype)
+        against the float64 oracle."""
+        kw = {**base, **kw}
         cases.append((
             f"ragged_attention[{name}]", parity.ATTENTION_TOL,
-            lambda: max(
-                parity.ragged_attention_error(**{**base, **kw}).values()
-            ),
+            lambda: max(parity.ragged_attention_error(**kw).values()),
+        ))
+        cases.append((
+            f"ragged_attention[{name}] f32 out / float64 oracle",
+            parity.RAGGED_ORACLE_TOL,
+            lambda: max(parity.ragged_attention_oracle_error(**kw).values()),
         ))
 
     grouped = [max(f, 2 * pg + 5) for f in fills]  # members past the run
@@ -437,11 +444,7 @@ def kernels_child(dry_run: bool) -> int:
             valid_len=grouped, group_rows=(0, 2, 3, 5), shared_pages=2,
             layer=(1, 2), **lanes)),
     ):
-        cases.append((
-            f"ragged_attention[latent {name}]", parity.ATTENTION_TOL,
-            lambda kw=kw: max(parity.ragged_attention_error(
-                **{**base, "hkv": 1, **lat, **kw}).values()),
-        ))
+        ragged(f"latent {name}", hkv=1, **lat, **kw)
     # The grouped expert matmul at DeepSeek-V2-Lite's two shapes: 8
     # experts of which two hold no row and one holds three tiles.
     for k_, n_ in (((128, 256), (256, 128)) if dry_run

@@ -587,10 +587,11 @@ def _decode_q8_stacked_kernel(
 # bit-for-bit the arithmetic of the two-phase kernels this replaces.
 # The pools stay in HBM and a step walks its row's LIVE pages only
 # (from the suffix start or the sliding window's edge up to the fill; a
-# group's shared run), copying each through a two-slot buffer with the
-# next page in flight: a call's time follows the pages its rows hold,
-# not the table's width (a grid step costs 0.16 us on a v5e even when
-# it folds nothing, and a table has 48 columns a row).
+# group's shared run), two pool pages a tile of 128 keys, copied through
+# a two-slot buffer with the next tile's pages in flight: a call's time
+# follows the pages its rows hold, not the table's width (a grid step
+# costs 0.16 us on a v5e even when it folds nothing, and a table has 48
+# columns a row).
 #
 # Three static layouts share the body (there is one kernel, not three):
 # the serving pool [n_pages, page, Hkv, D]; the dense int8 head-major
@@ -624,15 +625,42 @@ def _sp_block(s: int, cap: int = 128) -> int:
     return blk
 
 
+#: bfloat16 terms the softmax weights ``p`` are split into before the
+#: value product on bf16 pages. ``p`` is the fold's one operand with
+#: float32 digits; hi = bf16(p), mid = bf16(p - hi) are exact bf16
+#: values whose products with a bf16 ``v`` are exact in the f32
+#: accumulator, so n terms carry ~8n of p's 24 bits. Measured on a v5e
+#: (PERF.md §6, PR 33, step 0): the f32 product this replaced ran at
+#: Mosaic's default precision, which rounds BOTH operands to bf16 — one
+#: term reproduces its outputs to the digit (1e-3 of the float64
+#: oracle); two read 3e-6 and are no slower than one on any case
+#: timed (faster on the dense pools), so two is what ships. One is the
+#: floor: fewer digits than the kernel had is a precision decision.
+_P_TERMS = 2
+
+
+def _bf16_terms(p, n: int) -> list:
+    """``p`` (f32) as ``n`` bf16 terms, largest first, summing to it."""
+    terms = [p.astype(jnp.bfloat16)]
+    for _ in range(n - 1):
+        p = p - terms[-1].astype(jnp.float32)
+        terms.append(p.astype(jnp.bfloat16))
+    return terms
+
+
 def _online_fold(m_ref, l_ref, acc_ref, idx, scores, v, v_row_scale=None):
     """Fold one score block into running (m, l, acc) softmax state.
 
     ``idx`` selects the scratch slice (slice or int); scores [R, blk]
     fp32 (already masked to -inf outside the live range); v [blk, D].
-    ``v_row_scale`` [1, blk]: per-slot dequant scale folded into the
-    VALUE product only (the l denominator stays the true softmax sum) —
-    the same linear-dequant trick as :func:`_q8_attend`. Every program
-    class of the ragged kernel folds through this one function.
+    A bfloat16 ``v`` is used as it is: ``p`` meets it as
+    :data:`_P_TERMS` bf16 terms, each product one pass of the MXU into
+    the f32 accumulator. Any other ``v`` is widened and takes the f32
+    product. ``v_row_scale`` [1, blk]: per-slot dequant scale folded
+    into the VALUE product only (the l denominator stays the true
+    softmax sum) — the same linear-dequant trick as :func:`_q8_attend`.
+    Every program class of the ragged kernel folds through this one
+    function.
     """
     m_prev = m_ref[idx]
     m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
@@ -640,40 +668,56 @@ def _online_fold(m_ref, l_ref, acc_ref, idx, scores, v, v_row_scale=None):
     p = jnp.exp(scores - m_safe)
     alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
     l_ref[idx] = l_ref[idx] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p if v_row_scale is None else p * v_row_scale,
-        v.astype(jnp.float32),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    if v.dtype == jnp.bfloat16:
+        pv = functools.reduce(
+            jnp.add,
+            [
+                jax.lax.dot_general(
+                    term,
+                    v,
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.DEFAULT,
+                    preferred_element_type=jnp.float32,
+                )
+                for term in _bf16_terms(p, _P_TERMS)
+            ],
+        )
+    else:
+        pv = jax.lax.dot_general(
+            p if v_row_scale is None else p * v_row_scale,
+            v.astype(jnp.float32),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
     acc_ref[idx] = acc_ref[idx] * alpha + pv
     m_ref[idx] = m_new
 
 
-def _pool_head(ref, head: int):
-    """One kv head's [pg, D] slab, in f32, from a [1, pg, Hkv, D] pool
-    block.
+def _pool_head(ref, head: int, dtype):
+    """One kv head's [pg, D] slab, as ``dtype`` (the pool's own, or
+    f32), from a [1, pg, Hkv, D] pool block.
 
     The pool keeps kv heads on the second-minor axis, so a head's rows
     are a stride-Hkv walk over the block viewed as [pg * Hkv, D] — the
     addressing of jax's own TPU ragged paged attention kernel. Mosaic
     strides 32-bit rows only: a bf16 pool is read as uint32 words (two
     adjacent heads of one token per word) and the wanted half is
-    shifted into an f32's high bits, which is exactly bf16 -> f32. An
-    odd head count cannot pair up and takes the plain indexed load.
+    shifted into an f32's high bits, which is exactly bf16 -> f32;
+    narrowing those words back to bf16 drops sixteen zero bits. An odd
+    head count cannot pair up and takes the plain indexed load.
     """
     _, pg, hkv, d = ref.shape
     packing = 4 // ref.dtype.itemsize
     if hkv == 1:
-        return ref[0, :, 0, :].astype(jnp.float32)
+        return ref[0, :, 0, :].astype(dtype)
     if packing == 1:
         return ref.at[0].reshape(pg * hkv, d)[head::hkv, :]
     if packing != 2 or hkv % 2:
-        return ref[0, :, head, :].astype(jnp.float32)
+        return ref[0, :, head, :].astype(dtype)
     words = ref.at[0].reshape(pg * hkv, d).bitcast(jnp.uint32)
     w = words[head // 2 :: hkv // 2, :]
     bits = w << 16 if head % 2 == 0 else w & jnp.uint32(0xFFFF0000)
-    return pltpu.bitcast(bits, jnp.float32)
+    return pltpu.bitcast(bits, jnp.float32).astype(dtype)
 
 
 def _ragged_kernel(
@@ -693,6 +737,7 @@ def _ragged_kernel(
     window: int,
     quant: bool,
     stacked: bool,
+    fold: int,
     dv: int = 0,
 ):
     """One program-class row of the ragged kernel: a decode row, a
@@ -702,9 +747,13 @@ def _ragged_kernel(
     its row's live page range from the scalar-prefetched lengths (past
     the suffix start, under the fill, inside the sliding window; a
     group's shared run) and loops over it with a dynamic trip count,
-    each page copied from ``pool[layer, table[row, j]]`` into a two-slot
-    VMEM buffer, page j + 1 in flight while page j folds. A row with no
-    live page costs its one grid step, whatever the table's width.
+    ``fold`` pages an iteration: each copied from ``pool[layer,
+    table[row, j]]`` into its part of a two-slot VMEM buffer, side by
+    side, so that an iteration folds ONE tile of ``fold * pg`` keys
+    (128 on the pool's 64-token pages: the score tile fills its lanes
+    and the two products the MXU), the next tile's pages in flight
+    meanwhile. A row with no live page costs its one grid step,
+    whatever the table's width.
 
     ``dv`` (static, default 0 = off): the LATENT pool of an MLA model.
     There is one key a token, [pg, d] with no head axis, shared by all
@@ -725,7 +774,8 @@ def _ragged_kernel(
     [q_all?]), the pools in HBM (K(+scales), V(+scales)), outputs
     (decode partials, [chunk out?], [group partials?]), then scratch:
     row state, [group state], one two-slot page buffer a plane and
-    their DMA semaphores. Row state is re-initialized at every row; the
+    their DMA semaphores (a plane, a slot, a page of the tile). Row
+    state is re-initialized at every row; the
     group accumulator persists across all group programs (they run
     last) and is written once at the very last program.
 
@@ -734,7 +784,8 @@ def _ragged_kernel(
     [Hkv, rows, ·], outputs [·, Hkv, rows, ·]) so a head's slab is a
     tile-aligned view and nothing is reshaped across the (sublane,
     lane) dims in-kernel; masks are built 2-D from iotas; queries
-    arrive already in f32.
+    arrive in the dtype the fold's products take (bf16 on a bf16 pool,
+    else f32: ``_fold``).
     """
     i = 0
     if stacked:
@@ -777,6 +828,7 @@ def _ragged_kernel(
     s = pl.program_id(0)
     R = b + nc
     total = R + gm
+    tk = fold * pg  # keys a tile
 
     def _page_src(plane, page):
         """Plane ``plane``'s slab of pool page ``page``, in HBM. The
@@ -791,40 +843,52 @@ def _ragged_kernel(
             return pools[plane].at[(*at, slots)]
         return pools[plane].at[(*at, slots, slice(None))]
 
-    def _walk(row, j_lo, j_hi, fold_page):
-        """``fold_page(j, slot)`` for pages j_lo <= j < j_hi of table
-        row ``row``, ascending, each waited for in buffer slot ``slot``
-        with the next one's copy already started."""
-        n = j_hi - j_lo
+    def _page_dst(plane, slot, part):
+        """Where page ``part`` of a tile lands in buffer slot ``slot``."""
+        if quant:
+            return bufs[plane].at[slot]
+        return bufs[plane].at[slot, pl.ds(part * pg, pg)]
 
-        def copies(j, slot):
-            page = tbl_ref[row * p_per + j]
-            return [
-                pltpu.make_async_copy(
-                    _page_src(plane, page),
-                    bufs[plane].at[slot],
-                    sem.at[plane, slot],
-                )
-                for plane in range(n_planes)
-            ]
+    def _walk(row, j_lo, j_hi, fold_tile):
+        """``fold_tile(j, slot)`` for every tile of ``fold`` pages j, j +
+        1, .. that covers pages j_lo <= j < j_hi of table row ``row``,
+        ascending, each waited for in buffer slot ``slot`` with the next
+        one's copies already started. Where the last tile runs past
+        j_hi it holds the row's last page again: finite values under
+        slots that lie past every fill and run, which each mask drops."""
+        n = jax.lax.div(j_hi - j_lo + (fold - 1), jnp.int32(fold))
+
+        def copies(t, slot):
+            out = []
+            for part in range(fold):
+                j = jnp.minimum(j_lo + t * fold + part, j_hi - 1)
+                page = tbl_ref[row * p_per + j]
+                out += [
+                    pltpu.make_async_copy(
+                        _page_src(plane, page),
+                        _page_dst(plane, slot, part),
+                        sem.at[plane, slot, part],
+                    )
+                    for plane in range(n_planes)
+                ]
+            return out
 
         @pl.when(n > 0)
         def _start_first():
-            for copy in copies(j_lo, 0):
+            for copy in copies(0, 0):
                 copy.start()
 
         def step(t, carry):
-            j = j_lo + t
             slot = jax.lax.rem(t, 2)
 
             @pl.when(t + 1 < n)
             def _start_next():
-                for copy in copies(j + 1, 1 - slot):
+                for copy in copies(t + 1, 1 - slot):
                     copy.start()
 
-            for copy in copies(j, slot):
+            for copy in copies(t, slot):
                 copy.wait()
-            fold_page(j, slot)
+            fold_tile(j_lo + t * fold, slot)
             return carry
 
         jax.lax.fori_loop(0, n, step, 0)
@@ -837,29 +901,36 @@ def _ragged_kernel(
             jax.lax.div(hi + (pg - 1), page), p_per
         )
 
-    def _kv_head(plane, slot, head):
-        """The page in buffer slot ``slot``: one kv head's K (``plane``
-        0) or V slab [pg, D], plus its [1, pg] dequant row (None for
-        the pool layout)."""
+    def _kv_head(plane, slot, head, dtype):
+        """The tile in buffer slot ``slot``: one kv head's K (``plane``
+        0) or V slab [tk, D], plus its [1, tk] dequant row (None for
+        the pool layout, whose slab comes as ``dtype``)."""
         if quant:
             return (
                 bufs[2 * plane][slot, head],
                 bufs[2 * plane + 1][slot, pl.ds(head, 1), :],
             )
-        return _pool_head(bufs[plane].at[pl.ds(slot, 1)], head), None
+        return _pool_head(bufs[plane].at[pl.ds(slot, 1)], head, dtype), None
 
     def _fold(idx, q, head, mask, slot, mr, lr, ar):
+        # The operands' width is the query's: bf16 (the wrapper sends it
+        # so on a bf16 pool alone) meets the page's own bf16 values in
+        # one pass of the MXU, each product exact in the f32 it
+        # accumulates in; an f32 query widens the page and takes the f32
+        # product at the process's default precision, as it always did.
         if dv:
-            k = bufs[0][slot].astype(jnp.float32)  # [pg, d]: the page, once
+            k = bufs[0][slot].astype(q.dtype)  # [tk, d]: the tile, once
             v = k[:, :dv]
             ks = vs = None
         else:
-            k, ks = _kv_head(0, slot, head)
-            v, vs = _kv_head(1, slot, head)
+            k, ks = _kv_head(0, slot, head, q.dtype)
+            v, vs = _kv_head(1, slot, head, q.dtype)
+        narrow = q.dtype == jnp.bfloat16
         scores = jax.lax.dot_general(
             q,
-            k.astype(jnp.float32),
+            k.astype(q.dtype),  # the int8 layouts' alone is a change
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.DEFAULT if narrow else None,
             preferred_element_type=jnp.float32,
         ) * (scale if ks is None else ks * scale)
         scores = jnp.where(mask, scores, _NEG_INF)
@@ -872,13 +943,14 @@ def _ragged_kernel(
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
     def _causal_mask(n, qbase, lo, j):
-        """[n * g, pg] ragged-causal mask of page j for n queries at
-        absolute positions qbase + i (rows (query, g)-ordered): query i
-        sees slots <= its own position — chunk_decode_attention's rule;
-        n == 1 is the classic slot < valid decode mask."""
-        row = jax.lax.broadcasted_iota(jnp.int32, (n * g, pg), 0)
+        """[n * g, tk] ragged-causal mask of the tile that starts at
+        page j for n queries at absolute positions qbase + i (rows
+        (query, g)-ordered): query i sees slots <= its own position —
+        chunk_decode_attention's rule; n == 1 is the classic slot <
+        valid decode mask."""
+        row = jax.lax.broadcasted_iota(jnp.int32, (n * g, tk), 0)
         qpos = qbase + (row // g if g > 1 else row)
-        slot = j * pg + jax.lax.broadcasted_iota(jnp.int32, (n * g, pg), 1)
+        slot = j * pg + jax.lax.broadcasted_iota(jnp.int32, (n * g, tk), 1)
         mask = (slot <= qpos) & (slot >= lo)
         if window > 0:
             mask &= slot > qpos - window
@@ -899,7 +971,7 @@ def _ragged_kernel(
             # to ops.attention.decode_attention's rule).
             lo_all = jnp.maximum(lo, qbase + 1 - window)
 
-        def fold_page(j, slot):
+        def fold_tile(j, slot):
             # The cache so far plus (a chunk, verify row) the row itself.
             mask = _causal_mask(n, qbase, lo, j)
             for head in range(hkv):  # static unroll over kv heads
@@ -914,7 +986,7 @@ def _ragged_kernel(
                     acc_s,
                 )
 
-        _walk(row, *_pages(lo_all, valid), fold_page)
+        _walk(row, *_pages(lo_all, valid), fold_tile)
 
     # Slices, never [...]: the row scratch is sized for the WIDER of
     # the chunk lane (cq) and the decode/verify lane (nq) — each lane's
@@ -950,9 +1022,9 @@ def _ragged_kernel(
             gi = s - R
             ge = gend_ref[gi]
 
-            def fold_page(j, slot):
+            def fold_tile(j, slot):
                 at = j * pg + jax.lax.broadcasted_iota(
-                    jnp.int32, (b * nq * g, pg), 1
+                    jnp.int32, (b * nq * g, tk), 1
                 )
                 # Every decode query sits past the shared run's end
                 # (shared pages cover prompt prefixes only), so the
@@ -972,7 +1044,7 @@ def _ragged_kernel(
                         m2_s, l2_s, acc2_s,
                     )
 
-            _walk(rep_ref[gi], *_pages(jnp.int32(0), ge), fold_page)
+            _walk(rep_ref[gi], *_pages(jnp.int32(0), ge), fold_tile)
 
         @pl.when(s == total - 1)
         def _write_group():
@@ -1001,6 +1073,7 @@ def _ragged_attention(
     layer=None,
     scale: float | None = None,
     latent_dv: int = 0,
+    out_dtype=None,
     interpret: bool | None = None,
 ):
     """Assemble and launch ONE ragged program; merge group partials.
@@ -1018,7 +1091,7 @@ def _ragged_attention(
     the unstacked layout's. The dense layouts are addressed as
     identity-tabled virtual pages of width ``pg``. Returns out_dec
     shaped like q_dec (and out_chunk [nc, C, H, D] when ``q_chunk``) in
-    q's dtype.
+    ``out_dtype`` (default q's; the kernel's own outputs are float32).
 
     ``latent_dv`` > 0: ``k_kv`` is the latent pool [n_pages, pg, D]
     (stacked: [L, n_pages, pg, D]) of an MLA model and ``v_kv`` is
@@ -1058,6 +1131,8 @@ def _ragged_attention(
         interpret = interpret_default()
     if scale is None:
         scale = d**-0.5
+    if out_dtype is None:
+        out_dtype = q_dec.dtype
 
     kvlen = kv_len.astype(jnp.int32)
     sstart = suffix_start.astype(jnp.int32)
@@ -1082,9 +1157,14 @@ def _ragged_attention(
                 pl.BlockSpec((rows, 1), lambda s, *pf: (0, 0))
             )
     # Per-row q block rows are (nq, g)-ordered — the order the fold's
-    # mask and the write-out both assume. f32 here: the kernel computes
-    # in f32 and a sub-tile bf16 block buys nothing.
-    q4 = q_dec.astype(jnp.float32).reshape(b, nq, hkv, g, d)
+    # mask and the write-out both assume. The queries' dtype is the
+    # fold's operand width: bf16 queries on a bf16 pool ride in as they
+    # are and meet the pages' own values on the MXU; anything else (an
+    # f32 pool or query, whose digits narrowing would lose; the int8
+    # layouts, whose scales ride the products) is folded in f32.
+    narrow = not quant and q_dec.dtype == k_kv.dtype == jnp.bfloat16
+    q_dtype = jnp.bfloat16 if narrow else jnp.float32
+    q4 = q_dec.astype(q_dtype).reshape(b, nq, hkv, g, d)
     inputs.append(q4.transpose(0, 2, 1, 3, 4).reshape(b, hkv, nq * g, d))
     in_specs.append(
         pl.BlockSpec(
@@ -1096,7 +1176,7 @@ def _ragged_attention(
         # One lane's block at a time: the row's own while a chunk row
         # runs, an end lane's (already resident) otherwise.
         inputs.append(
-            q_chunk.astype(jnp.float32)
+            q_chunk.astype(q_dtype)
             .reshape(nc, cq, hkv, g, d)
             .transpose(0, 2, 1, 3, 4)
             .reshape(nc, hkv, cq * g, d)
@@ -1117,15 +1197,19 @@ def _ragged_attention(
             )
         )
     # The pools stay where they are, in HBM: the kernel copies the pages
-    # a row holds, one at a time, into its own two-slot buffers (one a
-    # plane; a page of a plane has the same shape stacked or not).
+    # a row holds, a tile at a time, into its own two-slot buffers (one a
+    # plane; a page of a plane has the same shape stacked or not). A
+    # tile is two pool pages side by side (128 keys of the serving
+    # pool's 64-token pages); the int8 caches' virtual pages are 128
+    # slots wide already and head-major, and stay one a tile.
+    fold = 1 if quant else 2
     if quant:
         planes = [k_kv, k_scale, v_kv, v_scale]
-        page_shapes = [(hkv, pg, d), (hkv, pg)] * 2
+        tile_shapes = [(hkv, pg, d), (hkv, pg)] * 2
     elif latent_dv:
-        planes, page_shapes = [k_kv], [(pg, d)]
+        planes, tile_shapes = [k_kv], [(fold * pg, d)]
     else:
-        planes, page_shapes = [k_kv, v_kv], [(pg, hkv, d)] * 2
+        planes, tile_shapes = [k_kv, v_kv], [(fold * pg, hkv, d)] * 2
     inputs += planes
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(planes)
 
@@ -1187,12 +1271,13 @@ def _ragged_attention(
         ]
     scratch += [
         pltpu.VMEM((2, *shape), plane.dtype)
-        for shape, plane in zip(page_shapes, planes)
+        for shape, plane in zip(tile_shapes, planes)
     ]
-    scratch.append(pltpu.SemaphoreType.DMA((len(planes), 2)))
+    scratch.append(pltpu.SemaphoreType.DMA((len(planes), 2, fold)))
     # A latent chunk lane stacks 16 heads on every query: 64 queries are
-    # 1,024 rows of 576 f32 lanes in, 512 out and 512 of accumulator,
-    # double-buffered — past Mosaic's default 16 MiB of scoped VMEM.
+    # 1,024 rows of 640 lanes in (bf16 on a bf16 pool), 512 f32 lanes
+    # out and 512 of accumulator, double-buffered — past Mosaic's
+    # default 16 MiB of scoped VMEM.
     params = (
         dict(compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_LATENT_VMEM_BYTES))
@@ -1218,6 +1303,7 @@ def _ragged_attention(
             window=window,
             quant=quant,
             stacked=stacked,
+            fold=fold,
             dv=latent_dv,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1250,7 +1336,7 @@ def _ragged_attention(
     out_dec = (
         out5.transpose(0, 2, 1, 3, 4)
         .reshape(b, nq, h, dv)
-        .astype(q_dec.dtype)
+        .astype(out_dtype)
     )
     if squeeze_nq:
         out_dec = out_dec[:, 0]
@@ -1261,7 +1347,7 @@ def _ragged_attention(
         oc.reshape(nc, hkv, cq, g, dv)
         .transpose(0, 2, 1, 3, 4)
         .reshape(nc, cq, h, dv)
-        .astype(q_dec.dtype)
+        .astype(out_dtype)
     )
     return out_dec, out_chunk
 
@@ -1281,6 +1367,7 @@ def ragged_paged_attention(
     layer=None,
     scale: float | None = None,
     latent_dv: int = 0,
+    out_dtype=None,
     interpret: bool | None = None,
 ):
     """Mixed prefill+decode attention over the page pool — ONE program.
@@ -1319,7 +1406,8 @@ def ragged_paged_attention(
     D] (stacked [L, n_pages, page, D]); ``v_pool`` is ignored, each page
     is read once as key (D lanes) and value (its first ``latent_dv``),
     and outputs are [.., H, latent_dv]. ``scale`` overrides
-    ``D ** -0.5``.
+    ``D ** -0.5``. ``out_dtype`` (default q's): the outputs' dtype —
+    float32 returns the kernel's own, unrounded.
     """
     b = q.shape[0]
     pg = k_pool.shape[-2 if latent_dv else -3]
@@ -1359,6 +1447,7 @@ def ragged_paged_attention(
         layer=layer,
         scale=scale,
         latent_dv=latent_dv,
+        out_dtype=out_dtype,
         interpret=interpret,
     )
     return (out[0], out[1][0]) if one_lane else out

@@ -20,6 +20,14 @@ import numpy as np
 #: unit-normal V rows, |out| <~ 4: one rounding of either side is up to
 #: 2^-8 * 4 = 1.6e-2; the online softmax's reassociation is far below it.
 ATTENTION_TOL = 2e-2
+#: The ragged kernel's FLOAT32 outputs on a bf16 pool against the
+#: float64 oracle (:func:`rel_err`): q.k is exact in f32 and the softmax
+#: weights meet the values as two bf16 terms, 16 of their 24 bits; an
+#: output is a convex combination of values, so 2^-16 of the outputs'
+#: scale. Measured 1e-6 - 4e-6 on a v5e; weights rounded to ONE bf16
+#: term (what an f32 product at Mosaic's default precision does to
+#: them) read 1e-3.
+RAGGED_ORACLE_TOL = 2.0**-16
 #: RMSNorm on f32 input is f32 end to end in kernel and reference.
 NORM_TOL = 2e-5
 #: On bf16 input both compute in f32 and round once: |out| <~ 5 with
@@ -53,7 +61,75 @@ def check(name: str, err: float, tol: float) -> float:
     return err
 
 
-def ragged_attention_error(
+def attention_oracle(q, keys, values, qpos, *, scale, window=0):
+    """Softmax attention in float64 numpy, the yardstick the kernels'
+    float32 outputs are held to: ``q`` [n, H, D] at absolute positions
+    ``qpos`` [n] over ``keys`` [S, Hkv, D] / ``values`` [S, Hkv, Dv]
+    (slot s at position s), causal, ``window`` > 0 a sliding window.
+    Returns [n, H, Dv]."""
+    q = np.asarray(q, np.float64)
+    keys = np.asarray(keys, np.float64)
+    values = np.asarray(values, np.float64)
+    n, h, d = q.shape
+    hkv = keys.shape[1]
+    qpos = np.asarray(qpos)[:, None]
+    slot = np.arange(keys.shape[0])[None, :]
+    mask = slot <= qpos
+    if window > 0:
+        mask &= slot > qpos - window
+    scores = np.einsum(
+        "nkgd,skd->kgns", q.reshape(n, hkv, h // hkv, d), keys
+    ) * scale
+    scores = np.where(mask, scores, -np.inf)
+    p = np.exp(scores - scores.max(axis=-1, keepdims=True))
+    out = np.einsum("kgns,skd->nkgd", p, values)
+    out /= p.sum(axis=-1).transpose(2, 0, 1)[..., None]
+    return out.reshape(n, h, -1)
+
+
+def ragged_oracle(q, k_pool, v_pool, table, valid_len, *, scale=None,
+                  window=0, latent_dv=0):
+    """:func:`attention_oracle` for rows of a page table: ``q`` [R, n,
+    H, D], row r's n queries ending at its fill ``valid_len[r]`` over
+    the pages ``table[r]`` of one layer's pools ([pages, pg, Hkv, D];
+    latent: [pages, pg, D], the value its first ``latent_dv`` lanes). A
+    row that holds nothing returns zeros. Returns [R, n, H, Dv] float64."""
+    q = np.asarray(q, np.float64)
+    rows, n, h, d = q.shape
+    scale = d**-0.5 if scale is None else scale
+    out = np.zeros((rows, n, h, latent_dv or d))
+    for r in range(rows):
+        fill = int(valid_len[r])
+        if fill <= 0:
+            continue
+        pages = np.asarray(table[r])
+        keys = np.asarray(k_pool[pages], np.float64)
+        keys = keys.reshape(-1, *keys.shape[2:])[:fill]
+        if latent_dv:
+            keys = keys[:, None]
+            vals = keys[..., :latent_dv]
+        else:
+            vals = np.asarray(v_pool[pages], np.float64)
+            vals = vals.reshape(-1, *vals.shape[2:])[:fill]
+        out[r] = attention_oracle(
+            q[r], keys, vals, fill - n + np.arange(n), scale=scale,
+            window=window,
+        )
+    return out
+
+
+def rel_err(got, want) -> float:
+    """max |got - want| over max |want|: the error in units of the
+    outputs' own scale (an attention output is a convex combination of
+    values, so single elements come arbitrarily near zero)."""
+    got = _finite(got).astype(np.float64)
+    want = np.asarray(want, np.float64)
+    if got.shape != want.shape:
+        raise AssertionError(f"shape {got.shape} != oracle {want.shape}")
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+def _ragged_inputs(
     *,
     seed: int,
     pg: int,
@@ -70,52 +146,28 @@ def ragged_attention_error(
     shared_pages: int = 1,
     window: int = 0,
     null_tables: bool = False,
-    layer: tuple[int, int] | None = None,
     latent_dv: int = 0,
-    interpret: bool | None = None,
-) -> dict[str, float]:
-    """Ragged paged attention kernel vs
-    :func:`~llm_consensus_tpu.ops.attention.ragged_paged_attention_reference`.
-
-    ``valid_len``: tokens readable per decode row (mid-page fills are
-    the interesting ones; a row of 0 holds nothing, and owes only a
-    finite output). ``nq`` > 1: verify rows. ``cq`` > 0: one
-    prefill-chunk row of cq queries from ``chunk_start`` — or, where
-    ``chunk_start`` is a list, one chunk lane a start, each over a table
-    of its own; a start of ``-cq`` is a dead lane (an all-NULL table),
-    which owes only a finite output. ``group_rows``:
-    these rows share their first ``shared_pages`` pages and ride the
-    kernel's group phase (the reference has no groups — grouped output
-    must equal ungrouped math). ``null_tables``: all-NULL decode tables
-    (an idle batcher's rows next to a live chunk); their output only has
-    to be finite. ``layer`` = (l, L): the pools are layer l of stacked
-    [L, ...] pools and the kernel indexes the stack (a traced
-    ``layer=``), as the step programs' layer scan calls it; its output
-    must also equal, bit for bit, the call on the slice ``pool[l]``.
-    ``latent_dv`` > 0: the latent pool of an MLA model — one key of
-    ``d`` lanes a token for all ``g`` heads (``hkv`` must be 1), no
-    value plane, values the key's first ``latent_dv`` lanes.
-    Returns {"decode": err[, "chunk": err]}.
-    """
-    from llm_consensus_tpu.ops.attention import (
-        ragged_paged_attention_reference,
-    )
-    from llm_consensus_tpu.ops.pallas.attention import ragged_paged_attention
-
+    dtype=jnp.bfloat16,
+):
+    """The seeded inputs of one ragged call (the parameters are
+    :func:`ragged_attention_error`'s): bfloat16 VALUES, held as
+    ``dtype``. Returns (rng, q, k_pool, v_pool, table, valid_len, groups,
+    the call's other keywords, the live lanes' mask or None)."""
     rng = np.random.default_rng(seed)
     b, h = len(valid_len), hkv * g
+
+    def normal(*shape):
+        return jnp.asarray(
+            rng.standard_normal(shape), jnp.bfloat16
+        ).astype(dtype)
+
     if latent_dv:
-        kp = jnp.asarray(rng.standard_normal((n_pages, pg, d)), jnp.bfloat16)
-        vp = jnp.zeros((n_pages, pg, 0), jnp.bfloat16)
+        kp = normal(n_pages, pg, d)
+        vp = jnp.zeros((n_pages, pg, 0), dtype)
     else:
-        kp = jnp.asarray(
-            rng.standard_normal((n_pages, pg, hkv, d)), jnp.bfloat16
-        )
-        vp = jnp.asarray(
-            rng.standard_normal((n_pages, pg, hkv, d)), jnp.bfloat16
-        )
-    q_shape = (b, h, d) if nq == 1 else (b, nq, h, d)
-    q = jnp.asarray(rng.standard_normal(q_shape), jnp.bfloat16)
+        kp = normal(n_pages, pg, hkv, d)
+        vp = normal(n_pages, pg, hkv, d)
+    q = normal(*((b, h, d) if nq == 1 else (b, nq, h, d)))
     perm = rng.permutation(np.arange(1, n_pages))
     tbl = np.asarray(perm[: b * p_per].reshape(b, p_per), np.int32)
     if null_tables:
@@ -147,25 +199,61 @@ def ragged_attention_error(
         )
         ctbl[~lane_live] = 0
         kw.update(
-            q_chunk=jnp.asarray(
-                rng.standard_normal((lanes, cq, h, d)), jnp.bfloat16
-            ),
+            q_chunk=normal(lanes, cq, h, d),
             chunk_table=jnp.asarray(ctbl),
             chunk_start=jnp.asarray(chunk_start, jnp.int32),
         )
     elif cq:
         kw.update(
-            q_chunk=jnp.asarray(
-                rng.standard_normal((cq, h, d)), jnp.bfloat16
-            ),
+            q_chunk=normal(cq, h, d),
             chunk_table=jnp.asarray(
                 perm[b * p_per : (b + 1) * p_per], jnp.int32
             ),
             chunk_start=jnp.int32(chunk_start),
         )
+    return rng, q, kp, vp, jnp.asarray(tbl), vl, groups, kw, lane_live
+
+
+def ragged_attention_error(
+    *,
+    layer: tuple[int, int] | None = None,
+    interpret: bool | None = None,
+    **case,
+) -> dict[str, float]:
+    """Ragged paged attention kernel vs
+    :func:`~llm_consensus_tpu.ops.attention.ragged_paged_attention_reference`.
+
+    ``valid_len``: tokens readable per decode row (mid-page fills are
+    the interesting ones; a row of 0 holds nothing, and owes only a
+    finite output). ``nq`` > 1: verify rows. ``cq`` > 0: one
+    prefill-chunk row of cq queries from ``chunk_start`` — or, where
+    ``chunk_start`` is a list, one chunk lane a start, each over a table
+    of its own; a start of ``-cq`` is a dead lane (an all-NULL table),
+    which owes only a finite output. ``group_rows``:
+    these rows share their first ``shared_pages`` pages and ride the
+    kernel's group phase (the reference has no groups — grouped output
+    must equal ungrouped math). ``null_tables``: all-NULL decode tables
+    (an idle batcher's rows next to a live chunk); their output only has
+    to be finite. ``layer`` = (l, L): the pools are layer l of stacked
+    [L, ...] pools and the kernel indexes the stack (a traced
+    ``layer=``), as the step programs' layer scan calls it; its output
+    must also equal, bit for bit, the call on the slice ``pool[l]``.
+    ``latent_dv`` > 0: the latent pool of an MLA model — one key of
+    ``d`` lanes a token for all ``g`` heads (``hkv`` must be 1), no
+    value plane, values the key's first ``latent_dv`` lanes.
+    Returns {"decode": err[, "chunk": err]}.
+    """
+    from llm_consensus_tpu.ops.attention import (
+        ragged_paged_attention_reference,
+    )
+    from llm_consensus_tpu.ops.pallas.attention import ragged_paged_attention
+
+    rng, q, kp, vp, tbl, vl, groups, kw, lane_live = _ragged_inputs(**case)
+    valid_len, cq = case["valid_len"], case.get("cq", 0)
+
     def kernel(k_pool, v_pool, layer_idx=None):
         return ragged_paged_attention(
-            q, k_pool, v_pool, jnp.asarray(tbl), vl, groups=groups,
+            q, k_pool, v_pool, tbl, vl, groups=groups,
             layer=layer_idx, interpret=interpret, **kw,
         )
 
@@ -188,9 +276,7 @@ def ragged_attention_error(
                     f"bytes than its slice: max diff {max_err(a, b_)}"
                 )
     with jax.default_matmul_precision("highest"):
-        ref = ragged_paged_attention_reference(
-            q, kp, vp, jnp.asarray(tbl), vl, **kw
-        )
+        ref = ragged_paged_attention_reference(q, kp, vp, tbl, vl, **kw)
     live = np.asarray(valid_len) > 0
 
     def decode_err(got_dec, ref_dec):
@@ -204,13 +290,78 @@ def ragged_attention_error(
         _finite(got_chunk)  # all a dead lane owes
         got_chunk = np.asarray(got_chunk)[lane_live]
         ref_chunk = np.asarray(ref_chunk)[lane_live]
-    if null_tables:
+    if case.get("null_tables"):
         _finite(got[0])
         return {"chunk": max_err(got_chunk, ref_chunk)}
     return {
         "decode": decode_err(got[0], ref[0]),
         "chunk": max_err(got_chunk, ref_chunk),
     }
+
+
+def ragged_attention_oracle_error(
+    *,
+    dtype=jnp.bfloat16,
+    layer: tuple[int, int] | None = None,
+    interpret: bool | None = None,
+    **case,
+) -> dict[str, float]:
+    """The ragged kernel's FLOAT32 outputs (before the cast to the
+    queries' dtype) vs :func:`ragged_oracle`, as :func:`rel_err`.
+
+    The case is :func:`ragged_attention_error`'s and the inputs are the
+    same bfloat16 values whatever ``dtype`` holds them (pool and queries
+    alike): bfloat16 takes the kernel's bf16 operands, float32 the f32
+    products — one yardstick under both. ``layer`` = (l, L): read as
+    layer l of stacked pools (the other layers zeros). Rows and lanes
+    that hold nothing are left out. Returns {"decode": err[, "chunk":
+    err]}."""
+    from llm_consensus_tpu.ops.pallas.attention import ragged_paged_attention
+
+    _, q, kp, vp, tbl, vl, groups, kw, lane_live = _ragged_inputs(
+        **case, dtype=dtype
+    )
+    k_pool, v_pool, layer_idx = kp, vp, None
+    if layer is not None:
+        at, n_layers = layer
+        layer_idx = jnp.int32(at)
+        k_pool = jnp.zeros((n_layers, *kp.shape), kp.dtype).at[at].set(kp)
+        v_pool = jnp.zeros((n_layers, *vp.shape), vp.dtype).at[at].set(vp)
+    got = jax.jit(
+        lambda *pools: ragged_paged_attention(
+            q, *pools, tbl, vl, groups=groups, layer=layer_idx,
+            out_dtype=jnp.float32, interpret=interpret, **kw,
+        )
+    )(k_pool, v_pool)
+    oracle = dict(
+        scale=kw.get("scale"), window=kw["window"],
+        latent_dv=kw.get("latent_dv", 0),
+    )
+    kp64, vp64 = np.asarray(kp, np.float64), np.asarray(vp, np.float64)
+    errs = {}
+    live = np.asarray(case["valid_len"]) > 0
+    if live.any() and not case.get("null_tables"):
+        got_dec = got[0] if "q_chunk" in kw else got
+        qd = np.asarray(q, np.float64)
+        if qd.ndim == 3:
+            qd, got_dec = qd[:, None], got_dec[:, None]
+        want = ragged_oracle(qd, kp64, vp64, tbl, vl, **oracle)
+        errs["decode"] = rel_err(np.asarray(got_dec)[live], want[live])
+    if "q_chunk" in kw:
+        qc, ct, cs = (
+            np.asarray(kw["q_chunk"], np.float64),
+            np.asarray(kw["chunk_table"]), np.asarray(kw["chunk_start"]),
+        )
+        got_chunk = np.asarray(got[1])
+        if qc.ndim == 3:
+            qc, ct, cs, got_chunk = qc[None], ct[None], cs[None], got_chunk[None]
+        if lane_live is None:
+            lane_live = np.ones((len(qc),), bool)
+        want = ragged_oracle(
+            qc, kp64, vp64, ct, cs + qc.shape[1], **oracle
+        )
+        errs["chunk"] = rel_err(got_chunk[lane_live], want[lane_live])
+    return errs
 
 
 def moe_grouped_matmul_error(

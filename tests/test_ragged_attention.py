@@ -255,15 +255,137 @@ def test_ragged_walk_matches_reference(case):
     )
 
 
+def _eqns_named(jaxpr, name):
+    """Every equation of primitive ``name`` in a jaxpr, nested ones too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _eqns_named(sub, name)
+    return found
+
+
+# The fold's operands (PR 33): on a bfloat16 pool with bfloat16 queries
+# the kernel's two products take bf16 operands — the pages' own values,
+# the softmax weights as ``_P_TERMS`` bf16 terms — where a float32 pool
+# or query keeps the f32 products. Both are held to ONE float64 oracle
+# on the same bf16 values, by the kernel's float32 outputs. Here the
+# kept path's f32 products are exact (the test process pins "highest");
+# on the chip they round both operands to bf16 and read 1e-3 (PERF.md
+# §6, PR 33) — so the new path is held to what its two terms carry
+# (``parity.RAGGED_ORACLE_TOL``) beside the kept path's own error. One
+# term reads ~1e-3 and fails by two orders.
+_OPERANDS = {
+    "decode rows": dict(g=3, valid_len=[13, 1, 40, 23]),
+    "one chunk lane": dict(
+        g=3, valid_len=[13, 1, 40, 23], cq=16, chunk_start=11,
+    ),
+    "three chunk lanes, one dead": dict(
+        g=3, valid_len=[13, 1, 40, 23], cq=16, chunk_start=[11, -16, 29],
+    ),
+    "grouped rows beside three lanes": dict(
+        g=3, valid_len=[37, 9, 45, 48], group_rows=(0, 2, 3),
+        shared_pages=2, cq=16, chunk_start=[11, 0, 29],
+    ),
+    "a window's edge": dict(
+        p_per=12, g=3, valid_len=[90, 61, 7], window=20, cq=16,
+        chunk_start=50,
+    ),
+    "verify rows, nq 4": dict(
+        g=3, valid_len=[29, 4, 40], nq=4, group_rows=(0, 2),
+        shared_pages=3,
+    ),
+    "latent pool": dict(
+        hkv=1, g=4, d=128, latent_dv=64, valid_len=[5, 13, 45],
+        group_rows=(1, 2), shared_pages=1, cq=16, chunk_start=[16, 3],
+    ),
+    "stacked": dict(
+        g=3, valid_len=[13, 1, 40, 23], cq=16, chunk_start=[11, 20],
+        layer=(1, 2),
+    ),
+    "group of 7, an odd page count": dict(
+        hkv=2, g=7, valid_len=[24, 33], cq=16, chunk_start=24,
+    ),
+}
+
+
+def _operand_errors(case):
+    kw = {**_TOY, "n_pages": 80, "seed": 7, **_OPERANDS[case]}
+    return (
+        parity.ragged_attention_oracle_error(**kw, dtype=jnp.bfloat16),
+        parity.ragged_attention_oracle_error(**kw, dtype=jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("case", list(_OPERANDS))
+def test_ragged_bf16_operands_are_as_near_the_oracle_as_f32s(case):
+    new, kept = _operand_errors(case)
+    assert set(new) == set(kept) and new
+    for lane, err in new.items():
+        assert kept[lane] < 1e-6, (lane, kept[lane])
+        assert err <= 2 * kept[lane] + parity.RAGGED_ORACLE_TOL, (lane, err, kept[lane])
+
+
+def test_ragged_oracle_bound_tells_a_p_of_one_bf16_term(monkeypatch):
+    """The bound above is sharp enough to refuse softmax weights
+    rounded to bfloat16: a precision decision, not a speed one."""
+    from llm_consensus_tpu.ops.pallas import attention
+
+    assert attention._P_TERMS > 1
+    monkeypatch.setattr(attention, "_P_TERMS", 1)
+    new, kept = _operand_errors("one chunk lane")
+    for lane, err in new.items():
+        assert err > 10 * (2 * kept[lane] + parity.RAGGED_ORACLE_TOL), (lane, err)
+
+
+@pytest.mark.parametrize(
+    "latent, dtype, operands",
+    [
+        (False, jnp.bfloat16, {"bfloat16"}),
+        (True, jnp.bfloat16, {"bfloat16"}),
+        (False, jnp.float32, {"float32"}),
+    ],
+    ids=["pool", "latent pool", "float32 pool"],
+)
+def test_ragged_products_take_the_pools_own_values(latent, dtype, operands):
+    """On a bfloat16 pool no product sees a float32 copy of a query, a
+    key or a value (the softmax weights arrive as bf16 terms), each
+    states the float32 it accumulates in and its precision; a float32
+    pool keeps float32 operands. Decode rows, lanes and groups alike."""
+    from llm_consensus_tpu.ops.pallas.attention import ragged_paged_attention
+
+    b, gm, hkv, g, d, pg, p_per = 3, 2, 1 if latent else 2, 3, 32, 8, 6
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    page = (pg, d) if latent else (pg, hkv, d)
+    pool = jnp.zeros((2, 64, *page), dtype)
+    kw = dict(latent_dv=16) if latent else {}
+    jaxpr = jax.make_jaxpr(
+        lambda layer: ragged_paged_attention(
+            jnp.zeros((b, hkv * g, d), dtype), pool, pool, i32(b, p_per),
+            i32(b), q_chunk=jnp.zeros((2, 16, hkv * g, d), dtype),
+            chunk_table=i32(2, p_per), chunk_start=i32(2),
+            groups=(i32(b), i32(gm), i32(gm), i32(b)), layer=layer,
+            interpret=True, **kw,
+        )
+    )(jnp.int32(1))
+    (call,) = _eqns_named(jaxpr.jaxpr, "pallas_call")
+    dots = _eqns_named(call.params["jaxpr"], "dot_general")
+    # q.k and p.v (a bf16 term each), for a row and for a group.
+    assert len(dots) >= 4
+    for dot in dots:
+        assert {str(v.aval.dtype) for v in dot.invars} == operands
+        assert dot.params["preferred_element_type"] == jnp.float32
+        if dtype == jnp.bfloat16:
+            assert dot.params["precision"] is not None
+
+
 def _pallas_grids(jaxpr):
     """The grid of every ``pallas_call`` in a jaxpr, nested ones too."""
-    grids = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            grids.append(tuple(eqn.params["grid_mapping"].grid))
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            grids += _pallas_grids(sub)
-    return grids
+    return [
+        tuple(eqn.params["grid_mapping"].grid)
+        for eqn in _eqns_named(jaxpr, "pallas_call")
+    ]
 
 
 @pytest.mark.parametrize("p_per", [8, 48])

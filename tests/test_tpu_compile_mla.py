@@ -56,13 +56,17 @@ _RAGGED_WIDTHS = {
 }
 
 
+@pytest.mark.parametrize("lanes", [1, 3])
 @pytest.mark.parametrize("model", list(_RAGGED_WIDTHS))
 def test_ragged_attention_walk_compiles_without_copying_the_pool(
-    one_chip, model
+    one_chip, model, lanes
 ):
     """The kernel that walks each row's live pages by its own DMAs, with
-    every lane it serves (decode rows, the chunk lane, group programs)
-    over the stacked pool in HBM."""
+    every lane it serves (decode rows, the one or three chunk lanes of a
+    step program, group programs) over the stacked pool in HBM — bf16
+    queries on bf16 pages, so the fold's operands are the pages' own
+    (PR 33): a lane's [64 x G, D] bf16 query block, sub-tile [G, D]
+    blocks for the decode rows, two pages a tile in four page slots."""
     from llm_consensus_tpu.ops.pallas.attention import ragged_paged_attention
 
     layers, pages, hkv, g, d, dv, b, gm = _RAGGED_WIDTHS[model]
@@ -82,8 +86,8 @@ def test_ragged_attention_walk_compiles_without_copying_the_pool(
     compiled = jax.jit(call).lower(
         _shape(one_chip, (b, h, d), jnp.bfloat16),
         pool, None if dv else pool,
-        i32(b, P), i32(b), _shape(one_chip, (C, h, d), jnp.bfloat16),
-        i32(P), i32(), i32(b), i32(gm), i32(gm), i32(b), i32(),
+        i32(b, P), i32(b), _shape(one_chip, (lanes, C, h, d), jnp.bfloat16),
+        i32(lanes, P), i32(lanes), i32(b), i32(gm), i32(gm), i32(b), i32(),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # The pools are 1.6-4.3 GB: any temporary near that is a copy of one
@@ -94,7 +98,9 @@ def test_ragged_attention_walk_compiles_without_copying_the_pool(
 def test_ragged_attention_walk_compiles_under_shard_map(topo):
     """The mesh kernel (``--mesh data=2,model=2``, no cell): mistral's
     pool over four described chips, kv heads over ``model``, rows and
-    pages over ``data``, the chunk lane and the groups riding along."""
+    pages over ``data``, the chunk lane (the mesh lowering carries one)
+    and the groups riding along; a shard folds 4 kv heads of bf16
+    operands, two pages a tile."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as Ps
