@@ -2587,8 +2587,8 @@ def prefill_chunk_paged(
 
     Returns ([L, C, D] hidden states, cache). ``cache.page_table`` and
     ``cache.length`` are untouched. The serving layer gathers the
-    last-valid position's hidden state from the FINAL chunk and
-    unembeds that single row (see :func:`unembed_one`) — never a
+    last-valid position's hidden state of each lane from the FINAL
+    chunk and unembeds those rows (see :func:`unembed_rows`) — never a
     [C, V] logits buffer per chunk.
     """
     table, start, pos, pages, offs, live, attn_start = _chunk_lanes(

@@ -52,6 +52,7 @@ __all__ = [
     "DECODE_STEP_SECONDS",
     "SCHED_OVERHEAD_SECONDS",
     "PIPELINE_FLUSHES",
+    "PIPELINE_DRAINS",
     "DISPATCH_INFLIGHT",
     "DEVICE_PROGRAMS",
     "BATCHER_PHASE_SECONDS",
@@ -591,6 +592,18 @@ DISPATCH_INFLIGHT = REGISTRY.gauge(
 PIPELINE_FLUSHES = REGISTRY.counter(
     "gateway_pipeline_flushes_total",
     "Decode-pipeline drains before stable-cache operations",
+)
+#: Programs enqueued to a device the loop knew to be empty while rows
+#: were decoding (PR 36), by what emptied the window: ``first_token``
+#: (the fetch that ended a prompt left nothing in flight: 0 at
+#: ``pipeline_depth`` >= 2, and where a wait put back on that path
+#: shows), ``standalone_chunk``, ``flush``
+#: (``gateway_pipeline_flushes_total``'s drains) and ``other`` (depth 1,
+#: a depth reduction). Over ``gateway_device_programs_total`` it is the
+#: share of programs the device had to wait for.
+PIPELINE_DRAINS = REGISTRY.counter(
+    "gateway_pipeline_drains_total",
+    "Programs enqueued to an empty device while rows decoded, by cause",
 )
 #: Fused scheduler step (PR 8): device programs the scheduler loop
 #: dispatched, labeled ``kind="fused"`` (one program carrying the

@@ -92,8 +92,9 @@ import threading
 import time
 from collections import OrderedDict, deque
 from concurrent.futures import Future
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -139,7 +140,6 @@ from llm_consensus_tpu.models.transformer import (
     model_param_bytes,
     prefill_chunk_paged,
     program_hbm_cost,
-    unembed_one,
     unembed_rows,
     verify_step_paged,
 )
@@ -302,6 +302,9 @@ from llm_consensus_tpu.server.metrics import (
 from llm_consensus_tpu.server.metrics import (
     KV_PREFETCH as _M_PREFETCH,
 )
+from llm_consensus_tpu.server.metrics import (
+    PIPELINE_DRAINS as _M_PIPELINE_DRAINS,
+)
 from llm_consensus_tpu.utils import tracing as _tracing
 
 log = logging.getLogger(__name__)
@@ -327,6 +330,16 @@ _SCREEN_CACHE_MAX = 512
 # profiler's host plane.
 _PHASES = ("admit", "restore", "dispatch", "device_wait", "retire", "idle")
 
+# What emptied the dispatch window before a program was enqueued to an
+# idle device while rows were decoding: the labels of
+# gateway_pipeline_drains_total. ``first_token`` — the fetch that ended
+# a prompt left nothing in flight — reads 0 at ``pipeline_depth`` >= 2
+# since PR 36, and is where a wait put back on that path would show.
+_DRAINS = ("first_token", "standalone_chunk", "flush", "other")
+
+# A row's entry in the patch of the device page tables (``_RowPatch``).
+_ROW_KEEP, _ROW_INSTALL, _ROW_RELEASE = 0, 1, 2
+
 
 def _step_program(name: str, fn):
     """``fn`` under the fixed function name ``name``: ``jax.jit`` names
@@ -343,6 +356,81 @@ def _step_program(name: str, fn):
 
     program.__name__ = program.__qualname__ = name
     return program
+
+
+def apply_rows(caches, ops, tables, lengths, states):
+    """The row changes of one fetch, for the target pool and (where
+    there is one) the draft's, as ONE device program: ``ops`` [pools,
+    slots] says for each pool's row whether it keeps what it has, is
+    installed (``tables`` [slots, pages_per_seq], ``lengths`` and
+    ``states`` [slots]: :func:`install_seq` for that row) or released
+    (:func:`release_seq`). Both run over every row and a select keeps
+    each row's own. ``caches`` come without their pools
+    (:func:`_rows_of`): a program that took them would have to be
+    given them for good, and a reader on another thread (a test, an
+    export) would find its pool deleted."""
+
+    def patched(cache, op):
+        rows = jnp.arange(cache.max_seqs)
+        installed = install_seq(cache, rows, tables, lengths, states)
+        released = release_seq(cache, rows)
+
+        def pick(kept, inst, rel):
+            o = op.reshape(op.shape + (1,) * (kept.ndim - 1))
+            return jnp.where(
+                o == _ROW_INSTALL, inst, jnp.where(o == _ROW_RELEASE, rel, kept)
+            )
+
+        state = cache.state
+        if state is not None:
+            state = replace(
+                state,
+                slot=pick(state.slot, installed.state.slot, released.state.slot),
+            )
+        return replace(
+            cache,
+            page_table=pick(
+                cache.page_table, installed.page_table, released.page_table
+            ),
+            length=pick(cache.length, installed.length, released.length),
+            state=state,
+        )
+
+    return tuple(patched(cache, ops[n]) for n, cache in enumerate(caches))
+
+
+def _rows_of(cache: PagedKVCache) -> PagedKVCache:
+    """``cache``'s per-row leaves alone — page tables, lengths, state
+    slots — as a cache whose pools are absent (None is no leaf)."""
+    state = cache.state
+    if state is not None:
+        state = replace(state, s=None, conv=None)
+    return replace(cache, k=None, v=None, state=state)
+
+
+def _with_rows(cache: PagedKVCache, rows: PagedKVCache) -> PagedKVCache:
+    """``cache``'s pools under the per-row leaves of ``rows``."""
+    state = cache.state
+    if state is not None:
+        state = replace(state, slot=rows.state.slot)
+    return replace(
+        cache, page_table=rows.page_table, length=rows.length, state=state
+    )
+
+
+class _RowPatch:
+    """The changes one retire phase makes to the device's page-table
+    rows, gathered on the host and applied by ONE :func:`apply_rows`
+    program at its end. The later entry of a row wins: a row installed
+    and released in one fetch (a first token that ends its request) is
+    released. Fresh arrays a patch — the program reads them after the
+    call returns (the snapshot rule of ``_dispatch.rows``)."""
+
+    def __init__(self, pools: int, slots: int, pages_per_seq: int):
+        self.ops = np.full((pools, slots), _ROW_KEEP, np.int32)
+        self.tables = np.full((slots, pages_per_seq), NULL_PAGE, np.int32)
+        self.lengths = np.zeros((slots,), np.int32)
+        self.states = np.zeros((slots,), np.int32)
 
 
 def _abstract(x):
@@ -647,21 +735,52 @@ class _Slot:
     pages_matched_n: int = 0
 
 
+class _LaneArgs(NamedTuple):
+    """What one chunk program is given for its L lanes
+    (``ContinuousBatcher._lane_args``). All numpy, made for the one
+    call and never written again: the programs take them as they are."""
+
+    lanes: int  # the program's width: 1, or ``_lanes_for``
+    ids: np.ndarray  # [L, C] chunk token ids
+    tables: np.ndarray  # [L, P]; a dead lane's is all NULL pages
+    starts: np.ndarray  # [L] chunk start positions
+    lasts: np.ndarray  # [L] last prompt positions
+    done: np.ndarray  # [L] bool: the lane ends its prompt here
+    # (seeds, temperatures, top_k, top_p), [L] each: the first token's
+    # draw for a lane that ends its prompt.
+    sampler: tuple
+    filters: bool  # a lane that ends needs the sampler's filters
+    ext: list  # cost extents (end, width) of the live lanes
+    state_kw: dict  # ``chunk_state`` [L, 4] for a recurrent model
+
+    @property
+    def program_args(self) -> tuple:
+        """The lanes' arguments behind a step program's own."""
+        return (
+            self.ids, self.tables, self.starts, self.lasts, self.done,
+            self.sampler,
+        )
+
+
 @dataclass
 class _InflightChunk:
     """A prefill chunk riding an in-flight FUSED program (PR 8).
 
-    The chunk's device work (K/V writes, ragged attention, final-chunk
-    first-token logits) is already ordered on the stream; what waits
-    for the fetch is the HOST bookkeeping — chunk accounting, the
-    final chunk's activation + ``install_seq``. ``slot`` is the
-    identity guard, exactly like ``_Inflight.rows``.
+    The chunk's device work (K/V writes, ragged attention, the final
+    chunk's first token sampled from its last position's logits) is
+    already ordered on the stream; what waits for the fetch is the HOST
+    bookkeeping — chunk accounting, the final chunk's activation and
+    its row's entry in the patch. ``slot`` is the identity guard,
+    exactly like ``_Inflight.rows``.
     """
 
     idx: int  # slot index
     slot: _Slot
     done: bool  # this program wrote the chunk covering the prompt end
-    logits: object  # device [V] last-real-position logits (done only)
+    lane: int  # its lane: the row of the program's ``chunk_first``
+    # device [V] last-real-position logits (done only), fetched for a
+    # ``"logits": n`` request alone
+    logits: object
     pos: int  # chunk start position (trace span meta)
     width: int  # chunk width
 
@@ -682,8 +801,11 @@ class _Inflight:
     t0: float  # host dispatch stamp (perf_counter)
     k: int  # decode steps folded into this program
     rows: list  # [(slot_idx, _Slot)] decoding at dispatch
-    # Fused prefill chunks (PR 8), one a live lane (PR 31).
+    # Fused prefill chunks (PR 8), one a live lane (PR 31), and the
+    # program's device [L] first tokens of the lanes that ended their
+    # prompts: fetched with ``tokens`` when one did.
     chunks: list = field(default_factory=list)
+    chunk_first: object = None
     # -- speculative round (PR 9) --------------------------------------
     # ``tokens`` is then the [slots, spec_k + 1] emit buffer; only
     # ``emit_cnt`` leading tokens per row are real. ``counts_out`` is
@@ -1150,6 +1272,11 @@ class ContinuousBatcher:
         self._inflight: deque[_Inflight] = deque()
         self._tok_dirty = np.zeros((c.max_slots,), bool)
         self._pipeline_flushes = 0
+        # What last emptied the window (a label of ``_DRAINS``; None:
+        # nothing has since the last dispatch), and the drains counted
+        # by it: the observations behind gateway_pipeline_drains_total.
+        self._drained_by: str | None = None
+        self._pipeline_drains = dict.fromkeys(_DRAINS, 0)
         # Fused scheduler step (PR 8): device programs by kind plus the
         # ragged-row occupancy — the same observations behind
         # gateway_device_programs_total / gateway_ragged_rows_per_program
@@ -1268,9 +1395,12 @@ class ContinuousBatcher:
         self._jit_install_pages = jax.jit(
             install_pages, donate_argnums=(0,)
         )
-        self._jit_unembed = jax.jit(
-            partial(unembed_one, self.cfg, mesh=self.mesh)
+        # The row patch of a fetch (PR 36): page tables, lengths and
+        # state slots in and out, what the host gathered as numpy.
+        self._jit_apply_rows = jax.jit(
+            _step_program("apply_rows", apply_rows)
         )
+        self._row_patch: _RowPatch | None = None
         # Speculative state (PR 9). _spec_cfg pins the MoE dispatch of
         # the k+1-token verify rows to the plain decode step's choice,
         # exactly as engine/speculative.py pins its verify chunk.
@@ -1704,6 +1834,7 @@ class ContinuousBatcher:
         chunk_start,
         chunk_last,
         chunk_done,
+        chunk_sampler,
         stop_rounds=0,
         budgets=None,
         screen=None,
@@ -1714,9 +1845,11 @@ class ContinuousBatcher:
         (PR 8; L lanes since PR 31). ``chunk_tokens`` [L, C],
         ``chunk_table`` [L, P], ``chunk_start`` / ``chunk_last`` /
         ``chunk_done`` [L]; a lane with an all-NULL table is dead.
-        ``chunk_state`` [L, 4] (a model with recurrent layers): each
-        lane's state slots and real tokens, as ``prefill_chunk_paged``
-        takes them.
+        ``chunk_sampler``: the lanes' (seeds, temperatures, top_k,
+        top_p), [L] each, for the first token of a lane that ends its
+        prompt here. ``chunk_state`` [L, 4] (a model with recurrent
+        layers): each lane's state slots and real tokens, as
+        ``prefill_chunk_paged`` takes them.
 
         ``stop_rounds`` (STATIC, PR 12): > 0 makes this the MULTI-ROUND
         fused step — the chunk rides round 1 exactly as before (every
@@ -1731,18 +1864,10 @@ class ContinuousBatcher:
         The chunk rides the decode step's layer pass
         (:func:`~llm_consensus_tpu.models.transformer.fused_step_paged`
         — shared token axis, one K/V scatter, the ragged attention
-        kernel). Returns the plain program's outputs
-        plus ``chunk_logits``, a tuple of L [V] rows — lane l's is the
-        unembedded hidden state of its prompt position ``chunk_last[l]``
-        (the host samples the request's first token from it at fetch,
-        exactly as the standalone path does after its final chunk; a
-        row a lane, so the fetch slices nothing). ``chunk_done`` is
-        traced, under a ``lax.cond`` taken when any lane ends its
-        prompt: a program of non-final chunks skips the full-vocab
-        unembed at run time (its ``chunk_logits`` are zeros nobody
-        reads) without being a program of its own — a program costs
-        seconds to trace and load in every process, and a last-chunk
-        variant first met under load would stall every row for them.
+        kernel). Returns the plain program's outputs plus
+        ``(chunk_first, chunk_logits)`` (:meth:`_lane_first`): the [L]
+        first tokens of the lanes that end their prompts here, and a
+        tuple of L [V] logits rows they were sampled from.
         """
         logits, hidden, cache, *moe = fused_step_paged(
             self.cfg,
@@ -1760,16 +1885,10 @@ class ContinuousBatcher:
         tok1, logp1 = self._sample_rows(
             logits, seeds, counts, temps, topks, topps, filters_active
         )
-        lanes, c = chunk_tokens.shape
-        chunk_logits = jax.lax.cond(
-            jnp.any(chunk_done),
-            lambda h: unembed_rows(self.cfg, params, h, mesh=self.mesh),
-            lambda h: jnp.zeros((lanes, self.cfg.vocab_size), jnp.float32),
-            hidden[
-                jnp.arange(lanes), jnp.clip(chunk_last - chunk_start, 0, c - 1)
-            ],
+        chunk_out = self._lane_first(
+            params, hidden, chunk_start, chunk_last, chunk_done,
+            chunk_sampler, filters_active,
         )
-        chunk_logits = tuple(chunk_logits[lane] for lane in range(lanes))
         if stop_rounds:
             # Multi-round tail (PR 12): round 1 was the fused step
             # above (all rows alive by the dispatch invariant); apply
@@ -1801,17 +1920,79 @@ class ContinuousBatcher:
                 tail = self._step_aux(extra)[1:]
                 aux = (logits, *(m + t for m, t in zip(moe, tail)))
                 return (
-                    toks, logps, cache, tok_end, chunk_logits, emitted,
+                    toks, logps, cache, tok_end, chunk_out, emitted,
                     cnt_out, aux,
                 )
             return (
-                tok1[:, None], logp1[:, None], cache, tok1, chunk_logits,
+                tok1[:, None], logp1[:, None], cache, tok1, chunk_out,
                 emitted, counts + 1, (logits, *moe),
             )
         return (
-            tok1[:, None], logp1[:, None], cache, tok1, chunk_logits,
+            tok1[:, None], logp1[:, None], cache, tok1, chunk_out,
             (logits, *moe),
         )
+
+    def _lane_first(
+        self, params, hidden, chunk_start, chunk_last, chunk_done,
+        chunk_sampler, filters_active,
+    ):
+        """The first generated token of each chunk lane that ends its
+        prompt in this program, sampled where its logits are: lane l's
+        last prompt position ``chunk_last[l]`` of ``hidden`` [L, C, D]
+        is unembedded and drawn with the ``(seed, 0)`` key through the
+        programs' one sampling site. Returns ``(first [L] int32, a
+        tuple of L [V] logits rows)`` — a row a lane, so a
+        ``"logits": n`` request's fetch slices nothing.
+
+        ``chunk_done`` is traced, under a ``lax.cond`` taken when any
+        lane ends its prompt: a program of non-final chunks skips the
+        full-vocab unembed and the sampler at run time (its outputs are
+        zeros nobody reads) without being a program of its own — a
+        program costs seconds to trace and load in every process, and
+        a last-chunk variant first met under load would stall every
+        row for them."""
+        lanes, c = hidden.shape[:2]
+        seeds, temps, topks, topps = chunk_sampler
+
+        def ended(h):
+            logits = unembed_rows(self.cfg, params, h, mesh=self.mesh)
+            first, _ = self._sample_rows(
+                logits, seeds, jnp.zeros_like(seeds), temps, topks, topps,
+                filters_active,
+            )
+            return first, logits
+
+        first, logits = jax.lax.cond(
+            jnp.any(chunk_done),
+            ended,
+            lambda h: (
+                jnp.zeros((lanes,), jnp.int32),
+                jnp.zeros((lanes, self.cfg.vocab_size), jnp.float32),
+            ),
+            hidden[
+                jnp.arange(lanes), jnp.clip(chunk_last - chunk_start, 0, c - 1)
+            ],
+        )
+        return first, tuple(logits[lane] for lane in range(lanes))
+
+    def _chunk_sample(
+        self, cfg_chunk, params, tokens, table, start, cache, chunk_last,
+        chunk_done, chunk_sampler, filters_active, chunk_state=None,
+    ):
+        """The standalone chunk program: one prompt chunk for each of L
+        lanes (:func:`prefill_chunk_paged`), and the first token of a
+        lane that ends its prompt (:meth:`_lane_first`, as the fused
+        step takes it). Returns ``((first, logits rows), cache,
+        *routing counts)``."""
+        hidden, cache, *moe = prefill_chunk_paged(
+            cfg_chunk, params, tokens, table, start, cache, mesh=self.mesh,
+            chunk_state=chunk_state,
+        )
+        chunk_out = self._lane_first(
+            params, hidden, start, chunk_last, chunk_done, chunk_sampler,
+            filters_active,
+        )
+        return (chunk_out, cache, *moe)
 
     def _spec_sample(
         self,
@@ -2061,7 +2242,9 @@ class ContinuousBatcher:
 
     def _chunk_fn(self, chunk: int, lanes: int, s_bucket: int):
         """Jitted per :meth:`_chunk_key`: one paged prefill chunk for
-        each of ``lanes`` sequences.
+        each of ``lanes`` sequences (:meth:`_chunk_sample`;
+        ``filters_active`` static as in the step programs, over the
+        lanes that end their prompts).
 
         Compile-once: chunk widths come from
         ``min(config.prefill_chunk, s_bucket)`` and lanes are 1 or
@@ -2072,10 +2255,10 @@ class ContinuousBatcher:
         if key not in self._jit_chunk:
             self._jit_chunk[key] = jax.jit(
                 _step_program(
-                    "prefill_chunk",
-                    partial(prefill_chunk_paged, cfg, mesh=self.mesh),
+                    "prefill_chunk", partial(self._chunk_sample, cfg)
                 ),
                 donate_argnums=(4,),
+                static_argnums=(8,),
             )
         return self._jit_chunk[key]
 
@@ -2185,12 +2368,11 @@ class ContinuousBatcher:
                     self.draft_cache,
                 )
                 cur += width
-            # install_seq is idempotent on the (unchanged) table row;
+            # An install is idempotent on the (unchanged) table row;
             # what this fixes is the row's draft length.
-            self.draft_cache = install_seq(
-                self.draft_cache, jnp.int32(idx), table_dev, jnp.int32(tlen)
-            )
+            self._row_install(idx, slot, tlen, draft_only=True)
             slot.draft_lag = 0
+        self._apply_row_patch()
 
     def _fused_fn(self, chunk: int, lanes: int, s_bucket: int):
         """Jitted per :meth:`_chunk_key`: the fused scheduler step
@@ -2206,7 +2388,7 @@ class ContinuousBatcher:
                     "fused_step", partial(self._fused_sample, cfg_chunk)
                 ),
                 donate_argnums=(1,),
-                static_argnums=(8, 15),
+                static_argnums=(8, 16),
             )
         return self._jit_fused[key]
 
@@ -2214,7 +2396,8 @@ class ContinuousBatcher:
         """Trace and compile the chunk programs a decoding row could
         meet, once: the fused step at both widths (one lane and
         :meth:`_lanes_for`), with ungrouped rows and — where rows group
-        — with grouped ones, and the wide standalone chunk.
+        — with grouped ones, the wide standalone chunk, and the row
+        patch of a fetch (:func:`apply_rows`).
 
         Called before a chunk runs alone because nothing decodes, so no
         decoding row waits for the builds: the prompt being prefilled
@@ -2233,6 +2416,7 @@ class ContinuousBatcher:
         t0 = time.perf_counter()
         c = self.config
         i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+        f32 = partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
         grouped = [None]
         if self._group_decode:
             rows, gm = i32((c.max_slots,)), i32((self._groups.max_groups,))
@@ -2243,20 +2427,33 @@ class ContinuousBatcher:
                 i32((lanes, chunk)), i32((lanes, c.pages_per_seq)),
                 i32((lanes,)),
             )
+            # Last positions, done, and the lanes' sampler rows.
+            ends = (
+                i32((lanes,)), jax.ShapeDtypeStruct((lanes,), jnp.bool_),
+                (i32((lanes,)), f32((lanes,)), i32((lanes,)), f32((lanes,))),
+            )
             state = (
                 {"chunk_state": i32((lanes, 4))}
                 if self._states is not None else {}
             )
             for groups in grouped:
                 self._fused_fn(chunk, lanes, s_bucket).lower(
-                    *self._plain_shapes, groups, *lane, i32((lanes,)),
-                    jax.ShapeDtypeStruct((lanes,), jnp.bool_), **state,
+                    *self._plain_shapes, groups, *lane, *ends, **state,
                 ).compile()
             if lanes > 1:
                 self._chunk_fn(chunk, lanes, s_bucket).lower(
                     self._plain_shapes[0], *lane, self._plain_shapes[1],
-                    **state,
+                    *ends, self._plain_shapes[8], **state,
                 ).compile()
+        pools = (_rows_of(self._plain_shapes[1]),) + (
+            (jax.tree.map(_abstract, _rows_of(self.draft_cache)),)
+            if self.draft_cache is not None else ()
+        )
+        self._jit_apply_rows.lower(
+            pools, i32((len(pools), c.max_slots)),
+            i32((c.max_slots, c.pages_per_seq)), i32((c.max_slots,)),
+            i32((c.max_slots,)),
+        ).compile()
         log.info(
             "fused step programs for chunks of %d, lanes %s, grouped and "
             "not: built in %.1f s", chunk, widths, time.perf_counter() - t0,
@@ -3009,6 +3206,11 @@ class ContinuousBatcher:
                 # gateway_pipeline_flushes_total (lockstep tested).
                 "dispatch_inflight": len(self._inflight),
                 "pipeline_flushes": self._pipeline_flushes,
+                # Mirror of gateway_pipeline_drains_total{after}.
+                **{
+                    f"pipeline_drains_{after}": n
+                    for after, n in self._pipeline_drains.items()
+                },
                 # Fused scheduler step (PR 8): device programs by kind
                 # (fused = decode rows + a prefill chunk in ONE
                 # program), ragged-row occupancy, and the count of loop
@@ -3689,6 +3891,7 @@ class ContinuousBatcher:
             self._pipeline_flushes += 1
         while self._inflight:
             self._fetch_one()
+        self._drained_by = "flush"
 
     def _store_key(self, chain: tuple) -> tuple:
         """Host-tier key for a token chain: the batcher's store scope
@@ -4091,15 +4294,11 @@ class ContinuousBatcher:
             self._prefill_rr = (picked[-1] + 1) % n
         return picked
 
-    def _lane_args(self, idxs: list[int]):
+    def _lane_args(self, idxs: list[int]) -> _LaneArgs:
         """The lane arguments of one chunk program carrying the next
-        chunk of each slot of ``idxs``: (program lanes, chunk ids
-        [L, C], tables [L, P], starts [L], last prompt positions [L],
-        done [L] bool, cost extents of the live lanes, and — for a model
-        with recurrent layers — the lanes' [L, 4] state slots and real
-        tokens as the program's ``chunk_state`` keyword). One ready slot
-        takes the one-lane program; more take the wide one, the lanes
-        past ``idxs`` dead — an all-NULL table, as an idle slot's row."""
+        chunk of each slot of ``idxs``. One ready slot takes the
+        one-lane program; more take the wide one, the lanes past
+        ``idxs`` dead — an all-NULL table, as an idle slot's row."""
         c = self.config
         first = self._slots[idxs[0]]
         lanes = 1 if len(idxs) == 1 else self._lanes_for(first.chunk)
@@ -4108,16 +4307,26 @@ class ContinuousBatcher:
         starts = np.zeros((lanes,), np.int32)
         lasts = np.zeros((lanes,), np.int32)
         done = np.zeros((lanes,), bool)
+        seeds = np.zeros((lanes,), np.int32)
+        temps = np.zeros((lanes,), np.float32)
+        topks = np.zeros((lanes,), np.int32)
+        topps = np.ones((lanes,), np.float32)
+        filters = False
         state = np.zeros((lanes, 4), np.int32)
         ext = []
         for lane, idx in enumerate(idxs):
             slot = self._slots[idx]
+            req = slot.request
             end = slot.next_pos + slot.chunk
             ids[lane] = slot.padded_ids[slot.next_pos : end]
             tables[lane] = slot.table
             starts[lane] = slot.next_pos
             lasts[lane] = slot.prompt_len - 1
             done[lane] = end >= slot.prompt_len
+            seeds[lane], temps[lane] = req.seed, req.temperature
+            topks[lane], topps[lane] = req.top_k, req.top_p
+            if done[lane] and (req.top_k != 0 or req.top_p != 1.0):
+                filters = True
             ext.append((end, slot.chunk))
             if self._states is not None:
                 state[lane] = (
@@ -4125,11 +4334,11 @@ class ContinuousBatcher:
                     self._snapshot_slot(slot, end),
                     min(end, slot.prompt_len) - slot.next_pos,
                 )
-        kw = (
-            {"chunk_state": jnp.asarray(state)}
-            if self._states is not None else {}
+        return _LaneArgs(
+            lanes, ids, tables, starts, lasts, done,
+            (seeds, temps, topks, topps), filters, ext,
+            {"chunk_state": state} if self._states is not None else {},
         )
-        return lanes, ids, tables, starts, lasts, done, ext, kw
 
     def _count_lanes(self, kind: str, live: int) -> None:
         """One chunk program of ``kind`` with ``live`` lanes filled."""
@@ -4186,6 +4395,7 @@ class ContinuousBatcher:
         """
         slots = [self._slots[i] for i in idxs]
         head = slots[0]
+        decoding = bool(self._decoding())
         if self._inflight:
             # Let in-flight decode work clear the device queue so the
             # stall histogram times ONLY this chunk. A device-order
@@ -4199,41 +4409,38 @@ class ContinuousBatcher:
             t0 = time.perf_counter()
             ev = self._count_program("prefill")
             self._count_lanes("prefill", len(idxs))
-            lanes, ids, tables, starts, _, done, ext, state_kw = (
-                self._lane_args(idxs)
-            )
-            hidden, self.cache, *moe = self._chunk_fn(
-                head.chunk, lanes, head.s_bucket
+            la = self._lane_args(idxs)
+            # A lane that ends its prompt has its first token sampled
+            # in the program, from the last REAL position's hidden
+            # state (a [D] gather + D x V unembed a lane — never a
+            # [C, V] logits buffer per chunk).
+            (first, chunk_logits), self.cache, *moe = self._chunk_fn(
+                head.chunk, la.lanes, head.s_bucket
             )(
-                self.params,
-                jnp.asarray(ids),
-                jnp.asarray(tables),
-                jnp.asarray(starts),
-                self.cache,
-                **state_kw,
+                self.params, la.ids, la.tables, la.starts, self.cache,
+                la.lasts, la.done, la.sampler, la.filters, **la.state_kw,
             )
-            firsts = {}
-            for lane, slot in enumerate(slots):
-                if self.draft_cache is not None:
-                    self._draft_prefill_chunk(slot, ids[lane], slot.next_pos)
-                if done[lane]:
-                    # Sample the first token from the last REAL
-                    # position's hidden state (a [D] gather + D x V
-                    # unembed — never a [C, V] logits buffer per chunk).
-                    h = hidden[lane, slot.prompt_len - 1 - slot.next_pos]
-                    logits = self._jit_unembed(self.params, h)
-                    firsts[lane] = self._sample_first(slot.request, logits)
+            if self.draft_cache is not None:
+                for lane, slot in enumerate(slots):
+                    self._draft_prefill_chunk(
+                        slot, la.ids[lane], slot.next_pos
+                    )
         # The device work above must COMPLETE before (a) the stall
         # histogram records it and (b) successors read the pages this
         # chunk wrote.
         with self._phase("device_wait"):
             jax.block_until_ready(self.cache.length)
+            first_np = np.asarray(first) if la.done.any() else None
+            moe_np = np.asarray(moe[0]) if moe else None
+        if decoding:
+            # Rows decode and the device has just run dry under them.
+            self._drained_by = "standalone_chunk"
         # What the fetch does for a fused chunk: credit it, and on the
         # last one activate the row.
         with self._phase("retire"):
             dur = time.perf_counter() - t0
-            if moe:
-                self._count_moe("prefill", np.asarray(moe[0]))
+            if moe_np is not None:
+                self._count_moe("prefill", moe_np)
             if ev is not None:
                 # Standalone chunk programs are host-blocking: the
                 # device window IS [t0, t0 + dur] — fill the flight
@@ -4246,7 +4453,8 @@ class ContinuousBatcher:
                     "width": head.chunk, "lanes": len(idxs),
                 }
             self._mbu_account(
-                "prefill", self._program_cost("prefill", [], 0, chunk_ext=ext),
+                "prefill",
+                self._program_cost("prefill", [], 0, chunk_ext=la.ext),
                 dur,
             )
             for lane, (idx, slot) in enumerate(zip(idxs, slots)):
@@ -4263,59 +4471,70 @@ class ContinuousBatcher:
                 self._chunk_dispatched(slot)
                 with self._lock:
                     self._prefill_chunks += 1
-                if not done[lane]:
+                if not la.done[lane]:
                     continue
-                # Final chunk landed: make the row visible to the decode
-                # program (table + true length in one pass) and flip to
-                # decoding.
-                self.cache = install_seq(
-                    self.cache,
-                    jnp.int32(idx),
-                    jnp.asarray(slot.table),
-                    jnp.int32(slot.prompt_len),
-                    jnp.int32(slot.state_slot),
+                self._prompt_ended(
+                    idx, slot, int(first_np[lane]), chunk_logits[lane]
                 )
-                self._install_draft_seq(idx, slot)
-                self._activate(idx, slot, firsts[lane])
+            self._apply_row_patch()
             return True
 
-    def _install_draft_seq(self, idx: int, slot: _Slot) -> None:
-        """Mirror a slot activation into the draft pool: same table,
-        same length — the draft's committed-minus-one invariant starts
-        in sync with the target's."""
-        if self.draft_cache is None:
-            return
-        self.draft_cache = install_seq(
-            self.draft_cache,
-            jnp.int32(idx),
-            jnp.asarray(slot.table),
-            jnp.int32(slot.prompt_len),
-        )
-
-    def _sample_first(self, req: _Request, logits) -> int:
-        """First generated token, sampled from the last chunk's
-        logits with the (seed, 0) PRNG draw, whichever program (a
-        standalone chunk or a fused step) carried that chunk.
-
-        All of it is a wait on the device (the ``device_wait`` phase),
-        not only the ``int()`` at its end: the sampling ops are eager
-        programs of their own, and with a step program in flight the
-        runtime holds the second of them back in its enqueue until
-        that program ends (PERF.md, Findings PR 26: ~50 ms a call on
-        the chip)."""
-        with self._phase("device_wait"):
-            if req.logits_n:
+    def _prompt_ended(self, idx: int, slot: _Slot, first: int, logits) -> None:
+        """A slot's last chunk has landed, with its first token sampled
+        by the program that carried it (a standalone chunk or a fused
+        step): make the row visible to the decode program — table, true
+        length and state slot, in the target pool and the draft's (whose
+        committed-minus-one invariant starts in sync with the target's),
+        as one entry of the fetch's patch — and flip it to decoding.
+        ``logits``: the device [V] row the token was sampled from,
+        fetched for a ``"logits": n`` request alone."""
+        req = slot.request
+        if req.logits_n:
+            with self._phase("device_wait"):
                 req.logit_rows.append(np.asarray(logits))
-            key = jax.random.fold_in(jax.random.PRNGKey(req.seed), 0)
-            tok, _ = sample_token_per_request(
-                logits[None],
-                key[None],
-                jnp.asarray([req.temperature], jnp.float32),
-                jnp.asarray([req.top_k], jnp.int32),
-                jnp.asarray([req.top_p], jnp.float32),
-                filters_active=(req.top_k != 0 or req.top_p != 1.0),
+        self._row_install(idx, slot, slot.prompt_len)
+        self._activate(idx, slot, first)
+
+    def _row_install(
+        self, idx: int, slot: _Slot, length: int, draft_only: bool = False
+    ) -> None:
+        """Enter row ``idx``'s install (``slot``'s table and state slot,
+        ``length`` tokens) into the pending patch: for the target's pool
+        and the draft's, or the draft's alone."""
+        patch = self._patch()
+        patch.ops[int(draft_only):, idx] = _ROW_INSTALL
+        patch.tables[idx] = slot.table
+        patch.lengths[idx] = length
+        patch.states[idx] = slot.state_slot
+
+    def _patch(self) -> _RowPatch:
+        """The pending patch (pool 0 the target's, 1 the draft's)."""
+        if self._row_patch is None:
+            c = self.config
+            self._row_patch = _RowPatch(
+                1 + (self.draft_cache is not None), c.max_slots,
+                c.pages_per_seq,
             )
-            return int(tok[0])
+        return self._row_patch
+
+    def _apply_row_patch(self) -> None:
+        """Apply what the retire phase gathered, as ONE program, where
+        it gathered anything: behind the programs in flight and before
+        the next dispatch, where the eager scatters it replaces ran, so
+        nothing that takes ``self.cache`` sees an unpatched one."""
+        patch, self._row_patch = self._row_patch, None
+        if patch is None:
+            return
+        pools = (self.cache,) + (
+            (self.draft_cache,) if self.draft_cache is not None else ()
+        )
+        rows, *draft = self._jit_apply_rows(
+            tuple(_rows_of(pool) for pool in pools),
+            patch.ops, patch.tables, patch.lengths, patch.states,
+        )
+        self.cache = _with_rows(self.cache, rows)
+        if draft:
+            self.draft_cache = _with_rows(self.draft_cache, draft[0])
 
     def _activate(self, idx: int, slot: _Slot, first: int) -> None:
         """Flip a slot to decoding with its first sampled token."""
@@ -4445,9 +4664,7 @@ class ContinuousBatcher:
         # plain per-row walk — nothing left to dedup).
         self._groups.remove(idx)
         self._stream_src_prev.pop(idx, None)
-        self.cache = release_seq(self.cache, jnp.int32(idx))
-        if self.draft_cache is not None:
-            self.draft_cache = release_seq(self.draft_cache, jnp.int32(idx))
+        self._patch().ops[:, idx] = _ROW_RELEASE
         pool = self._pools[self._shard_of_slot[idx]]
         with self._lock:
             # Refcounted release: private pages return to the free
@@ -4573,9 +4790,9 @@ class ContinuousBatcher:
         device work is ordered on the stream at dispatch — its registry
         nodes flip ready HERE, since every consumer is a later program
         on the same stream or a flush-first host operation — while its
-        host bookkeeping (activation, first-token sampling off the
-        returned logits) happens at the fetch, inside the pipeline's
-        overlap window.
+        host bookkeeping (activation with the first token the program
+        sampled, its row's entry in the fetch's patch) happens at the
+        fetch, inside the pipeline's overlap window.
 
         ``spec`` (PR 9): dispatch the speculative draft/verify program
         instead — one device program whose per-row token yield is
@@ -4614,10 +4831,15 @@ class ContinuousBatcher:
             if slot is not None and slot.phase == "decode":
                 temps[i] = slot.request.temperature
                 rows_now.append((i, slot))
+        # Static (two cached programs): over the decode rows and the
+        # chunk lanes that end their prompts in this program.
         filters_active = any(
             s.request.top_k != 0 or s.request.top_p != 1.0
             for _, s in rows_now
         )
+        if chunk_idxs:
+            la = self._lane_args(chunk_idxs)
+            filters_active = filters_active or la.filters
 
         def rows(x):
             # SNAPSHOT (np.array copies) before device_put: jax's CPU
@@ -4647,6 +4869,16 @@ class ContinuousBatcher:
             overhead = t0 - self._last_step_end
         elif self._inflight:
             overhead = 0.0
+        if self._last_step_end is not None or (
+            self._drained_by == "standalone_chunk"
+        ):
+            # Rows were decoding and the device holds nothing: this
+            # program is one it had to wait for.
+            after = self._drained_by or "other"
+            _M_PIPELINE_DRAINS.labels(after=after).inc()
+            with self._lock:
+                self._pipeline_drains[after] += 1
+        self._drained_by = None
         if overhead is not None:
             _M_SCHED_OVERHEAD.observe(overhead)
             if self.controller is not None:
@@ -4828,6 +5060,7 @@ class ContinuousBatcher:
         if self._plain_shapes is None and self._fused_ok and not rounds_now:
             self._plain_shapes = jax.tree.map(_abstract, args[:9])
         chunk_recs: list[_InflightChunk] = []
+        chunk_first = None
         if not chunk_idxs:
             if rounds_now:
                 # Same prepared device args as the one-step program
@@ -4849,35 +5082,28 @@ class ContinuousBatcher:
             cost = self._program_cost("decode", rows_now, k)
         else:
             head = self._slots[chunk_idxs[0]]
-            lanes, ids, tables, starts, lasts, done, ext, state_kw = (
-                self._lane_args(chunk_idxs)
-            )
-            out = self._fused_fn(head.chunk, lanes, head.s_bucket)(
-                *args,
-                jnp.asarray(ids),
-                jnp.asarray(tables),
-                jnp.asarray(starts),
-                jnp.asarray(lasts),
-                jnp.asarray(done),
+            out = self._fused_fn(head.chunk, la.lanes, head.s_bucket)(
+                *args, *la.program_args,
                 *(
                     (rounds_now, budgets_dev, screen_dev)
                     if rounds_now
                     else ()
                 ),
-                **state_kw,
+                **la.state_kw,
             )
             if rounds_now:
                 (
-                    next_tok, _, self.cache, next_in, chunk_logits,
+                    next_tok, _, self.cache, next_in, chunk_out,
                     emit_cnt, cnt_out, aux,
                 ) = out
             else:
-                next_tok, _, self.cache, next_in, chunk_logits, aux = out
+                next_tok, _, self.cache, next_in, chunk_out, aux = out
+            chunk_first, chunk_logits = chunk_out
             ev = self._count_program(
                 "fused", rows=len(rows_now) + len(chunk_idxs), rounds=k
             )
             self._count_lanes("fused", len(chunk_idxs))
-            cost = self._program_cost("fused", rows_now, k, chunk_ext=ext)
+            cost = self._program_cost("fused", rows_now, k, chunk_ext=la.ext)
             for lane, idx in enumerate(chunk_idxs):
                 slot = self._slots[idx]
                 _flight.flight_recorder().record(
@@ -4895,12 +5121,15 @@ class ContinuousBatcher:
                     # two touch disjoint pools; stream order is
                     # irrelevant between them, only their fetch/flush
                     # consumers care).
-                    self._draft_prefill_chunk(slot, ids[lane], slot.next_pos)
+                    self._draft_prefill_chunk(
+                        slot, la.ids[lane], slot.next_pos
+                    )
                 chunk_recs.append(
                     _InflightChunk(
                         idx=idx,
                         slot=slot,
-                        done=bool(done[lane]),
+                        done=bool(la.done[lane]),
+                        lane=lane,
                         logits=chunk_logits[lane],
                         pos=slot.next_pos,
                         width=slot.chunk,
@@ -4923,7 +5152,8 @@ class ContinuousBatcher:
                     s.draft_lag += k
         rec = _Inflight(
             tokens=next_tok, next_input=next_in, t0=t0, k=k,
-            rows=rows_now, chunks=chunk_recs, rounds=rounds_now,
+            rows=rows_now, chunks=chunk_recs, chunk_first=chunk_first,
+            rounds=rounds_now,
             rounds_clean=rounds_clean,
             emit_cnt=emit_cnt, counts_out=cnt_out, flight=ev, cost=cost,
             aux=aux,
@@ -4977,12 +5207,21 @@ class ContinuousBatcher:
                 if rec.aux is not None and len(rec.aux) > 1
                 else None
             )
+            # The first tokens of the lanes that ended their prompts:
+            # an output of the program that has just ended, as the
+            # tokens are — no wait on the one behind it.
+            first_np = (
+                np.asarray(rec.chunk_first)
+                if any(ch.done for ch in rec.chunks)
+                else None
+            )
         with self._phase("retire"):
             if moe_np is not None:
                 self._count_moe(
                     "fused" if rec.chunks else "decode", moe_np, rec.k
                 )
-            self._credit_fetched(rec, next_np, cnt_np)
+            self._credit_fetched(rec, next_np, cnt_np, first_np)
+            self._apply_row_patch()
 
     def _count_moe(self, kind: str, moe_np, steps: int = 1) -> None:
         """A retired program's expert-routing counts (int32 [experts
@@ -4994,11 +5233,15 @@ class ContinuousBatcher:
             steps * self.cfg.n_moe_layers
         )
 
-    def _credit_fetched(self, rec: "_Inflight", next_np, cnt_np) -> None:
+    def _credit_fetched(
+        self, rec: "_Inflight", next_np, cnt_np, first_np
+    ) -> None:
         """The host bookkeeping of one fetched program (the ``retire``
         phase): step telemetry, crediting its tokens to the rows still
         alive, stop scans, retirement, and a fused chunk's deferred
-        activation."""
+        activation. Row installs and releases go into the patch the
+        caller applies (:meth:`_apply_row_patch`); nothing here waits
+        on the device."""
         step_end = time.perf_counter()
         # Device-step latency: at depth 1 the program started at its
         # own dispatch; deeper, it started when its predecessor
@@ -5012,7 +5255,12 @@ class ContinuousBatcher:
         # The pipeline drained: host time from here to the next
         # dispatch is un-overlapped. With programs still in flight the
         # gap is hidden and the next dispatch observes 0.
-        self._last_step_end = step_end if not self._inflight else None
+        self._last_step_end = None
+        if not self._inflight:
+            self._last_step_end = step_end
+            self._drained_by = (
+                "first_token" if first_np is not None else "other"
+            )
         self._hb_step = time.monotonic()
         _M_STEP_SECONDS.observe(dur)
         if rec.flight is not None:
@@ -5202,20 +5450,12 @@ class ContinuousBatcher:
                     pos=ch.pos, chunk=ch.width, fused=1,
                 )
             if ch.done:
-                # Final chunk: sample the first token from the logits
-                # the fused program already computed (same PRNG draw,
-                # same unembed as the standalone path), make the row
-                # visible to the decode program, flip to decoding.
-                first = self._sample_first(slot.request, ch.logits)
-                self.cache = install_seq(
-                    self.cache,
-                    jnp.int32(ch.idx),
-                    jnp.asarray(slot.table),
-                    jnp.int32(slot.prompt_len),
-                    jnp.int32(slot.state_slot),
+                # Final chunk: the fused program sampled the first token
+                # from the logits it computed (same PRNG draw, same
+                # unembed as the standalone path).
+                self._prompt_ended(
+                    ch.idx, slot, int(first_np[ch.lane]), ch.logits
                 )
-                self._install_draft_seq(ch.idx, slot)
-                self._activate(ch.idx, slot, first)
 
     def _run(self) -> None:
         """The worker thread. A device program that raises (a kernel

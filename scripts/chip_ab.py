@@ -7,12 +7,16 @@ belongs to each run's own server child).
 ``side`` is ``parent`` (``.bench_checkout/parent``: ``git archive
 <parent> | tar -x -C`` there first), ``change`` (the repo root) or
 ``tree`` (``.bench_checkout/tree``: ``git archive $(git write-tree)``).
+``trace`` 2 is a traced run that keeps its ``.xplane.pb`` for
+``scripts/drain_count.py`` (this checkout's), whose line rides the record
+as ``drains``; the trace itself is deleted.
 Each run's result line goes to ``chiprun_out/<tag>/results.jsonl`` with
 its side, its server log's "built in" lines and the entries it added
 to the compile cache the runs share; the server logs are copied beside
 it. Measures nothing itself: ``benchmark/run.py`` does.
 """
 
+import glob
 import json
 import os
 import re
@@ -42,6 +46,26 @@ def cache_listing() -> list:
     return out
 
 
+def drains(profile_dir: str):
+    """``scripts/drain_count.py`` on the trace a run kept, then the
+    trace goes: it is too large to bring back."""
+    found = sorted(glob.glob(os.path.join(
+        profile_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not found:
+        return None
+    r = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "scripts", "drain_count.py"),
+         found[-1]],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True,
+    )
+    shutil.rmtree(profile_dir, ignore_errors=True)
+    try:
+        return json.loads(r.stdout.strip().splitlines()[-1])
+    except (ValueError, IndexError):
+        return {"error": r.stderr[-2000:]}
+
+
 def main() -> int:
     tag, specs = sys.argv[1], sys.argv[2:]
     out = os.path.join(ROOT, "chiprun_out", tag)
@@ -49,11 +73,13 @@ def main() -> int:
     rc_all = 0
     for n, spec in enumerate(specs):
         side, cell, seed, trace = spec.split(":")
+        keep = ["--keep-trace"] if trace == "2" else []
+        trace = "1" if keep else trace
         root = ROOTS[side]
         t0 = time.time()
         r = subprocess.run(
             [sys.executable, "benchmark/run.py", "--workload", cell,
-             "--seed", seed, "--seconds", "51", "--trace", trace],
+             "--seed", seed, "--seconds", "51", "--trace", trace, *keep],
             cwd=root, capture_output=True, text=True,
         )
         wall = time.time() - t0
@@ -83,6 +109,8 @@ def main() -> int:
             "trace": int(trace), "rc": r.returncode, "wall_s": round(wall, 1),
             "built": built, "result": result,
         }
+        if keep:
+            rec["drains"] = drains(os.path.join(log_dir, "profile"))
         if r.returncode != 0 or result is None:
             rc_all = 1
             rec["stderr"] = r.stderr[-3000:]
@@ -103,6 +131,8 @@ def main() -> int:
                           "trace": trace, "rc": r.returncode,
                           "wall_s": round(wall, 1), **short})[:3000],
               flush=True)
+        if keep:
+            print(json.dumps({"n": n, "drains": rec["drains"]}), flush=True)
     with open(os.path.join(out, "cache_listing.json"), "w") as f:
         json.dump(cache_listing(), f)
     return rc_all

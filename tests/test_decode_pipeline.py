@@ -13,6 +13,7 @@ with ``stats()``, and a wedged in-flight fetch still goes stale on the
 liveness heartbeat.
 """
 
+import functools
 import time
 
 import jax
@@ -386,3 +387,229 @@ def test_wedged_inflight_fetch_flips_heartbeat(params):
         assert b.heartbeat()["alive"] is True
     finally:
         b.close()
+
+
+# ---------------------------------------------------------------------------
+# PR 36: first tokens sampled in the step programs, row installs and
+# releases as one program a fetch — the loop's thread makes no
+# op-by-op jax call
+# ---------------------------------------------------------------------------
+
+_PROBE = "the probe's prompt!"  # BOS + 19 bytes: two 16-token chunks
+
+
+def _quiesce(batcher, timeout=30.0):
+    """Stats once the loop has nothing left in flight."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        st = batcher.stats()
+        if not (st["active_slots"] or st["prefilling_slots"]
+                or st["dispatch_inflight"] or st["waiting"]):
+            return st
+        time.sleep(0.01)
+    return batcher.stats()
+
+
+def _record_first_tokens(batcher) -> dict:
+    """seed -> the first token each request was activated with."""
+    firsts, activate = {}, batcher._activate
+
+    def recording(idx, slot, first):
+        firsts[slot.request.seed] = first
+        return activate(idx, slot, first)
+
+    batcher._activate = recording
+    return firsts
+
+
+@pytest.fixture(scope="module")
+def probe(params):
+    """A batcher that shares no pages (the same prompt is the same
+    computation every time), the float32 logits of ``_PROBE``'s last
+    position as a greedy ``logits=1`` request returns them, and the
+    first tokens its requests were activated with, by seed."""
+    b = ContinuousBatcher(
+        CFG, params,
+        config=ContinuousConfig(**dict(_CCFG, share_prefix=False)),
+    )
+    firsts = _record_first_tokens(b)
+    try:
+        row = b.submit(_PROBE, max_new_tokens=1, logits=1).result(
+            timeout=120
+        ).logits[0]
+        yield b, row, firsts
+    finally:
+        b.close()
+
+
+@pytest.mark.parametrize("top_k, top_p", [(0, 1.0), (5, 0.9)])
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+@pytest.mark.parametrize("seed", [3, 2147480011])
+def test_first_token_is_the_samplers_own_draw(
+    probe, seed, temperature, top_k, top_p
+):
+    """The first token a served request returns is
+    ``sample_token_per_request`` on the prompt's last-position logits
+    with the ``(seed, 0)`` key — whether its last chunk ran alone or
+    rode a decoding companion's step."""
+    from llm_consensus_tpu.engine.sampler import sample_token_per_request
+
+    b, row, firsts = probe
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), 0)
+    want, _ = sample_token_per_request(
+        jnp.asarray(row)[None], key[None],
+        jnp.asarray([temperature], jnp.float32),
+        jnp.asarray([top_k], jnp.int32), jnp.asarray([top_p], jnp.float32),
+    )
+    kw = dict(temperature=temperature, top_k=top_k, top_p=top_p,
+              max_new_tokens=2)
+    for beside in (False, True):
+        firsts.clear()
+        futs = []
+        if beside:
+            futs.append(b.submit("a companion that keeps decoding", seed=1,
+                                 max_new_tokens=8))
+            deadline = time.monotonic() + 60
+            while not b.stats()["active_slots"]:
+                assert time.monotonic() < deadline
+                time.sleep(0.002)
+        futs.append(b.submit(_PROBE, seed=seed, **kw))
+        for f in futs:
+            f.result(timeout=120)
+        assert firsts[seed] == int(want[0]), (beside, firsts)
+        _quiesce(b)
+
+
+def test_three_lanes_that_end_together_activate_three_rows(params):
+    """Three prompts whose last chunks ride ONE fused program beside a
+    decoding row: each row is activated with the first token it gets
+    when served alone, the lane whose request is one token long retires
+    at once, and once the burst is served every row of the device's
+    tables is released and every page is back."""
+    cfgkw = dict(_CCFG, share_prefix=False, max_new_tokens=6)
+    lanes = [("lane one, short", 5), ("lane two, short", 6),
+             ("lane 3, shorter", 7)]
+    kw = {5: dict(temperature=0.0), 6: dict(temperature=0.7),
+          7: dict(temperature=0.7, top_k=4, max_new_tokens=1)}
+    alone = ContinuousBatcher(CFG, params, config=ContinuousConfig(**cfgkw))
+    want = _record_first_tokens(alone)
+    try:
+        texts = [alone.submit(p, seed=s, **kw[s]).result(timeout=120).text
+                 for p, s in lanes]
+    finally:
+        alone.close()
+    b = ContinuousBatcher(CFG, params, config=ContinuousConfig(**cfgkw))
+    got = _record_first_tokens(b)
+    free0 = b._pools[0].available
+    try:
+        for _ in range(20):
+            got.clear()
+            long = b.submit("a companion that keeps decoding", seed=1,
+                            max_new_tokens=48)
+            deadline = time.monotonic() + 60
+            while not b.stats()["active_slots"]:
+                assert time.monotonic() < deadline
+                time.sleep(0.002)
+            # Wedge admission for a moment so the three arrive in one.
+            b._admit = lambda: time.sleep(0.05)
+            futs = [b.submit(p, seed=s, **kw[s]) for p, s in lanes]
+            del b._admit
+            outs = [f.result(timeout=120) for f in futs]
+            long.result(timeout=120)
+            st = _quiesce(b)
+            assert [o.text for o in outs] == texts
+            assert {s: got[s] for _, s in lanes} == want
+            assert outs[2].num_tokens == 1
+            if st.get("chunk_lanes_fused_3", 0):
+                break
+        assert st.get("chunk_lanes_fused_3", 0) >= 1, st
+        assert b._pools[0].available == free0
+        assert not np.asarray(b.cache.length).any()
+        assert not np.asarray(b.cache.page_table).any()  # NULL_PAGE rows
+    finally:
+        b.close()
+
+
+def _traced_only(fn):
+    """``fn``, refusing a call whose arguments hold no tracer: one made
+    op by op, outside a jitted program."""
+    @functools.wraps(fn)
+    def guarded(*args, **kwargs):
+        leaves = jax.tree_util.tree_leaves((args, kwargs))
+        if not any(isinstance(x, jax.core.Tracer) for x in leaves):
+            raise AssertionError(f"{fn.__name__} called op by op")
+        return fn(*args, **kwargs)
+
+    return guarded
+
+
+_BURST = [_HEADER + f"p{i} answers" for i in range(4)] + [
+    "an unshared prompt that arrives with them",
+    "and one more, long enough for three chunks",
+]
+_BURST_CAPS = [3, 12, 6, 12, 9, 5]  # staggered: slots free beside rows
+
+
+def _serve_burst(params, depth, **cfgkw):
+    """A panel-shaped burst: six prompts over four slots, so prompts end
+    (and requests retire) beside decoding rows; half sampled."""
+    b = ContinuousBatcher(
+        CFG, params,
+        config=ContinuousConfig(
+            **dict(_CCFG, max_new_tokens=12, **cfgkw), pipeline_depth=depth
+        ),
+    )
+    try:
+        futs = [
+            b.submit(p, seed=i, temperature=0.7 * (i % 2),
+                     top_k=3 * (i % 3 == 0), max_new_tokens=cap)
+            for i, (p, cap) in enumerate(zip(_BURST, _BURST_CAPS))
+        ]
+        outs = [(f.result(timeout=120).text, f.result().num_tokens)
+                for f in futs]
+        return outs, _quiesce(b)
+    finally:
+        b.close()
+
+
+def test_the_loop_makes_no_op_by_op_jax_call(params, monkeypatch):
+    """With the sampler, ``install_seq`` and ``release_seq`` refusing any
+    call from outside a trace, a panel-shaped burst at depth 2 is served
+    whole, with the text the serialized loop gives without the guard:
+    first tokens come out of the step programs and row changes go
+    through the one patch program."""
+    from llm_consensus_tpu.serving import continuous
+
+    want, _ = _serve_burst(params, 1)
+    for name in ("sample_token_per_request", "install_seq", "release_seq"):
+        monkeypatch.setattr(
+            continuous, name, _traced_only(getattr(continuous, name))
+        )
+    got, st = _serve_burst(params, 2)
+    assert got == want
+    assert st["device_programs_fused"] >= 1  # prompts ended beside rows
+    assert not hasattr(ContinuousBatcher, "_sample_first")
+    assert not hasattr(ContinuousBatcher, "_jit_unembed")
+
+
+@pytest.mark.parametrize("depth", [2, 1])
+def test_pipeline_drains_counted_by_cause(params, depth):
+    """gateway_pipeline_drains_total{after} and its stats() mirror move
+    together; over a burst whose prompts end beside decoding rows no
+    program at depth 2 waits for a first token — at depth 1 every
+    dispatch finds the device empty, and those after a fetch that ended
+    a prompt say so."""
+    from llm_consensus_tpu.server.metrics import PIPELINE_DRAINS
+
+    labels = ("first_token", "standalone_chunk", "flush", "other")
+    before = {a: PIPELINE_DRAINS.labels(after=a).value for a in labels}
+    _, st = _serve_burst(params, depth)
+    moved = {
+        a: PIPELINE_DRAINS.labels(after=a).value - before[a] for a in labels
+    }
+    assert moved == {a: st[f"pipeline_drains_{a}"] for a in labels}
+    assert st["device_programs_fused"] >= 1
+    if depth == 2:
+        assert moved["first_token"] == 0, moved
+    else:
+        assert moved["first_token"] >= 1 and moved["other"] >= 1, moved

@@ -533,6 +533,38 @@ def test_mappers_restore_recompute_and_survive_eviction():
         bt.close()
 
 
+def test_a_one_token_request_gives_back_its_pages_and_state_slot():
+    """A request one token long, its last chunk riding a companion's
+    decode step or not, is installed and released by the same fetch's
+    patch program (PR 36): the release wins, the companion's state slot
+    stays its own until it ends, and at rest every row of the device's
+    tables, every page and every state slot is back."""
+    import time
+
+    cfg, params = _model()
+    bt = _batcher(cfg, params, share_prefix=False)
+    try:
+        pages0, slots0 = bt._pools[0].available, bt._states.available
+        alone = bt.submit("a companion's prompt", max_new_tokens=8).result(
+            timeout=600
+        )
+        long = bt.submit("a companion's prompt", max_new_tokens=8)
+        while not bt.stats()["active_slots"]:
+            time.sleep(0.002)
+        one = bt.submit("ends at its first token", max_new_tokens=1)
+        assert one.result(timeout=600).num_tokens == 1
+        assert long.result(timeout=600).text == alone.text
+        while bt.stats()["dispatch_inflight"]:
+            time.sleep(0.002)
+        assert bt._pools[0].available == pages0
+        assert bt._states.available == slots0
+        assert not np.asarray(bt.cache.state.slot).any()
+        assert not np.asarray(bt.cache.length).any()
+        assert not np.asarray(bt.cache.page_table).any()
+    finally:
+        bt.close()
+
+
 @pytest.mark.parametrize(
     "kw,why",
     [

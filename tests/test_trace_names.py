@@ -82,8 +82,9 @@ class _Spy:
 def _spy_on(batcher, seen: set) -> None:
     """Put a spy before every step program of ``batcher``: the three
     jitted at construction, and the per-bucket families behind their
-    ``_*_fn`` getters."""
-    for attr in ("_jit_decode", "_jit_rounds", "_jit_spec"):
+    ``_*_fn`` getters — and the row patch of a fetch (PR 36), whose
+    module a trace shows between them."""
+    for attr in ("_jit_decode", "_jit_rounds", "_jit_spec", "_jit_apply_rows"):
         if hasattr(batcher, attr):
             setattr(batcher, attr, _Spy(getattr(batcher, attr), seen))
     for getter in ("_chunk_fn", "_fused_fn", "_chunk_fn_d"):
@@ -116,10 +117,11 @@ def _modules_of(params, prompts=PROMPTS, draft=None, **cfgkw) -> set:
     "cfgkw, with_draft, expected",
     [
         ({}, False,
-         {"jit_decode_step", "jit_fused_step", "jit_prefill_chunk"}),
-        ({"decode_rounds": 2}, False, {"jit_rounds_step"}),
+         {"jit_decode_step", "jit_fused_step", "jit_prefill_chunk",
+          "jit_apply_rows"}),
+        ({"decode_rounds": 2}, False, {"jit_rounds_step", "jit_apply_rows"}),
         ({"spec_k": 2}, True,
-         {"jit_verify_step", "jit_prefill_chunk_draft"}),
+         {"jit_verify_step", "jit_prefill_chunk_draft", "jit_apply_rows"}),
     ],
     ids=["chunked", "rounds", "draft"],
 )
@@ -160,7 +162,10 @@ def test_one_named_program_a_width_whatever_the_bucket(params):
         fused, chunk = dict(b._jit_fused), dict(b._jit_chunk)
     finally:
         b.close()
-    assert seen == {"jit_decode_step", "jit_fused_step", "jit_prefill_chunk"}
+    assert seen == {
+        "jit_decode_step", "jit_fused_step", "jit_prefill_chunk",
+        "jit_apply_rows",
+    }
     assert wide == 4
     # Called for three buckets and two widths ...
     assert {k[2] for k in keys["_fused_fn"]} >= {32, 64}
