@@ -29,6 +29,7 @@ from llm_consensus_tpu.backends.base import SamplingParams
 from llm_consensus_tpu.backends.fake import FakeBackend
 from llm_consensus_tpu.consensus.coordinator import Coordinator, CoordinatorConfig
 from llm_consensus_tpu.consensus.personas import default_panel, load_panel
+from llm_consensus_tpu.utils.tracing import PhaseClock
 
 log = logging.getLogger("llm_consensus_tpu")
 
@@ -112,35 +113,61 @@ def random_params(cfg, key, quant: str):
     return init_params_quantized(cfg, key, bits=8 if quant == "int8" else 4)
 
 
-def _build_backend(args):
+def _setup_clock() -> PhaseClock:
+    """The start-up phases' clock (PR 37), running from now: ``main``
+    makes it first thing, and the phases it times sum to the stretch
+    from there to the last one's end."""
+    from llm_consensus_tpu.server.metrics import SETUP_SECONDS
+
+    return PhaseClock(
+        "setup.", SETUP_SECONDS, ("runtime", "weights", "batcher", "gateway")
+    )
+
+
+def _build_backend(args, setup: PhaseClock | None = None):
+    """The backend ``args`` asks for; ``setup`` times its start-up
+    phases (a clock started here where the caller has none)."""
     if args.backend == "fake":
         return FakeBackend()
+    setup = setup or _setup_clock()
     # Local on-device inference ("local" = engine whole-batch programs,
     # "continuous" = token-level continuous batching over the paged
     # cache with shared-prefix CoW page tables + chunked prefill).
     # Import lazily: jax/device init is heavy
     # and the fake path must stay instant.
-    import jax
+    with setup.phase("runtime"):
+        require_tpu(args.cpu)
 
-    require_tpu(args.cpu)
+        from llm_consensus_tpu.server.metrics import COMPILE_SECONDS, COMPILES
+        from llm_consensus_tpu.utils.compile_cache import (
+            enable_compilation_cache,
+        )
+        from llm_consensus_tpu.utils.tracing import install_compile_listener
 
-    from llm_consensus_tpu.utils.compile_cache import enable_compilation_cache
-
-    enable_compilation_cache()
-
-    from llm_consensus_tpu.backends.local import LocalBackend
-    from llm_consensus_tpu.engine.engine import EngineConfig, InferenceEngine
-    from llm_consensus_tpu.engine.tokenizer import load_tokenizer
-    from llm_consensus_tpu.models.configs import get_config
-    from llm_consensus_tpu.models.transformer import init_params
-
+        enable_compilation_cache()
+        install_compile_listener(COMPILE_SECONDS, COMPILES)
     if getattr(args, "model_spec", None):
         if args.backend != "continuous":
             raise SystemExit(
                 "--model-spec needs --backend continuous (the "
                 "multi-model plane is built from continuous engines)"
             )
-        return _build_modelset_backend(args)
+        return _build_modelset_backend(args, setup)
+    with setup.phase("weights"):
+        cfg, params, draft, mesh = _weights(args)
+    with setup.phase("batcher"):
+        return _serving_backend(args, cfg, params, draft, mesh)
+
+
+def _weights(args):
+    """The model's config and weights (random, a checkpoint or HF),
+    any draft's, quantized and sharded as the backend will serve them:
+    ``(cfg, params, draft, mesh)``."""
+    import jax
+
+    from llm_consensus_tpu.models.configs import get_config
+    from llm_consensus_tpu.models.transformer import init_params
+
     if args.hf_checkpoint:
         from llm_consensus_tpu.models.hf_loader import (
             config_from_hf,
@@ -199,6 +226,24 @@ def _build_backend(args):
         params = shard_params(params, mesh)
         if draft is not None:
             draft = (draft[0], shard_params(draft[1], mesh))
+    if args.backend == "continuous" and args.quant != "none":
+        # Same weight-only quantization the engine path applies (paged
+        # decode + chunk prefill read QuantizedTensor leaves through
+        # ops.quant.matmul exactly like the dense programs). Checkpoint
+        # weights quantize here; random ones were born quantized and
+        # pass through untouched.
+        from llm_consensus_tpu.ops.quant import quantize_params
+
+        params = quantize_params(params, bits=8 if args.quant == "int8" else 4)
+    return cfg, params, draft, mesh
+
+
+def _serving_backend(args, cfg, params, draft, mesh):
+    """The engine, batcher or replica set over those weights."""
+    from llm_consensus_tpu.backends.local import LocalBackend
+    from llm_consensus_tpu.engine.engine import EngineConfig, InferenceEngine
+    from llm_consensus_tpu.engine.tokenizer import load_tokenizer
+
     if args.backend == "continuous":
         from llm_consensus_tpu.serving.continuous import (
             ContinuousBackend,
@@ -206,17 +251,6 @@ def _build_backend(args):
             ContinuousConfig,
         )
 
-        if args.quant != "none":
-            # Same weight-only quantization the engine path applies
-            # (paged decode + chunk prefill read QuantizedTensor leaves
-            # through ops.quant.matmul exactly like the dense programs).
-            # Checkpoint weights quantize here; random ones were born
-            # quantized and pass through untouched.
-            from llm_consensus_tpu.ops.quant import quantize_params
-
-            params = quantize_params(
-                params, bits=8 if args.quant == "int8" else 4
-            )
         if draft is not None and args.spec_k <= 0:
             raise SystemExit(
                 "--draft-model on --backend continuous needs --spec-k > 0 "
@@ -354,7 +388,7 @@ def _parse_model_spec(raw: str) -> dict[str, str]:
     return kv
 
 
-def _build_modelset_backend(args):
+def _build_modelset_backend(args, setup: PhaseClock):
     """Build the multi-model serving plane (PR 18) from --model-spec
     flags: one engine per member, cross-model draft pairings resolved
     through vocab alignment, one ModelSetBackend behind the gateway.
@@ -384,19 +418,20 @@ def _build_modelset_backend(args):
     for i, raw in enumerate(args.model_spec):
         kv = _parse_model_spec(raw)
         cfg = get_config(kv["preset"])
-        if kv.get("checkpoint"):
-            params = _load_checkpoint_params(cfg, kv["checkpoint"])
-        else:
-            log.warning(
-                "member %r: no checkpoint — RANDOM weights for %s "
-                "(plumbing only; text will be gibberish).",
-                kv["name"],
-                cfg.name,
-            )
-            # Distinct seed per member: two members of the same preset
-            # must not alias weights (their store scopes and consensus
-            # roles differ).
-            params = init_params(cfg, jax.random.PRNGKey(i))
+        with setup.phase("weights"):
+            if kv.get("checkpoint"):
+                params = _load_checkpoint_params(cfg, kv["checkpoint"])
+            else:
+                log.warning(
+                    "member %r: no checkpoint — RANDOM weights for %s "
+                    "(plumbing only; text will be gibberish).",
+                    kv["name"],
+                    cfg.name,
+                )
+                # Distinct seed per member: two members of the same
+                # preset must not alias weights (their store scopes and
+                # consensus roles differ).
+                params = init_params(cfg, jax.random.PRNGKey(i))
         pairs = bool(kv.get("draft_from"))
         config = ContinuousConfig(
             max_slots=int(kv.get("slots", args.serve_slots)),
@@ -435,7 +470,8 @@ def _build_modelset_backend(args):
                 control=control,
             )
         )
-    return ModelSetBackend(ModelSet(specs, default=args.model_default))
+    with setup.phase("batcher"):
+        return ModelSetBackend(ModelSet(specs, default=args.model_default))
 
 
 def _prefill_chunk_width(text: str) -> int:
@@ -1059,9 +1095,10 @@ def _parse_fleet_control(args):
     return cfg
 
 
-def _run_serve(argv: list[str]) -> int:
+def _run_serve(argv: list[str], setup: PhaseClock) -> int:
     """The ``serve`` subcommand: build backend + panel, run the gateway
-    until SIGTERM/SIGINT, then drain (stop admitting, finish in-flight)."""
+    until SIGTERM/SIGINT, then drain (stop admitting, finish in-flight).
+    ``setup`` times start-up until "gateway listening"."""
     import signal
 
     from llm_consensus_tpu.server.admission import AdmissionConfig
@@ -1086,7 +1123,7 @@ def _run_serve(argv: list[str]) -> int:
     _flight.flight_recorder().configure(capacity=args.flight_events)
     panel = load_panel(args.panel) if args.panel else default_panel()
     fleet_cfg = _parse_fleet_control(args)
-    backend = _build_backend(args)
+    backend = _build_backend(args, setup)
     # Fleet control plane (PR 19): one controller over the replica
     # fleet. Its config also seeds the gateway's SLO classes and
     # tenant weights (admission_kwargs below) so the two layers agree.
@@ -1111,34 +1148,35 @@ def _run_serve(argv: list[str]) -> int:
     if modelset is not None and args.model_lanes:
         priorities = priorities + modelset.admission_lanes()
     admission_kw = fleet_cfg.admission_kwargs() if fleet_cfg else {}
-    gateway = Gateway(
-        backend,
-        panel=panel,
-        config=GatewayConfig(
-            host=args.host,
-            port=args.port,
-            admission=AdmissionConfig(
-                priorities=priorities,
-                max_queue=args.queue_bound,
-                max_inflight=args.max_inflight,
-                default_deadline_s=args.default_deadline_s,
-                cost_budget_bytes=float(
-                    args.admission_cost_budget_mb << 20
+    with setup.phase("gateway"):
+        gateway = Gateway(
+            backend,
+            panel=panel,
+            config=GatewayConfig(
+                host=args.host,
+                port=args.port,
+                admission=AdmissionConfig(
+                    priorities=priorities,
+                    max_queue=args.queue_bound,
+                    max_inflight=args.max_inflight,
+                    default_deadline_s=args.default_deadline_s,
+                    cost_budget_bytes=float(
+                        args.admission_cost_budget_mb << 20
+                    ),
+                    **admission_kw,
                 ),
-                **admission_kw,
+                sampling=SamplingParams(
+                    max_new_tokens=args.max_new_tokens,
+                    temperature=args.temperature,
+                ),
+                max_rounds=args.max_rounds,
+                consensus_seed=args.seed,
+                ready_stall_s=args.ready_stall_s,
+                profile_dir=args.profile_dir,
+                peers=tuple(args.peer or ()),
+                fleet_obs=not args.no_fleet_obs,
             ),
-            sampling=SamplingParams(
-                max_new_tokens=args.max_new_tokens,
-                temperature=args.temperature,
-            ),
-            max_rounds=args.max_rounds,
-            consensus_seed=args.seed,
-            ready_stall_s=args.ready_stall_s,
-            profile_dir=args.profile_dir,
-            peers=tuple(args.peer or ()),
-            fleet_obs=not args.no_fleet_obs,
-        ),
-    )
+        )
     if fleet_controller is not None:
         # Burn-rate pressure (PR 20): give the controller a live view
         # of the admission tier's per-class SLO burn so _steer_elastic
@@ -1153,7 +1191,11 @@ def _run_serve(argv: list[str]) -> int:
                 loop.add_signal_handler(sig, stop.set)
             except NotImplementedError:  # non-Unix event loops
                 pass
-        await gateway.run_until(stop)
+        with setup.phase("gateway"):
+            await gateway.start()
+        setup.flush()
+        await stop.wait()
+        await gateway.drain()
 
     if fleet_controller is not None:
         fleet_controller.start()
@@ -1166,11 +1208,12 @@ def _run_serve(argv: list[str]) -> int:
 
 
 def main(argv: list[str] | None = None) -> int:
+    setup = _setup_clock()
     _init_logging()
     if argv is None:
         argv = sys.argv[1:]
     if argv[:1] == ["serve"]:
-        return _run_serve(argv[1:])
+        return _run_serve(argv[1:], setup)
     args = build_parser().parse_args(argv)
 
     if args.cpu:
@@ -1187,7 +1230,7 @@ def main(argv: list[str] | None = None) -> int:
         return _run_stream(args)
 
     panel = load_panel(args.panel) if args.panel else default_panel()
-    backend = _build_backend(args)
+    backend = _build_backend(args, setup)
     coord = Coordinator(
         panel,
         backend,

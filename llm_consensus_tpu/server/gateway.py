@@ -377,13 +377,6 @@ class Gateway:
             )
         log.info("gateway drained")
 
-    async def run_until(self, stop: asyncio.Event) -> None:
-        """Serve until ``stop`` is set, then drain. The serve CLI sets
-        ``stop`` from SIGTERM/SIGINT handlers."""
-        await self.start()
-        await stop.wait()
-        await self.drain()
-
     # -- connection handling --------------------------------------------
 
     async def _handle_conn(

@@ -56,6 +56,9 @@ __all__ = [
     "DISPATCH_INFLIGHT",
     "DEVICE_PROGRAMS",
     "BATCHER_PHASE_SECONDS",
+    "SETUP_SECONDS",
+    "COMPILE_SECONDS",
+    "COMPILES",
     "GENERATED_TOKENS",
     "PREFILL_TOKENS",
     "CHUNK_LANES",
@@ -635,6 +638,35 @@ DEVICE_PROGRAMS = REGISTRY.counter(
 BATCHER_PHASE_SECONDS = REGISTRY.counter(
     "gateway_batcher_phase_seconds_total",
     "Seconds of the continuous-batcher thread by loop phase",
+)
+#: Where start-up goes (PR 37), labeled ``phase="runtime"`` (from
+#: ``cli.main`` until the devices are up and the compile cache is on),
+#: ``"weights"`` (init or load, quantize, shard), ``"batcher"`` (the
+#: batcher or replica set: pools and jits), ``"gateway"`` (until
+#: "gateway listening") — a ``setup.<phase>`` annotation each, summing
+#: to the stretch from ``cli.main`` to listening — and ``"build_ahead"``
+#: (the batcher's ``_build_fused_ahead``, an annotation of that name on
+#: the batcher thread, also inside its ``batcher.dispatch``).
+SETUP_SECONDS = REGISTRY.counter(
+    "gateway_setup_seconds_total",
+    "Seconds of start-up by phase, and of the batcher's build-ahead",
+)
+#: Every XLA program the process builds (PR 37), from jax's own compile
+#: events: seconds by ``stage="trace"|"lower"|"backend"`` (``backend``
+#: is a compile or a load from the persistent cache) and ``program``
+#: (the jitted function's name, ``fused_step`` for ``jit_fused_step``).
+#: Outermost seconds only: a function traced inside another one's trace
+#: adds nothing, so no stage exceeds the wall time of the threads that
+#: compile. Each span is also a ``compile.<stage>`` annotation.
+COMPILE_SECONDS = REGISTRY.counter(
+    "gateway_compile_seconds_total",
+    "Seconds spent tracing, lowering and compiling programs, by stage",
+)
+#: One a backend compile, by ``program`` and ``cache="hit"|"miss"``
+#: (``hit``: loaded from the persistent compile cache).
+COMPILES = REGISTRY.counter(
+    "gateway_compiles_total",
+    "Backend compiles of XLA programs, by persistent-cache hit or miss",
 )
 #: Output tokens credited to live rows, counted where they are
 #: credited: the first at activation (sampled from prefill logits), the

@@ -83,7 +83,6 @@ is the TPU-native throughput-serving counterpart.
 
 from __future__ import annotations
 
-import contextlib
 import base64
 import functools
 import itertools
@@ -304,6 +303,9 @@ from llm_consensus_tpu.server.metrics import (
 )
 from llm_consensus_tpu.server.metrics import (
     PIPELINE_DRAINS as _M_PIPELINE_DRAINS,
+)
+from llm_consensus_tpu.server.metrics import (
+    SETUP_SECONDS as _M_SETUP_SECONDS,
 )
 from llm_consensus_tpu.utils import tracing as _tracing
 
@@ -1330,16 +1332,13 @@ class ContinuousBatcher:
         self._sched_overhead_sum = 0.0
         self._sched_overhead_count = 0
         self._last_step_end: float | None = None
-        # Loop phases (PR 26; worker thread only): seconds gathered
-        # since the last flush into gateway_batcher_phase_seconds_total,
-        # the phases open right now (innermost last), and the clock at
-        # the last phase boundary.
-        self._phase_s = dict.fromkeys(_PHASES, 0.0)
-        self._phase_children = {
-            name: _M_PHASE_SECONDS.labels(phase=name) for name in _PHASES
-        }
-        self._phase_open: list[str] = []
-        self._phase_t = time.perf_counter()
+        # Loop phases (PR 26; worker thread only): ``self._phase(name)``
+        # opens one, and the loop flushes their seconds into
+        # gateway_batcher_phase_seconds_total once an iteration.
+        self._phases = _tracing.PhaseClock(
+            "batcher.", _M_PHASE_SECONDS, _PHASES
+        )
+        self._phase = self._phases.phase
         # Liveness heartbeat: stamped at the top of every host-loop
         # iteration (the idle loop ticks at >= 10 Hz), and after each
         # decode step. The gateway's readiness probe compares the tick
@@ -1432,43 +1431,6 @@ class ContinuousBatcher:
             target=self._run, name="continuous-batcher", daemon=True
         )
         self._thread.start()
-
-    @contextlib.contextmanager
-    def _phase(self, name: str, **meta):
-        """One stretch of the batcher thread under a phase name, twice
-        over from the same two clock reads: a ``batcher.<name>``
-        ``jax.profiler.TraceAnnotation`` (the profiler's host plane, on
-        the device planes' clock; one flag check when no profile is
-        being taken; ``meta`` rides as the event's stats), and seconds
-        towards ``gateway_batcher_phase_seconds_total{phase=name}``.
-
-        Annotations nest as they ran; the seconds never overlap. The
-        clock runs for the innermost open phase only, so an outer phase
-        leaves out what ran inside it, and the time between two phases
-        (the loop's own branches) goes to the one that opens next: over
-        any stretch of the thread's life the phases sum to its length.
-        Worker thread only."""
-        open_ = self._phase_open
-        now = time.perf_counter()
-        self._phase_s[open_[-1] if open_ else name] += now - self._phase_t
-        self._phase_t = now
-        open_.append(name)
-        try:
-            with jax.profiler.TraceAnnotation("batcher." + name, **meta):
-                yield
-        finally:
-            now = time.perf_counter()
-            open_.pop()
-            self._phase_s[name] += now - self._phase_t
-            self._phase_t = now
-
-    def _flush_phases(self) -> None:
-        """Move the gathered phase seconds into the counter family:
-        once per loop iteration, not once per phase."""
-        for name, seconds in self._phase_s.items():
-            if seconds:
-                self._phase_children[name].inc(seconds)
-                self._phase_s[name] = 0.0
 
     def _named(self, spec) -> "object":
         """NamedSharding over this batcher's mesh for an axis tuple."""
@@ -2408,12 +2370,29 @@ class ContinuousBatcher:
         at once, or rows first group, with every decoding row stalled
         behind it; a chunk width or MoE pin first met under load is
         still built there. Lowering the jitted function from shapes
-        fills the caches its call reads, and runs nothing."""
+        fills the caches its call reads, and runs nothing.
+
+        The builds are one ``build_ahead`` span: an annotation on this
+        thread, inside the ``batcher.dispatch`` that called it (whose
+        seconds it leaves alone), and seconds towards
+        ``gateway_setup_seconds_total{phase="build_ahead"}``; the compile
+        spans it causes nest inside it."""
         ahead, _ = self._chunk_key(chunk, 0, s_bucket)
         if ahead in self._fused_ahead or self._plain_shapes is None:
             return
         self._fused_ahead.add(ahead)
-        t0 = time.perf_counter()
+        span = _tracing.PhaseClock("", _M_SETUP_SECONDS, ("build_ahead",))
+        with span.phase("build_ahead"):
+            widths = self._build_ahead(chunk, s_bucket)
+        log.info(
+            "fused step programs for chunks of %d, lanes %s, grouped and "
+            "not: built in %.1f s", chunk, widths, span.seconds["build_ahead"],
+        )
+        span.flush()
+
+    def _build_ahead(self, chunk: int, s_bucket: int) -> list[int]:
+        """The builds of :meth:`_build_fused_ahead`; returns the lane
+        widths built."""
         c = self.config
         i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
         f32 = partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
@@ -2454,10 +2433,7 @@ class ContinuousBatcher:
             i32((c.max_slots, c.pages_per_seq)), i32((c.max_slots,)),
             i32((c.max_slots,)),
         ).compile()
-        log.info(
-            "fused step programs for chunks of %d, lanes %s, grouped and "
-            "not: built in %.1f s", chunk, widths, time.perf_counter() - t0,
-        )
+        return widths
 
     @property
     def _fused_ok(self) -> bool:
@@ -5479,7 +5455,7 @@ class ContinuousBatcher:
     def _serve_loop(self) -> None:
         while not self._stop.is_set():
             self._hb_tick = time.monotonic()
-            self._flush_phases()
+            self._phases.flush()
             # Fleet requests first (PR 14): preemption frees pages the
             # admission below may need; exports are bounded spills.
             with self._phase("admit"):

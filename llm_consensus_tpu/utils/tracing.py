@@ -26,6 +26,20 @@ poll, ``src/main.rs:449-454``; SURVEY.md §5). Two layers here:
   (``perf_counter``) on the profile's clock, beside the device planes
   and the batcher's own ``batcher.<phase>`` annotations.
 
+- :class:`PhaseClock` (PR 26's batcher phases, PR 37's start-up) —
+  one thread's stretches under phase names, each a
+  ``jax.profiler.TraceAnnotation`` and seconds towards a counter from
+  the same two clock reads; and :func:`install_compile_listener` (PR
+  37), which turns the compile events jax emits into spans by program
+  and stage (``trace``, ``lower``, ``backend``).
+
+**One clock.** Every span here, the batcher's phases and the request
+spans alike, is on ``time.perf_counter``. On Linux CPython implements it
+with ``clock_gettime(CLOCK_MONOTONIC)``, the clock of ``time.monotonic``
+that the benchmark's parent stamps its window with, so a span has a
+place on that timeline and, through ``profile.anchor``, on a device
+trace, without another anchor.
+
 Process-wide tracing can be disabled entirely (:func:`set_enabled`,
 ``serve --no-trace``): :meth:`TraceStore.start` then returns ``None``
 and every downstream call site degrades to a no-op.
@@ -421,3 +435,178 @@ def trace_jax_profile(logdir: str):
         yield
     finally:
         jax.profiler.stop_trace()
+
+
+# ---------------------------------------------------------------------------
+# Phases of one thread, and the programs the process builds (PR 37)
+# ---------------------------------------------------------------------------
+
+
+class PhaseClock:
+    """One thread's stretches under phase names, twice over from the
+    same two clock reads: a ``<prefix><name>``
+    ``jax.profiler.TraceAnnotation`` (the profiler's host plane, on the
+    device planes' clock; one flag check when no profile is being
+    taken; ``meta`` rides as the event's stats), and seconds towards
+    ``family{phase=name}``.
+
+    Annotations nest as they ran; the seconds never overlap. The clock
+    runs for the innermost open phase only, so an outer phase leaves out
+    what ran inside it, and the time between two phases goes to the one
+    that opens next: from the clock's start (``t0``, else construction)
+    the phases sum to the stretch they cover. Seconds gather in
+    :attr:`seconds` until :meth:`flush` moves them into the family. One
+    thread only: the clock keeps no lock."""
+
+    def __init__(self, prefix: str, family, names, t0: float | None = None):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._prefix = prefix
+        self.seconds = dict.fromkeys(names, 0.0)
+        self._children = {name: family.labels(phase=name) for name in names}
+        self._open: list[str] = []
+        self._t = time.perf_counter() if t0 is None else t0
+
+    @contextlib.contextmanager
+    def phase(self, name: str, **meta):
+        open_ = self._open
+        now = time.perf_counter()
+        self.seconds[open_[-1] if open_ else name] += now - self._t
+        self._t = now
+        open_.append(name)
+        try:
+            with self._annotation(self._prefix + name, **meta):
+                yield
+        finally:
+            now = time.perf_counter()
+            open_.pop()
+            self.seconds[name] += now - self._t
+            self._t = now
+
+    def flush(self) -> None:
+        """Move the gathered seconds into the family."""
+        for name, seconds in self.seconds.items():
+            if seconds:
+                self._children[name].inc(seconds)
+                self.seconds[name] = 0.0
+
+
+# jax's compile events (jax 0.9: ``dispatch.log_elapsed_time`` emits the
+# start as a scalar and the end as a duration, on the thread that
+# compiles) and the stage each one is.
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # A compile, or a load from the persistent cache.
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+@dataclass(slots=True)
+class _CompileSpan:
+    program: str
+    stage: str
+    start: float  # perf_counter
+    parent: "_CompileSpan | None"  # the enclosing span of this thread
+    annotation: object
+    hit: bool = False
+
+
+def _program(fun_name: str) -> str:
+    """``fused_step`` for both ``fused_step`` (a trace) and
+    ``jit(fused_step)`` (its lowering and backend compile)."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name
+
+
+class _CompileListener:
+    """Spans from jax's compile events, kept per thread while open.
+
+    A span that ends while an enclosing one of the same thread is open
+    (a jitted ``jnp`` function traced inside the step that calls it) is
+    that span's child and adds no seconds: only outermost spans count,
+    so a stage's seconds never exceed the thread's wall seconds. Each
+    span is also a ``compile.<stage>`` annotation with a ``program``
+    stat, nested in whatever phase of the thread was open."""
+
+    def __init__(self, seconds, compiles):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._seconds = seconds
+        self._compiles = compiles
+        self._local = threading.local()
+
+    def _stack(self) -> list:
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
+
+    def start(self, event: str, value, **kw) -> None:
+        stage = _COMPILE_STAGES.get(event)
+        if stage is None:
+            return
+        program = _program(str(kw.get("fun_name", "")))
+        stack = self._stack()
+        annotation = self._annotation("compile." + stage, program=program)
+        annotation.__enter__()
+        stack.append(_CompileSpan(
+            program, stage, time.perf_counter(),
+            stack[-1] if stack else None, annotation,
+        ))
+
+    def end(self, event: str, duration: float, **kw) -> None:
+        stage = _COMPILE_STAGES.get(event)
+        if stage is None:
+            return
+        now = time.perf_counter()
+        stack = self._stack()
+        program = _program(str(kw.get("fun_name", "")))
+        if not stack or (stack[-1].stage, stack[-1].program) != (
+            stage, program
+        ):
+            return  # began before the listener was installed
+        span = stack.pop()
+        span.annotation.__exit__(None, None, None)
+        if span.parent is None:
+            self._seconds.labels(stage=stage, program=program).inc(
+                now - span.start
+            )
+        if stage == "backend":
+            self._compiles.labels(
+                program=program, cache="hit" if span.hit else "miss"
+            ).inc()
+
+    def event(self, event: str, **kw) -> None:
+        if event == _CACHE_HIT:
+            stack = self._stack()
+            if stack and stack[-1].stage == "backend":
+                stack[-1].hit = True
+
+
+_LISTENER: _CompileListener | None = None
+_LISTENER_LOCK = threading.Lock()
+
+
+def install_compile_listener(seconds, compiles) -> None:
+    """Count every XLA program this process builds from here on:
+    outermost seconds into ``seconds{stage, program}`` and one
+    ``compiles{program, cache}`` a backend span (``cache="hit"`` when
+    it was a load from the persistent cache). Once a process; a second
+    call changes nothing. The listener runs only while something is
+    traced, lowered or compiled, so a warm loop pays nothing."""
+    global _LISTENER
+    from jax import monitoring
+
+    with _LISTENER_LOCK:
+        if _LISTENER is not None:
+            return
+        _LISTENER = _CompileListener(seconds, compiles)
+        monitoring.register_scalar_listener(_LISTENER.start)
+        monitoring.register_event_duration_secs_listener(_LISTENER.end)
+        monitoring.register_event_listener(_LISTENER.event)

@@ -267,13 +267,14 @@ def test_serve_parser_defaults_and_dispatch(monkeypatch):
 
     seen = {}
 
-    def fake_run(argv):
-        seen["argv"] = argv
+    def fake_run(argv, setup):
+        seen["argv"], seen["setup"] = argv, setup
         return 0
 
     monkeypatch.setattr(cli, "_run_serve", fake_run)
     assert main(["serve", "--port", "0"]) == 0
     assert seen["argv"] == ["--port", "0"]
+    assert isinstance(seen["setup"], cli.PhaseClock)
 
 
 def test_serve_refuses_prefill_chunk_zero(capsys):

@@ -566,3 +566,301 @@ def test_check_metrics_detects_undeclared_family(tmp_path):
     )
     assert r.returncode == 1
     assert "gateway_rogue_total" in r.stderr
+
+
+# ---------------------------------------------------------------------------
+# Start-up and compile spans (PR 37)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def compile_listener():
+    from llm_consensus_tpu.server import metrics as M
+
+    tracing.install_compile_listener(M.COMPILE_SECONDS, M.COMPILES)
+    return M
+
+
+def _samples(family) -> dict:
+    return {k: c.value for k, c in list(family._children.items())}
+
+
+def _grew(before: dict, after: dict) -> dict:
+    return {
+        dict(k).get("program", "") + "/" + (
+            dict(k).get("stage") or dict(k).get("cache")
+        ): v - before.get(k, 0.0)
+        for k, v in after.items() if v != before.get(k, 0.0)
+    }
+
+
+def test_a_jitted_program_counts_one_trace_of_its_own(compile_listener):
+    """The ``jnp`` functions it calls are traced inside its trace: they
+    are its children and add no seconds, so the stages of one program
+    sum to no more than the wall time its first call took."""
+    import jax
+    import jax.numpy as jnp
+
+    M = compile_listener
+
+    @jax.jit
+    def pr37_probe_step(x, w):
+        return jnp.sin(x) @ w + jnp.cos(w)
+
+    x = jnp.ones((8, 8))
+    s0, c0 = _samples(M.COMPILE_SECONDS), _samples(M.COMPILES)
+    t0 = time.perf_counter()
+    pr37_probe_step(x, x).block_until_ready()
+    wall = time.perf_counter() - t0
+    grew = _grew(s0, _samples(M.COMPILE_SECONDS))
+    assert set(grew) == {
+        "pr37_probe_step/trace", "pr37_probe_step/lower",
+        "pr37_probe_step/backend",
+    }, grew
+    assert 0 < sum(grew.values()) <= wall
+    assert _grew(c0, _samples(M.COMPILES)) == {"pr37_probe_step/miss": 1}
+
+
+def test_a_cached_program_adds_nothing(compile_listener):
+    import jax
+    import jax.numpy as jnp
+
+    M = compile_listener
+    f = jax.jit(lambda a: a * 3.0)
+    x = jnp.ones((4,))
+    f(x).block_until_ready()
+    s0, c0 = _samples(M.COMPILE_SECONDS), _samples(M.COMPILES)
+    f(x).block_until_ready()
+    assert _samples(M.COMPILE_SECONDS) == s0
+    assert _samples(M.COMPILES) == c0
+
+
+def test_lower_then_compile_is_counted(compile_listener):
+    """The build-ahead's way of building: ``.lower(shapes).compile()``."""
+    import jax
+    import jax.numpy as jnp
+
+    M = compile_listener
+
+    def pr37_ahead(a, b):
+        return jnp.tanh(a) * b
+
+    shape = jax.ShapeDtypeStruct((16, 16), jnp.float32)
+    s0, c0 = _samples(M.COMPILE_SECONDS), _samples(M.COMPILES)
+    jax.jit(pr37_ahead).lower(shape, shape).compile()
+    grew = _grew(s0, _samples(M.COMPILE_SECONDS))
+    assert set(grew) == {
+        "pr37_ahead/trace", "pr37_ahead/lower", "pr37_ahead/backend",
+    }, grew
+    assert _grew(c0, _samples(M.COMPILES)) == {"pr37_ahead/miss": 1}
+
+
+def test_a_load_from_the_persistent_cache_counts_a_hit(
+    compile_listener, tmp_path
+):
+    """A CPU compile is under jax's 1 s threshold for writing an entry,
+    so the test lowers the threshold to 0 (as the benchmark's server
+    does) around a cache of its own."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    M = compile_listener
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+    )
+    saved = {n: getattr(jax.config, n) for n in names}
+    try:
+        cc.reset_cache()
+        jax.config.update(names[0], str(tmp_path))
+        jax.config.update(names[1], 0)
+
+        def pr37_cached(a):
+            return jnp.exp(a) - 1.0
+
+        c0 = _samples(M.COMPILES)
+        x = jnp.ones((32,))
+        jax.jit(pr37_cached)(x).block_until_ready()
+        jax.clear_caches()
+        jax.jit(pr37_cached)(x).block_until_ready()
+        grew = _grew(c0, _samples(M.COMPILES))
+        assert {k: v for k, v in grew.items() if "pr37" in k} == {
+            "pr37_cached/miss": 1, "pr37_cached/hit": 1,
+        }
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)
+        cc.reset_cache()
+
+
+def test_phases_sum_to_the_stretch_they_cover():
+    """Nested phases stop the outer one's clock and the gaps between
+    phases go to the next one: the seconds sum to the wall time from the
+    clock's start to the last phase's end, and each is flushed once."""
+    fam = MetricsRegistry().counter("setup_seconds")
+    t0 = time.perf_counter()
+    clock = tracing.PhaseClock("setup.", fam, ("a", "b", "c"), t0=t0)
+    time.sleep(0.02)
+    with clock.phase("a"):
+        time.sleep(0.02)
+        with clock.phase("b"):
+            time.sleep(0.03)
+    time.sleep(0.01)
+    with clock.phase("c", note=1):
+        time.sleep(0.02)
+    wall = time.perf_counter() - t0
+    seconds = dict(clock.seconds)
+    assert seconds["b"] >= 0.03 and seconds["c"] >= 0.03
+    assert seconds["a"] >= 0.04 and seconds["a"] < 0.04 + 0.03
+    assert sum(seconds.values()) == pytest.approx(wall, rel=0.01)
+    clock.flush()
+    assert clock.seconds == {"a": 0.0, "b": 0.0, "c": 0.0}
+    assert {p: fam.labels(phase=p).value for p in "abc"} == seconds
+
+
+def _old_batcher_phase(state: dict, name: str):
+    """``ContinuousBatcher._phase`` as it was before PR 37 (its
+    annotation aside): the reference the helper must reproduce."""
+    open_ = state["open"]
+    now = time.perf_counter()
+    state["s"][open_[-1] if open_ else name] += now - state["t"]
+    state["t"] = now
+    open_.append(name)
+    yield
+    now = time.perf_counter()
+    open_.pop()
+    state["s"][name] += now - state["t"]
+    state["t"] = now
+
+
+def test_the_batcher_phase_clock_sums_as_before(monkeypatch):
+    """The same loop of phases, nested as the batcher nests them, on a
+    clock that ticks 1, 2, 3, ...: the helper gathers exactly the seconds
+    the old ``_phase`` did, phase by phase."""
+    import contextlib
+    import itertools
+
+    from llm_consensus_tpu.serving.continuous import _PHASES
+
+    def run(open_phase):
+        for _ in range(3):
+            with open_phase("admit"):
+                pass
+            with open_phase("dispatch"):
+                with open_phase("device_wait"):
+                    pass
+            with open_phase("retire"):
+                with open_phase("device_wait"):
+                    pass
+            with open_phase("idle"):
+                pass
+
+    ticks = itertools.count(1)
+    monkeypatch.setattr(time, "perf_counter", lambda: float(next(ticks)))
+    fam = MetricsRegistry().counter("phases")
+    clock = tracing.PhaseClock("batcher.", fam, _PHASES)
+    run(clock.phase)
+    new = dict(clock.seconds)
+
+    ticks = itertools.count(1)
+    state = {"s": dict.fromkeys(_PHASES, 0.0), "open": [],
+             "t": time.perf_counter()}
+    run(lambda name: contextlib.contextmanager(_old_batcher_phase)(
+        state, name))
+    assert new == state["s"]
+    assert new["device_wait"] > 0 and new["restore"] == 0.0
+
+
+def test_build_ahead_is_a_setup_span(tiny_batcher, compile_listener, caplog):
+    """The build-ahead's seconds go to
+    ``gateway_setup_seconds_total{phase="build_ahead"}`` and its "built
+    in" line prints them; the compiles it causes fit inside it."""
+    import logging
+
+    M = compile_listener
+    tiny_batcher.submit("warm the plain step", max_new_tokens=2).result(
+        timeout=300
+    )
+    ahead = M.SETUP_SECONDS.labels(phase="build_ahead")
+    a0, s0 = ahead.value, sum(_samples(M.COMPILE_SECONDS).values())
+    with caplog.at_level(logging.INFO, "llm_consensus_tpu.serving"):
+        # A chunk width this batcher has not built for.
+        tiny_batcher._build_fused_ahead(16, 32)
+    spent = ahead.value - a0
+    compiled = sum(_samples(M.COMPILE_SECONDS).values()) - s0
+    assert 0 < compiled <= spent
+    line = [r.getMessage() for r in caplog.records if "built in" in r.message]
+    assert line == [
+        f"fused step programs for chunks of 16, lanes "
+        f"{sorted({1, tiny_batcher._lanes_for(16)})}, grouped and not: "
+        f"built in {spent:.1f} s"
+    ]
+
+
+def test_perf_counter_is_the_monotonic_clock():
+    """Every span is on ``perf_counter``; the benchmark stamps its
+    window with ``monotonic``. On Linux both read one clock."""
+    if not sys.platform.startswith("linux"):
+        pytest.skip("the one-clock guarantee is CPython's on Linux")
+    pc, mono = time.get_clock_info("perf_counter"), time.get_clock_info(
+        "monotonic")
+    assert pc.implementation == mono.implementation
+    assert "CLOCK_MONOTONIC" in pc.implementation
+
+
+_TEXT_WITH = """\
+# HELP gateway_setup_seconds_total Seconds of start-up by phase
+# TYPE gateway_setup_seconds_total counter
+gateway_setup_seconds_total{phase="batcher"} 2.5
+gateway_setup_seconds_total{phase="weights"} 14.0
+# TYPE gateway_compile_seconds_total counter
+gateway_compile_seconds_total{program="fused_step",stage="trace"} 3.0
+gateway_compile_seconds_total{program="fused_step",stage="lower"} 1.5
+"""
+_TEXT_LATER = _TEXT_WITH.replace('stage="lower"} 1.5', 'stage="lower"} 2.0')
+_TEXT_WITHOUT = "# TYPE gateway_requests_total counter\n"
+
+
+@pytest.fixture
+def counter_value(monkeypatch):
+    """The benchmark's reader, imported as ``benchmark/run.py`` does."""
+    import importlib
+
+    modules = ("server", "stats", "layer_metrics",
+               "layer_metrics.counter_value")
+    monkeypatch.syspath_prepend(str(ROOT / "benchmark"))
+    for name in modules:
+        monkeypatch.delitem(sys.modules, name, raising=False)
+    yield importlib.import_module("layer_metrics.counter_value")
+    for name in modules:
+        sys.modules.pop(name, None)
+
+
+@pytest.mark.parametrize(
+    "before, after, counters, at, want",
+    [
+        # A program without the family: nothing, never 0.
+        (_TEXT_WITHOUT, _TEXT_WITHOUT,
+         [["gateway_setup_seconds_total", 'phase="weights"']], "open", None),
+        (_TEXT_WITH, _TEXT_WITHOUT,
+         [["gateway_compile_seconds_total", ""]], "window", None),
+        # Present and unmoved: 0.
+        (_TEXT_WITH, _TEXT_WITH,
+         [["gateway_compile_seconds_total", ""]], "window", 0.0),
+        (_TEXT_WITH, _TEXT_LATER,
+         [["gateway_setup_seconds_total", 'phase="weights"']], "open", 14.0),
+        (_TEXT_WITH, _TEXT_LATER,
+         [["gateway_compile_seconds_total", ""]], "open", 4.5),
+        (_TEXT_WITH, _TEXT_LATER,
+         [["gateway_compile_seconds_total", ""]], "window", 0.5),
+    ],
+    ids=["absent-open", "absent-after", "unmoved", "open-one-label",
+         "open-family", "window"],
+)
+def test_counter_value_reader(counter_value, before, after, counters, at,
+                              want):
+    run = type("Run", (), {"metrics_before": before,
+                           "metrics_after": after})()
+    got = counter_value.read(run, counters=counters, at=at)
+    assert got == (None if want is None else pytest.approx(want))
