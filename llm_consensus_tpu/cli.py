@@ -138,14 +138,18 @@ def _build_backend(args, setup: PhaseClock | None = None):
     with setup.phase("runtime"):
         require_tpu(args.cpu)
 
-        from llm_consensus_tpu.server.metrics import COMPILE_SECONDS, COMPILES
+        from llm_consensus_tpu.server.metrics import (
+            COMPILE_SECONDS,
+            COMPILES,
+            EXECUTABLES,
+        )
         from llm_consensus_tpu.utils.compile_cache import (
             enable_compilation_cache,
         )
         from llm_consensus_tpu.utils.tracing import install_compile_listener
 
         enable_compilation_cache()
-        install_compile_listener(COMPILE_SECONDS, COMPILES)
+        install_compile_listener(COMPILE_SECONDS, COMPILES, EXECUTABLES)
     if getattr(args, "model_spec", None):
         if args.backend != "continuous":
             raise SystemExit(

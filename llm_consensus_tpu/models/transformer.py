@@ -57,6 +57,7 @@ from llm_consensus_tpu.ops.norms import rms_norm
 from llm_consensus_tpu.ops.quant import matmul as _qmm
 from llm_consensus_tpu.ops.quant import maybe_dequantize as _w
 from llm_consensus_tpu.ops.rope import apply_rope, apply_rope_tail, rope_cos_sin
+from llm_consensus_tpu.utils.compile_cache import StoredJit
 
 
 def _local_kernels(cfg: ModelConfig, mesh) -> bool:
@@ -256,11 +257,19 @@ def init_params(
         k = next(keys)
         axis = quant_axis(name, len(shape)) if quant_bits else None
         if axis is not None:
-            return _init_quantized_leaf(
+            w = _init_quantized_leaf(
                 k, shape, scale, axis, quant_bits, dtype, pad
             )
-        w = (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
-        return w if pad is None else _zero_pad(w, *pad)
+        else:
+            w = (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+            w = w if pad is None else _zero_pad(w, *pad)
+        if quant_bits:
+            # One leaf at a time: the runtime takes a program's buffers
+            # when it is enqueued, so a draw enqueued behind another (a
+            # program loaded, not traced, leaves no gap between them)
+            # would hold both one's float temporaries at once.
+            jax.block_until_ready(w)
+        return w
 
     L, D, H, Hkv, F, V = (
         cfg.n_layers,
@@ -498,7 +507,7 @@ def _init_plan_stack(cfg, kind, n, normal, key, resid_scale, dtype):
     return blocks
 
 
-@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
+@partial(StoredJit, static_argnums=(1, 2, 3, 4, 5, 6))
 def _init_quantized_leaf(key, shape, scale, axis, bits, dtype, pad=None):
     """One quantized weight leaf whose float form never exists whole.
 

@@ -59,6 +59,7 @@ __all__ = [
     "SETUP_SECONDS",
     "COMPILE_SECONDS",
     "COMPILES",
+    "EXECUTABLES",
     "GENERATED_TOKENS",
     "PREFILL_TOKENS",
     "CHUNK_LANES",
@@ -667,6 +668,15 @@ COMPILE_SECONDS = REGISTRY.counter(
 COMPILES = REGISTRY.counter(
     "gateway_compiles_total",
     "Backend compiles of XLA programs, by persistent-cache hit or miss",
+)
+#: One a program the store of executables hands out
+#: (``utils.compile_cache.StoredJit``), by ``program`` and
+#: ``source="loaded"|"built"`` (``built``: traced, lowered and compiled,
+#: then written to the store). A load's seconds are
+#: ``gateway_compile_seconds_total{stage="load"}``.
+EXECUTABLES = REGISTRY.counter(
+    "gateway_executables_total",
+    "Programs from the store of executables, loaded or built",
 )
 #: Output tokens credited to live rows, counted where they are
 #: credited: the first at activation (sampled from prefill logits), the

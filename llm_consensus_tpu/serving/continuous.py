@@ -85,6 +85,7 @@ from __future__ import annotations
 
 import base64
 import functools
+import hashlib
 import itertools
 import logging
 import threading
@@ -107,6 +108,7 @@ from llm_consensus_tpu.engine.sampler import (
     stop_scan_hit,
 )
 from llm_consensus_tpu.engine.tokenizer import ByteTokenizer, Tokenizer
+from llm_consensus_tpu.utils.compile_cache import StoredJit
 from llm_consensus_tpu.utils.stops import (
     VisibleIdFilter,
     derived_stop_screen,
@@ -946,6 +948,9 @@ class ContinuousBatcher:
         self._mp = 1
         self._row_sharding = None
         self._refuse_unsupported(cfg, self._draft_cfg, mesh, host_store)
+        # What this batcher's programs read besides their arguments: the
+        # store of executables keys every one of them on it.
+        self._key = self._program_key()
         # The ragged attention kernel runs compiled (or interpreted) iff
         # the config asks for it and, on a mesh, the mesh can shard it.
         attn_kernel = bool(cfg.use_pallas) and (
@@ -1355,16 +1360,18 @@ class ContinuousBatcher:
         self._work = threading.Event()
         # params ride as a jit argument (not a closure constant) so the
         # weights aren't baked into the executable.
-        self._jit_decode = jax.jit(
+        self._jit_decode = StoredJit(
             _step_program("decode_step", self._decode_sample),
+            key=self._key,
             donate_argnums=(1,),
             static_argnums=(8,),
         )
         # Multi-round decode program (PR 12): rounds is static (the
         # scan length; two cached traces per variant — R, and the
         # stop-bound 1), filters_active as in _jit_decode.
-        self._jit_rounds = jax.jit(
+        self._jit_rounds = StoredJit(
             _step_program("rounds_step", self._rounds_sample),
+            key=self._key,
             donate_argnums=(2,),
             static_argnums=(0, 9),
         )
@@ -1386,17 +1393,17 @@ class ContinuousBatcher:
         # (:meth:`_build_fused_ahead`).
         self._fused_ahead: set[tuple] = set()
         self._plain_shapes: tuple | None = None
-        self._jit_copy_page = jax.jit(copy_page, donate_argnums=(0,))
-        self._jit_install_page = jax.jit(install_page, donate_argnums=(0,))
+        self._jit_copy_page = StoredJit(copy_page, donate_argnums=(0,))
+        self._jit_install_page = StoredJit(install_page, donate_argnums=(0,))
         # Batched restore install (PR 17): one scatter per restore
-        # BATCH — jit caches one trace per batch size actually seen
+        # BATCH — one executable per batch size actually seen
         # (1 and the controller's restore_batch, in practice).
-        self._jit_install_pages = jax.jit(
+        self._jit_install_pages = StoredJit(
             install_pages, donate_argnums=(0,)
         )
         # The row patch of a fetch (PR 36): page tables, lengths and
         # state slots in and out, what the host gathered as numpy.
-        self._jit_apply_rows = jax.jit(
+        self._jit_apply_rows = StoredJit(
             _step_program("apply_rows", apply_rows)
         )
         self._row_patch: _RowPatch | None = None
@@ -1414,15 +1421,16 @@ class ContinuousBatcher:
             self._spec_cfg = cfg.moe_pin_for(
                 c.max_slots, c.max_slots * (c.spec_k + 1)
             )
-            self._jit_spec = jax.jit(
+            self._jit_spec = StoredJit(
                 _step_program("verify_step", self._spec_sample),
+                key=self._key,
                 static_argnums=(0, 11, 12),
                 donate_argnums=(3, 4),
             )
             self._jit_chunk_d = {}  # (chunk, s_bucket) -> draft chunk
             # Draft-pool copy/install ride _jit_copy_page /
-            # _jit_install_page: jit caches per input shape, so the
-            # draft planes just add a second cached trace.
+            # _jit_install_page: one executable per input shape, so the
+            # draft planes just add a second one.
         # Round-robin pointer over prefilling slots (fairness when
         # several prompts fill concurrently).
         self._prefill_rr = 0
@@ -1431,6 +1439,23 @@ class ContinuousBatcher:
             target=self._run, name="continuous-batcher", daemon=True
         )
         self._thread.start()
+
+    def _program_key(self) -> tuple:
+        """What the step programs read besides their arguments, as text:
+        the model (kernel choice resolved), the batcher's configuration,
+        the draft, the mesh and its devices, the tokenizer's EOS id and
+        the cross-model remap tables (trace constants)."""
+        mesh = self.mesh
+        tables = [
+            None if t is None
+            else hashlib.sha256(np.asarray(t).tobytes()).hexdigest()
+            for t in (self._t2d, self._d2t)
+        ]
+        return (
+            repr(self.cfg), repr(self.config), repr(self._draft_cfg),
+            None if mesh is None else (repr(mesh), mesh.device_ids.tolist()),
+            self.tokenizer.eos_id, tables,
+        )
 
     def _named(self, spec) -> "object":
         """NamedSharding over this batcher's mesh for an axis tuple."""
@@ -1502,8 +1527,9 @@ class ContinuousBatcher:
         )
         if self.mesh is None:
             return create()
-        return jax.jit(
-            create, out_shardings=self._pool_sharding_for(cfg)
+        return StoredJit(
+            create, program="create_pool", key=(self._key, repr(cfg)),
+            out_shardings=self._pool_sharding_for(cfg),
         )()
 
     def _pool_sharding_for(self, cfg: ModelConfig) -> PagedKVCache:
@@ -2215,10 +2241,11 @@ class ContinuousBatcher:
         """
         key, cfg = self._chunk_key(chunk, lanes, s_bucket)
         if key not in self._jit_chunk:
-            self._jit_chunk[key] = jax.jit(
+            self._jit_chunk[key] = StoredJit(
                 _step_program(
                     "prefill_chunk", partial(self._chunk_sample, cfg)
                 ),
+                key=(self._key, key),
                 donate_argnums=(4,),
                 static_argnums=(8,),
             )
@@ -2247,8 +2274,9 @@ class ContinuousBatcher:
                     mesh=self.mesh,
                 )[:2]
 
-            self._jit_chunk_d[key] = jax.jit(
-                prefill_chunk_draft, donate_argnums=(4,)
+            self._jit_chunk_d[key] = StoredJit(
+                prefill_chunk_draft, key=(self._key, key),
+                donate_argnums=(4,),
             )
         return self._jit_chunk_d[key]
 
@@ -2345,10 +2373,11 @@ class ContinuousBatcher:
         and off)."""
         key, cfg_chunk = self._chunk_key(chunk, lanes, s_bucket)
         if key not in self._jit_fused:
-            self._jit_fused[key] = jax.jit(
+            self._jit_fused[key] = StoredJit(
                 _step_program(
                     "fused_step", partial(self._fused_sample, cfg_chunk)
                 ),
+                key=(self._key, key),
                 donate_argnums=(1,),
                 static_argnums=(8, 16),
             )
@@ -2369,8 +2398,9 @@ class ContinuousBatcher:
         chunk first rides a dispatch, or when two slots are first ready
         at once, or rows first group, with every decoding row stalled
         behind it; a chunk width or MoE pin first met under load is
-        still built there. Lowering the jitted function from shapes
-        fills the caches its call reads, and runs nothing.
+        still built there. Building from shapes gives the executable its
+        call takes (from the store of executables where an earlier
+        process built it), and runs nothing.
 
         The builds are one ``build_ahead`` span: an annotation on this
         thread, inside the ``batcher.dispatch`` that called it (whose
@@ -2416,23 +2446,23 @@ class ContinuousBatcher:
                 if self._states is not None else {}
             )
             for groups in grouped:
-                self._fused_fn(chunk, lanes, s_bucket).lower(
+                self._fused_fn(chunk, lanes, s_bucket).build(
                     *self._plain_shapes, groups, *lane, *ends, **state,
-                ).compile()
+                )
             if lanes > 1:
-                self._chunk_fn(chunk, lanes, s_bucket).lower(
+                self._chunk_fn(chunk, lanes, s_bucket).build(
                     self._plain_shapes[0], *lane, self._plain_shapes[1],
                     *ends, self._plain_shapes[8], **state,
-                ).compile()
+                )
         pools = (_rows_of(self._plain_shapes[1]),) + (
             (jax.tree.map(_abstract, _rows_of(self.draft_cache)),)
             if self.draft_cache is not None else ()
         )
-        self._jit_apply_rows.lower(
+        self._jit_apply_rows.build(
             pools, i32((len(pools), c.max_slots)),
             i32((c.max_slots, c.pages_per_seq)), i32((c.max_slots,)),
             i32((c.max_slots,)),
-        ).compile()
+        )
         return widths
 
     @property

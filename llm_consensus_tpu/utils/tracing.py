@@ -492,6 +492,13 @@ class PhaseClock:
                 self.seconds[name] = 0.0
 
 
+#: Events of the store of executables (``utils.compile_cache``): the
+#: seconds of a load, under the program's ``fun_name`` as jax's own
+#: stages carry it, and one event a program the store hands out, with
+#: its ``program`` and ``source`` (``loaded`` or ``built``).
+LOAD_EVENT = "/llm_consensus_tpu/executable_load_duration"
+EXECUTABLE_EVENT = "/llm_consensus_tpu/executable"
+
 # jax's compile events (jax 0.9: ``dispatch.log_elapsed_time`` emits the
 # start as a scalar and the end as a duration, on the thread that
 # compiles) and the stage each one is.
@@ -500,6 +507,8 @@ _COMPILE_STAGES = {
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
     # A compile, or a load from the persistent cache.
     "/jax/core/compile/backend_compile_duration": "backend",
+    # A load from the store of executables: no trace, lower or compile.
+    LOAD_EVENT: "load",
 }
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
@@ -532,12 +541,13 @@ class _CompileListener:
     span is also a ``compile.<stage>`` annotation with a ``program``
     stat, nested in whatever phase of the thread was open."""
 
-    def __init__(self, seconds, compiles):
+    def __init__(self, seconds, compiles, executables):
         from jax.profiler import TraceAnnotation
 
         self._annotation = TraceAnnotation
         self._seconds = seconds
         self._compiles = compiles
+        self._executables = executables
         self._local = threading.local()
 
     def _stack(self) -> list:
@@ -583,7 +593,11 @@ class _CompileListener:
             ).inc()
 
     def event(self, event: str, **kw) -> None:
-        if event == _CACHE_HIT:
+        if event == EXECUTABLE_EVENT:
+            self._executables.labels(
+                program=kw["program"], source=kw["source"]
+            ).inc()
+        elif event == _CACHE_HIT:
             stack = self._stack()
             if stack and stack[-1].stage == "backend":
                 stack[-1].hit = True
@@ -593,20 +607,23 @@ _LISTENER: _CompileListener | None = None
 _LISTENER_LOCK = threading.Lock()
 
 
-def install_compile_listener(seconds, compiles) -> None:
+def install_compile_listener(seconds, compiles, executables) -> None:
     """Count every XLA program this process builds from here on:
-    outermost seconds into ``seconds{stage, program}`` and one
-    ``compiles{program, cache}`` a backend span (``cache="hit"`` when
-    it was a load from the persistent cache). Once a process; a second
-    call changes nothing. The listener runs only while something is
-    traced, lowered or compiled, so a warm loop pays nothing."""
+    outermost seconds into ``seconds{stage, program}`` (``load``: read
+    from the store of executables), one ``compiles{program, cache}`` a
+    backend span (``cache="hit"`` when it was a load from the persistent
+    cache) and one ``executables{program, source}`` a program the store
+    hands out (``utils.compile_cache.StoredJit``). Once a process; a
+    second call changes nothing. The listener runs only while something
+    is traced, lowered, compiled or loaded, so a warm loop pays
+    nothing."""
     global _LISTENER
     from jax import monitoring
 
     with _LISTENER_LOCK:
         if _LISTENER is not None:
             return
-        _LISTENER = _CompileListener(seconds, compiles)
+        _LISTENER = _CompileListener(seconds, compiles, executables)
         monitoring.register_scalar_listener(_LISTENER.start)
         monitoring.register_event_duration_secs_listener(_LISTENER.end)
         monitoring.register_event_listener(_LISTENER.event)

@@ -2,9 +2,9 @@
 """Where ``setup_s`` goes, one cell run at a time (stdlib only: the chip
 belongs to each run's own server child).
 
-    chiprun -- python3 scripts/setup_table.py <tag> <side>:<cell>:<seed>:<trace> ...
+    python3 scripts/setup_table.py [--seconds N] <tag> <side>:<cell>:<seed>:<trace> ...
 
-``side`` as in ``scripts/chip_ab.py`` (``parent``, ``change``, ``tree``).
+on a host with the chip. ``side`` as in ``scripts/chip_ab.py`` (``parent``, ``change``, ``tree``).
 Each run is that side's own ``benchmark/run.py``, unchanged, watched from
 outside: the ``/metrics`` text at the window's edges is kept beside the
 server log (``metrics_open.txt``, ``metrics_close.txt``), and the server
@@ -91,7 +91,7 @@ def watch_server() -> None:
     atexit.register(lambda: json.dump(rec, open(out, "w")))
 
 
-def one(root: str, cell: str, seed: str, trace: str) -> int:
+def one(root: str, cell: str, seed: str, trace: str, seconds: str) -> int:
     """One run of ``root``'s harness, with the two observers."""
     bench = os.path.join(root, "benchmark")
     sys.path.insert(0, bench)
@@ -120,7 +120,7 @@ def one(root: str, cell: str, seed: str, trace: str) -> int:
     run.Ctx.close_window = keep(
         run.Ctx.close_window, "metrics_close.txt", "metrics_after")
     sys.argv = ["run.py", "--workload", cell, "--seed", seed,
-                "--seconds", SECONDS, "--trace", trace]
+                "--seconds", seconds, "--trace", trace]
     return run.main()
 
 
@@ -137,7 +137,10 @@ def _samples(text: str, family: str) -> list:
 
 def row(result: dict, log_dir: str) -> dict:
     """The run's setup, split: the harness's stretches, the program's
-    phases at the window's open, compile inside each, the top programs."""
+    phases at the window's open, compile inside each, the top programs
+    by seconds (``trace`` / ``lower`` / ``backend`` / ``load``: read from
+    the store of executables) with how many of each the store ``built``
+    and ``loaded``."""
     setup = result["setup"]
     r = {"setup_s": setup["setup_s"], "listening_s": setup["listening_s"],
          "warm_s": setup["warm_s"], "ramp_s": setup["ramp_s"]}
@@ -172,12 +175,19 @@ def row(result: dict, log_dir: str) -> dict:
         programs.setdefault(labels["program"], {})[labels["stage"]] = v
     for labels, v in _samples(text, "gateway_compiles_total"):
         programs.setdefault(labels["program"], {})[labels["cache"]] = v
+    for labels, v in _samples(text, "gateway_executables_total"):
+        programs.setdefault(labels["program"], {})[labels["source"]] = v
     top = sorted(programs.items(), key=lambda kv: -sum(
-        kv[1].get(s, 0.0) for s in ("trace", "lower", "backend")))
+        kv[1].get(s, 0.0) for s in ("trace", "lower", "backend", "load")))
     r["top_programs"] = top[:5]
     r["programs"] = len(programs)
-    r["misses"] = sum(p.get("miss", 0) for p in programs.values())
-    r["hits"] = sum(p.get("hit", 0) for p in programs.values())
+    for name, count in (("misses", "miss"), ("hits", "hit"),
+                        ("built", "built"), ("loaded", "loaded")):
+        r[name] = sum(p.get(count, 0) for p in programs.values())
+    r["stages"] = {
+        stage: sum(p.get(stage, 0.0) for p in programs.values())
+        for stage in ("trace", "lower", "backend", "load")
+    }
     try:
         with open(os.path.join(log_dir, "metrics_close.txt")) as f:
             after = _samples(f.read(), "gateway_compile_seconds_total")
@@ -195,8 +205,11 @@ def row(result: dict, log_dir: str) -> dict:
 
 def main() -> int:
     if sys.argv[1] == "--one":
-        return one(*sys.argv[2:6])
-    tag, specs = sys.argv[1], sys.argv[2:]
+        return one(*sys.argv[2:7])
+    args, seconds = sys.argv[1:], SECONDS
+    if args[0] == "--seconds":
+        seconds, args = args[1], args[2:]
+    tag, specs = args[0], args[1:]
     out = os.path.join(ROOT, "chiprun_out", tag)
     os.makedirs(out, exist_ok=True)
     rc_all = 0
@@ -205,7 +218,7 @@ def main() -> int:
         root = ROOTS[side]
         p = subprocess.run(
             [sys.executable, os.path.abspath(__file__), "--one", root, cell,
-             seed, trace], capture_output=True, text=True)
+             seed, trace, seconds], capture_output=True, text=True)
         lines = p.stdout.strip().splitlines()
         try:
             result = json.loads(lines[-1])

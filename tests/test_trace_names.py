@@ -75,8 +75,8 @@ class _Spy:
             self.seen.add(re.match(r"module @(\w+)", text).group(1))
         return self.jitted(*args)
 
-    def lower(self, *args):  # the batcher's build-ahead
-        return self.jitted.lower(*args)
+    def build(self, *args, **kwargs):  # the batcher's build-ahead
+        return self.jitted.build(*args, **kwargs)
 
 
 def _spy_on(batcher, seen: set) -> None:

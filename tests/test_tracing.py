@@ -577,7 +577,9 @@ def test_check_metrics_detects_undeclared_family(tmp_path):
 def compile_listener():
     from llm_consensus_tpu.server import metrics as M
 
-    tracing.install_compile_listener(M.COMPILE_SECONDS, M.COMPILES)
+    tracing.install_compile_listener(
+        M.COMPILE_SECONDS, M.COMPILES, M.EXECUTABLES
+    )
     return M
 
 
@@ -636,7 +638,8 @@ def test_a_cached_program_adds_nothing(compile_listener):
 
 
 def test_lower_then_compile_is_counted(compile_listener):
-    """The build-ahead's way of building: ``.lower(shapes).compile()``."""
+    """How the store of executables builds a program it does not hold:
+    ``.lower(shapes).compile()``."""
     import jax
     import jax.numpy as jnp
 
