@@ -14,6 +14,10 @@ functions. Nemotron-3-Nano (PR 32) is the first whose layers are not
 "attention then MLP": a per-layer plan of ONE-mixer layers (Mamba-2
 state-space, GQA attention without positions, ungated relu² experts
 behind a sigmoid router), read by the same functions again.
+SDAR-30B-A3B-Chat is the first that does not decode one token
+after another: a Qwen3-MoE stack (per-head QK norm, 128 experts top-8)
+generating by block diffusion — a block of ``block_length`` tokens
+denoised in place under a block-causal attention rule.
 """
 
 from __future__ import annotations
@@ -173,6 +177,24 @@ class ModelConfig:
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_conv: int = 4
+    # Per-head RMSNorm of every query and key head over its
+    # ``head_dim`` values, before the rotation (Qwen3-MoE's layer):
+    # learned weights ``q_norm`` / ``k_norm`` [head_dim] a layer.
+    qk_norm: bool = False
+    # Generation: "autoregressive" (one token a row a step), or
+    # "block_diffusion" (SDAR): a block of ``block_length`` positions
+    # starts as the prompt's tail and ``mask_token_id``s, and is
+    # denoised in place over ``denoising_steps`` forwards — each commits
+    # the masked positions whose greedy probability passes
+    # ``unmask_threshold``, or the most confident ones where fewer pass
+    # than the step's share of the block — then written to the cache by
+    # one more forward. Attention is block-causal: a query at i sees a
+    # key at j iff j // block_length <= i // block_length.
+    generation: str = "autoregressive"
+    block_length: int = 1
+    denoising_steps: int = 1
+    unmask_threshold: float = 0.9
+    mask_token_id: int = -1
 
     def __post_init__(self):
         if self.layer_plan:
@@ -182,6 +204,29 @@ class ModelConfig:
                     f"{self.name}: layer_plan {self.layer_plan!r} must be "
                     f"{self.n_layers} characters of {''.join(LAYER_KINDS)}"
                 )
+        if self.is_diffusion and not (
+            self.block_length > 1
+            and not self.block_length & (self.block_length - 1)
+            and not self.sliding_window
+            and 1 <= self.denoising_steps <= self.block_length
+            and 0 <= self.mask_token_id < self.vocab_size
+        ):
+            raise ValueError(
+                f"{self.name}: block diffusion needs a block_length that "
+                "is a power of two > 1, no sliding window, 1 <= "
+                "denoising_steps <= block_length and a mask token id "
+                "inside the vocabulary"
+            )
+
+    @property
+    def is_diffusion(self) -> bool:
+        return self.generation == "block_diffusion"
+
+    @property
+    def attn_block(self) -> int:
+        """The attention rule's block: a query sees the keys of every
+        block up to its own. 1 is the plain causal rule."""
+        return self.block_length if self.is_diffusion else 1
 
     @property
     def is_mla(self) -> bool:
@@ -676,6 +721,69 @@ PRESETS: dict[str, ModelConfig] = {
         ssm_state=16,
         ssm_groups=2,
         ssm_conv=4,
+    ),
+    # SDAR-30B-A3B-Chat at its published sizes
+    # (huggingface.co/JetLM/SDAR-30B-A3B-Chat config.json, model_type
+    # sdar_moe, Qwen3-MoE's layer): 48 layers alike, 32 query heads and
+    # 4 KV heads of 128 (queries 4096 wide over a 2048 stream), per-head
+    # QK RMSNorm, 128 SwiGLU experts of 768, top-8 of a float32 softmax
+    # renormalised, no shared expert. Every layer is an expert layer,
+    # so the published dense intermediate_size (6144) is read by
+    # nothing; d_ff is the experts' width. Block diffusion with SDAR's
+    # released generation defaults (the config publishes none): blocks
+    # of 4, 4 denoising steps, threshold 0.9; the mask id is one the
+    # byte tokenizer never emits. max_seq_len is the repo's cap.
+    "sdar-30b-a3b-chat": ModelConfig(
+        name="sdar-30b-a3b-chat",
+        vocab_size=151936,
+        d_model=2048,
+        n_layers=48,
+        n_heads=32,
+        n_kv_heads=4,
+        d_ff=768,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-6,
+        max_seq_len=8192,
+        attn_head_dim=128,
+        qk_norm=True,
+        n_experts=128,
+        n_experts_per_token=8,
+        moe_d_ff=768,
+        moe_router="softmax_topk",
+        moe_renormalize=True,
+        moe_dropless=True,
+        generation="block_diffusion",
+        block_length=4,
+        denoising_steps=4,
+        unmask_threshold=0.9,
+        mask_token_id=151669,
+    ),
+    # The same shapes at CPU-test size: 2 layers, 8 experts top-2 of
+    # 32, heads of 16 over a 64 stream (queries 128 wide), blocks of 4.
+    "test-tiny-sdar": ModelConfig(
+        name="test-tiny-sdar",
+        vocab_size=384,
+        d_model=64,
+        n_layers=2,
+        n_heads=8,
+        n_kv_heads=2,
+        d_ff=32,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-6,
+        max_seq_len=256,
+        attn_head_dim=16,
+        qk_norm=True,
+        n_experts=8,
+        n_experts_per_token=2,
+        moe_d_ff=32,
+        moe_router="softmax_topk",
+        moe_renormalize=True,
+        moe_dropless=True,
+        generation="block_diffusion",
+        block_length=4,
+        denoising_steps=4,
+        unmask_threshold=0.9,
+        mask_token_id=383,
     ),
     "test-tiny-moe": ModelConfig(
         name="test-tiny-moe",

@@ -78,6 +78,10 @@ def _rms(cfg: ModelConfig, x, w, mesh=None):
 
 
 def _attn_causal(cfg: ModelConfig, q, k, v, positions, mesh=None):
+    if cfg.attn_block > 1:
+        # Block diffusion: the block-causal rule, which neither the ring
+        # nor the fused causal kernel implements.
+        return causal_attention(q, k, v, positions, block=cfg.attn_block)
     # Sequence-parallel long context: the ring (parallel/ring.py) handles
     # index-causal layouts over a seq-sharded mesh; K/V chunks rotate on
     # ICI instead of any device holding the full sequence.
@@ -352,9 +356,12 @@ def init_params(
 
 def _two_stacks(cfg: ModelConfig) -> bool:
     """Models :func:`_init_stack` builds: latent attention, leading
-    dense layers, shared experts. Every older preset keeps the one
-    stack, and the key order, it always had."""
-    return cfg.is_mla or cfg.n_dense_layers > 0 or cfg.n_shared_experts > 0
+    dense layers, shared experts, QK norm. Every older preset keeps the
+    one stack, and the key order, it always had."""
+    return (
+        cfg.is_mla or cfg.n_dense_layers > 0 or cfg.n_shared_experts > 0
+        or cfg.qk_norm
+    )
 
 
 def _init_stack(cfg: ModelConfig, n: int, normal, resid_scale, dtype, moe):
@@ -384,6 +391,9 @@ def _init_stack(cfg: ModelConfig, n: int, normal, resid_scale, dtype, moe):
             blocks["bq"] = jnp.zeros((n, H * Dh), dtype)
             blocks["bk"] = jnp.zeros((n, Hkv * Dh), dtype)
             blocks["bv"] = jnp.zeros((n, Hkv * Dh), dtype)
+        if cfg.qk_norm:
+            blocks["q_norm"] = jnp.ones((n, Dh), dtype)
+            blocks["k_norm"] = jnp.ones((n, Dh), dtype)
     if not moe:
         F = cfg.d_ff
         blocks["w_gate"] = normal("w_gate", (n, D, F))
@@ -679,6 +689,10 @@ def _project_qkv(cfg: ModelConfig, p: dict, h: jnp.ndarray):
     q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        # Per head, over its head_dim values (before the rotation).
+        q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
     return q, k, v
 
 
@@ -1877,7 +1891,8 @@ def _attn_paged(
         return ragged_paged_attention(
             q_dec, k_pools, v_pools, tables, valid, layer=layer,
             q_chunk=q_chunk, chunk_table=chunk_table,
-            chunk_start=chunk_start, groups=gtuple, window=window, **latent,
+            chunk_start=chunk_start, groups=gtuple, window=window,
+            block=cfg.attn_block, **latent,
         )
     k_pool = jax.lax.dynamic_index_in_dim(k_pools, layer, 0, keepdims=False)
     v_pool = jax.lax.dynamic_index_in_dim(v_pools, layer, 0, keepdims=False)
@@ -1909,7 +1924,7 @@ def _attn_paged(
     return ragged_paged_attention_reference(
         q_dec, k_pool, v_pool, tables, valid,
         q_chunk=q_chunk, chunk_table=chunk_table, chunk_start=chunk_start,
-        window=window, **latent,
+        window=window, block=cfg.attn_block, **latent,
     )
 
 
@@ -2551,6 +2566,106 @@ def verify_step_paged(
     )
     logits = _unembed(cfg, params, x, mesh)  # [B, NQ, V]
     return (logits, new_cache, *stats)
+
+
+def denoise_step_paged(
+    cfg: ModelConfig,
+    params: dict,
+    tokens: jnp.ndarray,
+    cache,
+    groups=None,
+    chunk_tokens: jnp.ndarray | None = None,
+    chunk_table: jnp.ndarray | None = None,
+    chunk_start: jnp.ndarray | None = None,
+    write_mask=None,
+    unembed: bool = True,
+):
+    """One forward of every row's diffusion BLOCK (the verify lane
+    widened to a block-diffusion model's blocks), plus optionally the
+    next prefill chunk of up to L other sequences — one device program.
+
+    tokens: [max_seqs, B] — row b's block, at absolute positions
+    ``length[b] + i`` (``length`` is a multiple of B: the committed
+    sequence). Its K/V are written through the row's table and the
+    block attends to the row's cache and to ITSELF under the
+    block-causal rule (``cfg.attn_block``): every position sees the
+    whole block, masked positions included. Rows that carry no request,
+    and rows ``write_mask`` ([max_seqs] bool or None) leaves out, write
+    into the NULL page, read nothing and take no expert
+    (:func:`_decoding_rows`).
+    ``chunk_*``: lanes as :func:`fused_step_paged` takes them; they ride
+    the same token axis (one weight read for both) and sample nothing
+    (a block-diffusion prompt ends in a block, not in a first token).
+
+    Returns (logits [max_seqs, B, V] fp32 — None where ``unembed`` is
+    False, as in the forward that commits a finished block —, chunk
+    hidden [L, C, D] or None, the cache with ``length`` UNCHANGED: the
+    caller advances it by B when the block is committed, *routing
+    counts of a ``moe_dropless`` model).
+    """
+    from llm_consensus_tpu.models.paged_cache import NULL_PAGE
+
+    b, nq = tokens.shape
+    pg = cache.page_size
+    pos0 = cache.length
+    pos = pos0[:, None] + jnp.arange(nq)[None]  # [B, NQ]
+    live = _decoding_rows(cache, write_mask)
+    pages = jnp.where(
+        live[:, None],
+        jnp.take_along_axis(cache.page_table, pos // pg, axis=1),
+        NULL_PAGE,
+    )
+    valid = jnp.where(live, pos0 + nq, 0)
+    tables = cache.page_table
+    ids, all_pos = tokens.reshape(-1), pos.reshape(-1)
+    pages_all, offs_all = pages.reshape(-1), (pos % pg).reshape(-1)
+    lanes = c = 0
+    active = _live_rows(cfg, cache, write_mask, repeat=nq)
+    if chunk_tokens is not None:
+        lanes, c = chunk_tokens.shape
+        chunk_table, _, chunk_pos, chunk_pages, chunk_offs, lane_live, (
+            attn_start
+        ) = _chunk_lanes(cache, chunk_tokens, chunk_table, chunk_start)
+        ids = jnp.concatenate([ids, chunk_tokens.reshape(-1)])
+        all_pos = jnp.concatenate([all_pos, chunk_pos.reshape(-1)])
+        pages_all = jnp.concatenate([pages_all, chunk_pages.reshape(-1)])
+        offs_all = jnp.concatenate([offs_all, chunk_offs.reshape(-1)])
+        if active is not None:
+            active = jnp.concatenate([active, jnp.repeat(lane_live, c)])
+    x = params["embed"][ids][None]  # [1, B * NQ (+ L * C), D]
+    cos, sin = rope_cos_sin(
+        all_pos[None], cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
+    )
+    n_rows = b * nq
+
+    def attend(q, k_pools, v_pools, layer):
+        q_rows = q[0, :n_rows].reshape(b, nq, *q.shape[2:])
+        if not lanes:
+            out = _attn_paged(
+                cfg, q_rows, None, k_pools, v_pools, layer, tables, valid,
+                groups=groups,
+            )
+            return out.reshape(1, n_rows, *out.shape[2:])
+        out, out_ch = _attn_paged(
+            cfg, q_rows, q[0, n_rows:].reshape(lanes, c, *q.shape[2:]),
+            k_pools, v_pools, layer, tables, valid,
+            chunk_table=chunk_table, chunk_start=attn_start, groups=groups,
+        )
+        return jnp.concatenate([
+            out.reshape(n_rows, *out.shape[2:]),
+            out_ch.reshape(lanes * c, *out_ch.shape[2:]),
+        ])[None]
+
+    x, new_cache, *stats = _run_paged(
+        cfg, params, x, cos, sin, cache, pages_all[None], offs_all[None],
+        attend, active=active,
+    )
+    logits = (
+        _unembed(cfg, params, x[0, :n_rows]).reshape(b, nq, -1)
+        if unembed else None
+    )
+    hidden = x[0, n_rows:].reshape(lanes, c, -1) if lanes else None
+    return (logits, hidden, new_cache, *stats)
 
 
 def prefill_chunk_paged(

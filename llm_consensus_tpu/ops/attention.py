@@ -45,6 +45,7 @@ def causal_attention(
     positions: jnp.ndarray | None = None,
     window: int = 0,
     scale: float | None = None,
+    block: int = 1,
 ) -> jnp.ndarray:
     """Causal self-attention over a full (prefill) sequence.
 
@@ -54,13 +55,20 @@ def causal_attention(
     is index-causal. ``window`` > 0 adds Mistral-style sliding-window
     masking: query i also ignores keys with pos_i - pos_j >= window.
     ``scale`` (default ``D ** -0.5``) and a value width other than the
-    key's are what a latent-attention model passes.
+    key's are what a latent-attention model passes. ``block`` > 1 is the
+    block-causal rule of a block-diffusion model: key j is seen iff
+    ``j // block <= i // block`` (no window with it).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     scores = _gqa_scores(q, k) * scale  # [B, Hkv, G, Sq, Sk] fp32
     sq, sk = scores.shape[-2], scores.shape[-1]
-    if positions is None:
+    if block > 1:
+        qi = jnp.arange(sq)[None] if positions is None else positions
+        kj = jnp.arange(sk)[None] if positions is None else positions
+        mask = kj[:, None, :] // block <= qi[:, :, None] // block
+        mask = mask[:, None, None]  # [B or 1, 1, 1, Sq, Sk]
+    elif positions is None:
         qi = jnp.arange(sq)[:, None]
         kj = jnp.arange(sk)[None, :]
         mask = kj <= qi  # [Sq, Sk]
@@ -284,6 +292,7 @@ def ragged_paged_attention_reference(
     window: int = 0,
     scale: float | None = None,
     latent_dv: int = 0,
+    block: int = 1,
 ):
     """XLA reference for the ragged paged attention kernel — the parity
     oracle, the non-Pallas serving path, AND the mesh fallback: when
@@ -322,7 +331,9 @@ def ragged_paged_attention_reference(
     [n_pages, page, D] — one key a token, shared by all H query heads
     (multi-query attention) — ``v_pool`` is ignored and the value is the
     key's first ``latent_dv`` lanes; outputs are [.., H, latent_dv].
-    ``scale`` overrides ``D ** -0.5``.
+    ``scale`` overrides ``D ** -0.5``. ``block`` > 1: the block-causal
+    rule on the verify rows and the chunk lanes (:func:`
+    chunk_decode_attention`).
     """
     nq = None
     if q.ndim == 4:
@@ -342,7 +353,8 @@ def ragged_paged_attention_reference(
         )[:, 0]
     else:
         out = chunk_decode_attention(
-            q, k_seq, v_seq, valid_len - nq, window=window, scale=scale
+            q, k_seq, v_seq, valid_len - nq, window=window, scale=scale,
+            block=block,
         )
     if q_chunk is None:
         return out
@@ -354,7 +366,7 @@ def ragged_paged_attention_reference(
     vc = v_pool[chunk_table].reshape(lanes, -1, hkv, dv)
     start = jnp.asarray(chunk_start, jnp.int32).reshape(lanes)
     out_chunk = chunk_decode_attention(
-        q_chunk, kc, vc, start, window=window, scale=scale
+        q_chunk, kc, vc, start, window=window, scale=scale, block=block
     )
     return out, out_chunk[0] if one_lane else out_chunk
 
@@ -366,6 +378,7 @@ def chunk_decode_attention(
     valid_len: jnp.ndarray,
     window: int = 0,
     scale: float | None = None,
+    block: int = 1,
 ) -> jnp.ndarray:
     """K-token chunk decode against the cache (speculative verification).
 
@@ -377,6 +390,9 @@ def chunk_decode_attention(
     forward verifies a whole draft, the speculative-decoding hot path).
     ``window`` > 0 (Mistral): token i also ignores slots
     <= valid_len + i - window (cache slot j holds position j).
+    ``block`` > 1 (block diffusion): token i sees every slot of its
+    block, ``< (qpos // block + 1) * block``, where ``qpos = valid_len +
+    i`` (so the block's slots must be written, as they are here).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -384,6 +400,8 @@ def chunk_decode_attention(
     kq = q.shape[1]
     s = k_cache.shape[1]
     limit = valid_len[:, None, None] + jnp.arange(kq)[None, :, None] + 1
+    if block > 1:
+        limit = ((limit - 1) // block + 1) * block
     slots = jnp.arange(s)[None, None, :]
     mask = slots < limit  # [B, K, S]
     if window > 0:

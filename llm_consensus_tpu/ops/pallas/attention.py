@@ -739,6 +739,7 @@ def _ragged_kernel(
     stacked: bool,
     fold: int,
     dv: int = 0,
+    block: int = 1,
 ):
     """One program-class row of the ragged kernel: a decode row, a
     chunk lane or a group, walking ITS OWN live pages.
@@ -947,11 +948,18 @@ def _ragged_kernel(
         page j for n queries at absolute positions qbase + i (rows
         (query, g)-ordered): query i sees slots <= its own position —
         chunk_decode_attention's rule; n == 1 is the classic slot <
-        valid decode mask."""
+        valid decode mask. ``block`` > 1 widens each query to its
+        whole block (the rows' and lanes' blocks start on multiples of
+        ``block``, so a row's walk to its fill covers them)."""
         row = jax.lax.broadcasted_iota(jnp.int32, (n * g, tk), 0)
         qpos = qbase + (row // g if g > 1 else row)
         slot = j * pg + jax.lax.broadcasted_iota(jnp.int32, (n * g, tk), 1)
-        mask = (slot <= qpos) & (slot >= lo)
+        # Block-causal (``block`` a power of two): a query sees through
+        # the last slot of its block, ``qpos | (block - 1)`` for the
+        # positions >= 0 a live row has. 1 is the plain rule, traced
+        # as it always was.
+        last = qpos | (block - 1) if block > 1 else qpos
+        mask = (slot <= last) & (slot >= lo)
         if window > 0:
             mask &= slot > qpos - window
         return mask
@@ -1075,6 +1083,7 @@ def _ragged_attention(
     latent_dv: int = 0,
     out_dtype=None,
     interpret: bool | None = None,
+    block: int = 1,
 ):
     """Assemble and launch ONE ragged program; merge group partials.
 
@@ -1097,7 +1106,9 @@ def _ragged_attention(
     (stacked: [L, n_pages, pg, D]) of an MLA model and ``v_kv`` is
     ignored — one key a token for all H heads, value = its first
     ``latent_dv`` lanes; outputs are [.., H, latent_dv]. ``scale``
-    overrides ``D ** -0.5``.
+    overrides ``D ** -0.5``. ``block`` (static, a power of two): the
+    block-causal rule of a block-diffusion model on the verify rows and
+    the chunk lanes; 1 is the plain ragged-causal one.
     """
     squeeze_nq = q_dec.ndim == 3
     if squeeze_nq:
@@ -1305,6 +1316,7 @@ def _ragged_attention(
             stacked=stacked,
             fold=fold,
             dv=latent_dv,
+            block=block,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(pf),
@@ -1369,6 +1381,7 @@ def ragged_paged_attention(
     latent_dv: int = 0,
     out_dtype=None,
     interpret: bool | None = None,
+    block: int = 1,
 ):
     """Mixed prefill+decode attention over the page pool — ONE program.
 
@@ -1407,7 +1420,11 @@ def ragged_paged_attention(
     is read once as key (D lanes) and value (its first ``latent_dv``),
     and outputs are [.., H, latent_dv]. ``scale`` overrides
     ``D ** -0.5``. ``out_dtype`` (default q's): the outputs' dtype —
-    float32 returns the kernel's own, unrounded.
+    float32 returns the kernel's own, unrounded. ``block`` > 1 (a power
+    of two): the block-causal rule — a query at i sees slot j iff
+    ``j // block <= i // block`` — on the verify rows and the chunk
+    lanes (a group's shared run lies before every member's queries, so
+    the group phase needs no rule of its own).
     """
     b = q.shape[0]
     pg = k_pool.shape[-2 if latent_dv else -3]
@@ -1449,6 +1466,7 @@ def ragged_paged_attention(
         latent_dv=latent_dv,
         out_dtype=out_dtype,
         interpret=interpret,
+        block=block,
     )
     return (out[0], out[1][0]) if one_lane else out
 

@@ -103,6 +103,10 @@ __all__ = [
     "REMOTE_STORE_ERRORS",
     "REMOTE_STORE_RTT",
     "ROLE_HANDOFFS",
+    "DENOISE_STEPS",
+    "DIFFUSION_TOKENS",
+    "DIFFUSION_BLOCKS",
+    "ATTENTION_PAIRS",
 ]
 
 # Seconds: spans ~1 ms .. 2 min, the TTFT / request-latency range of a
@@ -765,6 +769,33 @@ ATTENTION_TOKENS_READ = REGISTRY.counter(
 ATTENTION_PAGES_READ = REGISTRY.counter(
     "gateway_attention_pages_read_total",
     "Pool pages attention folded a layer (shared runs once a group), by kind",
+)
+#: Block diffusion: row-forwards of a block — each of its
+#: denoising steps and the forward that commits it, for every row that
+#: carries a request — by program ``kind``; tokens its committed blocks
+#: gave requests (after the prompt's tail, cut at EOS and
+#: ``max_new_tokens``); and the blocks committed. Steps over tokens is
+#: the forwards a generated token costs.
+DENOISE_STEPS = REGISTRY.counter(
+    "gateway_denoise_steps_total",
+    "Row-forwards of diffusion blocks (denoising steps + commit), by kind",
+)
+DIFFUSION_TOKENS = REGISTRY.counter(
+    "gateway_diffusion_tokens_total",
+    "Tokens committed blocks gave requests (block diffusion)",
+)
+DIFFUSION_BLOCKS = REGISTRY.counter(
+    "gateway_diffusion_blocks_total",
+    "Diffusion blocks committed to the cache",
+)
+#: (query, key) pairs the attention of a retired program computed under
+#: its model's rule — causal, or block-causal for block diffusion,
+#: where a query also sees the rest of its block — summed over its
+#: forwards (not over layers), by ``kind``; from the lengths of the
+#: program's cost model, with no device op.
+ATTENTION_PAIRS = REGISTRY.counter(
+    "gateway_attention_pairs_total",
+    "(query, key) pairs attention computed a layer, summed over forwards",
 )
 #: Device memory as the allocator reports it, labeled
 #: ``kind="in_use"|"peak"|"limit"``: the largest value over the local

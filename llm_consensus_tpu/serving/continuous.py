@@ -130,12 +130,14 @@ from llm_consensus_tpu.models.paged_cache import (
     install_seq,
     release_seq,
 )
+from llm_consensus_tpu.engine import diffusion as _diffusion
 from llm_consensus_tpu.engine.accept import verify_tokens
 from llm_consensus_tpu.ops.pallas.quant_matmul import _MAX_M as _QMM_MAX_ROWS
 from llm_consensus_tpu.serving import flight as _flight
 from llm_consensus_tpu.serving.offload import HostPageStore
 from llm_consensus_tpu.models.transformer import (
     decode_step_paged,
+    denoise_step_paged,
     fused_step_paged,
     kv_plane_token_bytes,
     model_param_bytes,
@@ -308,6 +310,12 @@ from llm_consensus_tpu.server.metrics import (
 )
 from llm_consensus_tpu.server.metrics import (
     SETUP_SECONDS as _M_SETUP_SECONDS,
+)
+from llm_consensus_tpu.server.metrics import (
+    ATTENTION_PAIRS as _M_ATTN_PAIRS,
+    DENOISE_STEPS as _M_DENOISE_STEPS,
+    DIFFUSION_BLOCKS as _M_DIFF_BLOCKS,
+    DIFFUSION_TOKENS as _M_DIFF_TOKENS,
 )
 from llm_consensus_tpu.utils import tracing as _tracing
 
@@ -633,6 +641,9 @@ class ServeResult:
     # the sampler (row 0 from the prefill's unembed, the rest from the
     # decode or fused steps the row rode). None unless asked.
     logits: np.ndarray | None = field(default=None, compare=False)
+    # A block-diffusion model's read-out: ``logits`` holds B rows a
+    # denoising step, and this the block's input ids at each step.
+    logit_ids: list | None = field(default=None, compare=False)
 
 
 @dataclass
@@ -675,6 +686,7 @@ class _Request:
     # ``logits_n`` generated positions, fetched for this row alone.
     logits_n: int = 0
     logit_rows: list = field(default_factory=list)
+    logit_ids: list = field(default_factory=list)
 
 
 @dataclass
@@ -737,6 +749,15 @@ class _Slot:
     state_dep: object = None
     chain: list = field(default_factory=list)
     pages_matched_n: int = 0
+    # -- block diffusion -------------------------------------------------
+    # ``prompt_len`` is what chunked prefill covers, the prompt's last
+    # whole block; ``tail`` is the rest of the prompt, which rides the
+    # row's first block (empty once that block is dispatched). ``length``
+    # mirrors the row's committed device length at its next dispatch;
+    # ``planned`` counts the tokens its dispatched blocks give at most.
+    tail: np.ndarray | None = None
+    length: int = 0
+    planned: int = 0
 
 
 class _LaneArgs(NamedTuple):
@@ -849,6 +870,12 @@ class _Inflight:
     # asked for them, and a dropless-MoE model's routing counts, which
     # the fetch brings home with the tokens.
     aux: tuple | None = None
+    # A block-diffusion program (``_denoise_sample``): ``tokens`` are
+    # each row's committed block, generated positions first, of which
+    # ``emit_cnt`` belong to the request; ``aux`` is (logits [T, slots,
+    # B, V] and block ids [T, slots, B] of its T denoising steps,
+    # *routing counts).
+    denoise: bool = False
 
 
 class ContinuousBatcher:
@@ -947,6 +974,9 @@ class ContinuousBatcher:
         self._dp = 1
         self._mp = 1
         self._row_sharding = None
+        # Positions a decode row advances by: 1, or a block-diffusion
+        # model's block (its rows are blocks, denoised in place).
+        self._block = cfg.attn_block if cfg.is_diffusion else 1
         self._refuse_unsupported(cfg, self._draft_cfg, mesh, host_store)
         # What this batcher's programs read besides their arguments: the
         # store of executables keys every one of them on it.
@@ -1252,7 +1282,7 @@ class ContinuousBatcher:
                 "seconds": 0.0,
                 "programs": 0,
             }
-            for kind in ("fused", "decode", "prefill", "spec")
+            for kind in ("fused", "decode", "prefill", "spec", "denoise")
         }
         # Per-request token timeline (PR 10): stats() mirrors of the
         # gateway_ttft-equivalent (submit -> first token, batcher side)
@@ -1291,7 +1321,13 @@ class ContinuousBatcher:
         # denominator of "device programs per scheduler iteration").
         self._programs = {
             "fused": 0, "decode": 0, "prefill": 0, "spec": 0, "draft": 0,
+            "denoise": 0,
         }
+        # Block diffusion: row-forwards, tokens committed to requests,
+        # blocks committed (the gateway_denoise_steps / diffusion_*
+        # families' mirrors), and attention pairs by kind.
+        self._diffusion = dict.fromkeys(("steps", "tokens", "blocks"), 0)
+        self._attn_pairs: dict[str, int] = {}
         self._ragged_rows_sum = 0
         self._ragged_rows_count = 0
         self._work_iterations = 0
@@ -1388,6 +1424,7 @@ class ContinuousBatcher:
         # (:meth:`_chunk_key`): (chunk width, lanes, MoE pin).
         self._jit_chunk = {}  # key -> compiled chunk prefill
         self._jit_fused = {}  # key -> compiled fused step
+        self._jit_denoise = {}  # (chunk, lanes) -> block-diffusion step
         # Keys whose programs were built ahead, and the shapes of a
         # plain dispatch's arguments to build them from
         # (:meth:`_build_fused_ahead`).
@@ -1472,9 +1509,11 @@ class ContinuousBatcher:
         rather than fall back or serve wrong state."""
         models = [m for m in (cfg, draft_cfg) if m is not None]
         recurrent = any(m.is_recurrent for m in models)
+        c = self.config
+        if any(m.is_diffusion for m in models):
+            self._refuse_for_diffusion(cfg, draft_cfg, mesh, host_store)
         if not (recurrent or any(m.is_mla for m in models)):
             return
-        c = self.config
         why = None
         if draft_cfg is not None:
             why = "a draft model (the draft/verify lane" + (
@@ -1513,6 +1552,35 @@ class ContinuousBatcher:
                 else "latent-attention (MLA) models"
             )
             raise ValueError(f"{cfg.name}: {kind} do not serve with {why} yet")
+
+    def _refuse_for_diffusion(self, cfg, draft_cfg, mesh, host_store) -> None:
+        """A block-diffusion model serves through the chunked, fused
+        and grouped programs of one device, greedily; what would need
+        its own block path refuses here, by name."""
+        c = self.config
+        b = cfg.block_length
+        why = None
+        if draft_cfg is not None:
+            why = "a draft model (a block is no draft to verify)"
+        elif not single_device(mesh):
+            why = "a mesh"
+        elif c.host_cache_bytes > 0 or host_store is not None:
+            why = "the host tier / a remote page store"
+        elif c.decode_rounds > 1:
+            why = "decode_rounds > 1 (a program already holds a block)"
+        elif any(self._chunk_width(k) % b for k in c.seq_buckets):
+            why = (
+                f"prefill_chunk {c.prefill_chunk} / seq_buckets "
+                f"{c.seq_buckets} whose chunks are no multiple of the block "
+                f"length {b} (chunks end on block boundaries)"
+            )
+        elif c.page_size % b:
+            why = f"page_size {c.page_size}, no multiple of the block {b}"
+        if why:
+            raise ValueError(
+                f"{cfg.name}: block-diffusion models do not serve with "
+                f"{why} yet"
+            )
 
     def _create_pool(self, cfg: ModelConfig) -> PagedKVCache:
         """An empty pool for ``cfg`` at this batcher's geometry. On a
@@ -1593,11 +1661,11 @@ class ContinuousBatcher:
         window (PR 12) — counted from the CONFIG regardless of live
         engagement, exactly like spec_k, so in-flight admissions stay
         budgeted across a flip. With a draft configured: spec_k + 1
-        verify tokens."""
+        verify tokens. Block diffusion: a block."""
         rt = self._rounds
         if self._draft_cfg is not None:
             rt = max(rt, self.config.spec_k + 1)
-        return rt
+        return max(rt, self._block)
 
     # -- device programs ------------------------------------------------
 
@@ -1976,11 +2044,98 @@ class ContinuousBatcher:
             cfg_chunk, params, tokens, table, start, cache, mesh=self.mesh,
             chunk_state=chunk_state,
         )
+        if self._block > 1:
+            # A block-diffusion prompt ends in its first block, which
+            # the step program denoises: no first token, no unembed.
+            lanes = tokens.shape[0]
+            return (
+                (jnp.zeros((lanes,), jnp.int32), (None,) * lanes), cache,
+                *moe,
+            )
         chunk_out = self._lane_first(
             params, hidden, start, chunk_last, chunk_done, chunk_sampler,
             filters_active,
         )
         return (chunk_out, cache, *moe)
+
+    def _denoise_sample(
+        self,
+        params,
+        cache,
+        block,
+        live,
+        groups=None,
+        chunk_tokens=None,
+        chunk_table=None,
+        chunk_start=None,
+    ):
+        """One BLOCK for every row of a block-diffusion model as ONE
+        device program, plus optionally the next prefill chunk of up to
+        L other sequences riding its first forward.
+
+        ``block`` [slots, B]: each row's block as it starts — the
+        prompt's tail, if the row has one still to ride, then masks;
+        ``live`` [slots] bool: the rows that need a block (a row whose
+        blocks in flight already cover its request writes nothing, as
+        an idle one). The program runs the model's ``denoising_steps``
+        forwards of the blocks against their rows' caches
+        (:func:`~llm_consensus_tpu.models.transformer.denoise_step_paged`),
+        each followed by the unmask rule (:func:`~llm_consensus_tpu.
+        engine.diffusion.unmask`), then one forward of the finished
+        blocks that writes their K/V (SDAR's commit: no block reaches the
+        cache with masks in it), and advances each live row's length by
+        B. Nothing leaves the device between the steps.
+
+        Returns ``(tokens [slots, B], emit_cnt [slots], cache, aux)``:
+        each row's generated positions first, of which ``emit_cnt``
+        belong to the request (up to and including an EOS); ``aux`` =
+        (logits [T, slots, B, V] float32 and the blocks' ids [T, slots,
+        B] at each step — a ``"logits": n`` request's read-out, left on
+        the device otherwise —, *routing counts summed over the
+        forwards)."""
+        cfg = self.cfg
+        steps = cfg.denoising_steps
+        masked0 = jnp.sum(block == cfg.mask_token_id, axis=1).astype(
+            jnp.int32
+        )
+        live = live & (cache.page_table[:, 0] != NULL_PAGE)
+
+        def forward(x, cache, *lanes, unembed=True):
+            logits, _, cache, *moe = denoise_step_paged(
+                cfg, params, x, cache, groups, *lanes, write_mask=live,
+                unembed=unembed,
+            )
+            return logits, cache, moe
+
+        logits, cache, moe = forward(
+            block, cache, chunk_tokens, chunk_table, chunk_start
+        )
+        x = _diffusion.unmask(cfg, logits, block, masked0, 0)
+        logits_all, ids_all = logits[None], block[None]
+        if steps > 1:
+
+            def body(carry, t):
+                cache, x, moe = carry
+                logits, cache, more = forward(x, cache)
+                x_next = _diffusion.unmask(cfg, logits, x, masked0, t)
+                moe = [a + b for a, b in zip(moe, more)]
+                return (cache, x_next, moe), (logits, x)
+
+            (cache, x, moe), (logits_t, ids_t) = jax.lax.scan(
+                body, (cache, x, moe), jnp.arange(1, steps)
+            )
+            logits_all = jnp.concatenate([logits_all, logits_t])
+            ids_all = jnp.concatenate([ids_all, ids_t])
+        _, cache, more = forward(x, cache, unembed=False)
+        moe = [a + b for a, b in zip(moe, more)]
+        cache = replace(
+            cache,
+            length=cache.length + live.astype(cache.length.dtype) * x.shape[1],
+        )
+        new, emit = _diffusion.emitted(
+            cfg, x, masked0, self.tokenizer.eos_id
+        )
+        return new, emit, cache, (logits_all, ids_all, *moe)
 
     def _spec_sample(
         self,
@@ -2195,7 +2350,8 @@ class ContinuousBatcher:
 
     def _lanes_for(self, chunk: int) -> int:
         """Chunk lanes of the WIDE chunk programs at this chunk width:
-        what fits, beside every slot's decode row, on the token axis
+        what fits, beside every slot's decode row (a block of B rows
+        for a block-diffusion model), on the token axis
         the int8 matmul kernel takes (``quant_matmul._MAX_M`` rows: past
         it every matrix leaves the kernel, and a decoding row's wait
         stops buying throughput well before) — and never more lanes
@@ -2214,7 +2370,9 @@ class ContinuousBatcher:
             or (self.cfg.is_moe and self.cfg.moe_capacity_factor > 0)
         ):
             return 1
-        return max(1, min(c.max_slots, (_QMM_MAX_ROWS - c.max_slots) // chunk))
+        return max(1, min(
+            c.max_slots, (_QMM_MAX_ROWS - c.max_slots * self._block) // chunk
+        ))
 
     def _chunk_key(self, chunk: int, lanes: int, s_bucket: int):
         """(program key, pinned config) of a chunk program. The bucket
@@ -2383,6 +2541,20 @@ class ContinuousBatcher:
             )
         return self._jit_fused[key]
 
+    def _denoise_fn(self, chunk: int, lanes: int):
+        """Jitted per (chunk width, lanes): the block-diffusion step
+        (:meth:`_denoise_sample`), with ``lanes`` chunk lanes riding
+        its first forward (0: none). Module ``jit_denoise_step`` either
+        way."""
+        key = (chunk, lanes)
+        if key not in self._jit_denoise:
+            self._jit_denoise[key] = StoredJit(
+                _step_program("denoise_step", self._denoise_sample),
+                key=(self._key, "denoise", key),
+                donate_argnums=(1,),
+            )
+        return self._jit_denoise[key]
+
     def _build_fused_ahead(self, chunk: int, s_bucket: int) -> None:
         """Trace and compile the chunk programs a decoding row could
         meet, once: the fused step at both widths (one lane and
@@ -2431,11 +2603,32 @@ class ContinuousBatcher:
             rows, gm = i32((c.max_slots,)), i32((self._groups.max_groups,))
             grouped.append(DecodeGroupArrays(rows, gm, gm, rows))
         widths = sorted({1, self._lanes_for(chunk)})
+        if self._block > 1:
+            # Block diffusion: the block program without lanes, grouped
+            # (the plain one is what gave the shapes), and with each
+            # width of lanes, grouped and not.
+            for groups in grouped[1:]:
+                self._denoise_fn(0, 0).build(*self._plain_shapes, groups)
         for lanes in widths:
             lane = (
                 i32((lanes, chunk)), i32((lanes, c.pages_per_seq)),
                 i32((lanes,)),
             )
+            if self._block > 1:
+                for groups in grouped:
+                    self._denoise_fn(chunk, lanes).build(
+                        *self._plain_shapes, groups, *lane
+                    )
+                if lanes > 1:
+                    self._chunk_fn(chunk, lanes, s_bucket).build(
+                        self._plain_shapes[0], *lane, self._plain_shapes[1],
+                        i32((lanes,)),
+                        jax.ShapeDtypeStruct((lanes,), jnp.bool_),
+                        (i32((lanes,)), f32((lanes,)), i32((lanes,)),
+                         f32((lanes,))),
+                        False,
+                    )
+                continue
             # Last positions, done, and the lanes' sampler rows.
             ends = (
                 i32((lanes,)), jax.ShapeDtypeStruct((lanes,), jnp.bool_),
@@ -2501,7 +2694,9 @@ class ContinuousBatcher:
         programs computed them: no second forward pass. Greedy requests
         only, and only where a program is one decode step (no
         ``decode_rounds`` or draft): elsewhere a program keeps only its
-        last step's logits.
+        last step's logits. For a block-diffusion model n counts
+        denoising steps: B rows of logits a step, the block's input ids
+        at each in ``ServeResult.logit_ids``.
 
         ``top_k``/``top_p``: ``None`` inherits the batcher's
         config-level sampler; any EXPLICIT value is authoritative —
@@ -2529,6 +2724,11 @@ class ContinuousBatcher:
             max_new_tokens = c.max_new_tokens
         if max_new_tokens <= 0:
             raise ValueError(f"max_new_tokens must be > 0, got {max_new_tokens}")
+        if self._block > 1 and temperature > 0:
+            raise ValueError(
+                f"{self.cfg.name}: block diffusion serves greedy requests "
+                "(temperature 0) only yet"
+            )
         if logits:
             if temperature > 0:
                 raise ValueError("'logits' is for greedy requests only")
@@ -2556,6 +2756,12 @@ class ContinuousBatcher:
                 cap,
             )
         ids = np.asarray(full_ids[-cap:], np.int32)
+        if len(ids) < self._block:
+            raise ValueError(
+                f"{self.cfg.name}: a prompt of {len(ids)} tokens is shorter "
+                f"than one block ({self._block}): block diffusion prefills "
+                "whole blocks"
+            )
         dflt = c.sampler or SamplerConfig()
         stop = tuple(stop or ())
         window = stop_tail_window(self.tokenizer, stop)
@@ -3229,6 +3435,17 @@ class ContinuousBatcher:
                 "device_programs_prefill": self._programs["prefill"],
                 "device_programs_spec": self._programs["spec"],
                 "device_programs_draft": self._programs["draft"],
+                "device_programs_denoise": self._programs["denoise"],
+                # Block diffusion: the mirrors of
+                # gateway_denoise_steps_total (summed over kinds),
+                # gateway_diffusion_tokens_total and
+                # gateway_diffusion_blocks_total, and of
+                # gateway_attention_pairs_total{kind}.
+                **{f"diffusion_{k}": n for k, n in self._diffusion.items()},
+                **{
+                    f"attention_pairs_{k}": n
+                    for k, n in self._attn_pairs.items()
+                },
                 "ragged_rows_sum": self._ragged_rows_sum,
                 "ragged_rows_count": self._ragged_rows_count,
                 # Chunk programs by how many lanes carried a chunk
@@ -3481,9 +3698,15 @@ class ContinuousBatcher:
         coverage, evict registry-only pages if the free list falls
         short, allocate, optionally copy the boundary page, and
         register this prompt's own full pages for successors.
+
+        A block-diffusion prompt prefills its whole blocks alone (the
+        tail rides the first block), so that is the prompt matched,
+        copied and registered here: a page that will hold an unfinished
+        block is never offered to successors.
         """
         c = self.config
         ids = req.prompt_ids
+        ids = ids[: len(ids) - len(ids) % self._block]
         L = len(ids)
         pg = c.page_size
         bucket = self._bucket(L)
@@ -3546,7 +3769,14 @@ class ContinuousBatcher:
                     shared_pages = match.pages
                     start0 = match.shared_tokens
                     if match.boundary_page is not None:
+                        # A block's K/V depend on the whole block: a
+                        # copy ends on a block boundary.
                         boundary = match.boundary_common
+                        boundary -= boundary % self._block
+                        if boundary < max(2, c.page_size // 4):
+                            boundary = 0
+                            match.boundary_page = None
+                            match.boundary_common = 0
                     # Fall through registry-miss -> host-hit (PR 4):
                     # extend the matched chain through pages the
                     # offload tier still holds. Each hit is page_size
@@ -3723,6 +3953,7 @@ class ContinuousBatcher:
                     state_dep=state_node,
                     chain=chain,
                     pages_matched_n=matched_n,
+                    tail=req.prompt_ids[L:] if self._block > 1 else None,
                 )
                 _flight.flight_recorder().record(
                     "admit",
@@ -4180,25 +4411,46 @@ class ContinuousBatcher:
         ``saved_tokens_per_step``, which is whole pages), the chunk
         lane's up to its end. Like ``kv_read_tokens`` it does not
         deduct what a sliding window skips.
+
+        A ``"denoise"`` program (block diffusion) is ``denoising_steps
+        + 1`` forwards of each row's block of B at its committed length
+        L — each reads the row's L + B positions, rewrites the block and
+        reads the weights — with the chunk lanes in the first.
+        ``attn_pairs`` counts the (query, key) pairs attention computes
+        a layer under the model's rule, summed over the forwards.
         """
         pg = self.config.page_size
-        kv_read = kv_write = tokens = pages = 0
+        kv_read = kv_write = tokens = pages = pairs = 0
         lengths = []
-        for _, s in rows_now:
-            L = s.prompt_len + len(s.generated)
-            lengths.append(L)
-            if kind == "spec":
-                kv_read += L + k
-                kv_write += k + 1
-                tokens += k + 1
-                pages += -(-(L + k) // pg)
-            else:
-                kv_read += k * L + k * (k - 1) // 2
-                kv_write += k
-                tokens += k
-                pages += sum(-(-(L + j) // pg) for j in range(k))
+        passes = 1
+        if kind == "denoise":
+            b = self._block
+            passes = self.cfg.denoising_steps + 1
+            for _, s in rows_now:
+                L = s.length
+                kv_read += passes * (L + b)
+                kv_write += passes * b
+                tokens += passes * b
+                pages += passes * -(-(L + b) // pg)
+                pairs += passes * b * (L + b)
+        else:
+            for _, s in rows_now:
+                L = s.prompt_len + len(s.generated)
+                lengths.append(L)
+                if kind == "spec":
+                    kv_read += L + k
+                    kv_write += k + 1
+                    tokens += k + 1
+                    pages += -(-(L + k) // pg)
+                    pairs += (k + 1) * (L + 1) + k * (k + 1) // 2
+                else:
+                    kv_read += k * L + k * (k - 1) // 2
+                    kv_write += k
+                    tokens += k
+                    pages += sum(-(-(L + j) // pg) for j in range(k))
+                    pairs += k * (L + 1) + k * (k - 1) // 2
         if self._group_decode and rows_now:
-            shared_steps = 1 if kind == "spec" else k
+            shared_steps = 1 if kind == "spec" else k * passes
             saved = self._groups.saved_tokens_per_step * shared_steps
             kv_read -= min(kv_read, saved)
             pages -= min(pages, saved // pg)
@@ -4207,9 +4459,14 @@ class ContinuousBatcher:
             kv_write += width
             tokens += width
             pages += -(-read_end // pg)
+            # Queries at [end - width, end), each seeing through the
+            # last slot of its block (its own under the causal rule).
+            b = self._block
+            n0, n1 = (read_end - width) // b, read_end // b
+            pairs += b * b * (n1 * (n1 + 1) - n0 * (n0 + 1)) // 2
         cost = program_hbm_cost(
             self.cfg,
-            weight_bytes=self._weight_bytes,
+            weight_bytes=self._weight_bytes * passes,
             weight_params=self._weight_params,
             kv_token_bytes=self._kv_token_bytes,
             kv_read_tokens=kv_read,
@@ -4232,6 +4489,7 @@ class ContinuousBatcher:
             cost["hbm_bytes"] += d["hbm_bytes"]
             cost["flops"] += d["flops"]
         cost["attn_pages_read"] = pages
+        cost["attn_pairs"] = pairs
         return cost
 
     def _mbu_account(self, kind: str, cost: dict | None, dur: float) -> None:
@@ -4252,6 +4510,7 @@ class ContinuousBatcher:
             self._ssm_tokens[kind] += cost["tokens"]
         _M_ATTN_TOKENS_READ.labels(kind=kind).inc(cost["kv_read_tokens"])
         _M_ATTN_PAGES_READ.labels(kind=kind).inc(pages)
+        _M_ATTN_PAIRS.labels(kind=kind).inc(cost["attn_pairs"])
         with self._lock:
             m = self._mbu[kind]
             m["hbm_bytes"] += cost["hbm_bytes"]
@@ -4259,6 +4518,9 @@ class ContinuousBatcher:
             m["kv_read_tokens"] += cost["kv_read_tokens"]
             m["kv_write_tokens"] += cost["kv_write_tokens"]
             m["attn_pages_read"] += pages
+            self._attn_pairs[kind] = (
+                self._attn_pairs.get(kind, 0) + cost["attn_pairs"]
+            )
             m["seconds"] += dur
             m["programs"] += 1
         peak = self.config.hbm_gbps * 1e9
@@ -4480,12 +4742,16 @@ class ContinuousBatcher:
                 if not la.done[lane]:
                     continue
                 self._prompt_ended(
-                    idx, slot, int(first_np[lane]), chunk_logits[lane]
+                    idx, slot,
+                    None if self._block > 1 else int(first_np[lane]),
+                    chunk_logits[lane],
                 )
             self._apply_row_patch()
             return True
 
-    def _prompt_ended(self, idx: int, slot: _Slot, first: int, logits) -> None:
+    def _prompt_ended(
+        self, idx: int, slot: _Slot, first: int | None, logits
+    ) -> None:
         """A slot's last chunk has landed, with its first token sampled
         by the program that carried it (a standalone chunk or a fused
         step): make the row visible to the decode program — table, true
@@ -4493,9 +4759,11 @@ class ContinuousBatcher:
         committed-minus-one invariant starts in sync with the target's),
         as one entry of the fetch's patch — and flip it to decoding.
         ``logits``: the device [V] row the token was sampled from,
-        fetched for a ``"logits": n`` request alone."""
+        fetched for a ``"logits": n`` request alone. A block-diffusion
+        prompt ends with no token (``first`` None): its row starts
+        decoding with its first block."""
         req = slot.request
-        if req.logits_n:
+        if first is not None and req.logits_n:
             with self._phase("device_wait"):
                 req.logit_rows.append(np.asarray(logits))
         self._row_install(idx, slot, slot.prompt_len)
@@ -4542,11 +4810,14 @@ class ContinuousBatcher:
         if draft:
             self.draft_cache = _with_rows(self.draft_cache, draft[0])
 
-    def _activate(self, idx: int, slot: _Slot, first: int) -> None:
-        """Flip a slot to decoding with its first sampled token."""
+    def _activate(self, idx: int, slot: _Slot, first: int | None) -> None:
+        """Flip a slot to decoding with its first sampled token — or,
+        for a block-diffusion model (``first`` None), with its first
+        block still to denoise."""
         req = slot.request
-        slot.generated.append(first)
-        _M_GENERATED.inc()
+        if first is not None:
+            slot.generated.append(first)
+            _M_GENERATED.inc()
         slot.phase = "decode"
         slot.deps = []
         # First generated token: the request's TTFT anchor (batcher
@@ -4554,7 +4825,8 @@ class ContinuousBatcher:
         # gateway_ttft_seconds keeps its arrival-to-first-byte view)
         # and the origin of the inter-token-gap timeline.
         now = time.perf_counter()
-        slot.t_first = now
+        if first is not None:
+            slot.t_first = now
         slot.t_last_tok = now
         if self._group_decode or self.draft_cache is not None:
             # The row's prompt-prefix page run (full pages only — the
@@ -4579,6 +4851,9 @@ class ContinuousBatcher:
             )
         with self._lock:
             _M_ACTIVE.set(self._decoding())
+            if first is None:
+                slot.length = slot.prompt_len
+                return
             self._ttft_sum += now - req.t_submit
             self._ttft_count += 1
         self._last_tokens[idx] = first
@@ -4633,7 +4908,7 @@ class ContinuousBatcher:
         return {
             "id": req.rid,
             "trace_id": _tracing.trace_id_of(req.trace),
-            "prompt_tokens": slot.prompt_len,
+            "prompt_tokens": len(req.prompt_ids),
             "new_tokens": len(slot.generated),
             "ttft_s": (
                 slot.t_first - req.t_submit
@@ -4714,11 +4989,16 @@ class ContinuousBatcher:
                     num_tokens=len(slot.generated),
                     timing=summary,
                     logits=(
-                        np.stack(
+                        np.concatenate([
+                            np.atleast_2d(r) for r in
                             slot.request.logit_rows[: slot.request.logits_n]
-                        )
+                        ])
                         if slot.request.logit_rows
                         else None
+                    ),
+                    logit_ids=(
+                        slot.request.logit_ids[: slot.request.logits_n]
+                        or None
                     ),
                 )
             )
@@ -4771,6 +5051,52 @@ class ContinuousBatcher:
                 )
             return counts_dev
         return rows(self._counts)
+
+    def _note_dispatch_gap(self, t0: float) -> None:
+        """A program is about to be enqueued at ``t0``: observe the
+        un-overlapped host time before it and, where rows decode on a
+        device that holds nothing, the drain that left it so."""
+        # Un-overlapped host time: the gap since the pipeline drained
+        # (retirement, admission, prefill chunks, group rebuilds that
+        # no in-flight program hid). A dispatch issued with a program
+        # still in flight spent its host time in that program's shadow
+        # and observes 0, keeping depth-1 and depth-2 distributions
+        # count-comparable; idle waits reset _last_step_end and never
+        # count.
+        overhead = None
+        if self._last_step_end is not None:
+            overhead = t0 - self._last_step_end
+        elif self._inflight:
+            overhead = 0.0
+        if self._last_step_end is not None or (
+            self._drained_by == "standalone_chunk"
+        ):
+            # Rows were decoding and the device holds nothing: this
+            # program is one it had to wait for.
+            after = self._drained_by or "other"
+            _M_PIPELINE_DRAINS.labels(after=after).inc()
+            with self._lock:
+                self._pipeline_drains[after] += 1
+        self._drained_by = None
+        if overhead is not None:
+            _M_SCHED_OVERHEAD.observe(overhead)
+            if self.controller is not None:
+                # Chunk/depth steering signal: the same
+                # un-overlapped observation the histogram gets.
+                self.controller.note_overhead(overhead)
+            with self._lock:
+                self._sched_overhead_sum += overhead
+                self._sched_overhead_count += 1
+            if overhead > 0 and self._last_step_end is not None:
+                # The Chrome export's host track: un-overlapped
+                # scheduler work between the pipeline draining and this
+                # dispatch (overlapped dispatches observe 0 and emit
+                # nothing — the track shows exactly the time the device
+                # sat idle waiting on the host).
+                _flight.flight_recorder().record(
+                    "host", self._last_step_end, overhead
+                )
+        self._last_step_end = None
 
     def _dispatch(
         self,
@@ -4863,47 +5189,7 @@ class ContinuousBatcher:
 
         groups = self._groups.arrays() if self._group_decode else None
         t0 = time.perf_counter()
-        # Un-overlapped host time: the gap since the pipeline drained
-        # (retirement, admission, prefill chunks, group rebuilds that
-        # no in-flight program hid). A dispatch issued with a program
-        # still in flight spent its host time in that program's shadow
-        # and observes 0, keeping depth-1 and depth-2 distributions
-        # count-comparable; idle waits reset _last_step_end and never
-        # count.
-        overhead = None
-        if self._last_step_end is not None:
-            overhead = t0 - self._last_step_end
-        elif self._inflight:
-            overhead = 0.0
-        if self._last_step_end is not None or (
-            self._drained_by == "standalone_chunk"
-        ):
-            # Rows were decoding and the device holds nothing: this
-            # program is one it had to wait for.
-            after = self._drained_by or "other"
-            _M_PIPELINE_DRAINS.labels(after=after).inc()
-            with self._lock:
-                self._pipeline_drains[after] += 1
-        self._drained_by = None
-        if overhead is not None:
-            _M_SCHED_OVERHEAD.observe(overhead)
-            if self.controller is not None:
-                # Chunk/depth steering signal (PR 15): the same
-                # un-overlapped observation the histogram gets.
-                self.controller.note_overhead(overhead)
-            with self._lock:
-                self._sched_overhead_sum += overhead
-                self._sched_overhead_count += 1
-            if overhead > 0 and self._last_step_end is not None:
-                # The Chrome export's host track: un-overlapped
-                # scheduler work between the pipeline draining and this
-                # dispatch (overlapped dispatches observe 0 and emit
-                # nothing — the track shows exactly the time the device
-                # sat idle waiting on the host).
-                _flight.flight_recorder().record(
-                    "host", self._last_step_end, overhead
-                )
-        self._last_step_end = None
+        self._note_dispatch_gap(t0)
         # Snapshot rule as rows(): _tok_dirty is reset and _last_tokens
         # mutated right after this dispatch; the spec branch reuses the
         # same snapshot for its counts patch.
@@ -5166,6 +5452,87 @@ class ContinuousBatcher:
         )
         self._dispatch_tail(rec, groups, k)
 
+    def _block_rows(self) -> list[tuple[int, _Slot]]:
+        """Decoding rows of a block-diffusion model that need another
+        block: those whose blocks in flight do not already cover their
+        ``max_new_tokens`` (an EOS still ends one early)."""
+        return [
+            (i, s) for i, s in enumerate(self._slots)
+            if s is not None and s.phase == "decode"
+            and s.planned < s.request.max_new_tokens
+        ]
+
+    def _dispatchable(self) -> int:
+        """Rows the next step program would advance: every decoding
+        row, or for block diffusion :meth:`_block_rows`."""
+        if self._block == 1:
+            return self._decoding()
+        return len(self._block_rows())
+
+    def _dispatch_denoise(self, chunk_idxs: list[int] | None = None) -> None:
+        """Enqueue ONE block-diffusion program (:meth:`_denoise_sample`)
+        for the rows that need a block, with the ready chunks of
+        ``chunk_idxs`` riding its first forward, a lane each, as the
+        fused step takes them. Its input is host data alone — a row's
+        block starts as its prompt's tail, once, then as masks — so a
+        program n+1 enqueued before program n's fetch needs nothing of
+        n but the cache it donated."""
+        c = self.config
+        b = self._block
+        rows_now = self._block_rows()
+        block = np.full((c.max_slots, b), self.cfg.mask_token_id, np.int32)
+        live = np.zeros((c.max_slots,), bool)
+        for i, s in rows_now:
+            live[i] = True
+            block[i, : len(s.tail)] = s.tail
+        groups = self._groups.arrays() if self._group_decode else None
+        t0 = time.perf_counter()
+        self._note_dispatch_gap(t0)
+        args = (self.params, self.cache, jnp.asarray(block), jnp.asarray(live))
+        if self._plain_shapes is None:
+            self._plain_shapes = jax.tree.map(_abstract, args)
+        chunk_recs: list[_InflightChunk] = []
+        if chunk_idxs:
+            la = self._lane_args(chunk_idxs)
+            head = self._slots[chunk_idxs[0]]
+            new, emit, self.cache, aux = self._denoise_fn(
+                head.chunk, la.lanes
+            )(*args, groups, la.ids, la.tables, la.starts)
+            kind = "fused"
+            self._count_lanes(kind, len(chunk_idxs))
+            for lane, idx in enumerate(chunk_idxs):
+                slot = self._slots[idx]
+                _flight.flight_recorder().record(
+                    "chunk", t0,
+                    trace_id=_tracing.trace_id_of(slot.request.trace),
+                    slot=idx, pos=slot.next_pos, width=slot.chunk, fused=1,
+                )
+                chunk_recs.append(_InflightChunk(
+                    idx=idx, slot=slot, done=bool(la.done[lane]), lane=lane,
+                    logits=None, pos=slot.next_pos, width=slot.chunk,
+                ))
+                self._chunk_dispatched(slot)
+        else:
+            la = None
+            new, emit, self.cache, aux = self._denoise_fn(0, 0)(*args, groups)
+            kind = "denoise"
+        ev = self._count_program(
+            kind, rows=len(rows_now) + len(chunk_recs), rounds=1
+        )
+        cost = self._program_cost(
+            "denoise", rows_now, 1, chunk_ext=la.ext if la else ()
+        )
+        for _, s in rows_now:
+            s.length += b
+            s.planned += b - len(s.tail)
+            s.tail = s.tail[:0]
+        rec = _Inflight(
+            tokens=new, next_input=None, t0=t0, k=1, rows=rows_now,
+            chunks=chunk_recs, emit_cnt=emit, flight=ev, cost=cost, aux=aux,
+            denoise=True,
+        )
+        self._dispatch_tail(rec, groups, self.cfg.denoising_steps + 1)
+
     def _dispatch_tail(self, rec: "_Inflight", groups, k: int) -> None:
         """Enqueue the dispatched program and account the window —
         shared by the spec and plain branches so the bookkeeping
@@ -5205,12 +5572,15 @@ class ContinuousBatcher:
             next_np = np.asarray(rec.tokens)  # [slots, k] — THE host sync
             cnt_np = (
                 np.asarray(rec.emit_cnt)
-                if (rec.spec or rec.rounds)
+                if (rec.spec or rec.rounds or rec.denoise)
                 else None
             )
+            # A denoise program's aux holds its steps' logits and ids
+            # before the routing counts.
+            at = 2 if rec.denoise else 1
             moe_np = (
-                np.asarray(rec.aux[1])
-                if rec.aux is not None and len(rec.aux) > 1
+                np.asarray(rec.aux[at])
+                if rec.aux is not None and len(rec.aux) > at
                 else None
             )
             # The first tokens of the lanes that ended their prompts:
@@ -5218,16 +5588,52 @@ class ContinuousBatcher:
             # tokens are — no wait on the one behind it.
             first_np = (
                 np.asarray(rec.chunk_first)
-                if any(ch.done for ch in rec.chunks)
+                if rec.chunk_first is not None
+                and any(ch.done for ch in rec.chunks)
                 else None
             )
         with self._phase("retire"):
+            forwards = rec.k
+            if rec.denoise:
+                forwards = self.cfg.denoising_steps + 1
+                self._count_blocks(self._kind_of(rec), len(rec.rows))
             if moe_np is not None:
-                self._count_moe(
-                    "fused" if rec.chunks else "decode", moe_np, rec.k
-                )
+                self._count_moe(self._kind_of(rec), moe_np, forwards)
             self._credit_fetched(rec, next_np, cnt_np, first_np)
             self._apply_row_patch()
+
+    def _kind_of(self, rec: "_Inflight") -> str:
+        """A fetched program's kind, as its dispatch counted it."""
+        if rec.spec:
+            return "spec"
+        if rec.chunks:
+            return "fused"
+        return "denoise" if rec.denoise else "decode"
+
+    def _count_blocks(self, kind: str, rows: int) -> None:
+        """A retired block-diffusion program committed ``rows`` blocks,
+        each after the model's denoising steps and one commit forward."""
+        steps = rows * (self.cfg.denoising_steps + 1)
+        _M_DENOISE_STEPS.labels(kind=kind).inc(steps)
+        _M_DIFF_BLOCKS.inc(rows)
+        with self._lock:
+            self._diffusion["steps"] += steps
+            self._diffusion["blocks"] += rows
+
+    def _read_denoise_logits(self, rec: "_Inflight", i: int, req) -> None:
+        """A ``"logits": n`` request's read-out from the block program
+        of row ``i``: each denoising step's B rows of logits and the
+        block's ids at that step, until n steps are in."""
+        steps = min(
+            req.logits_n - len(req.logit_rows), self.cfg.denoising_steps
+        )
+        if steps <= 0:
+            return
+        with self._phase("device_wait"):
+            logits = np.asarray(rec.aux[0][:steps, i])
+            ids = np.asarray(rec.aux[1][:steps, i])
+        req.logit_rows.extend(logits)
+        req.logit_ids.extend(ids.tolist())
 
     def _count_moe(self, kind: str, moe_np, steps: int = 1) -> None:
         """A retired program's expert-routing counts (int32 [experts
@@ -5275,11 +5681,7 @@ class ContinuousBatcher:
             # export's device track is these windows back to back.
             rec.flight.t0 = start
             rec.flight.dur = dur
-        self._mbu_account(
-            "spec" if rec.spec else ("fused" if rec.chunks else "decode"),
-            rec.cost,
-            dur,
-        )
+        self._mbu_account(self._kind_of(rec), rec.cost, dur)
         _M_DISPATCH_INFLIGHT.set(len(self._inflight))
         alive = [(i, s) for i, s in rec.rows if self._slots[i] is s]
         with self._lock:
@@ -5378,7 +5780,9 @@ class ContinuousBatcher:
             done = False
             n_emit = int(cnt_np[i]) if cnt_np is not None else rec.k
             want = slot.request.logits_n
-            if want > len(slot.request.logit_rows) and rec.aux is not None:
+            if rec.denoise:
+                self._read_denoise_logits(rec, i, slot.request)
+            elif want > len(slot.request.logit_rows) and rec.aux is not None:
                 # One step a program (``submit`` saw to it): this row of
                 # the step's logits is the token credited just below.
                 slot.request.logit_rows.append(np.asarray(rec.aux[0][i]))
@@ -5386,19 +5790,28 @@ class ContinuousBatcher:
                 tok = int(next_np[i, j])
                 slot.generated.append(tok)
                 self._last_tokens[i] = tok
-                # Token-timeline stamp (PR 10): tokens surface at the
-                # fetch — the first of this fetch carries the gap since
-                # the row's previous token, the rest arrived with it
-                # (gap 0), which is exactly what a streaming client
-                # observes. One observation per generated token past
-                # the request's first (that one is TTFT's).
-                gap = step_end - slot.t_last_tok if j == 0 else 0.0
-                slot.t_last_tok = step_end
-                slot.gaps.append(gap)
-                _M_TBT.observe(gap)
-                tbt_sum += gap
-                tbt_count += 1
                 emitted_total += 1
+                if slot.t_first is None:
+                    # A block-diffusion row's first token comes with its
+                    # first block: the request's TTFT anchor.
+                    slot.t_first = slot.t_last_tok = step_end
+                    with self._lock:
+                        self._ttft_sum += step_end - slot.request.t_submit
+                        self._ttft_count += 1
+                else:
+                    # Token-timeline stamp: tokens surface at
+                    # the fetch — the first of this fetch carries the
+                    # gap since the row's previous token, the rest
+                    # arrived with it (gap 0), which is exactly what a
+                    # streaming client observes. One observation per
+                    # generated token past the request's first (that
+                    # one is TTFT's).
+                    gap = step_end - slot.t_last_tok if j == 0 else 0.0
+                    slot.t_last_tok = step_end
+                    slot.gaps.append(gap)
+                    _M_TBT.observe(gap)
+                    tbt_sum += gap
+                    tbt_count += 1
                 done = (
                     tok == self.tokenizer.eos_id
                     or len(slot.generated) >= slot.request.max_new_tokens
@@ -5412,6 +5825,10 @@ class ContinuousBatcher:
                 self._retire(i)
         if emitted_total:
             _M_GENERATED.inc(emitted_total)
+            if rec.denoise:
+                _M_DIFF_TOKENS.inc(emitted_total)
+                with self._lock:
+                    self._diffusion["tokens"] += emitted_total
         if tbt_count:
             with self._lock:
                 self._tbt_sum += tbt_sum
@@ -5458,9 +5875,12 @@ class ContinuousBatcher:
             if ch.done:
                 # Final chunk: the fused program sampled the first token
                 # from the logits it computed (same PRNG draw, same
-                # unembed as the standalone path).
+                # unembed as the standalone path) — a block-diffusion
+                # program none: its prompt ends in a block.
                 self._prompt_ended(
-                    ch.idx, slot, int(first_np[ch.lane]), ch.logits
+                    ch.idx, slot,
+                    None if first_np is None else int(first_np[ch.lane]),
+                    ch.logits,
                 )
 
     def _run(self) -> None:
@@ -5584,7 +6004,7 @@ class ContinuousBatcher:
             fused = (
                 bool(chunk_idxs)
                 and self._fused_ok
-                and self._decoding()
+                and self._dispatchable()
                 and not spec_now
             )
             if chunk_idxs and not fused:
@@ -5603,7 +6023,7 @@ class ContinuousBatcher:
                     with self._lock:
                         self._work_iterations += 1
                     continue
-            if self._decoding():
+            if self._dispatchable():
                 # Software pipeline: enqueue the next program FIRST,
                 # then fetch the oldest once the window is full — the
                 # fetch's host sync lands while the newer program(s)
@@ -5639,21 +6059,26 @@ class ContinuousBatcher:
                     kind=(
                         "spec"
                         if spec_now
+                        else "denoise" if self._block > 1
                         else ("fused" if fused else "decode")
                     ),
                     rows=self._decoding(),
                 ):
-                    if spec_now:
-                        # Rows that decoded through an off window need
-                        # their draft mirror replayed first — no-op in
-                        # the steady state (every lag-free iteration).
-                        self._spec_catch_up()
-                    self._dispatch(
-                        chunk_idxs if fused else None,
-                        spec=spec_now,
-                        rounds=rounds_now,
-                        rounds_choice=rounds_choice,
-                    )
+                    if self._block > 1:
+                        self._dispatch_denoise(chunk_idxs if fused else None)
+                    else:
+                        if spec_now:
+                            # Rows that decoded through an off window
+                            # need their draft mirror replayed first —
+                            # no-op in the steady state (every lag-free
+                            # iteration).
+                            self._spec_catch_up()
+                        self._dispatch(
+                            chunk_idxs if fused else None,
+                            spec=spec_now,
+                            rounds=rounds_now,
+                            rounds_choice=rounds_choice,
+                        )
                 while len(self._inflight) >= self._depth:
                     self._fetch_one()
                 progress = True
@@ -5681,22 +6106,25 @@ class ContinuousBatcher:
                 self._work.clear()
 
 
-def _meta_with_logits(timing: dict | None, logits) -> dict | None:
+def _meta_with_logits(
+    timing: dict | None, logits, block_ids: list | None = None
+) -> dict | None:
     """The response's ``meta``: the request's summary, and for a
     ``"logits": n`` request a COPY of it (the summary ring keeps the
-    original) with the rows as base64 of little-endian float32."""
+    original) with the rows as base64 of little-endian float32 — and,
+    for a block-diffusion model, each denoising step's block ids."""
     if logits is None:
         return timing
     rows = np.ascontiguousarray(logits, dtype="<f4")
-    return {
-        **(timing or {}),
-        "logits": {
-            "positions": int(rows.shape[0]),
-            "vocab": int(rows.shape[1]),
-            "dtype": "float32",
-            "b64": base64.b64encode(rows.tobytes()).decode("ascii"),
-        },
+    doc = {
+        "positions": int(rows.shape[0]),
+        "vocab": int(rows.shape[1]),
+        "dtype": "float32",
+        "b64": base64.b64encode(rows.tobytes()).decode("ascii"),
     }
+    if block_ids is not None:
+        doc["block_ids"] = block_ids
+    return {**(timing or {}), "logits": doc}
 
 
 class ContinuousBackend(_backend_base.Backend):
@@ -5749,7 +6177,7 @@ class ContinuousBackend(_backend_base.Backend):
         return [
             GenerationResult(
                 text=o.text, num_tokens=o.num_tokens,
-                meta=_meta_with_logits(o.timing, o.logits),
+                meta=_meta_with_logits(o.timing, o.logits, o.logit_ids),
             )
             for o in outs
         ]

@@ -11,9 +11,11 @@ belongs to each run's own server child).
 ``scripts/drain_count.py`` (this checkout's), whose line rides the record
 as ``drains``; the trace itself is deleted.
 Each run's result line goes to ``chiprun_out/<tag>/results.jsonl`` with
-its side, its server log's "built in" lines and the entries it added
-to the compile cache the runs share; the server logs are copied beside
-it. Measures nothing itself: ``benchmark/run.py`` does.
+its side, its server log's "built in" lines, the entries it added to the
+compile cache the runs share and ``left``: the processes a run starts
+(the server, its launcher, a reference child) still alive after it,
+which must be none; the server logs are copied beside it. Measures
+nothing itself: ``benchmark/run.py`` does.
 """
 
 import glob
@@ -44,6 +46,16 @@ def cache_listing() -> list:
         st = os.stat(os.path.join(d, name))
         out.append((name, st.st_size, st.st_mtime))
     return out
+
+
+def left_alive() -> list:
+    """Processes of a run (server, launcher, reference child) alive."""
+    time.sleep(2)
+    r = subprocess.run(
+        ["pgrep", "-af", r"launcher\.py|llm_consensus_tpu serve|reference/"],
+        capture_output=True, text=True,
+    )
+    return [line for line in r.stdout.splitlines() if "pgrep" not in line]
 
 
 def drains(profile_dir: str):
@@ -104,7 +116,7 @@ def main() -> int:
             if mtime >= t0 and not name.endswith("-atime")
         )
         rec = {
-            "t0": t0, "cache_wrote": wrote,
+            "t0": t0, "cache_wrote": wrote, "left": left_alive(),
             "n": n, "side": side, "cell": cell, "seed": int(seed),
             "trace": int(trace), "rc": r.returncode, "wall_s": round(wall, 1),
             "built": built, "result": result,
@@ -129,7 +141,8 @@ def main() -> int:
             }
         print(json.dumps({"n": n, "side": side, "cell": cell, "seed": seed,
                           "trace": trace, "rc": r.returncode,
-                          "wall_s": round(wall, 1), **short})[:3000],
+                          "wall_s": round(wall, 1), "left": rec["left"],
+                          **short})[:3000],
               flush=True)
         if keep:
             print(json.dumps({"n": n, "drains": rec["drains"]}), flush=True)

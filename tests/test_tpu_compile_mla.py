@@ -365,3 +365,63 @@ def test_recurrent_models_step_programs_compile_without_copying_a_pool(
     for kernel in ("ssm_scan", "moe_grouped_matmul", "quant_matmul_stacked"):
         assert kernel in text, kernel
     assert compiled.memory_analysis().temp_size_in_bytes < 96 * 2**20
+
+
+# -- a block-diffusion model's programs -------------------------------------
+# sdar-30b-a3b-chat's cell: 18 layers of 128 experts, 16 slots of 4-token
+# blocks, 768 pages.
+
+
+@pytest.mark.parametrize("lanes", [0, 3], ids=["blocks", "blocks+lanes"])
+def test_block_diffusion_program_compiles_without_copying_a_pool(
+    one_chip, monkeypatch, lanes
+):
+    """``jit_denoise_step`` of the 18-layer cut at the cell's sizes: the
+    four denoising forwards and the commit in one program, grouped, with
+    three chunk lanes on its first forward or none — the ragged kernel
+    under the block rule, the grouped expert matmul and the int8 stacks
+    all kernels, and no copy of the pool or a weight stack."""
+    import types
+    from functools import partial
+
+    from llm_consensus_tpu.engine.tokenizer import ByteTokenizer
+    from llm_consensus_tpu.models import transformer as T
+    from llm_consensus_tpu.models.configs import PRESETS
+    from llm_consensus_tpu.models.paged_cache import (
+        DecodeGroupArrays,
+        PagedKVCache,
+    )
+    from llm_consensus_tpu.serving.continuous import ContinuousBatcher
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = PRESETS["sdar-30b-a3b-chat"].with_layers(18).with_(use_pallas=True)
+    slots, gm = 16, 8
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: _shape(one_chip, a.shape, a.dtype), tree
+        )
+
+    params = described(jax.eval_shape(
+        lambda: T.init_params_quantized(cfg, jax.random.PRNGKey(0))
+    ))
+    cache = described(jax.eval_shape(
+        lambda: PagedKVCache.create(cfg, 768, PG, slots, P)
+    ))
+    i32 = lambda *s: _shape(one_chip, s, jnp.int32)  # noqa: E731
+    groups = DecodeGroupArrays(i32(slots), i32(gm), i32(gm), i32(slots))
+    batcher = types.SimpleNamespace(cfg=cfg, tokenizer=ByteTokenizer())
+    lane = (i32(lanes, C), i32(lanes, P), i32(lanes)) if lanes else ()
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            partial(ContinuousBatcher._denoise_sample, batcher),
+            donate_argnums=(1,),
+        ).lower(
+            params, cache, i32(slots, 4),
+            _shape(one_chip, (slots,), jnp.bool_), groups, *lane,
+        ).compile()
+    text = compiled.as_text()
+    for kernel in ("ragged_attention", "moe_grouped_matmul",
+                   "quant_matmul_stacked"):
+        assert kernel in text, kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < 160 * 2**20

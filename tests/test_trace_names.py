@@ -87,7 +87,7 @@ def _spy_on(batcher, seen: set) -> None:
     for attr in ("_jit_decode", "_jit_rounds", "_jit_spec", "_jit_apply_rows"):
         if hasattr(batcher, attr):
             setattr(batcher, attr, _Spy(getattr(batcher, attr), seen))
-    for getter in ("_chunk_fn", "_fused_fn", "_chunk_fn_d"):
+    for getter in ("_chunk_fn", "_fused_fn", "_chunk_fn_d", "_denoise_fn"):
         real, spies = getattr(batcher, getter), {}
 
         def spied(*key, _real=real, _spies=spies):
@@ -133,6 +133,23 @@ def test_step_programs_lower_to_named_modules(
     assert expected <= seen, seen
     # No step program is left to jax's default for a partial or a
     # bound method.
+    assert not {m for m in seen if "unknown" in m or "sample" in m}, seen
+
+
+def test_block_diffusion_programs_lower_to_named_modules():
+    """A block-diffusion model's blocks run as ``jit_denoise_step``,
+    with chunk lanes riding or not, beside the standalone chunks."""
+    cfg = get_config("test-tiny-sdar")
+    sdar = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    seen: set = set()
+    b = ContinuousBatcher(cfg, sdar, config=ContinuousConfig(**_CCFG))
+    try:
+        _spy_on(b, seen)
+        for f in [b.submit(p) for p in PROMPTS]:
+            f.result(timeout=120)
+    finally:
+        b.close()
+    assert {"jit_denoise_step", "jit_prefill_chunk", "jit_apply_rows"} <= seen
     assert not {m for m in seen if "unknown" in m or "sample" in m}, seen
 
 
